@@ -198,9 +198,9 @@ class Suppression:
 
 
 def load_suppressions(path: pathlib.Path) -> list[Suppression]:
-    import tomli
+    import tomllib
     with open(path, "rb") as fh:
-        data = tomli.load(fh)
+        data = tomllib.load(fh)
     out = []
     for i, entry in enumerate(data.get("suppression", [])):
         try:

@@ -274,7 +274,7 @@ def _ivf_top_n_kernel(Y, Q, y8p, sy_b, l1y_b, pen_i, activep, perm,
     Returned indices are original row indices; rows outside the probed
     cells are simply not candidates — that pruning is what the recall
     certificate measured at generation load."""
-    from .serving_model import _I8_PENALTY, _q_cast
+    from .serving_model import _I8_PENALTY, _q_cast, _score_precision
 
     B = Q.shape[0]
     W = int(y8p.shape[1])
@@ -338,7 +338,8 @@ def _ivf_top_n_kernel(Y, Q, y8p, sy_b, l1y_b, pen_i, activep, perm,
     ok = jnp.take(activep, rows_p)
     Yg = jnp.take(Y, orig, axis=0)                        # (B, R, W)
     scores = jnp.einsum("bf,brf->br", Qc, Yg,
-                        preferred_element_type=jnp.float32)
+                        preferred_element_type=jnp.float32,
+                        precision=_score_precision(Y))
     scores = jnp.where(ok, scores, -jnp.inf)
     ts, ti = jax.lax.top_k(scores, k)
     idx = jnp.take_along_axis(orig, ti, axis=1)

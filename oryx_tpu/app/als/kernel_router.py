@@ -192,10 +192,17 @@ def measure_routes(model, batch: int | None = None,
                         jax.device_get, m), 3)
                     sm._PALLAS_STATE[key] = "ok"
                 except Exception as e:  # noqa: BLE001 — backend-dep.
+                    # the CPU backend cannot lower pallas (routine: it
+                    # serves the scan build); on a TPU this is a defect
+                    # — same level policy as the serving dispatch
                     costs[kind] = None
-                    route.setdefault("errors", {})[
-                        f"{kind}{'/lsh' if lsh_on else ''}"] = \
-                        str(e)[:120]
+                    label = f"{kind}{'/lsh' if lsh_on else ''}"
+                    route.setdefault("errors", {})[label] = \
+                        sm.error_text(e)
+                    _log.log(sm.pallas_failure_level(),
+                             "phase-A build %s failed to measure for "
+                             "%d rows x %df: %s", label, n_rows,
+                             features, e)
             model._evict_unused_mirrors(None)
         if not twophase_ok:
             for lsh_on in variants:
@@ -282,8 +289,13 @@ def measure_routes(model, batch: int | None = None,
             jax.device_get(model._dispatch_kind(
                 route["chosen"], Q, vecs, active, version, buckets, hp,
                 k, bs, ksel, mb, fold, {}, chunk=chunk))
-        except Exception:  # noqa: BLE001 — warm-up only, never fatal
-            pass
+        except Exception as e:  # noqa: BLE001 — never a load gate,
+            # but the build that just measured fastest failing to run
+            # again must not pass silently
+            route.setdefault("errors", {})[
+                f"rebuild/{route['chosen']}"] = sm.error_text(e)
+            _log.exception("re-materializing the routed %s mirror "
+                           "failed", route["chosen"])
     _log.info(
         "kernel route for %d rows x %df (%s): chosen=%s use_lsh=%s "
         "exact=%s lsh=%s", n_rows, features, route["path"],

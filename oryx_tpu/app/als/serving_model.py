@@ -101,9 +101,23 @@ def _q_cast(Q, Y):
     return Q.astype(Y.dtype) if Y.dtype == jnp.bfloat16 else Q
 
 
+def _score_precision(Y):
+    """Matmul precision for kernels whose products become SERVED scores.
+    On a TPU the MXU's default precision rounds float32 operands to
+    bfloat16 (one pass, relative error ~2^-8 per product — measured
+    1.4e-3 on /recommend scores at 50f/1M), which is a lower precision
+    than a float32 store states; float32 stores therefore score at
+    HIGHEST.  bfloat16 stores multiply exactly in one pass with float32
+    accumulation either way, and the CPU backend ignores the setting.
+    Phase A of the two-phase scan only SELECTS blocks (phase B rescores
+    what is served), so it stays on the fast default pass."""
+    return jax.lax.Precision.HIGHEST if Y.dtype == jnp.float32 else None
+
+
 @jax.jit
 def _dot_scores(Y, x):
-    return jnp.matmul(Y, _q_cast(x, Y), preferred_element_type=jnp.float32)
+    return jnp.matmul(Y, _q_cast(x, Y), preferred_element_type=jnp.float32,
+                      precision=_score_precision(Y))
 
 
 @jax.jit
@@ -114,11 +128,13 @@ def _cosine_mean_scores(Y, V):
         V = jnp.pad(V, [(0, Y.shape[1] - V.shape[0]), (0, 0)])
     # bf16-stored factors: norms must accumulate in f32 like the dot
     # kernels do, or 250-term squared sums lose ~1% per item norm
+    precision = _score_precision(Y)  # the STORE's dtype decides
     Y = Y.astype(jnp.float32)
     y_norm = jnp.linalg.norm(Y, axis=1, keepdims=True)
     v_norm = jnp.linalg.norm(V, axis=0, keepdims=True)
     denom = jnp.maximum(y_norm * v_norm, 1e-12)
-    return jnp.mean(jnp.matmul(Y, V, preferred_element_type=jnp.float32)
+    return jnp.mean(jnp.matmul(Y, V, preferred_element_type=jnp.float32,
+                               precision=precision)
                     / denom, axis=1)
 
 
@@ -148,7 +164,8 @@ def _batch_top_n_kernel(Y, Q, active, k: int):
     kernel (SURVEY §2.14 P6: Tomcat's 400-thread fan-out becomes one
     MXU matmul over the batched queries)."""
     scores = jnp.matmul(_q_cast(Q, Y), Y.T,
-                        preferred_element_type=jnp.float32)
+                        preferred_element_type=jnp.float32,
+                        precision=_score_precision(Y))
     scores = jnp.where(active[None, :], scores, -jnp.inf)
     return jax.lax.top_k(scores, k)
 
@@ -163,7 +180,8 @@ def _batch_top_n_lsh_kernel(Y, Q, active, buckets, hyperplanes,
     ALSServingModel.java:265-280)."""
     target = _query_buckets(Q, hyperplanes)
     scores = jnp.matmul(_q_cast(Q, Y), Y.T,
-                        preferred_element_type=jnp.float32)
+                        preferred_element_type=jnp.float32,
+                        precision=_score_precision(Y))
     ok = _lsh_ok(active[None, :], buckets[None, :], target[:, None],
                  max_bits)
     return jax.lax.top_k(jnp.where(ok, scores, -jnp.inf), k)
@@ -221,7 +239,8 @@ def _phase_b(Y, Qc, active, buckets, target, M, k: int, bs: int,
     Yg = jnp.take(Y.reshape(-1, bs, Y.shape[1]), bi,
                   axis=0)                              # (B, ksel, bs, F)
     scores = jnp.einsum("bf,bkcf->bkc", Qc, Yg,
-                        preferred_element_type=jnp.float32
+                        preferred_element_type=jnp.float32,
+                        precision=_score_precision(Y)
                         ).reshape(b, ksel * bs)
     ok = jnp.take(active.reshape(-1, bs), bi, axis=0).reshape(b, ksel * bs)
     if target is not None:
@@ -258,11 +277,16 @@ def _phase_b(Y, Qc, active, buckets, target, M, k: int, bs: int,
 # ~860 GB/s); LSH variant pays the per-(item,query) popcount on the
 # VPU.  Tile 4096 fits VMEM with double-buffering at F=250 bf16.
 _PA_TILE = 4096
-# runtime-fallback state for the pallas build, PER SHAPE: pallas is
-# unsupported on some backends (plain CPU tests) and a compile failure
-# for one (rows, features, batch, lsh) signature must not disable the
-# kernel for other models/shapes in the same process
+# runtime-fallback state for the pallas build, PER SHAPE: pallas cannot
+# lower on the CPU backend (tier-1 serves the lax.scan build there), and
+# a compile failure for one (rows, features, batch, lsh) signature must
+# not disable the kernel for other models/shapes in the same process
 _PALLAS_STATE: dict = {}  # shape key -> "ok" | "broken" | fail count
+# why each non-"ok" shape failed (last error text): merged into the
+# model's /metrics kernel_route.errors, so a failure that surfaced at
+# dispatch time — a ladder window the route measurement never timed —
+# is as visible as one the measurement itself hit
+_PALLAS_ERRORS: dict = {}
 # transient (non-lowering) failures tolerated on a shape before it is
 # retired to the lax.scan build for the life of the process
 _PALLAS_MAX_TRANSIENT = 3
@@ -281,6 +305,24 @@ def _pallas_error_is_fatal(e: Exception) -> bool:
         m.lower() in text for m in _PALLAS_FATAL_MARKERS)
 
 
+def error_text(e: Exception, limit: int = 400) -> str:
+    """One bounded line for an ``errors`` table on /metrics or in a
+    report: the exception's type and message."""
+    return f"{type(e).__name__}: {e}"[:limit]
+
+
+def pallas_failure_level() -> int:
+    """Log level for a phase-A build that failed to compile or run.
+    The lax.scan build is the only phase A the CPU backend has, so
+    there the substitution is routine (WARNING).  On a TPU every build
+    is expected to lower: a failure is a defect in the program that
+    the substitution would otherwise hide behind a green run (ERROR —
+    and fatal to ``warmup`` and ``chip_smoke.py``).  Decided from the
+    backend this process observes; there is no key."""
+    return logging.ERROR if jax.default_backend() == "tpu" \
+        else logging.WARNING
+
+
 def _classify_pallas_failure(keys: list, e: Exception) -> None:
     """Record a pallas dispatch/fetch failure against the given shape
     keys: fatal (lowering/unsupported) retires them to the scan build;
@@ -290,13 +332,15 @@ def _classify_pallas_failure(keys: list, e: Exception) -> None:
     fresh = [k for k in keys if _PALLAS_STATE.get(k) != "ok"]
     if not fresh:
         raise e
+    for k in fresh:
+        _PALLAS_ERRORS[k] = error_text(e)
     if _pallas_error_is_fatal(e):
         for k in fresh:
             _PALLAS_STATE[k] = "broken"
-        _log.warning(
-            "pallas two-phase kernel unavailable for shape(s) %s "
-            "(serving falls back to the lax.scan build, ~4x slower at "
-            "20M items): %s", fresh, e)
+        _log.log(
+            pallas_failure_level(),
+            "pallas two-phase kernel unavailable for shape(s) %s; "
+            "serving substitutes the lax.scan build: %s", fresh, e)
     else:
         # e.g. a device OOM from a concurrent dispatch: leave the
         # kernel eligible for the next drain
@@ -304,7 +348,8 @@ def _classify_pallas_failure(keys: list, e: Exception) -> None:
             fails = _PALLAS_STATE.get(k, 0) + 1
             _PALLAS_STATE[k] = ("broken" if fails >= _PALLAS_MAX_TRANSIENT
                                 else fails)
-        _log.warning(
+        _log.log(
+            pallas_failure_level(),
             "pallas two-phase dispatch failed transiently for "
             "shape(s) %s (3 strikes retires a shape): %s", fresh, e)
 
@@ -584,7 +629,8 @@ def _batch_top_n_chunked_kernel(Y, Q, active, buckets, hyperplanes,
         best_s, best_i = carry
         Yc, Ac, base = x[:3]
         scores = jnp.matmul(Qc, Yc.T,
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=_score_precision(Y))
         ok = Ac[None, :]
         if target is not None:
             ok = _lsh_ok(ok, x[3][None, :], target[:, None], max_bits)
@@ -1051,6 +1097,10 @@ class ALSServingModel(FactorModelBase, ServingModel):
     def metrics(self) -> dict:
         """App-level gauges merged into /metrics (framework hook)."""
         out = {
+            # where this model's kernels actually run, as JAX reports
+            # it: JAX drops to the CPU with only a warning when it
+            # finds no chip, and nothing else on /metrics would say so
+            "backend": jax.default_backend(),
             "users": len(self.X),
             "items": len(self.Y),
             # exact-scan recomputes forced by a failed streaming top-k
@@ -1062,6 +1112,15 @@ class ALSServingModel(FactorModelBase, ServingModel):
         # operator-visible answer to "why is LSH off / which build ran"
         r = self._route
         if r is not None:
+            # plus every build that failed at DISPATCH for this shape
+            # (a ladder window the measurement never timed): one
+            # `errors` table answers "did any kernel fail to lower"
+            late = {f"{key[-1]}{'/lsh' if key[4] else ''} B={key[2]}": err
+                    for key, err in list(_PALLAS_ERRORS.items())
+                    if key[0] == r.get("capacity")
+                    and _PALLAS_STATE.get(key) != "ok"}
+            if late:
+                r = dict(r, errors={**late, **r.get("errors", {})})
             out["kernel_route"] = r
         return out
 
@@ -1512,12 +1571,12 @@ class ALSServingModel(FactorModelBase, ServingModel):
                            hp, k: int, chunk: int, bs: int, ksel: int,
                            mb: int) -> list:
         """Dispatch every window's two-phase program (async) and fetch
-        once.  Prefers the pallas phase-A build (scores never leave
-        VMEM; measured ~3x faster end-to-end on the 20M cells); falls
-        back to the lax.scan build per WINDOW SHAPE on backends where
-        pallas cannot lower (plain CPU) or on a compile failure — a
-        drain may mix full windows and one small tail window, and each
-        shape stands or falls alone."""
+        once.  Prefers the pallas phase-A builds (scores never leave
+        VMEM); substitutes the lax.scan build per WINDOW SHAPE where a
+        build cannot lower — routine on the CPU backend, a logged ERROR
+        that lands in ``kernel_route.errors`` on a TPU
+        (``pallas_failure_level``).  A drain may mix full windows and
+        one small tail window, and each shape stands or falls alone."""
         n_rows = int(vecs.shape[0])
         static_kinds, fold = self._phase_a_kinds(n_rows,
                                                  int(vecs.shape[1]), bs)
@@ -1727,19 +1786,25 @@ class ALSServingModel(FactorModelBase, ServingModel):
                 return r
             try:
                 route = measure_routes(self, batch=batch, m=m)
-            except Exception:  # noqa: BLE001 — measurement is advisory
-                # routing is an optimization, never a load gate: a
-                # failure here (device OOM building a mirror, transient
-                # transport error) must NOT abort the MODEL consume —
-                # an escaped exception would trap the update consumer
-                # in replay-from-0 against the same deterministic
-                # failure.  Serving continues on the static
-                # config-driven chain; the stale/absent route is
-                # ignored by _route_current.
+            except Exception as e:  # noqa: BLE001 — never a load gate
+                # a failure here (device OOM building a mirror) must
+                # NOT abort the MODEL consume — an escaped exception
+                # would trap the update consumer in replay-from-0
+                # against the same deterministic failure.  Serving
+                # continues on the static config-driven chain, and the
+                # failure is published where the route would have
+                # been: an unmeasured stub (capacity -1, so
+                # _route_current ignores it and the next load
+                # re-measures) whose `errors` an operator — and
+                # chip_smoke.py — can see.
                 _log.exception(
                     "kernel route measurement failed; serving keeps "
                     "the static config-driven kernel order")
-                return self._route
+                self._route = {
+                    "measured": False,
+                    "errors": {"measure_routes": error_text(e)}}
+                self._route_capacity = -1
+                return None
             self._route = route
             self._route_capacity = n_rows
             self._evict_unused_mirrors(

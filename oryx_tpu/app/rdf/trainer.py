@@ -39,10 +39,7 @@ from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
-try:  # moved out of experimental in JAX 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older JAX
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 import numpy as np
 

@@ -259,8 +259,7 @@ def _write_conf(path: str, broker_dir: str, port: int,
 
 def _spawn(args: list[str], conf: str, threads: int | None,
            log_path: str) -> subprocess.Popen:
-    env = dict(os.environ, JAX_PLATFORMS=os.environ.get(
-        "JAX_PLATFORMS", "cpu"))
+    env = dict(os.environ)  # children inherit the platform as given
     if threads:
         # one compute thread per replica: fixed per-replica hardware
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
@@ -849,9 +848,11 @@ def run_cell(replicas: int, items: int, features: int, users: int,
         # the whole stream
         per_replica_load = []
         for p in replica_ports:
-            g = _get_json(p, "/metrics").get("freshness", {})
+            m = _get_json(p, "/metrics")
+            g = m.get("freshness", {})
             per_replica_load.append({
                 "port": p,
+                "backend": m.get("model_metrics", {}).get("backend"),
                 "model_load_s": g.get("model_load_s"),
                 "model_slice_bytes": g.get("model_slice_bytes"),
                 "slice_load_fallbacks": g.get("slice_load_fallbacks"),
@@ -2042,8 +2043,12 @@ def main(argv: list[str] | None = None) -> int:
         "zipf_a": args.zipf or None,
         "tracing_sample": args.tracing_sample,
         "emulated_device_ms_per_mrow": args.device_ms_per_mrow,
-        "backend": "cpu" if os.environ.get(
-            "JAX_PLATFORMS", "cpu") == "cpu" else "tpu",
+        # jax.default_backend() of the processes that ran the cells,
+        # as each replica reports it on /metrics
+        "backend": "+".join(sorted({
+            str(r.get("backend")) for row in rows
+            for r in (row.get("model_load") or {}).get(
+                "per_replica", [])})) or None,
         "host_cpus": os.cpu_count(),
         "rows": rows,
         "scaling_vs_1": {

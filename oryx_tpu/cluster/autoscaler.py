@@ -251,11 +251,12 @@ class ProcessReplicaLauncher(ReplicaLauncher):
         argv = [self._python, "-m", "oryx_tpu", "serving",
                 "--shard", f"{shard}/{of}", "--conf", conf]
         log_path = os.path.join(self._work_dir, f"{member_id}.log")
-        env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
-
+        # members inherit this process's environment as given: which
+        # device a replica computes on is the operator's placement
+        # (one process per chip — README "Sharing a host"), never a
+        # default applied here
         supervisor = Supervisor.from_config(
-            lambda: _MemberProcess(argv, log_path, env),
+            lambda: _MemberProcess(argv, log_path, dict(os.environ)),
             f"autoscale-member[{member_id}]", self._config)
         thread = threading.Thread(target=self._run_supervised,
                                   args=(supervisor, member_id),

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import argparse
 import logging
+import os
 import sys
 
 from ..common.config import Config, from_file, get_default
@@ -32,6 +33,43 @@ _log = logging.getLogger(__name__)
 
 def _load_config(conf: str | None) -> Config:
     return from_file(conf) if conf else get_default()
+
+
+def _claim_device(role: str, config: Config) -> None:
+    """Touch the accelerator ONCE, on the main thread, before any
+    supervisor or worker thread exists, and say what it is.
+
+    A chip belongs to one process at a time.  A second process on the
+    same chip fails inside the runtime's start-up — and without this
+    call that failure would first surface inside a layer's worker
+    thread, where the survival handlers log it and retry for ever
+    (a serving layer that answers 503 until someone reads its log).
+    Here it is one named error and exit code 3, before anything is
+    supervised.  How layers share a host: README "Sharing a host"."""
+    import jax
+
+    from ..parallel.mesh import initialize_multihost
+    try:
+        # a configured multi-host join must precede the first
+        # jax.devices() call (no-op when oryx.distributed.* is unset)
+        initialize_multihost(config)
+        devs = jax.devices()
+    except RuntimeError as e:  # backend initialization failed
+        print(f"oryx_tpu {role}: cannot initialize the JAX backend — if "
+              "another process holds the chip, run one process per chip "
+              "(pin with TPU_VISIBLE_CHIPS) or all layers in one process "
+              f"(README \"Sharing a host\").\n  {e}", file=sys.stderr)
+        raise SystemExit(3) from None
+    _log.info("%s computes on %d x %s (%s backend)", role, len(devs),
+              devs[0].device_kind, jax.default_backend())
+    if jax.default_backend() == "cpu" and not os.environ.get(
+            "JAX_PLATFORMS"):
+        # with no platform named, JAX drops to the CPU when the chip's
+        # runtime cannot start (e.g. another process holds it) and says
+        # so only in a warning about a missing libtpu
+        _log.warning("%s: JAX chose the CPU backend on its own; if a "
+                     "chip was expected, set JAX_PLATFORMS=tpu so a "
+                     "chip that cannot be claimed is an error", role)
 
 
 def _run_layer(make_layer, name: str, config: Config) -> None:
@@ -68,6 +106,7 @@ def _run_layer(make_layer, name: str, config: Config) -> None:
 def _cmd_batch(args) -> int:
     from ..lambda_rt.batch import BatchLayer
     config = _load_config(args.conf)
+    _claim_device("batch", config)
     _run_layer(lambda: BatchLayer(config), "batch", config)
     return 0
 
@@ -75,6 +114,7 @@ def _cmd_batch(args) -> int:
 def _cmd_speed(args) -> int:
     from ..lambda_rt.speed import SpeedLayer
     config = _load_config(args.conf)
+    _claim_device("speed", config)
     if getattr(args, "shard", None):
         # sharded fold-in worker: consume the full input topic, fold
         # only the murmur2 item slices this worker owns, publish into
@@ -91,6 +131,7 @@ def _cmd_speed(args) -> int:
 def _cmd_serving(args) -> int:
     from ..lambda_rt.serving import ServingLayer
     config = _load_config(args.conf)
+    _claim_device("serving", config)
     if getattr(args, "shard", None):
         # replica mode of the sharded serving cluster: materialize one
         # catalog slice, expose /shard/* scatter targets, heartbeat on
@@ -236,6 +277,7 @@ def _cmd_warmup(args) -> int:
 
     from .warmup import run_warmup
     config = _load_config(args.conf)
+    _claim_device("warmup", config)
     items_list = [round(float(x) * 1e6) if "." in x or float(x) < 1000
                   else int(x) for x in args.items.split(",") if x]
     # default dtype ladder = the DEPLOYMENT'S factor dtype: warming a
@@ -253,7 +295,7 @@ def _cmd_warmup(args) -> int:
         train_rank=args.train_rank)
     print(json.dumps(report if args.verbose else {
         k: v for k, v in report.items() if k not in ("compiled",)}))
-    return 1 if report["compiled_count"] == 0 else 0
+    return 0 if report["ok"] else 1
 
 
 def _cmd_config_to_properties(args) -> int:

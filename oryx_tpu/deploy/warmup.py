@@ -27,9 +27,11 @@ on.  Install time is when an operator expects to pay one-time costs, so
   install-time iteration seeds the per-epoch programs a first real
   generation would otherwise compile.
 
-Backends where a pallas build cannot lower (plain CPU) record the
-failure and continue — exactly mirroring the serving dispatch's own
-fallback chain, so what warms is what serves.
+The CPU backend cannot lower a pallas build: it records the failure and
+continues — exactly mirroring the serving dispatch's own fallback
+chain, so what warms is what serves.  On a TPU backend every build is
+expected to lower, and any entry in ``failed`` makes the report not
+``ok`` (the CLI exits non-zero).
 """
 
 from __future__ import annotations
@@ -53,15 +55,23 @@ def _aval(shape, dtype):
 def _compile(report: dict, name: str, fn, *args, **static) -> None:
     """Lower+compile one jitted function from avals, recording outcome.
     Compilation lands in the persistent cache (keyed by HLO
-    fingerprint); failures are per-variant, never fatal — a backend
-    that cannot lower a pallas build still warms the scan build."""
+    fingerprint).  A failure is recorded per variant and the ladder
+    carries on, so ONE run lists every build the backend refuses; what
+    a non-empty ``failed`` means is the caller's call (``run_warmup``'s
+    ``ok``): routine on the CPU backend, which cannot lower pallas, a
+    defect on a TPU."""
+    from ..app.als.serving_model import error_text, pallas_failure_level
+
     t0 = time.perf_counter()
     try:
         fn.lower(*args, **static).compile()
         report["compiled"].append(
             {"kernel": name, "sec": round(time.perf_counter() - t0, 2)})
     except Exception as e:  # noqa: BLE001 — backend-dependent builds
-        report["failed"].append({"kernel": name, "error": str(e)[:140]})
+        report["failed"].append(
+            {"kernel": name, "error": error_text(e, 2000)})
+        _log.log(pallas_failure_level(), "warmup: %s failed to compile: "
+                 "%s", name, e)
 
 
 def warm_serving_shapes(features: int, items: int, dtype: str,
@@ -324,4 +334,8 @@ def run_warmup(config, items_list: list[int], features_list: list[int],
     report["compiled_count"] = len(report["compiled"])
     report["failed_count"] = len(report["failed"])
     report["wall_s"] = round(time.perf_counter() - t0, 2)
+    # on a TPU nothing may fall back: a build that fails to lower there
+    # is the defect this command exists to find before traffic does
+    report["ok"] = report["compiled_count"] > 0 and not (
+        report["failed"] and report["backend"] == "tpu")
     return report

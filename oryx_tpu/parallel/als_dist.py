@@ -50,16 +50,14 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-try:  # moved out of experimental in JAX 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older JAX
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..app.als.common import ParsedRatings
-from ..app.als.trainer import ALSModel, _solve_batch
+from ..app.als.trainer import (_BATCH_SLOT_BUDGET, _MAX_B, ALSModel,
+                                _solve_batch)
 from ..common.rand import RandomManager
 
 __all__ = ["BlockedRatings", "block_ratings", "block_ratings_ring",
@@ -196,13 +194,25 @@ def make_train_step(mesh: Mesh, lam: float, alpha: float, implicit: bool,
         g_local = jnp.matmul(opposite_local.T, opposite_local,
                              preferred_element_type=jnp.float32)
         G = jax.lax.psum(g_local, axis)
-        Yg = full[cols]  # (rows_local, P, k)
-        x = _solve_batch(Yg, vals, mask, G,
-                         jnp.float32(lam), jnp.float32(alpha), implicit)
-        # padding rows (no interactions) can produce a singular system;
-        # pin them to zero so they never poison the next Gramian/gather
-        n = jnp.sum(mask, axis=1)
-        return jnp.where((n > 0.0)[:, None], x, 0.0)
+        lam32, alpha32 = jnp.float32(lam), jnp.float32(alpha)
+
+        def solve_row(row):
+            # (_solve_batch pins rows without interactions — the mesh
+            # padding — to zero, so they never poison the next
+            # Gramian/gather)
+            c, v, m = row
+            return _solve_batch(full[c][None], v[None], m[None], G,
+                                lam32, alpha32, implicit)[0]
+
+        # the device's rows in slot-budgeted chunks, like the
+        # single-device trainer's batches: one batched solve over ALL
+        # local rows holds (rows, k, k) systems plus the LU's copies at
+        # once — 15 GB of temporaries per device for 250k rows at k=50
+        # (1M items over 4 chips; XLA:TPU memory analysis), i.e. an OOM
+        # exactly where a mesh is needed
+        chunk = max(1, min(_MAX_B, _BATCH_SLOT_BUDGET // cols.shape[1]))
+        return jax.lax.map(solve_row, (cols, vals, mask),
+                           batch_size=chunk)
 
     def _half_ring(opposite_local, cols_b, vals_b, mask_b):
         """One ring half-sweep: the opposite factor's blocks rotate via

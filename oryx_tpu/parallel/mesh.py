@@ -46,14 +46,7 @@ def initialize_multihost(config=None) -> bool:
             pid = config.get_int("oryx.distributed.process-id")
     if coord is None and num is None and pid is None:
         return False
-    # already joined — idempotent (the introspection surface moved
-    # across JAX versions: is_initialized() on newer, global_state
-    # earlier; tolerate both)
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None and is_init():
-        return True
-    state = getattr(jax.distributed, "global_state", None)
-    if state is not None and getattr(state, "client", None) is not None:
+    if jax.distributed.is_initialized():  # already joined — idempotent
         return True
     # a genuine join failure (unreachable coordinator, bad params) must
     # propagate: silently training single-host when multi-host was

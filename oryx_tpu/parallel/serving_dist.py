@@ -24,28 +24,13 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:  # moved out of experimental in JAX 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older JAX
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..app.als.feature_vectors import resolve_dtype
-from ..app.als.serving_model import _pad_k, _q_cast
+from ..app.als.serving_model import _pad_k, _q_cast, _score_precision
 
 __all__ = ["ShardedItemScorer"]
-
-
-def _shardmap_norepcheck_kwargs() -> dict:
-    """The all_gather-merged outputs ARE replicated, but shard_map's
-    static replication checker cannot infer that; the disabling kwarg
-    was renamed across JAX versions (check_rep -> check_vma)."""
-    import inspect
-    params = inspect.signature(shard_map).parameters
-    for name in ("check_vma", "check_rep"):
-        if name in params:
-            return {name: False}
-    return {}
 
 
 def _make_kernel(mesh: Mesh, k_shard: int, k_final: int, axis: str):
@@ -56,13 +41,16 @@ def _make_kernel(mesh: Mesh, k_shard: int, k_final: int, axis: str):
     @partial(shard_map, mesh=mesh,
              in_specs=(P(axis, None), P(axis), P(None, None)),
              out_specs=(P(None, None), P(None, None)),
-             **_shardmap_norepcheck_kwargs())
+             # the all_gather-merged outputs ARE replicated, but the
+             # static replication checker cannot infer that
+             check_vma=False)
     def scorer(Y_local, active_local, Q):
         n_local = Y_local.shape[0]
         # bf16 stores: keep the scan on the native bf16 MXU path
         # (serving_model._q_cast rationale)
         scores = jnp.matmul(_q_cast(Q, Y_local), Y_local.T,
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=_score_precision(Y_local))
         scores = jnp.where(active_local[None, :], scores, -jnp.inf)
         ls, li = jax.lax.top_k(scores, k_shard)        # (B, ks) local
         gi = li + jax.lax.axis_index(axis) * n_local   # global row ids
@@ -143,6 +131,11 @@ class ShardedItemScorer:
     def memory_bytes_per_device(self) -> int:
         return (self._Y.nbytes + self._active.nbytes) \
             // self.mesh.devices.size
+
+    @property
+    def items(self) -> jax.Array:
+        """The row-sharded item matrix as placed on the mesh."""
+        return self._Y
 
     def top_n_batch(self, how_many: int,
                     queries: np.ndarray) -> list[list[tuple[str, float]]]:

@@ -189,11 +189,13 @@ def test_warmup_cli_reports_compiles(tmp_path):
          str(conf), "--items", "0.002", "--features", "8",
          "--dtypes", "float32"],
         capture_output=True, text=True,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+        # no exported cache placement: the conf's directory decides
+        env={k: v for k, v in dict(os.environ, JAX_PLATFORMS="cpu").items()
+             if k != "JAX_COMPILATION_CACHE_DIR"})
     assert out.returncode == 0, out.stderr
     report = json.loads(out.stdout.strip().splitlines()[-1])
     assert report["metric"] == "aot_warmup"
-    assert report["compiled_count"] > 0
+    assert report["compiled_count"] > 0 and report["ok"]
     assert report["cache_dir"] == str(tmp_path / "cache")
 
 

@@ -256,19 +256,30 @@ def test_choose_free_port():
     assert 0 < p < 65536
 
 
+def _fresh_compile_cache(monkeypatch):
+    """Un-latch the process-wide cache state; returns jax's current
+    cache dir for the caller to restore."""
+    import jax
+    from jax._src import compilation_cache as _cc
+
+    from oryx_tpu.common import compile_cache
+
+    monkeypatch.setattr(compile_cache, "_enabled_dir", None)
+    # JAX memoizes the cache instance at first use; earlier tests that
+    # started layers may have initialized it at another path
+    _cc.reset_cache()
+    return jax.config.jax_compilation_cache_dir
+
+
 def test_compile_cache_enable_from_config(tmp_path, monkeypatch):
     import jax
+    from jax._src import compilation_cache as _cc
 
     from oryx_tpu.common import compile_cache
     from oryx_tpu.common.config import from_dict
 
-    prev = jax.config.jax_compilation_cache_dir
-    monkeypatch.setattr(compile_cache, "_enabled_dir", None)
-    # JAX memoizes the cache instance at first use; earlier tests that
-    # started layers may have initialized it at the default path
-    from jax._src import compilation_cache as _cc
-
-    _cc.reset_cache()
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    prev = _fresh_compile_cache(monkeypatch)
     try:
         cc = str(tmp_path / "cc")
         cfg = from_dict({"oryx.compile-cache-dir": cc,
@@ -288,10 +299,117 @@ def test_compile_cache_enable_from_config(tmp_path, monkeypatch):
         _cc.reset_cache()
 
 
+def test_compile_cache_env_placement_outranks_config(tmp_path, monkeypatch):
+    """An exported JAX_COMPILATION_CACHE_DIR is the operator's (or the
+    chip harness's) placement: the program sets NO directory in code."""
+    import jax
+    from jax._src import compilation_cache as _cc
+
+    from oryx_tpu.common import compile_cache
+    from oryx_tpu.common.config import from_dict
+
+    placed = str(tmp_path / "placed-from-outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    prev = _fresh_compile_cache(monkeypatch)
+    try:
+        cfg = from_dict({"oryx.compile-cache-dir": str(tmp_path / "cfg")})
+        assert compile_cache.enable_from_config(cfg) == placed
+        # untouched: whatever jax itself read from the environment at
+        # import stays; the config key's path was never written to it
+        assert jax.config.jax_compilation_cache_dir == prev
+        assert not (tmp_path / "cfg").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+        _cc.reset_cache()
+
+
+def test_compile_cache_default_is_fixed_in_checkout(monkeypatch):
+    """Unset, the default resolves to ONE fixed git-ignored directory
+    inside the checkout — from the package's own location, never the
+    working directory, a temp name, a pid or a timestamp."""
+    import pathlib
+
+    import jax
+    from jax._src import compilation_cache as _cc
+
+    from oryx_tpu.common import compile_cache
+    from oryx_tpu.common.config import get_default
+
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.chdir("/")
+    prev = _fresh_compile_cache(monkeypatch)
+    try:
+        want = str(repo / ".jax_cache")
+        assert compile_cache.enable_from_config(get_default()) == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+        _cc.reset_cache()
+
+
 def test_compile_cache_disabled_when_null(monkeypatch):
     from oryx_tpu.common import compile_cache
     from oryx_tpu.common.config import from_dict
 
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.setattr(compile_cache, "_enabled_dir", None)
     cfg = from_dict({"oryx.compile-cache-dir": None})
     assert compile_cache.enable_from_config(cfg) is None
+
+
+def test_compile_cache_key_of_a_pallas_program_ignores_the_call_path(
+        tmp_path, monkeypatch):
+    """jax hashes a pallas_call's Mosaic payload into the persistent
+    cache key as opaque bytes, call-stack locations included, so the AOT
+    warmup and the live dispatch — two call paths to one kernel — would
+    never share an entry.  enable_from_config drops the call stack from
+    locations; the TPU lowering is then identical from any caller."""
+    import jax
+    import jax.numpy as jnp
+    from jax._src import compilation_cache as _cc
+    from jax.experimental import pallas as pl
+
+    from oryx_tpu.common import compile_cache
+    from oryx_tpu.common.config import from_dict
+
+    def lowered_from_two_call_paths():
+        # a FRESH jit each time: a cached trace would hide the flag
+        @jax.jit
+        def double(x):
+            def kern(x_ref, o_ref):
+                o_ref[...] = x_ref[...] * 2.0
+            return pl.pallas_call(
+                kern, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype))(x)
+
+        x = jax.ShapeDtypeStruct((8, 128), jnp.float32)
+
+        def path_a():
+            return double.trace(x).lower(
+                lowering_platforms=("tpu",)).as_text()
+
+        def path_b():
+            return (lambda: double.trace(x).lower(
+                lowering_platforms=("tpu",)).as_text())()
+
+        a = path_a()
+        double.clear_cache()
+        return a, path_b()
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    prev_dir = _fresh_compile_cache(monkeypatch)
+    prev_tb = jax.config.jax_include_full_tracebacks_in_locations
+    try:
+        jax.config.update("jax_include_full_tracebacks_in_locations", True)
+        a, b = lowered_from_two_call_paths()
+        assert a != b  # the hazard, on this jax
+        compile_cache.enable_from_config(from_dict(
+            {"oryx.compile-cache-dir": str(tmp_path / "cc")}))
+        a, b = lowered_from_two_call_paths()
+        assert a == b
+    finally:
+        jax.config.update("jax_include_full_tracebacks_in_locations",
+                          prev_tb)
+        jax.config.update("jax_compilation_cache_dir", prev_dir)
+        _cc.reset_cache()

@@ -24,7 +24,7 @@ import os
 import time
 
 import pytest
-import tomli
+import tomllib
 
 from oryx_tpu.sim import SimFailure, run_scenario
 
@@ -45,7 +45,7 @@ _REPLAY_SAMPLE = (0, 67, 133, 199)
 
 def _corpus() -> list[dict]:
     with open(_FIXTURE, "rb") as fh:
-        return tomli.load(fh)["seed"]
+        return tomllib.load(fh)["seed"]
 
 
 def _corpus_ids() -> list[str]:
