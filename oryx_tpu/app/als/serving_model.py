@@ -36,6 +36,7 @@ import numpy as np
 
 from ...api.serving import ServingModel
 from ...common.lang import AutoReadWriteLock
+from ...obs import trace as obstrace
 from .factor_model import FactorModelBase, SolverCache  # noqa: F401 (re-export)
 from .lsh import LocalitySensitiveHash, _popcount
 from .rescorer import Rescorer
@@ -1492,6 +1493,13 @@ class ALSServingModel(FactorModelBase, ServingModel):
             else [set()] * n_req
         if self._item_shards > 1:
             return self._sharded_top_n_batch(hm, Q, excl, use_lsh)
+        # the phases of this drain — prepare, scan, a fallback per
+        # window whose certificate failed, decode — for the batcher's
+        # recorder (obs/trace.py); None, and one branch a site, for
+        # every caller that opened none
+        rec = obstrace.current_drain()
+        if rec is not None:
+            rec.mark("serving.prepare", rows=n_req)
         vecs, active, version = self.Y.device_arrays_versioned()
         n_rows = int(vecs.shape[0])
         k = min(_pad_k(max(h + len(e) for h, e in zip(hm, excl))), n_rows)
@@ -1522,6 +1530,11 @@ class ALSServingModel(FactorModelBase, ServingModel):
             for size in sizes:
                 windows.append(jnp.asarray(Q[w:w + size]))
                 w += size
+            if rec is not None:
+                # from the first program enqueued to the last result
+                # fetched: it waits on the device, and on whatever
+                # other drain the device is running
+                rec.mark("serving.scan", k=k, windows=sizes)
             if n_rows % bs == 0 and 1 <= ksel < n_rows // bs \
                     and k <= ksel * bs:
                 fetched = self._dispatch_twophase(
@@ -1533,8 +1546,13 @@ class ALSServingModel(FactorModelBase, ServingModel):
                         # some row; recompute on the exact scan.  Count
                         # per certificate-failing row, under the lock —
                         # batcher dispatcher threads race on this gauge.
+                        rows_failed = int((~cert).sum())
                         with self._bucket_lock:
-                            self.twophase_fallbacks += int((~cert).sum())
+                            self.twophase_fallbacks += rows_failed
+                        if rec is not None:
+                            rec.mark("serving.fallback", k=k,
+                                     width=sizes[w],
+                                     rows_failed=rows_failed)
                         ts, ti = jax.device_get(
                             _batch_top_n_chunked_kernel(
                                 vecs, windows[w], active, buckets, hp,
@@ -1552,6 +1570,8 @@ class ALSServingModel(FactorModelBase, ServingModel):
                 Q = np.concatenate(
                     [Q, np.zeros((b_pad - n_req, Q.shape[1]), np.float32)])
             Qd = jnp.asarray(Q)
+            if rec is not None:
+                rec.mark("serving.scan", k=k, windows=[b_pad])
             if lsh_on:
                 out_dev = _batch_top_n_lsh_kernel(
                     vecs, Qd, active, buckets,
@@ -1562,6 +1582,8 @@ class ALSServingModel(FactorModelBase, ServingModel):
             # fetch both outputs in ONE host round-trip (matters when the
             # device sits behind a high-latency transport)
             top_scores, top_idx = jax.device_get(out_dev)
+        if rec is not None:
+            rec.mark("serving.decode", rows=n_req)
         return self._decode_top_n(top_scores, top_idx, hm, excl, n_req,
                                   k < n_rows, np.asarray(user_vectors,
                                                          np.float32),

@@ -19,6 +19,15 @@ Context crosses process boundaries two ways:
   writes, so the speed layer can attribute its fold-in work to the
   originating request's trace.
 
+The batched device call is the one place where work is recorded once
+and read twice: :class:`DrainPhases` holds the phases of one drain
+(``serving.prepare`` / ``scan`` / ``fallback`` / ``decode``), each
+marked where the work happens.  A phase is a
+``jax.profiler.TraceAnnotation`` on the dispatcher thread — so it sits
+on the profiler's clock beside the device's operations — and a
+monotonic stamp; the batcher replays the stamps as ring spans under
+every sampled job of that drain.
+
 Recording is STRICTLY best-effort: a raising recorder (the
 ``obs-trace-drop`` chaos point stands in for any internal failure)
 degrades that span to a no-op and bumps ``record_failures`` — tracing
@@ -40,9 +49,9 @@ from ..resilience import faults
 
 _log = logging.getLogger(__name__)
 
-__all__ = ["Span", "NOOP_SPAN", "Tracer", "parse_traceparent",
-           "format_traceparent", "unsampled_traceparent",
-           "tracer_from_config"]
+__all__ = ["Span", "NOOP_SPAN", "Tracer", "DrainPhases", "current_drain",
+           "annotation", "parse_traceparent", "format_traceparent",
+           "unsampled_traceparent", "tracer_from_config"]
 
 _FLAG_SAMPLED = 0x01
 # spans kept per trace: a runaway instrumentation loop must not let one
@@ -177,6 +186,91 @@ class Span:
         return False
 
 
+def annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` of that name: a host event on
+    the calling thread's line of whatever profiler trace is running
+    (``/admin/profile``, ``jax.profiler.start_trace``), next to free
+    when none is.  The one place this module reaches jax, and not at
+    the top: the router tier traces without ever loading it."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name)
+
+
+# the drain being recorded on the calling thread, if any: the batcher's
+# dispatcher thread opens it around model.top_n_batch, and the model
+# reaches it through current_drain() without a tracer of its own
+# (top_n_batch keeps its signature for the callers that record nothing)
+_drain = threading.local()
+
+
+def current_drain() -> "DrainPhases | None":
+    """The recorder open on this thread, or None — tracing off, or a
+    ``top_n_batch`` caller that is not the batcher.  A phase site is
+    ``if rec is not None: rec.mark(...)``: with no recorder that is one
+    branch, no annotation, no clock read, no allocation."""
+    return getattr(_drain, "open", None)
+
+
+class DrainPhases:
+    """The phases of ONE batched device call, recorded once where the
+    work happens.  While open (a context manager, on the thread that
+    makes the call) :meth:`mark` closes the phase that is running and
+    opens the next: one clock read and one profiler annotation each.
+    Phases follow each other and never nest; the last one closes with
+    the recorder, as an error if an exception ends it.  Afterwards
+    :meth:`replay` writes them into a tracer's ring under one job's
+    ``serving.device_execute`` span."""
+
+    __slots__ = ("_phases", "_note", "_spans")
+
+    def __init__(self):
+        # [name, start, end, attrs, status] in the order they ran
+        self._phases: list[list] = []
+        self._note = None
+        self._spans: list[dict] | None = None
+
+    def __enter__(self):
+        _drain.open = self
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        _drain.open = None
+        self._close(clockmod.monotonic(),
+                    "ok" if exc_type is None else "error")
+        return False
+
+    def _close(self, now: float, status: str) -> None:
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
+            self._note = None
+            self._phases[-1][2] = now
+            self._phases[-1][4] = status
+
+    def mark(self, name: str, **attrs) -> None:
+        """``name`` starts here, with the counts known at this boundary,
+        and whatever phase was running ends here."""
+        now = clockmod.monotonic()
+        self._close(now, "ok")
+        note = annotation(name)
+        note.__enter__()
+        # together, and last: an open annotation IS an open last phase,
+        # so the recorder's exit leaves no phase without its end
+        self._note = note
+        self._phases.append([name, now, None, attrs, "ok"])
+
+    def replay(self, tracer: "Tracer", trace_id: str,
+               parent_id: str) -> None:
+        """The drain's phases as ring spans under ``parent_id``, written
+        under one acquisition of the tracer's lock.  What is the same
+        for every sampled job of the drain — names, stamps, attributes
+        (shared between the jobs' spans, read-only from here on) — is
+        worked out on the first call."""
+        if self._spans is None:
+            self._spans = [tracer.span_fields(*p) for p in self._phases]
+        tracer.record_spans(trace_id, parent_id, self._spans)
+
+
 class Tracer:
     """Per-process span recorder + sampling/propagation policy."""
 
@@ -265,48 +359,89 @@ class Tracer:
     def record_span(self, name: str, trace_ctx: tuple[str, str] | None,
                     start_mono: float, end_mono: float,
                     attrs: dict | None = None,
-                    status: str = "ok") -> None:
+                    status: str = "ok") -> str | None:
         """Retroactive span from stored monotonic stamps and a
         ``(trace_id, parent_span_id)`` context captured earlier (the
-        batcher records queue-wait this way after the fact)."""
+        batcher records queue-wait this way after the fact).
+
+        Returns the new span's id, so the caller can record children
+        under it — the batcher parents a drain's phases under each
+        job's ``serving.device_execute`` this way — or None when there
+        was no context to record under.  The id comes back even where
+        the recorder failed: recording is best-effort, parenting is not
+        an error path."""
         if not trace_ctx:
-            return
-        self._record(name, trace_ctx[0], _new_span_id(), trace_ctx[1],
+            return None
+        span_id = _new_span_id()
+        self._record(name, trace_ctx[0], span_id, trace_ctx[1],
                      start_mono, end_mono, attrs or {}, status)
+        return span_id
+
+    def span_fields(self, name: str, start_mono: float, end_mono: float,
+                    attrs: dict, status: str = "ok") -> dict:
+        """A finished span less its three ids, for :meth:`record_spans`:
+        what one piece of work shared by several traces (a drain's
+        phase) computes once."""
+        return {
+            "name": name,
+            "service": self.service,
+            "trace_id": None,
+            "span_id": None,
+            "parent_id": None,
+            "start_ms": round((start_mono + self._mono_anchor) * 1000.0, 3),
+            "duration_ms": round((end_mono - start_mono) * 1000.0, 3),
+            "attrs": attrs,
+            "status": status,
+        }
+
+    def record_spans(self, trace_id: str, parent_id: str,
+                     fields: list[dict]) -> None:
+        """Several finished spans (:meth:`span_fields`) of one trace,
+        siblings under ``parent_id``, under ONE acquisition of the
+        ring's lock.  ``fields`` is left as it was, so the same list
+        can be recorded under other traces; each span lost to a failing
+        recorder counts in ``record_failures``."""
+        try:
+            spans = []
+            for f in fields:
+                span = dict(f)
+                span["trace_id"] = trace_id
+                span["span_id"] = _new_span_id()
+                span["parent_id"] = parent_id
+                spans.append(span)
+            self._append(trace_id, spans)
+        except Exception:  # noqa: BLE001 — observability is best-effort
+            with self._lock:
+                self.record_failures += len(fields)
 
     # -- recording (best-effort, bounded) ------------------------------------
 
     def _record(self, name, trace_id, span_id, parent_id, start_mono,
                 end_mono, attrs, status) -> None:
         try:
-            # chaos seam: a raising recorder must degrade to a no-op +
-            # counter, never fail the request being traced
-            faults.fire("obs-trace-drop")
-            span = {
-                "name": name,
-                "service": self.service,
-                "trace_id": trace_id,
-                "span_id": span_id,
-                "parent_id": parent_id,
-                "start_ms": round(
-                    (start_mono + self._mono_anchor) * 1000.0, 3),
-                "duration_ms": round((end_mono - start_mono) * 1000.0, 3),
-                "attrs": attrs,
-                "status": status,
-            }
-            with self._lock:
-                spans = self._traces.get(trace_id)
-                if spans is None:
-                    while len(self._traces) >= self.max_traces:
-                        self._traces.popitem(last=False)
-                    spans = self._traces[trace_id] = []
-                if len(spans) < _MAX_SPANS_PER_TRACE:
-                    spans.append(span)
+            span = self.span_fields(name, start_mono, end_mono, attrs,
+                                    status)
+            span["trace_id"] = trace_id
+            span["span_id"] = span_id
+            span["parent_id"] = parent_id
+            self._append(trace_id, [span])
         except Exception:  # noqa: BLE001 — observability is best-effort
             # under the lock: concurrent failing recorders must not
             # lose increments of the evidence counter
             with self._lock:
                 self.record_failures += 1
+
+    def _append(self, trace_id: str, spans: list[dict]) -> None:
+        # chaos seam: a raising recorder must degrade to a no-op +
+        # counter, never fail the request being traced
+        faults.fire("obs-trace-drop")
+        with self._lock:
+            kept = self._traces.get(trace_id)
+            if kept is None:
+                while len(self._traces) >= self.max_traces:
+                    self._traces.popitem(last=False)
+                kept = self._traces[trace_id] = []
+            kept.extend(spans[:_MAX_SPANS_PER_TRACE - len(kept)])
 
     def _dump_slow(self, trace_id: str, route: str | None,
                    dur_ms: float) -> None:

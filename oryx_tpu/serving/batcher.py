@@ -33,6 +33,7 @@ from typing import Iterable
 import numpy as np
 
 from ..common import clock as clockmod
+from ..obs import trace as obstrace
 from ..resilience import faults
 from ..resilience.policy import Deadline, DeadlineExceeded
 
@@ -95,7 +96,15 @@ class TopNBatcher:
         ``tracer`` (obs/trace.py, or None) splits each sampled
         request's batcher residence into a queue-wait span and a
         device-execute span — the evidence that separates "the device
-        is slow" from "the queue is deep".
+        is slow" from "the queue is deep" — and opens a phase recorder
+        (obs/trace.py ``DrainPhases``) around every batched call, so
+        the model's prepare / scan / fallback / decode phases land
+        under each sampled job's device-execute span and, as profiler
+        annotations, on the dispatcher thread's line of a device trace.
+        The pool's two idle states are annotated there too, each on one
+        thread at a time (``serving.await_work``: nothing queued and
+        nothing in flight; ``serving.await_slot``: work queued behind
+        the in-flight cap).
 
         ``accountant`` (obs/device_time.py, or None) books every
         batched device-execute bracket as route-class ``serve`` time
@@ -106,7 +115,14 @@ class TopNBatcher:
         self._tracer = tracer
         self._accountant = accountant
         self._idle_wait = idle_wait_s
-        self._cond = threading.Condition()
+        lock = threading.RLock()
+        self._cond = threading.Condition(lock)
+        # with a tracer, the ONE dispatcher whose wait is annotated as
+        # the pool's state parks here (same lock), so that whatever
+        # ends the state wakes that thread first (_await_locked,
+        # _wake_locked)
+        self._noted = threading.Condition(lock)
+        self._noted_waiting = False
         self._pending: list[_Job] = []
         self._stopped = False
         # service-rate pacing state (all under _cond)
@@ -175,7 +191,7 @@ class TopNBatcher:
             else:
                 stopped = False
                 self._pending.append(job)
-                self._cond.notify()
+                self._wake_locked(1)
         if stopped:
             return model.top_n_batch([how_many], job.vector[None, :],
                                      [job.exclude])[0]
@@ -220,6 +236,8 @@ class TopNBatcher:
         with self._cond:
             self._stopped = True
             self._cond.notify_all()
+            self._noted_waiting = False
+            self._noted.notify_all()
         for t in self._threads:
             t.join(5.0)
 
@@ -246,7 +264,11 @@ class TopNBatcher:
             with self._cond:
                 while not self._stopped:
                     if not self._pending:
-                        self._cond.wait()  # wall-clock: Condition poll on the real dispatch thread
+                        # the pool's state only while nothing is in
+                        # flight either: the device then idles for want
+                        # of requests, not behind host work
+                        self._await_locked("serving.await_work",
+                                           self._in_flight == 0)
                         continue
                     # Hold-time is measured from the oldest pending
                     # arrival's age, not time since the last dispatch —
@@ -262,7 +284,7 @@ class TopNBatcher:
                         # pacing: a blocked dispatcher wakes on the next
                         # completion and drains everything that queued
                         # during one service interval.
-                        self._cond.wait()  # wall-clock: Condition poll on the real dispatch thread
+                        self._await_locked("serving.await_slot", True)
                         continue
                     # below the in-flight cap: hold only briefly so a
                     # synchronized burst coalesces, then go.  A lone
@@ -304,7 +326,7 @@ class TopNBatcher:
                         # the estimators would collapse _wall_min /
                         # _exec_ewma and disable coalescing long after
                         # the deadline burst ends
-                        self._cond.notify(2)
+                        self._wake_locked(2)
                         continue
                     now = clockmod.monotonic()
                     # decay toward recent walls so a transient stall
@@ -330,16 +352,51 @@ class TopNBatcher:
                     # notify_all costs O(threads) lock churn per
                     # completion, and pacing waiters self-wake on their
                     # timeout anyway
-                    self._cond.notify(2)
+                    self._wake_locked(2)
             if stopped:
                 return
 
+    def _await_locked(self, name: str, pool_state: bool) -> None:
+        """Wait on the condition (held by the caller) until notified.
+        With a tracer, and where the wait is the whole pool's state and
+        not just this thread's (``pool_state``), ONE thread at a time
+        takes it as a profiler annotation of that name: a device-idle
+        gap in which the host had nothing to dispatch then says so,
+        where it would otherwise carry no host event at all.  That
+        thread is the first one :meth:`_wake_locked` wakes, so the
+        annotation ends when the state does and covers no later gap.
+        No ring span: no request owns the wait."""
+        if self._tracer is None or not pool_state or self._noted_waiting:
+            self._cond.wait()  # wall-clock: Condition poll on the real dispatch thread
+            return
+        with obstrace.annotation(name):
+            # cleared by whoever wakes this thread (_wake_locked,
+            # close()): by the time it runs again another may hold
+            # the place
+            self._noted_waiting = True
+            self._noted.wait()  # wall-clock: Condition poll on the real dispatch thread
+
+    def _wake_locked(self, n: int) -> None:
+        """Wake ``n`` waiting dispatchers (the caller holds the
+        condition): the annotated one first, whose state has just
+        ended — work arrived, or a dispatch completed."""
+        if self._noted_waiting:
+            self._noted_waiting = False
+            self._noted.notify()
+            n -= 1
+        if n:
+            self._cond.notify(n)
+
     def _record_spans(self, group: list[_Job], t_exec: float,
-                      t_done: float, status: str) -> None:
+                      t_done: float, status: str,
+                      phases: obstrace.DrainPhases) -> None:
         """Queue-wait / device-execute spans for the sampled jobs of a
-        drained group.  Recorded retroactively from stored monotonic
-        stamps (the dispatcher has no thread-local trace context), and
-        strictly best-effort — the tracer absorbs recorder failures."""
+        drained group, and the drain's phases under each job's
+        device-execute span (grandchildren of the request, so the
+        request's own children stay the two they were).  Recorded
+        retroactively from stored monotonic stamps (the dispatcher has
+        no thread-local trace context), and strictly best-effort — the
+        tracer absorbs recorder failures."""
         traced = [j for j in group if j.trace_ctx is not None]
         if not traced:
             return
@@ -352,9 +409,10 @@ class TopNBatcher:
         for j in traced:
             self._tracer.record_span("serving.queue_wait", j.trace_ctx,
                                      j.t_enq, t_exec)
-            self._tracer.record_span("serving.device_execute",
-                                     j.trace_ctx, t_exec, t_done,
-                                     dict(exec_attrs), status)
+            exec_id = self._tracer.record_span(
+                "serving.device_execute", j.trace_ctx, t_exec, t_done,
+                dict(exec_attrs), status)
+            phases.replay(self._tracer, j.trace_ctx[0], exec_id)
 
     def _dispatch(self, jobs: list[_Job]) -> int:
         """Score a drained batch; returns how many jobs actually reached
@@ -412,11 +470,16 @@ class TopNBatcher:
             model = group[0].model
             t_exec = next_exec_start
             status = "ok"
+            # per drain, whatever was sampled: the annotations belong to
+            # the dispatcher thread, only the ring spans to requests
+            phases = obstrace.DrainPhases() \
+                if self._tracer is not None else None
             try:
-                results = model.top_n_batch(
-                    [j.how_many for j in group],
-                    np.stack([j.vector for j in group]),
-                    [j.exclude for j in group])
+                with phases or obstrace.NOOP_SPAN:
+                    results = model.top_n_batch(
+                        [j.how_many for j in group],
+                        np.stack([j.vector for j in group]),
+                        [j.exclude for j in group])
                 for j, r in zip(group, results):
                     j.result = r
             except BaseException as e:  # noqa: BLE001 — surfaced per job
@@ -433,9 +496,9 @@ class TopNBatcher:
                     getattr(model, "kernel_route_label", None),
                     getattr(model, "generation", None),
                     next_exec_start - t_exec)
-            if self._tracer is not None:
+            if phases is not None:
                 self._record_spans(group, t_exec, next_exec_start,
-                                   status)
+                                   status, phases)
             with self._cond:
                 # under the lock: up to `pipeline` dispatcher threads
                 # land here concurrently, and a bare += loses updates

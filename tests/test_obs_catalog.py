@@ -36,7 +36,10 @@ _SPAN_RE = re.compile(r"^[a-z][a-z0-9_]*\.[a-z][a-z0-9_]*$")
 _NAME_RE = re.compile(r"^[a-z][a-z0-9_]*$")
 
 # (method attribute, index of the positional name argument)
-_SPAN_METHODS = {"span": 0, "child_span": 1, "record_span": 0}
+# mark/annotation: obs/trace.py's drain phases and bare profiler
+# annotations; _await_locked: the batcher's annotated condition wait
+_SPAN_METHODS = {"span": 0, "child_span": 1, "record_span": 0,
+                 "mark": 0, "annotation": 0, "_await_locked": 0}
 _COUNTER_METHODS = {"inc": 0}
 _GAUGE_METHODS = {"set_gauge": 0, "gauge_fn": 0}
 
@@ -114,6 +117,8 @@ def test_walk_sees_the_known_call_sites(source_names):
     # linting nothing
     assert "router.merge" in source_names["span"]
     assert "serving.queue_wait" in source_names["span"]
+    assert "serving.scan" in source_names["span"]
+    assert "serving.await_work" in source_names["span"]
     assert "partial_answers" in source_names["counter"]
     assert "ingest_to_servable_ms" in source_names["gauge"]
     assert "update_lag_records" in source_names["gauge"]

@@ -1,0 +1,501 @@
+"""The phases of a drain (``serving.prepare`` / ``scan`` / ``fallback``
+/ ``decode``): recorded once where the work happens, read twice — as
+ring spans under each sampled job's ``serving.device_execute`` and as
+profiler annotations on the dispatcher thread — plus the dispatchers'
+two annotated waits, and the program names the benchmark's device
+metrics tell the two-phase scan from its exact-scan fallback by."""
+
+import ast
+import inspect
+import textwrap
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from oryx_tpu.app.als import serving_model as sm
+from oryx_tpu.app.als.serving_model import ALSServingModel
+from oryx_tpu.obs import trace as obstrace
+from oryx_tpu.obs.trace import Tracer
+from oryx_tpu.resilience import faults
+from oryx_tpu.serving.batcher import TopNBatcher, _Job
+
+ITEMS, FEATURES = 4096, 8
+PHASES = ["serving.prepare", "serving.scan", "serving.decode"]
+
+
+@pytest.fixture(scope="module")
+def model():
+    rng = np.random.default_rng(24)
+    m = ALSServingModel(FEATURES, implicit=True)
+    m.Y.bulk_load([f"i{j}" for j in range(ITEMS)],
+                  rng.standard_normal((ITEMS, FEATURES)).astype(np.float32))
+    return m
+
+
+@pytest.fixture
+def ladder(monkeypatch):
+    """The streaming two-phase branch at toy scale (tests/test_als.py's
+    monkeypatches)."""
+    monkeypatch.setattr(sm, "_FLAT_SCORES_LIMIT", 1)
+    monkeypatch.setattr(sm, "_MAX_CHUNK_ROWS", 1024)
+    monkeypatch.setattr(sm, "_BLOCK_ROWS", 64)
+    monkeypatch.setattr(sm, "_BLOCK_KSEL", 8)
+
+
+@pytest.fixture
+def notes(monkeypatch):
+    """A capturing stand-in for ``jax.profiler.TraceAnnotation``: every
+    construction as ``(name, thread name)``, and the open/close order."""
+    seen, order = [], []
+
+    class StandIn:
+        def __init__(self, name, **kwargs):
+            self.name = name
+            seen.append((name, threading.current_thread().name))
+
+        def __enter__(self):
+            order.append(("open", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            order.append(("close", self.name))
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", StandIn)
+    return seen, order
+
+
+def _vectors(n, seed=7):
+    return np.random.default_rng(seed).standard_normal(
+        (n, FEATURES)).astype(np.float32)
+
+
+def _drain(batcher, tracer, model, n, sampled):
+    """One drain of ``n`` jobs dispatched on this thread, the first
+    ``sampled`` of them traced; returns (jobs, their request spans)."""
+    requests, jobs = [], []
+    for i, vec in enumerate(_vectors(n)):
+        ctx = None
+        if i < sampled:
+            req = tracer.begin_request("serving.request")
+            tracer._swap(None)
+            requests.append(req)
+            ctx = (req.trace_id, req.span_id)
+        jobs.append(_Job(model, 5, vec, set(), trace_ctx=ctx))
+    assert batcher._dispatch(jobs) == n
+    for j in jobs:
+        assert j.error is None and len(j.result) == 5
+    return jobs, requests
+
+
+def _by_name(spans):
+    out: dict = {}
+    for s in spans:
+        out.setdefault(s["name"], []).append(s)
+    return out
+
+
+@pytest.fixture
+def traced():
+    tracer = Tracer("serving", sample_ratio=1.0)
+    batcher = TopNBatcher(pipeline=1, tracer=tracer)
+    yield batcher, tracer
+    batcher.close()
+
+
+@pytest.mark.parametrize("branch", ["ladder", "flat"])
+def test_phases_land_under_each_jobs_device_execute(branch, model, traced,
+                                                    request):
+    if branch == "ladder":
+        request.getfixturevalue("ladder")
+    batcher, tracer = traced
+    _, requests = _drain(batcher, tracer, model, 3, sampled=3)
+    stamps = set()
+    for req in requests:
+        spans = _by_name(tracer.spans_for(req.trace_id))
+        (execute,) = spans["serving.device_execute"]
+        assert execute["parent_id"] == req.span_id
+        assert "serving.fallback" not in spans
+        lo = execute["start_ms"]
+        hi = lo + execute["duration_ms"]
+        total, at = 0.0, lo
+        for name in PHASES:
+            (s,) = spans[name]
+            assert s["parent_id"] == execute["span_id"]
+            assert s["trace_id"] == req.trace_id
+            # inside the parent, one after the other (stamps are
+            # rounded to a microsecond)
+            assert at - 0.002 <= s["start_ms"]
+            at = s["start_ms"] + s["duration_ms"]
+            assert at <= hi + 0.002
+            total += s["duration_ms"]
+        assert total <= execute["duration_ms"] + 0.002
+        # the counts known at the boundary where each phase begins
+        assert spans["serving.prepare"][0]["attrs"] == {"rows": 3}
+        assert spans["serving.scan"][0]["attrs"] == {"k": 8,
+                                                     "windows": [8]}
+        assert spans["serving.decode"][0]["attrs"] == {"rows": 3}
+        stamps.add(tuple((spans[n][0]["start_ms"],
+                          spans[n][0]["duration_ms"]) for n in PHASES))
+    # recorded once, replayed three times: the same stamps under each job
+    assert len(stamps) == 1
+
+
+@pytest.mark.parametrize("failing, widths", [("tail", [8]),
+                                             ("all", [256, 8])])
+def test_a_certificate_miss_records_a_fallback_per_failing_window(
+        failing, widths, model, traced, ladder, monkeypatch):
+    real = sm._batch_top_n_twophase_kernel
+
+    def sabotaged(Y, Q, *args, **kw):
+        ts, ti, cert = real(Y, Q, *args, **kw)
+        if failing == "all" or Q.shape[0] == 8:
+            return ts, ti, cert & False
+        return ts, ti, cert | True
+
+    monkeypatch.setattr(sm, "_batch_top_n_twophase_kernel", sabotaged)
+    batcher, tracer = traced
+    before = model.twophase_fallbacks
+    want = model.top_n_batch(5, _vectors(257))
+    assert model.twophase_fallbacks - before == sum(widths)
+    before = model.twophase_fallbacks
+    jobs, (req,) = _drain(batcher, tracer, model, 257, sampled=1)
+    assert [j.result for j in jobs] == want
+    spans = _by_name(tracer.spans_for(req.trace_id))
+    (execute,) = spans["serving.device_execute"]
+    fallbacks = spans["serving.fallback"]
+    assert [s["attrs"] for s in fallbacks] == [
+        {"k": 8, "width": w, "rows_failed": w} for w in widths]
+    assert all(s["parent_id"] == execute["span_id"] for s in fallbacks)
+    assert model.twophase_fallbacks - before \
+        == sum(s["attrs"]["rows_failed"] for s in fallbacks)
+    assert spans["serving.scan"][0]["attrs"]["windows"] == [256, 8]
+    # one mark ends a phase and begins the next: scan runs up to the
+    # first fallback, the last fallback up to decode (stamps are
+    # rounded to a microsecond)
+    in_order = [spans["serving.scan"][0], *fallbacks,
+                spans["serving.decode"][0]]
+    for before, after in zip(in_order, in_order[1:]):
+        assert before["start_ms"] + before["duration_ms"] \
+            == pytest.approx(after["start_ms"], abs=0.002)
+
+
+def test_the_requests_own_children_stay_the_two_they_were(model, ladder):
+    tracer = Tracer("serving", sample_ratio=1.0)
+    batcher = TopNBatcher(pipeline=2, tracer=tracer)
+    try:
+        req = tracer.begin_request("serving.request")
+        assert len(batcher.top_n(model, 5, _vectors(1)[0])) == 5
+        tracer.end_request(req, 200, "GET /recommend/{userID}")
+    finally:
+        batcher.close()
+    spans = tracer.spans_for(req.trace_id)
+    children = sorted(s["name"] for s in spans
+                      if s["parent_id"] == req.span_id)
+    assert children == ["serving.device_execute", "serving.queue_wait"]
+    (execute,) = [s for s in spans if s["name"] == "serving.device_execute"]
+    assert sorted(s["name"] for s in spans
+                  if s["parent_id"] == execute["span_id"]) == sorted(PHASES)
+    assert len(spans) == 6
+
+
+@pytest.mark.parametrize("jobs, sampled", [(1, 0), (3, 1), (3, 3), (9, 2)])
+def test_each_phase_is_annotated_once_per_drain(jobs, sampled, model,
+                                                traced, ladder, notes):
+    seen, order = notes
+    batcher, tracer = traced
+    me = threading.current_thread().name
+    _, requests = _drain(batcher, tracer, model, jobs, sampled)
+    mine = [name for name, thread in seen if thread == me]
+    # whatever the drain's size and however many of it were sampled
+    assert mine == PHASES
+    assert [e for e in order if e[1] in PHASES] == [
+        (what, name) for name in PHASES for what in ("open", "close")]
+    recorded = [s for r in requests for s in tracer.spans_for(r.trace_id)]
+    assert sorted(s["name"] for s in recorded) == sorted(
+        (PHASES + ["serving.queue_wait", "serving.device_execute"])
+        * sampled)
+
+
+def _until(cond, what, timeout=10.0):
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, what
+        time.sleep(0.002)
+
+
+class _Gated:
+    """Stalls its first drain until released; the later ones go straight
+    to the model."""
+
+    def __init__(self, model):
+        self.model = model
+        self.first = True
+        self.in_dispatch, self.release = threading.Event(), threading.Event()
+
+    def top_n_batch(self, how_many, vectors, exclude):
+        if self.first:
+            self.first = False
+            self.in_dispatch.set()
+            assert self.release.wait(10.0)
+        return self.model.top_n_batch(how_many, vectors, exclude)
+
+
+def test_the_waits_are_annotated_and_never_ring_recorded(model, ladder,
+                                                         notes):
+    seen, _ = notes
+    tracer = Tracer("serving", sample_ratio=1.0)
+    batcher = TopNBatcher(pipeline=2, tracer=tracer)
+    batcher._in_flight_target = lambda: 1
+    gated, answers = _Gated(model), []
+
+    def call():
+        req = tracer.begin_request("serving.request")
+        answers.append(batcher.top_n(gated, 5, _vectors(1)[0]))
+        tracer.end_request(req, 200)
+
+    callers = [threading.Thread(target=call) for _ in range(2)]
+    try:
+        callers[0].start()
+        assert gated.in_dispatch.wait(10.0)
+        # the second request finds the in-flight cap taken
+        callers[1].start()
+        _until(lambda: "serving.await_slot" in [n for n, _ in seen],
+               "no dispatcher waited for a slot")
+        gated.release.set()
+        for t in callers:
+            t.join(10.0)
+            assert not t.is_alive()
+    finally:
+        gated.release.set()
+        batcher.close()
+    assert len(answers) == 2
+    waits = {(n, t) for n, t in seen if n.startswith("serving.await")}
+    assert {n for n, _ in waits} == {"serving.await_work",
+                                     "serving.await_slot"}
+    assert all(t.startswith("TopNBatcher-") for _, t in waits)
+    ring = {s["name"] for spans in tracer.traces_snapshot().values()
+            for s in spans}
+    assert ring == set(PHASES) | {"serving.request", "serving.queue_wait",
+                                  "serving.device_execute"}
+
+
+def _open_waits(order):
+    """How many wait annotations are open after each event."""
+    n, out = 0, []
+    for what, name in order:
+        if name.startswith("serving.await"):
+            n += 1 if what == "open" else -1
+            out.append(n)
+    return out
+
+
+def test_one_dispatcher_carries_the_pools_wait_and_work_ends_it(
+        model, ladder, notes):
+    """``serving.await_work`` is the POOL's state — nothing queued,
+    nothing in flight — so one of the eight idle threads says it, and
+    the request that ends the state wakes that thread, so its
+    annotation closes there and then, not some completions later."""
+    seen, order = notes
+    batcher = TopNBatcher(pipeline=8, tracer=Tracer("serving", 1.0))
+
+    class Slow:
+        """Long enough a drain that the woken thread has run by its
+        end, however busy the machine."""
+
+        def top_n_batch(self, how_many, vectors, exclude):
+            time.sleep(0.1)
+            return model.top_n_batch(how_many, vectors, exclude)
+
+    def settled(times):
+        _until(lambda: _open_waits(order)[-1:] == [1]
+               and [n for n, _ in seen].count("serving.await_work")
+               == times,
+               "the idle pool did not settle on one annotated wait")
+
+    try:
+        for served in range(3):
+            settled(served + 1)
+            assert len(batcher.top_n(Slow(), 5, _vectors(1)[0])) == 5
+        settled(4)
+    finally:
+        batcher.close()
+    assert set(_open_waits(order)) == {0, 1}
+    assert _open_waits(order)[-1] == 0  # close() let the last one go
+    # each request ended one wait, and the drained pool began the next
+    assert [name for what, name in order if what == "open"] == (
+        ["serving.await_work"] + PHASES) * 3 + ["serving.await_work"]
+    closes = [i for i, e in enumerate(order)
+              if e == ("close", "serving.await_work")]
+    decodes = [i for i, e in enumerate(order)
+               if e == ("close", "serving.decode")]
+    # ... before that request's drain was over (whichever dispatcher
+    # got to the queue first ran it)
+    assert all(c < d for c, d in zip(closes, decodes))
+
+
+def test_await_work_is_not_said_while_a_drain_is_in_flight(model, ladder,
+                                                           notes):
+    """With a drain in flight the device is not idle for want of
+    requests: a dispatcher that finds the queue empty then waits
+    without the annotation, and the gap belongs to the drain's phases."""
+    seen, order = notes
+    batcher = TopNBatcher(pipeline=4, tracer=Tracer("serving", 1.0))
+    gated = _Gated(model)
+    stalled = threading.Thread(
+        target=lambda: batcher.top_n(gated, 5, _vectors(1)[0]))
+
+    def said():
+        return len([n for n, _ in seen if n == "serving.await_work"])
+
+    try:
+        _until(lambda: said() == 1, "no annotated wait on the idle pool")
+        stalled.start()
+        assert gated.in_dispatch.wait(10.0)
+        # served by other dispatchers, which then find the queue empty
+        for _ in range(3):
+            assert len(batcher.top_n(model, 5, _vectors(1)[0])) == 5
+        _until(lambda: batcher.stats()["in_flight"] == 1, "still busy")
+        assert said() == 1 and _open_waits(order)[-1] == 0
+        gated.release.set()
+        stalled.join(10.0)
+        # the pool ran dry again: whoever finished last says so
+        _until(lambda: said() == 2 and _open_waits(order)[-1] == 1,
+               "the drained pool did not annotate its wait")
+    finally:
+        gated.release.set()
+        batcher.close()
+
+
+def test_without_a_tracer_nothing_is_annotated_and_answers_match(
+        model, traced, ladder, monkeypatch):
+    batcher, tracer = traced
+    jobs, _ = _drain(batcher, tracer, model, 3, sampled=3)
+
+    def never(*args, **kwargs):
+        raise AssertionError("an annotation was built with tracing off")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", never)
+    assert obstrace.current_drain() is None
+    plain = TopNBatcher(pipeline=2)
+    try:
+        for j in jobs:
+            assert plain.top_n(model, 5, j.vector) == j.result
+        untraced_jobs = [_Job(model, 5, j.vector, set()) for j in jobs]
+        assert plain._dispatch(untraced_jobs) == 3
+        assert [j.result for j in untraced_jobs] == [j.result for j in jobs]
+        # and a direct caller (route measurement, warm-up) records nothing
+        assert model.top_n_batch(5, np.stack([j.vector for j in jobs])) \
+            == [j.result for j in jobs]
+    finally:
+        plain.close()
+
+
+def test_a_raising_recorder_still_answers_the_drain(model, traced, ladder):
+    batcher, tracer = traced
+    faults.clear()
+    try:
+        faults.inject("obs-trace-drop", mode="error", times=100)
+        _, (req,) = _drain(batcher, tracer, model, 3, sampled=1)
+        # queue_wait, device_execute and the three phases all dropped
+        assert tracer.record_failures == 5
+        assert tracer.spans_for(req.trace_id) == []
+    finally:
+        faults.clear()
+
+
+def test_a_failing_scan_closes_its_phase_as_an_error(model, traced, ladder,
+                                                     monkeypatch, notes):
+    _, order = notes
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(ALSServingModel, "_dispatch_twophase", broken)
+    batcher, tracer = traced
+    req = tracer.begin_request("serving.request")
+    tracer._swap(None)
+    job = _Job(model, 5, _vectors(1)[0], set(),
+               trace_ctx=(req.trace_id, req.span_id))
+    batcher._dispatch([job])
+    assert isinstance(job.error, RuntimeError)
+    spans = _by_name(tracer.spans_for(req.trace_id))
+    assert spans["serving.device_execute"][0]["status"] == "error"
+    assert spans["serving.prepare"][0]["status"] == "ok"
+    assert spans["serving.scan"][0]["status"] == "error"
+    assert "serving.decode" not in spans
+    # no annotation is left open on the dispatcher's line
+    assert order == [(what, name) for name in PHASES[:2]
+                     for what in ("open", "close")]
+    assert obstrace.current_drain() is None
+
+
+def test_record_span_hands_back_the_id_it_made():
+    tracer = Tracer("svc", sample_ratio=1.0)
+    ctx = ("f" * 32, "e" * 16)
+    span_id = tracer.record_span("serving.device_execute", ctx, 1.0, 2.0)
+    tracer.record_span("serving.scan", (ctx[0], span_id), 1.2, 1.8)
+    parent, child = tracer.spans_for(ctx[0])
+    assert parent["span_id"] == span_id == child["parent_id"]
+    assert tracer.record_span("serving.scan", None, 1.0, 2.0) is None
+
+
+# -- the program names the device metrics read --------------------------------
+
+TWOPHASE_BUILDS = ["_batch_top_n_twophase_pallas",
+                   "_batch_top_n_twophase_pallas_fold",
+                   "_batch_top_n_twophase_pallas_i8",
+                   "_batch_top_n_twophase_pallas_i8_fold",
+                   "_batch_top_n_twophase_kernel"]
+EXACT_SCAN = "_batch_top_n_chunked_kernel"
+
+
+def _scan_programs_called_by(method) -> set[str]:
+    tree = ast.parse(textwrap.dedent(inspect.getsource(method)))
+    return {n.func.id for n in ast.walk(tree)
+            if isinstance(n, ast.Call) and isinstance(n.func, ast.Name)
+            and n.func.id.startswith("_batch_top_n")}
+
+
+def test_the_exact_ladder_enqueues_only_the_named_programs():
+    """Every build ``_dispatch_kind`` can enqueue for the exact ladder
+    (the IVF kind, ``ivf.batch_top_n_ivf``, is outside the contract) is
+    one of the two-phase programs, and the fallback is the exact scan:
+    ``benchmark/layers/kernel.twophase_ms.json`` and
+    ``kernel.exact_scan_ms.json`` match their names in the device trace."""
+    assert _scan_programs_called_by(ALSServingModel._dispatch_kind) \
+        == set(TWOPHASE_BUILDS)
+    assert _scan_programs_called_by(ALSServingModel._dispatch_twophase) \
+        == {"_batch_top_n_twophase_kernel"}
+    assert _scan_programs_called_by(ALSServingModel.top_n_batch) \
+        >= {EXACT_SCAN}
+    assert not any("twophase" in name for name in
+                   _scan_programs_called_by(ALSServingModel.top_n_batch))
+
+
+@pytest.mark.parametrize("name, pattern",
+                         [(n, "twophase") for n in TWOPHASE_BUILDS]
+                         + [(EXACT_SCAN, "chunked_kernel")])
+def test_each_scan_program_is_jitted_under_its_own_name(name, pattern):
+    """The device trace names a program ``jit_<function name>(<hash>)``:
+    the wrapper must be ONE jitted function carrying that name."""
+    fn = getattr(sm, name)
+    assert hasattr(fn, "lower"), f"{name} is not a jitted function"
+    assert fn.__name__ == name and pattern in name
+    assert ("twophase" in name) != ("chunked_kernel" in name)
+
+
+def test_the_lowered_programs_carry_the_names_the_trace_shows():
+    Y = jnp.zeros((512, FEATURES), jnp.float32)
+    Q = jnp.zeros((8, FEATURES), jnp.float32)
+    active = jnp.ones((512,), bool)
+    two = sm._batch_top_n_twophase_kernel.lower(
+        Y, Q, active, None, None, 8, 256, 64, 4, 0)
+    assert "@jit__batch_top_n_twophase_kernel" in two.as_text()[:200]
+    exact = sm._batch_top_n_chunked_kernel.lower(
+        Y, Q, active, None, None, 8, 256, 0)
+    assert "@jit__batch_top_n_chunked_kernel" in exact.as_text()[:200]
