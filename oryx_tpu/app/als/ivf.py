@@ -274,7 +274,9 @@ def _ivf_top_n_kernel(Y, Q, y8p, sy_b, l1y_b, pen_i, activep, perm,
     Returned indices are original row indices; rows outside the probed
     cells are simply not candidates — that pruning is what the recall
     certificate measured at generation load."""
-    from .serving_model import _I8_PENALTY, _q_cast, _score_precision
+    from .serving_model import (_I8_PENALTY, _map_row_groups,
+                                _phase_b_group_rows, _q_cast,
+                                _row_bytes, _score_precision)
 
     B = Q.shape[0]
     W = int(y8p.shape[1])
@@ -326,25 +328,31 @@ def _ivf_top_n_kernel(Y, Q, y8p, sy_b, l1y_b, pen_i, activep, perm,
     masked = m_int <= _I8_PENALTY // 2
     bound = jnp.where(masked | (l1q[:, None] == 0.0), -jnp.inf, bound)
 
-    _, pi = jax.lax.approx_max_k(bound, ksel, recall_target=0.99999)
-    m_rest = bound.at[jnp.arange(B)[:, None], pi].set(-jnp.inf).max(-1)
-    m_guard = jnp.where(jnp.isfinite(m_rest),
-                        m_rest + jnp.abs(m_rest) * 1e-4, m_rest)
-    bi_sel = jnp.take_along_axis(bi, pi, axis=1)          # (B, ksel)
-    rows_p = (bi_sel[:, :, None] * bs
-              + jnp.arange(bs, dtype=jnp.int32)[None, None, :]
-              ).reshape(B, ksel * bs)
-    orig = jnp.take(perm, rows_p)                         # (B, R)
-    ok = jnp.take(activep, rows_p)
-    Yg = jnp.take(Y, orig, axis=0)                        # (B, R, W)
-    scores = jnp.einsum("bf,brf->br", Qc, Yg,
-                        preferred_element_type=jnp.float32,
-                        precision=_score_precision(Y))
-    scores = jnp.where(ok, scores, -jnp.inf)
-    ts, ti = jax.lax.top_k(scores, k)
-    idx = jnp.take_along_axis(orig, ti, axis=1)
-    cert = ts[:, k - 1] >= m_guard
-    return ts, idx, cert
+    def rescore(Qc, bound, bi):
+        b = Qc.shape[0]
+        _, pi = jax.lax.approx_max_k(bound, ksel, recall_target=0.99999)
+        m_rest = bound.at[jnp.arange(b)[:, None], pi].set(-jnp.inf).max(-1)
+        m_guard = jnp.where(jnp.isfinite(m_rest),
+                            m_rest + jnp.abs(m_rest) * 1e-4, m_rest)
+        bi_sel = jnp.take_along_axis(bi, pi, axis=1)      # (b, ksel)
+        rows_p = (bi_sel[:, :, None] * bs
+                  + jnp.arange(bs, dtype=jnp.int32)[None, None, :]
+                  ).reshape(b, ksel * bs)
+        orig = jnp.take(perm, rows_p)                     # (b, R)
+        ok = jnp.take(activep, rows_p)
+        Yg = jnp.take(Y, orig, axis=0)                    # (b, R, W)
+        scores = jnp.einsum("bf,brf->br", Qc, Yg,
+                            preferred_element_type=jnp.float32,
+                            precision=_score_precision(Y))
+        scores = jnp.where(ok, scores, -jnp.inf)
+        ts, ti = jax.lax.top_k(scores, k)
+        idx = jnp.take_along_axis(orig, ti, axis=1)
+        return ts, idx, ts[:, k - 1] >= m_guard
+
+    # the gather is phase B's, and bounded like it: a window too wide
+    # for one runs in row groups (serving_model._phase_b)
+    g = _phase_b_group_rows(B, ksel, bs, _row_bytes(Y))
+    return _map_row_groups(rescore, g, Qc, bound, bi)
 
 
 def batch_top_n_ivf(mirror: IVFMirror, Y, Q, k: int, bs: int,
@@ -406,7 +414,7 @@ def measure_recall(model, mirror: IVFMirror, cfg: AnnConfig) -> float:
         ex_s, ex_i = jax.device_get(sm._batch_top_n_kernel(
             vecs, Qd, active, k))
     bs = sm._BLOCK_ROWS
-    ksel = sm._i8_ksel(min(sm._BLOCK_KSEL, n_rows // bs), n_rows, bs)
+    ksel = sm._i8_ksel(sm._block_ksel(k, n_rows, bs), n_rows, bs)
     an_s, an_i, _cert = jax.device_get(batch_top_n_ivf(
         mirror, vecs, Qd, k, bs, ksel, cfg.nprobe))
     hits = total = 0

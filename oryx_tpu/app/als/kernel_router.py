@@ -140,9 +140,8 @@ def measure_routes(model, batch: int | None = None,
 
     if streaming:
         bs = sm._BLOCK_ROWS
-        ksel = min(sm._BLOCK_KSEL, n_rows // max(1, bs))
-        twophase_ok = (n_rows % bs == 0 and 1 <= ksel < n_rows // bs
-                       and k <= ksel * bs)
+        ksel = sm._block_ksel(k, n_rows, bs)
+        twophase_ok = sm._twophase_admits(k, ksel, vecs, bs)
         # the dispatch's own chain — one derivation, so what is
         # measured IS what can be served
         kinds, fold = model._phase_a_kinds(n_rows, int(vecs.shape[1]),

@@ -33,6 +33,7 @@ from typing import Callable, Iterable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ...api.serving import ServingModel
 from ...common.lang import AutoReadWriteLock
@@ -207,21 +208,112 @@ def _stream_plan(n_rows: int, b_pad: int) -> tuple[bool, int]:
 
 # Two-phase streaming top-k tuning: 128-row blocks match the TPU's
 # lane granularity (a block gather moves aligned ~13-64 KB slabs, not
-# sub-tile rows).  The block-selection approx_max_k's RECALL sets the
-# certificate-failure rate directly: at recall 0.999 over the 20M
-# cells' 157k block maxima, ~15% of 256-query windows had one row
-# whose head block was genuinely missed (diagnosed: pallas kth 37.068
-# vs exact 37.223 — a real miss the certificate caught, not a rounding
-# artifact), and every failure recomputes a window on the ~10x slower
-# exact scan.  Recall 0.99999 makes misses ~100x rarer; the partial
-# reduce is still far cheaper than an exact lax.top_k over the maxima
-# (the ~40x-the-matmul per-row sort the design exists to avoid).
-# Widening ksel does NOT help — a missed head block stays missed no
-# matter how many other blocks are selected (measured: ksel 64 still
-# failed 6 of 40 windows at recall 0.999).
+# sub-tile rows).  What decides a certificate, in this order:
+#
+# 1. ``ksel`` against k.  The certificate passes when the k-th served
+#    score is at least the best UNSELECTED block maximum.  Item rows
+#    sit in arbitrary order, so the best k items lie in about k
+#    different blocks and the (ksel+1)-th best block maximum is about
+#    the (ksel+1)-th best item: with fewer blocks selected than rows
+#    fetched it beats the k-th score and the certificate cannot hold
+#    (ksel 32 at k = 64/128/256: 0 of 8 rows certified, the 20M cells'
+#    23-27% "misses" of PERF.md PR 22/24, all of them fetches of
+#    k >= 64).  With ksel >= k and an exact selection it cannot fail
+#    but for the margin: at most k-1 blocks hold a better item.
+# 2. The margin.  ``m_guard`` inflates the best unselected maximum by
+#    a relative 1e-4 (below).  At ksel = k that maximum is about item
+#    k+1, and a row whose k-th and (k+1)-th scores lie that close
+#    fails (1 of 8 rows at k = ksel = 32; the "3-4% of rows at k <=
+#    32").  At ksel = 2k it is about item 2k, some 3% under the k-th
+#    score at 20M rows, and the margin never bites: _block_ksel.
+# 3. Recall.  approx_max_k's ``recall_target`` is what a genuine miss
+#    costs: at 0.999 over the 20M cells' 157k block maxima ~15% of
+#    256-query windows had one row whose head block was really missed
+#    (pallas kth 37.068 vs exact 37.223: a miss the certificate
+#    caught, not rounding).  At 0.99999 the partial reduce keeps more
+#    candidates than there are blocks, i.e. the selection is exact,
+#    and still far cheaper than a lax.top_k over the maxima.  A head
+#    block that IS missed stays missed however wide ksel is.
 _BLOCK_ROWS = 128
 _BLOCK_KSEL = 32
 _APPROX_RECALL = 0.99999
+# Phase B gathers (rows, ksel, bs, F) of the store's dtype.  A window
+# whose gather would pass this many bytes runs its rows in equal
+# groups, one after the other inside the same program (_phase_b): a
+# 256-wide window fetching k = 256 at 250f bfloat16 would otherwise
+# ask for 8.4 GB beside a 10 GB store.  Of the order of
+# _FLAT_SCORES_LIMIT, the other transient this module bounds.
+_PHASE_B_GATHER_BYTES = 1 << 30
+
+
+def _block_ksel(k: int, n_rows: int, bs: int) -> int:
+    """How many ``bs``-row blocks phase B selects for a fetch of ``k``
+    rows: twice the fetch (see the notes above: as many blocks as rows
+    for the certificate to hold at all, twice for its margin never to
+    bite), never under the floor ``_BLOCK_KSEL`` (k = 16 keeps 32) and
+    always under the block count, since the certificate needs a block
+    left unselected.  THE rule for every caller: the dispatch, the
+    route measurement, the AOT warm-up, IVF's recall and the probe."""
+    return min(max(_BLOCK_KSEL, 2 * k), n_rows // bs - 1)
+
+
+def _row_bytes(Y) -> int:
+    """Bytes of one stored row of ``Y`` (an array or its aval)."""
+    return int(Y.shape[1]) * Y.dtype.itemsize
+
+
+def _twophase_admits(k: int, ksel: int, Y, bs: int) -> bool:
+    """Whether the two-phase program may answer a fetch of ``k`` from
+    the store ``Y`` (an array or its aval): the store splits into whole
+    blocks, the selection is at least as wide as the fetch (where the
+    block count capped it under k the certificate is certain to fail,
+    and the exact scan would run AFTER a two-phase program paid for
+    nothing), and one query row's gather fits the budget.  Otherwise
+    the exact scan is the primary path."""
+    n_rows = int(Y.shape[0])
+    return (n_rows % bs == 0 and k <= ksel < n_rows // bs
+            and ksel * bs * _row_bytes(Y) <= _PHASE_B_GATHER_BYTES)
+
+
+def _phase_b_group_rows(b: int, ksel: int, bs: int,
+                        row_bytes: int) -> int:
+    """Query rows phase B rescores at once: the largest divisor of the
+    window's ``b`` rows whose (rows, ksel, bs, F) gather stays inside
+    ``_PHASE_B_GATHER_BYTES`` (all of them for every 8- and 32-wide
+    window up to k = 256 at 250f bfloat16; one row at least)."""
+    fit = max(1, _PHASE_B_GATHER_BYTES // (ksel * bs * row_bytes))
+    return next(g for g in range(min(b, fit), 0, -1) if b % g == 0)
+
+
+def _map_row_groups(fn, g: int, *xs):
+    """``fn`` over equal groups of ``g`` leading rows of every ``x``,
+    one group after the other inside the program (lax.map), its
+    results rejoined along the rows; ``fn(*xs)`` itself where one
+    group holds them all."""
+    b = xs[0].shape[0]
+    if g == b:
+        return fn(*xs)
+    out = jax.lax.map(lambda x: fn(*x), tuple(
+        x.reshape(b // g, g, *x.shape[1:]) for x in xs))
+    return jax.tree.map(lambda o: o.reshape(b, *o.shape[2:]), out)
+
+
+def _selects_row_major(b: int, ksel: int) -> bool:
+    """Whether phase B pins the block maxima to the row-major layout
+    before it selects from them.  The pallas phase A writes them as
+    (blocks, B), and XLA hands that array to the selection's TopK as
+    it lies: the query rows on the 128 lanes, of which an 8-wide
+    window fills 8, at a cost in proportion to ``ksel``.  Whole 8-wide
+    programs at 250f x 20M on a v5e (PERF.md PR 26): 18.1 / 22.1 /
+    30.8 ms at k = 32 / 64 / 128 as it lies, 14.2 / 14.6 / 15.6 ms
+    pinned (XLA makes the copy itself at ksel 512, either way 16.0).
+    From 128 rows on the lanes are full and the pin changes nothing
+    (256 x k = 64, two groups of 128: 45.8 ms both ways).  The floor
+    width keeps the layout it has had since these programs were first
+    measured: the k = 16 program is the one PR 26 was to leave as it
+    was (its control), and the same pin there (15.97 -> 14.02 ms) is
+    PERF.md section 7's next step."""
+    return b < 128 and ksel > _BLOCK_KSEL
 
 
 def _phase_b(Y, Qc, active, buckets, target, M, k: int, bs: int,
@@ -230,8 +322,23 @@ def _phase_b(Y, Qc, active, buckets, target, M, k: int, bs: int,
     ``ksel`` best 128-row blocks per query from the block maxima ``M``
     with approx_max_k, exactly rescore the gathered rows, and emit
     top-k plus the exactness certificate kth_score >= max(unselected
-    block maxima)."""
+    block maxima).  A window too wide for one gather
+    (_phase_b_group_rows) runs in equal row groups under lax.map:
+    static shapes, one program, rows independent of each other."""
+    g = _phase_b_group_rows(Qc.shape[0], ksel, bs, _row_bytes(Y))
+    xs = (Qc, M) if target is None else (Qc, M, target)
+    return _map_row_groups(
+        lambda q, m, t=None: _phase_b_rows(Y, q, active, buckets, t, m, k,
+                                           bs, ksel, max_bits), g, *xs)
+
+
+def _phase_b_rows(Y, Qc, active, buckets, target, M, k: int, bs: int,
+                  ksel: int, max_bits: int):
+    """Phase B for one group of query rows (the whole window where its
+    gather fits)."""
     b = Qc.shape[0]
+    if _selects_row_major(b, ksel):
+        M = with_layout_constraint(M, Layout(major_to_minor=(0, 1)))
     _, bi = jax.lax.approx_max_k(M, ksel, recall_target=_APPROX_RECALL)
     m_rest = M.at[jnp.arange(b)[:, None], bi].set(-jnp.inf).max(-1)
     # gathered blocks stay in the store dtype: phase B must reduce the
@@ -688,12 +795,17 @@ _I8_PENALTY = -(1 << 29)
 
 
 def _i8_ksel(ksel: int, n_rows: int, bs: int) -> int:
-    """Block-selection width for the int8 phase A: selection runs on
-    margin-inflated BOUNDS, so gather twice the blocks — the
-    certificate compares kth against the best unselected bound, and
-    the wider window buys back the margin's false-failure rate for
-    ~0.5 ms of extra gather.  Shared by the serving dispatch and the
-    kernel probe so published numbers time what serving runs."""
+    """Block-selection width for the int8 phase A (and IVF), given the
+    width ``_block_ksel`` chose for the fetch: twice that.  Selection
+    there runs on margin-inflated BOUNDS, so the best unselected BOUND
+    sits above the block maximum it stands for by the quantization
+    margin, on top of ``m_guard``'s; what decides the certificate is
+    still ``ksel`` against k first (_block_ksel's notes), and the
+    doubling moves the best unselected block from about item 2k to
+    about item 4k, buying back the bound's false-failure rate for
+    ~0.5 ms of extra gather at k = 16.  Shared by the serving dispatch
+    and the kernel probe so published numbers time what serving
+    runs."""
     return min(ksel * 2, max(1, n_rows // bs - 1))
 
 
@@ -1045,7 +1157,7 @@ class ALSServingModel(FactorModelBase, ServingModel):
         self._ivf_mirror_version: int = -1
         self._bucket_lock = threading.Lock()
         # observability: exact-scan recomputes forced by a failed
-        # two-phase certificate (expected ~0; see _APPROX_RECALL)
+        # two-phase certificate (expected ~0; see _block_ksel's notes)
         self.twophase_fallbacks = 0
 
     # -- known items ---------------------------------------------------------
@@ -1512,7 +1624,7 @@ class ALSServingModel(FactorModelBase, ServingModel):
         buckets = self._cached_buckets(vecs, version) if lsh_on else None
         big, chunk = _stream_plan(n_rows, b_pad)
         bs = _BLOCK_ROWS
-        ksel = min(_BLOCK_KSEL, n_rows // max(1, bs))
+        ksel = _block_ksel(k, n_rows, bs)
         if big and n_rows % chunk == 0 and k <= chunk:
             # streaming path: static window shapes from the ladder
             # (computed from the TRUE request count — a 257-query drain
@@ -1530,20 +1642,24 @@ class ALSServingModel(FactorModelBase, ServingModel):
             for size in sizes:
                 windows.append(jnp.asarray(Q[w:w + size]))
                 w += size
+            twophase = _twophase_admits(k, ksel, vecs, bs)
             if rec is not None:
                 # from the first program enqueued to the last result
                 # fetched: it waits on the device, and on whatever
-                # other drain the device is running
-                rec.mark("serving.scan", k=k, windows=sizes)
-            if n_rows % bs == 0 and 1 <= ksel < n_rows // bs \
-                    and k <= ksel * bs:
+                # other drain the device is running.  ``ksel`` is the
+                # width phase B selects (the int8 builds double it), 0
+                # where the exact scan is the primary path
+                rec.mark("serving.scan", k=k,
+                         ksel=ksel if twophase else 0, windows=sizes)
+            if twophase:
                 fetched = self._dispatch_twophase(
                     vecs, windows, active, version, buckets, hp, k,
                     chunk, bs, ksel, mb)
                 for w, (ts, ti, cert) in enumerate(fetched):
                     if not cert.all():
-                        # approx block selection missed a head block for
-                        # some row; recompute on the exact scan.  Count
+                        # a genuine miss (the margin, or a head block
+                        # the approx selection dropped) for some row;
+                        # recompute on the exact scan.  Count
                         # per certificate-failing row, under the lock —
                         # batcher dispatcher threads race on this gauge.
                         rows_failed = int((~cert).sum())
@@ -1571,7 +1687,7 @@ class ALSServingModel(FactorModelBase, ServingModel):
                     [Q, np.zeros((b_pad - n_req, Q.shape[1]), np.float32)])
             Qd = jnp.asarray(Q)
             if rec is not None:
-                rec.mark("serving.scan", k=k, windows=[b_pad])
+                rec.mark("serving.scan", k=k, ksel=0, windows=[b_pad])
             if lsh_on:
                 out_dev = _batch_top_n_lsh_kernel(
                     vecs, Qd, active, buckets,

@@ -257,7 +257,7 @@ def probe_model(model, batch: int = 256, how_many: int = 10,
         out["kernel_route"] = route
 
     bs = sm._BLOCK_ROWS
-    ksel = min(sm._BLOCK_KSEL, n_rows // max(1, bs))
+    ksel = sm._block_ksel(k, n_rows, bs)
     fold = sm._fold_eligible(int(vecs.shape[1]), model.features, bs) \
         if model._fold_enabled() else 1
     # standalone phase-B time PER SELECTION WIDTH: the int8 paths run
@@ -286,7 +286,7 @@ def probe_model(model, batch: int = 256, how_many: int = 10,
         out[name] = timing
 
     if big and n_rows % chunk == 0 and k <= chunk:
-        if n_rows % bs == 0 and 1 <= ksel < n_rows // bs and k <= ksel * bs:
+        if sm._twophase_admits(k, ksel, vecs, bs):
             # phase B standalone over synthetic block maxima (its cost
             # is value-independent: same approx_max_k + gather +
             # einsum), so every two-phase path's full time decomposes

@@ -118,10 +118,9 @@ def warm_serving_shapes(features: int, items: int, dtype: str,
 
     big, chunk = sm._stream_plan(cap, sm._CHUNKED_BATCH)
     bs = sm._BLOCK_ROWS
-    ksel = min(sm._BLOCK_KSEL, cap // max(1, bs))
+    ksel = sm._block_ksel(k, cap, bs)
     twophase_ok = (big and cap % chunk == 0 and k <= chunk
-                   and cap % bs == 0 and 1 <= ksel < cap // bs
-                   and k <= ksel * bs)
+                   and sm._twophase_admits(k, ksel, Y, bs))
     pallas_ok = twophase_ok and cap % sm._PA_TILE == 0
     fold = sm._fold_eligible(W, F, bs)
     tag = f"{F}f/{items}"

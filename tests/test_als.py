@@ -601,6 +601,18 @@ def test_bulk_load_exact_fit_capacity():
     assert cap - n < _LARGE_ALIGN
 
 
+def _exact_scan_only(monkeypatch):
+    """No gather fits, so the streaming branch admits no two-phase
+    program and the exact scan is its primary path."""
+    from oryx_tpu.app.als import serving_model as sm
+
+    def never(*args, **kwargs):
+        raise AssertionError("a two-phase program was dispatched")
+
+    monkeypatch.setattr(sm, "_PHASE_B_GATHER_BYTES", 0)
+    monkeypatch.setattr(ALSServingModel, "_dispatch_twophase", never)
+
+
 def test_top_n_batch_chunked_matches_flat(monkeypatch):
     from oryx_tpu.app.als import serving_model as sm
     rng = np.random.default_rng(3)
@@ -612,6 +624,7 @@ def test_top_n_batch_chunked_matches_flat(monkeypatch):
     flat = model.top_n_batch(6, Q)
     monkeypatch.setattr(sm, "_FLAT_SCORES_LIMIT", 1)
     monkeypatch.setattr(sm, "_MAX_CHUNK_ROWS", 256)
+    _exact_scan_only(monkeypatch)
     chunked = model.top_n_batch(6, Q)
     for f, c in zip(flat, chunked):
         assert [i for i, _ in f] == [i for i, _ in c]
@@ -648,6 +661,7 @@ def test_top_n_batch_chunked_lsh(monkeypatch):
     flat = model.top_n_batch(5, Q)
     monkeypatch.setattr(sm, "_FLAT_SCORES_LIMIT", 1)
     monkeypatch.setattr(sm, "_MAX_CHUNK_ROWS", 256)
+    _exact_scan_only(monkeypatch)
     chunked = model.top_n_batch(5, Q)
     for f, c in zip(flat, chunked):
         assert [i for i, _ in f] == [i for i, _ in c]
@@ -713,6 +727,217 @@ def test_top_n_batch_twophase_cert_fallback(monkeypatch):
     assert model.twophase_fallbacks >= 1
     for f, c in zip(want, got):
         assert [i for i, _ in f] == [i for i, _ in c]
+
+
+def _spy_on_scans(monkeypatch):
+    """Counts of two-phase dispatches and exact scans from here on."""
+    from oryx_tpu.app.als import serving_model as sm
+    seen = {"twophase": 0, "exact": 0}
+    real_two = ALSServingModel._dispatch_twophase
+    real_exact = sm._batch_top_n_chunked_kernel
+
+    def two(self, *args, **kwargs):
+        seen["twophase"] += 1
+        return real_two(self, *args, **kwargs)
+
+    def exact(*args, **kwargs):
+        seen["exact"] += 1
+        return real_exact(*args, **kwargs)
+
+    monkeypatch.setattr(ALSServingModel, "_dispatch_twophase", two)
+    monkeypatch.setattr(sm, "_batch_top_n_chunked_kernel", exact)
+    return seen
+
+
+@pytest.fixture(scope="module")
+def wide_fetch_model():
+    """8192 items in 1024 blocks of 8 rows once the ladder is forced:
+    room for the 512 blocks a fetch of 256 selects."""
+    rng = np.random.default_rng(26)
+    model = ALSServingModel(8, implicit=True)
+    model.Y.bulk_load([f"i{j}" for j in range(8192)],
+                      rng.standard_normal((8192, 8)).astype(np.float32))
+    return model
+
+
+@pytest.mark.parametrize("known, k", [(0, 16), (20, 32), (50, 64),
+                                      (100, 128), (240, 256)])
+def test_top_n_batch_twophase_certifies_every_fetched_width(
+        known, k, wide_fetch_model, monkeypatch):
+    """A user's known items widen the fetch (k = pad2(howMany + known)),
+    and the block selection widens with it: every width of the 20M
+    cells' traffic is certified by ONE two-phase program and equals the
+    flat exact kernel, where 32 blocks at k >= 64 never certified and
+    each such request ran the exact scan after it."""
+    from oryx_tpu.app.als import serving_model as sm
+    model = wide_fetch_model
+    rng = np.random.default_rng(k)
+    Q = rng.standard_normal((3, 8)).astype(np.float32)
+    exclude = [{f"i{j}" for j in rng.choice(8192, known, replace=False)},
+               set(), set()]
+    assert sm._pad_k(10 + known) == k
+    flat = model.top_n_batch(10, Q, exclude)
+    monkeypatch.setattr(sm, "_FLAT_SCORES_LIMIT", 1)
+    monkeypatch.setattr(sm, "_MAX_CHUNK_ROWS", 1024)
+    monkeypatch.setattr(sm, "_BLOCK_ROWS", 8)
+    seen = _spy_on_scans(monkeypatch)
+    before = model.twophase_fallbacks
+    two = model.top_n_batch(10, Q, exclude)
+    assert seen == {"twophase": 1, "exact": 0}
+    assert model.twophase_fallbacks == before
+    assert sm._block_ksel(k, 8192, 8) == max(32, 2 * k)
+    assert not {i for i, _ in two[0]} & exclude[0]
+    for f, c in zip(flat, two):
+        assert len(f) == 10
+        assert [i for i, _ in f] == [i for i, _ in c]
+        np.testing.assert_allclose([s for _, s in f], [s for _, s in c],
+                                   rtol=1e-5)
+
+
+def _store(n_rows):
+    """The aval of a 250-feature bfloat16 item store."""
+    import jax
+    import jax.numpy as jnp
+    return jax.ShapeDtypeStruct((n_rows, 250), jnp.bfloat16)
+
+
+@pytest.mark.parametrize("k, n_rows, bs, want", [
+    (8, 20054016, 128, 32),      # the floor
+    (16, 20054016, 128, 32),     # ... which k = 16 keeps
+    (32, 20054016, 128, 64),     # twice the fetch from there on
+    (256, 20054016, 128, 512),
+    (16, 4096, 128, 31),         # capped under the 32 blocks
+    (256, 8192, 64, 127),        # ... and then under k itself
+    (8, 128, 128, 0)])           # one block: nothing to leave out
+def test_block_ksel_is_twice_the_fetch_between_floor_and_cap(
+        k, n_rows, bs, want):
+    from oryx_tpu.app.als import serving_model as sm
+    assert sm._BLOCK_KSEL == 32
+    ksel = sm._block_ksel(k, n_rows, bs)
+    assert ksel == want
+    # the int8 builds double what they are given, under the same cap
+    assert sm._i8_ksel(ksel, n_rows, bs) \
+        == min(2 * ksel, max(1, n_rows // bs - 1))
+    # two-phase runs where the selection is as wide as the fetch
+    assert sm._twophase_admits(k, ksel, _store(n_rows), bs) == (want >= k)
+    assert not sm._twophase_admits(k, ksel, _store(n_rows + 1), bs)
+
+
+@pytest.mark.parametrize("b, ksel, want", [
+    (8, 32, False),      # the floor: the k = 16 program stays as it was
+    (8, 64, True), (32, 512, True),
+    (128, 64, False), (256, 128, False)])   # the lanes are full
+def test_narrow_windows_select_from_row_major_maxima(b, ksel, want):
+    from oryx_tpu.app.als import serving_model as sm
+    assert sm._selects_row_major(b, ksel) is want
+
+
+def test_a_fetch_wider_than_the_cap_goes_straight_to_the_exact_scan(
+        monkeypatch):
+    """Where the block count caps ksel under k the certificate is sure
+    to fail: such a fetch is ONE exact scan — no two-phase program
+    first, and no fallback counted."""
+    from oryx_tpu.app.als import serving_model as sm
+    rng = np.random.default_rng(27)
+    model = ALSServingModel(8, implicit=True)
+    model.Y.bulk_load([f"i{j}" for j in range(4096)],
+                      rng.standard_normal((4096, 8)).astype(np.float32))
+    Q = rng.standard_normal((3, 8)).astype(np.float32)
+    exclude = [{f"i{j}" for j in range(0, 400, 8)}, set(), set()]
+    flat = model.top_n_batch(10, Q, exclude)           # k = 64
+    narrow = model.top_n_batch(10, Q)                  # k = 16
+    monkeypatch.setattr(sm, "_FLAT_SCORES_LIMIT", 1)
+    monkeypatch.setattr(sm, "_MAX_CHUNK_ROWS", 1024)
+    monkeypatch.setattr(sm, "_BLOCK_ROWS", 64)         # 64 blocks
+    assert sm._block_ksel(64, 4096, 64) == 63
+    seen = _spy_on_scans(monkeypatch)
+    assert model.top_n_batch(10, Q, exclude) == flat
+    assert seen == {"twophase": 0, "exact": 1}
+    # the same store certifies the fetch the cap leaves room for
+    got = model.top_n_batch(10, Q)
+    assert seen == {"twophase": 1, "exact": 1}
+    assert [[i for i, _ in r] for r in got] \
+        == [[i for i, _ in r] for r in narrow]
+    assert model.twophase_fallbacks == 0
+
+
+def test_phase_b_groups_fit_the_gather_budget_at_250f_20m():
+    """No (window, k) of the ladder plans a phase-B gather over the
+    budget at 250 features x 20M bfloat16 rows — the int8 builds'
+    doubled width included — and every window that fits runs as ONE
+    group, i.e. the ungrouped program."""
+    from oryx_tpu.app.als import serving_model as sm
+    n_rows, bs = 20054016, 128
+    row_bytes = sm._row_bytes(_store(n_rows))
+    assert row_bytes == 500
+    budget = sm._PHASE_B_GATHER_BYTES
+    assert budget == 1 << 30
+    one_group = set()
+    for b in sm._WINDOW_LADDER:
+        for k in (16, 32, 64, 128, 256):
+            ksel = sm._block_ksel(k, n_rows, bs)
+            assert sm._twophase_admits(k, ksel, _store(n_rows), bs)
+            for width in (ksel, sm._i8_ksel(ksel, n_rows, bs)):
+                g = sm._phase_b_group_rows(b, width, bs, row_bytes)
+                assert b % g == 0 and g >= 1
+                assert g * width * bs * row_bytes <= budget
+                # the largest such group: twice as many rows would not fit
+                assert g == b or b % (2 * g) \
+                    or 2 * g * width * bs * row_bytes > budget
+                if g == b and width == ksel:
+                    one_group.add((b, k))
+    assert one_group == ({(8, k) for k in (16, 32, 64, 128, 256)}
+                         | {(32, k) for k in (16, 32, 64, 128, 256)}
+                         | {(256, 16), (256, 32)})
+    # a 256-wide window fetching 256 would gather 8.4 GB at once
+    assert sm._phase_b_group_rows(256, 512, bs, row_bytes) == 32
+    # one row over the budget still runs, a row at a time ...
+    assert sm._phase_b_group_rows(8, 1 << 20, bs, row_bytes) == 1
+    # ... but the dispatch does not admit it
+    assert not sm._twophase_admits(1 << 15, 1 << 16, _store(n_rows), bs)
+
+
+@pytest.mark.parametrize("lsh", [False, True])
+@pytest.mark.parametrize("rows_at_once, ksel", [(1, 32), (2, 32), (4, 32),
+                                                (2, 64)])
+def test_phase_b_in_row_groups_returns_what_one_gather_returns(
+        rows_at_once, ksel, lsh, monkeypatch):
+    """A window over the gather budget runs phase B in equal row
+    groups inside the same program; scores, indices and certificates
+    are those of the ungrouped program, bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from oryx_tpu.app.als import serving_model as sm
+
+    rng = np.random.default_rng(28)
+    n, f, b, k, bs = 4096, 8, 8, 16, 16
+    Y = jnp.asarray(rng.standard_normal((n, f)).astype(np.float32))
+    Q = jnp.asarray(rng.standard_normal((b, f)).astype(np.float32))
+    act = np.ones(n, bool)
+    act[::7] = False
+    active = jnp.asarray(act)
+    buckets = hp = None
+    if lsh:
+        hp = jnp.asarray(rng.standard_normal((4, f)).astype(np.float32))
+        buckets = sm._query_buckets(Y, hp)
+
+    def program():
+        # a fresh jit each time: the budget is read at trace time
+        return jax.device_get(jax.jit(
+            lambda: sm._batch_top_n_twophase_kernel.__wrapped__(
+                Y, Q, active, buckets, hp, k, 1024, bs, ksel, 2))())
+
+    whole = program()
+    assert sm._phase_b_group_rows(b, ksel, bs, f * 4) == b
+    monkeypatch.setattr(sm, "_PHASE_B_GATHER_BYTES",
+                        rows_at_once * ksel * bs * f * 4)
+    assert sm._phase_b_group_rows(b, ksel, bs, f * 4) == rows_at_once
+    grouped = program()
+    for w, g in zip(whole, grouped):
+        assert w.shape == g.shape and w.dtype == g.dtype
+        np.testing.assert_array_equal(w, g)
+    assert whole[2].all()
 
 
 def test_pallas_phase_a_interpret_agrees_with_scan_kernel():

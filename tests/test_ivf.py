@@ -219,6 +219,48 @@ def test_index_build_and_kernel_are_deterministic():
         np.testing.assert_array_equal(a, b)
 
 
+@pytest.mark.parametrize("rows_at_once", [1, 4])
+def test_ivf_rescore_in_row_groups_returns_what_one_gather_returns(
+        rows_at_once, monkeypatch):
+    """The IVF kernel's rescore gathers like phase B and is bounded like
+    it (``sm._PHASE_B_GATHER_BYTES``): a window over the budget runs in
+    row groups inside the program and returns the ungrouped answer."""
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(43)
+    n, features, cells, k, ksel = 2048, 16, 8, 10, 4
+    _, yp = _mixture(rng, n, features, cells)
+    Q = np.zeros((8, 128), np.float32)
+    Q[:, :features] = rng.standard_normal((8, features))
+    cfg = _cfg(cells, nprobe=4)
+    state = ivf.AnnState(
+        cfg, ivf.train_generation_centroids(yp[:, :features], cfg))
+    vecs = jnp.asarray(yp)
+    mirror = ivf.build_mirror(vecs, jnp.ones(n, bool), state, BS)
+
+    def program():
+        # jitted afresh: the budget is read when a program is traced
+        monkeypatch.setattr(ivf, "_ivf_top_n_kernel", jax.jit(
+            ivf._ivf_top_n_kernel.__wrapped__,
+            static_argnames=("k", "bs", "ksel", "nprobe", "pchunk")))
+        return jax.device_get(ivf.batch_top_n_ivf(
+            mirror, vecs, jnp.asarray(Q), k, BS, ksel, 4))
+
+    whole = program()
+    row_bytes = 128 * 4
+    assert sm._phase_b_group_rows(8, ksel, BS, row_bytes) == 8
+    monkeypatch.setattr(sm, "_PHASE_B_GATHER_BYTES",
+                        rows_at_once * ksel * BS * row_bytes)
+    assert sm._phase_b_group_rows(8, ksel, BS, row_bytes) == rows_at_once
+    grouped = program()
+    # the same rows with the same certificates; a one-row einsum may
+    # add its float32 products in another order than an 8-row one
+    np.testing.assert_allclose(whole[0], grouped[0], rtol=1e-6)
+    np.testing.assert_array_equal(whole[1], grouped[1])
+    np.testing.assert_array_equal(whole[2], grouped[2])
+
+
 # -- warmup lock-step (satellite 3) -------------------------------------------
 
 def test_mirror_shapes_lockstep_with_build_and_warmup_ladder():

@@ -136,13 +136,45 @@ def test_phases_land_under_each_jobs_device_execute(branch, model, traced,
         assert total <= execute["duration_ms"] + 0.002
         # the counts known at the boundary where each phase begins
         assert spans["serving.prepare"][0]["attrs"] == {"rows": 3}
-        assert spans["serving.scan"][0]["attrs"] == {"k": 8,
-                                                     "windows": [8]}
+        # ... the width phase B selects among them: twice the fetch on
+        # the ladder, 0 where no block is selected (the flat kernel)
+        assert spans["serving.scan"][0]["attrs"] == {
+            "k": 8, "ksel": 16 if branch == "ladder" else 0,
+            "windows": [8]}
         assert spans["serving.decode"][0]["attrs"] == {"rows": 3}
         stamps.add(tuple((spans[n][0]["start_ms"],
                           spans[n][0]["duration_ms"]) for n in PHASES))
     # recorded once, replayed three times: the same stamps under each job
     assert len(stamps) == 1
+
+
+@pytest.mark.parametrize("known, k, ksel", [
+    (0, 8, 16), (10, 16, 32),
+    (20, 32, 63),   # 2k, capped under the toy store's 64 blocks
+    (50, 64, 0)])   # the cap leaves ksel < k: the exact scan, alone
+def test_the_scan_span_says_which_width_ran(known, k, ksel, model, traced,
+                                            ladder, monkeypatch):
+    """``serving.scan`` carries the block-selection width the fetched k
+    chose, and a wide fetch is one scan with no fallback after it."""
+    exact = []
+    real = sm._batch_top_n_chunked_kernel
+    monkeypatch.setattr(sm, "_batch_top_n_chunked_kernel",
+                        lambda *a, **kw: exact.append(1) or real(*a, **kw))
+    batcher, tracer = traced
+    req = tracer.begin_request("serving.request")
+    tracer._swap(None)
+    before = model.twophase_fallbacks
+    job = _Job(model, 5, _vectors(1)[0], {f"i{j}" for j in range(known)},
+               trace_ctx=(req.trace_id, req.span_id))
+    assert batcher._dispatch([job]) == 1
+    assert job.error is None and len(job.result) == 5
+    assert model.twophase_fallbacks == before
+    assert len(exact) == (1 if ksel == 0 else 0)
+    spans = _by_name(tracer.spans_for(req.trace_id))
+    assert "serving.fallback" not in spans
+    assert spans["serving.scan"][0]["attrs"] == {"k": k, "ksel": ksel,
+                                                 "windows": [8]}
+    assert ksel == 0 or ksel == sm._block_ksel(k, ITEMS, 64)
 
 
 @pytest.mark.parametrize("failing, widths", [("tail", [8]),
@@ -489,13 +521,31 @@ def test_each_scan_program_is_jitted_under_its_own_name(name, pattern):
     assert ("twophase" in name) != ("chunked_kernel" in name)
 
 
-def test_the_lowered_programs_carry_the_names_the_trace_shows():
-    Y = jnp.zeros((512, FEATURES), jnp.float32)
+@pytest.mark.parametrize("k, rows_at_once", [(8, 8), (64, 8), (64, 2)])
+def test_the_lowered_programs_carry_the_names_the_trace_shows(
+        k, rows_at_once, monkeypatch):
+    """... for the widths a wide fetch selects too, and where phase B
+    runs a window in row groups: the groups are a loop INSIDE the one
+    two-phase program, not programs of their own."""
+    # a shape nothing else traces: the budget is read when a program
+    # is traced, and a cached trace would keep the one it was made with
+    rows, bs = 16384 + 256 * (k + rows_at_once), 8
+    ksel = sm._block_ksel(k, rows, bs)
+    assert ksel == max(sm._BLOCK_KSEL, 2 * k)
+    monkeypatch.setattr(sm, "_PHASE_B_GATHER_BYTES",
+                        rows_at_once * ksel * bs * FEATURES * 4)
+    Y = jnp.zeros((rows, FEATURES), jnp.float32)
     Q = jnp.zeros((8, FEATURES), jnp.float32)
-    active = jnp.ones((512,), bool)
+    active = jnp.ones((rows,), bool)
     two = sm._batch_top_n_twophase_kernel.lower(
-        Y, Q, active, None, None, 8, 256, 64, 4, 0)
+        Y, Q, active, None, None, k, 256, bs, ksel, 0)
     assert "@jit__batch_top_n_twophase_kernel" in two.as_text()[:200]
+    # the floor width's program is as it was; a wider selection reads
+    # the block maxima row-major (``_selects_row_major``)
+    assert ("@LayoutConstraint" in two.as_text()) == (k == 64)
+    # phase A's lax.scan is one loop, the row groups a second
+    assert two.as_text().count("stablehlo.while") \
+        == (1 if rows_at_once == 8 else 2)
     exact = sm._batch_top_n_chunked_kernel.lower(
-        Y, Q, active, None, None, 8, 256, 0)
+        Y, Q, active, None, None, k, 256, 0)
     assert "@jit__batch_top_n_chunked_kernel" in exact.as_text()[:200]
