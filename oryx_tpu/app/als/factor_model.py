@@ -14,6 +14,7 @@ from typing import Callable, Sequence
 
 import numpy as np
 
+from ...obs import trace as obstrace
 from ...ops.solver import Solver, SingularMatrixSolverException, get_solver
 from .feature_vectors import FeatureVectorStore
 
@@ -28,8 +29,14 @@ class SolverCache:
     blocking first get, non-blocking maybe-stale get thereafter.
     """
 
-    def __init__(self, vtv_supplier: Callable[[], np.ndarray]):
+    def __init__(self, vtv_supplier: Callable[[], np.ndarray],
+                 what: str = ""):
         self._supplier = vtv_supplier
+        # which Gramian this is ("X^T X", "Y^T Y"), for spans and logs
+        self.what = what
+        # solvers built, and why the last attempt gave none
+        self.rebuilds = 0
+        self.last_failure: str | None = None
         self._solver: Solver | None = None
         self._dirty = True
         self._in_flight = False
@@ -39,7 +46,9 @@ class SolverCache:
         with self._cond:
             self._dirty = True
 
-    def compute_now(self) -> None:
+    def compute_now(self, parent=None) -> None:
+        """``parent``: the span this rebuild is recorded under when it
+        runs on a thread of its own (``compute_async``)."""
         with self._cond:
             if self._in_flight:
                 # another thread is computing; wait for that attempt
@@ -51,16 +60,25 @@ class SolverCache:
             # solve re-marks it and the next get() recomputes, so updates
             # arriving mid-solve are never lost
             self._dirty = False
-        solver = None
+        solver = failure = None
         try:
-            vtv = self._supplier()
-            try:
-                solver = get_solver(vtv)
-            except SingularMatrixSolverException:
-                solver = None
+            # one span per rebuild: the Gramian (a scan of the store the
+            # first time, corrections for the rows written since after
+            # it: FeatureVectorStore.vtv) and its factorisation
+            with obstrace.phase("speed.gramian", parent=parent,
+                                what=self.what):
+                try:
+                    solver = get_solver(self._supplier())
+                except SingularMatrixSolverException as e:
+                    failure = f"singular: {e}"
+                except Exception as e:  # noqa: BLE001 — named, re-raised
+                    failure = f"{type(e).__name__}: {e}"
+                    raise
         finally:
             with self._cond:
+                self.last_failure = failure
                 if solver is not None:
+                    self.rebuilds += 1
                     self._solver = solver
                 self._in_flight = False
                 self._cond.notify_all()
@@ -69,7 +87,9 @@ class SolverCache:
         with self._cond:
             if self._in_flight or not self._dirty:
                 return
-        threading.Thread(target=self.compute_now, daemon=True).start()
+        # under the caller's span: the micro-batch that asked
+        threading.Thread(target=self.compute_now,
+                         args=(obstrace.open_span(),), daemon=True).start()
 
     def get(self, blocking: bool = True) -> Solver | None:
         """Current solver, recomputing synchronously when dirty and
@@ -90,9 +110,28 @@ class FactorModelBase:
     """X/Y stores + expected-ID accounting + cached solvers."""
 
     def __init__(self, features: int, implicit: bool, dtype="float32",
-                 item_sharding=None):
+                 item_sharding=None, resident: "FactorModelBase | None" = None):
+        """``resident``: another model of this process whose state this
+        one SHARES instead of holding its own — the speed model of a
+        co-located speed + serving process folds in against the stores
+        the serving model serves (one copy of the catalog on the chip,
+        one host mirror, one pair of solver caches), and the serving
+        layer's update consumer is the only writer."""
         self.features = features
         self.implicit = implicit
+        self.resident = resident
+        if resident is not None:
+            if resident.features != features:
+                raise ValueError(
+                    f"the resident model has {resident.features} "
+                    f"features, not {features}")
+            self.X, self.Y = resident.X, resident.Y
+            self._expected_user_ids = resident._expected_user_ids
+            self._expected_item_ids = resident._expected_item_ids
+            self._expected_lock = resident._expected_lock
+            self.cached_xtx_solver = resident.cached_xtx_solver
+            self.cached_yty_solver = resident.cached_yty_solver
+            return
         self.X = FeatureVectorStore(features, dtype=dtype)
         # item matrix optionally row-sharded over a device mesh — the
         # serving capacity mode past one chip's HBM (P4/P5)
@@ -101,8 +140,8 @@ class FactorModelBase:
         self._expected_user_ids: set[str] = set()
         self._expected_item_ids: set[str] = set()
         self._expected_lock = threading.Lock()
-        self.cached_xtx_solver = SolverCache(self.X.vtv)
-        self.cached_yty_solver = SolverCache(self.Y.vtv)
+        self.cached_xtx_solver = SolverCache(self.X.vtv, "X^T X")
+        self.cached_yty_solver = SolverCache(self.Y.vtv, "Y^T Y")
 
     # -- vectors ------------------------------------------------------------
 
@@ -118,8 +157,9 @@ class FactorModelBase:
         with self._expected_lock:
             self._expected_user_ids.discard(user_id)
 
-    def set_item_vector(self, item_id: str, vector: np.ndarray) -> None:
-        self.Y.set_vector(item_id, vector)
+    def set_item_vector(self, item_id: str, vector: np.ndarray,
+                        tag=None) -> None:
+        self.Y.set_vector(item_id, vector, tag=tag)
         self.cached_yty_solver.set_dirty()
         with self._expected_lock:
             self._expected_item_ids.discard(item_id)
@@ -174,6 +214,8 @@ class FactorModelBase:
         self.cached_yty_solver.set_dirty()
 
     def get_fraction_loaded(self) -> float:
+        if self.resident is not None:
+            return self.resident.get_fraction_loaded()
         with self._expected_lock:
             expected = len(self._expected_user_ids) + len(self._expected_item_ids)
         loaded = len(self.X) + len(self.Y)

@@ -9,17 +9,33 @@ serving-time sharded matrix).
 TPU-native design (the "dynamic ID universe on a static-shape device"
 hard part): IDs live in a host dict mapping to rows of a padded device
 array.  Single-row "UP" mutations write a host mirror and enqueue the
-row; the device copy is refreshed lazily at the next read — a batched
-scatter for few dirty rows, a full re-upload when many changed — so
-serving reads always see a consistent device snapshot and per-event
-device dispatch never happens.  Removed rows are zeroed and recycled via
-a free list; capacity grows by doubling.
+row; the device copy is refreshed lazily at the next read — the dirty
+rows scattered IN PLACE into the resident array by a jitted program that
+donates it (no second copy of the store ever exists: at 20M x 250 the
+store is 10 of the chip's 16 GB), a full re-upload when half the rows
+changed — so serving reads always see a consistent device snapshot and
+per-event device dispatch never happens.  Removed rows are zeroed and
+recycled via a free list; capacity grows by doubling.
+
+The ordering rule between readers and syncs (``dispatching``): a sync
+donates the resident arrays, which deletes the Python handles of the
+version before it.  Syncs and readers are therefore ordered under one
+lock, the store's DISPATCH lock: a reader holds it from the moment it
+fetches ``(vecs, active, version)`` until it has ENQUEUED every program
+that reads them — not until their results arrive: the device runs
+programs in the order they were enqueued, so a scatter enqueued later
+waits for them — and a sync happens only inside that lock, between two
+readers' enqueues.  A handle fetched under the lock is never used after
+the lock is released.
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import threading
-from typing import Callable, Iterable
+from functools import partial
+from typing import Callable, Iterable, Iterator, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -27,11 +43,19 @@ import numpy as np
 
 from ...common.lang import AutoReadWriteLock
 
-__all__ = ["FeatureVectorStore", "resolve_dtype"]
+__all__ = ["FeatureVectorStore", "DeviceSnapshot", "resolve_dtype"]
 
 # above this fraction of dirty rows, re-upload the whole array instead of
 # scattering individual rows
 _FULL_UPLOAD_FRACTION = 0.5
+
+# the most rows one in-place scatter carries; more go in several.  Row
+# counts are padded up to a power of two (floor 8) by repeating a row,
+# so a store compiles at most this ladder of scatter programs
+_SYNC_MAX_ROWS = 4096
+
+# how many syncs back ``rows_changed_since`` can answer
+_SYNC_LOG = 256
 
 # beyond this many rows, capacity is rounded to a multiple of this chunk
 # instead of the next power of two: a 20M-item model must not allocate a
@@ -72,6 +96,45 @@ def resolve_dtype(name) -> np.dtype:
     if name in ("float32", "f32"):
         return np.dtype(np.float32)
     raise ValueError(f"unsupported factor dtype: {name}")
+
+
+class DeviceSnapshot(NamedTuple):
+    """What ``FeatureVectorStore.dispatching`` hands a reader."""
+
+    vecs: jax.Array
+    active: jax.Array
+    # bumped by every sync; the cache key of state derived from ``vecs``
+    # (state derived from ``active`` alone hangs on the handle itself:
+    # a sync that changes vectors only leaves it as it is)
+    version: int
+    # rows this acquisition's own sync wrote to the device (0: none
+    # were pending), the bytes they came to, and the tags writers
+    # attached to them (``set_vector(tag=...)``)
+    synced_rows: int
+    synced_bytes: int
+    tags: tuple
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _scatter_rows(resident, rows, values):
+    """Write ``values`` at ``rows`` of a resident array, which is
+    donated: XLA aliases the output to it and touches only the rows
+    named (the compiled program holds no temporary; a duplicate row
+    carries the same value twice)."""
+    return resident.at[rows].set(values)
+
+
+@jax.jit
+def _gramian(vecs):
+    """V^T V, float32 accumulation, contracting the row axis of the
+    array as it lies: no transpose is materialised (an eager ``vecs.T``
+    was a second copy of the store)."""
+    return jax.lax.dot_general(vecs, vecs, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _sync_bucket(n: int) -> int:
+    return max(8, 1 << (n - 1).bit_length())
 
 
 class FeatureVectorStore:
@@ -122,16 +185,40 @@ class FeatureVectorStore:
         self._host = np.zeros((cap, features), dtype=self.dtype)
         self._active = np.zeros(cap, dtype=bool)
         self._dirty: set[int] = set()
-        self._device: jax.Array | None = None
-        self._device_active: jax.Array | None = None
+        # both written under _lock.write() only (an AutoReadWriteLock,
+        # which the guarded-by lint does not model); _device_lock, held
+        # around some of those writes, ORDERS readers and syncs
+        self._device: jax.Array | None = None  # guarded-by: none — see above
+        self._device_active: jax.Array | None = None  # guarded-by: none — see above
         self._device_version = 0
+        # a write made a row live or retired one since the last sync
+        self._active_dirty = False
+        # opaque marks of the writers whose rows wait in _dirty
+        self._dirty_tags: set = set()
+        # (version, rows) of the latest syncs, for rows_changed_since
+        self._sync_log: collections.deque = collections.deque(
+            maxlen=_SYNC_LOG)
+        # counters: device syncs made and rows they carried
+        self.device_syncs = 0
+        self.rows_synced = 0
+        # V^T V kept current by corrections: the Gramian of the rows as
+        # they were at _vtv_base's moment, and the stored value each row
+        # written since had then (first overwrite only)
+        self._vtv_base: np.ndarray | None = None
+        self._vtv_tracking = False
+        self._vtv_old: dict[int, np.ndarray] = {}
+        self.gramian_scans = 0
         self._recent: set[str] = set()
         self._lock = AutoReadWriteLock()
+        # the dispatch lock (module docstring): syncs and readers'
+        # enqueues, one at a time.  Taken BEFORE _lock, never inside it
+        self._device_lock = threading.RLock()
         # row->id snapshot cache for the serving hot path; invalidated
         # by bumping _mutations under the write lock
         self._mutations = 0
-        self._row_ids_cache: list[str | None] | None = None
-        self._row_ids_mutations = -1
+        # (mutation count it was copied at, the copy): one tuple, so
+        # that row_ids() can read both without the lock
+        self._row_ids_cache: tuple[int, list[str | None]] | None = None
 
     # -- basic map ops ------------------------------------------------------
 
@@ -164,7 +251,9 @@ class FeatureVectorStore:
         with self._lock.read():
             return self._row_to_id[row] if 0 <= row < len(self._row_to_id) else None
 
-    def set_vector(self, id_: str, vector: np.ndarray) -> None:
+    def set_vector(self, id_: str, vector: np.ndarray, tag=None) -> None:
+        """``tag``, if given, comes back in ``DeviceSnapshot.tags`` of
+        the sync that carries this row to the device."""
         vector = np.asarray(vector, dtype=np.float32)
         with self._lock.write():
             row = self._id_to_row.get(id_)
@@ -175,10 +264,21 @@ class FeatureVectorStore:
                 self._id_to_row[id_] = row
                 self._row_to_id[row] = id_
                 self._mutations += 1
+            self._overwriting(row)
             self._host[row] = vector
-            self._active[row] = True
+            if not self._active[row]:
+                self._active[row] = True
+                self._active_dirty = True
             self._dirty.add(row)
+            if tag is not None:
+                self._dirty_tags.add(tag)
             self._recent.add(id_)
+
+    def _overwriting(self, row: int) -> None:
+        """Before a single row's stored value changes (write lock held):
+        keep what V^T V was computed from, once per row."""
+        if self._vtv_tracking and row not in self._vtv_old:
+            self._vtv_old[row] = self._host[row].astype(np.float32)
 
     def bulk_load(self, ids: list[str], matrix: np.ndarray) -> None:
         """Set many vectors at once — the fast path for MODEL publish
@@ -210,8 +310,10 @@ class FeatureVectorStore:
                 rows[j] = row
             self._host[rows] = matrix
             self._active[rows] = True
+            self._active_dirty = True
             self._dirty.update(rows.tolist())
             self._recent.update(ids)
+            self._vtv_forget()
 
     def remove(self, id_: str) -> None:
         with self._lock.write():
@@ -219,8 +321,10 @@ class FeatureVectorStore:
             if row is not None:
                 self._row_to_id[row] = None
                 self._mutations += 1
+                self._overwriting(row)
                 self._host[row] = 0.0
                 self._active[row] = False
+                self._active_dirty = True
                 self._dirty.add(row)
                 self._free.append(row)
 
@@ -242,8 +346,11 @@ class FeatureVectorStore:
                 self._mutations += 1
                 self._host[row] = 0.0
                 self._active[row] = False
+                self._active_dirty = True
                 self._dirty.add(row)
                 self._free.append(row)
+            # a model swap drops rows by the thousand: scan again
+            self._vtv_forget()
             self._recent.clear()
 
     def reserve(self, n_rows: int) -> None:
@@ -291,20 +398,53 @@ class FeatureVectorStore:
     # -- device snapshot ----------------------------------------------------
 
     def device_arrays(self) -> tuple[jax.Array, jax.Array]:
-        """(vectors, active_mask) on device, syncing pending host writes.
-
-        Few dirty rows -> one batched scatter; many -> full upload.
-        """
+        """(vectors, active_mask) on device, syncing pending host writes;
+        see ``device_arrays_versioned`` for how long they stay valid."""
         vecs, active, _ = self.device_arrays_versioned()
         return vecs, active
 
     def device_arrays_versioned(self) -> tuple[jax.Array, jax.Array, int]:
         """Like device_arrays but also returns the snapshot's version,
         read atomically under the same lock — the safe cache key for
-        derived device state (e.g. LSH buckets)."""
+        derived device state (e.g. LSH buckets).
+
+        The handles are valid until the NEXT sync, which donates them:
+        a caller that can run while another thread writes to the store
+        (every serving path) fetches them inside ``dispatching()``
+        instead and enqueues its programs before leaving it.  This
+        form is for callers that own the store (loads, tests, tools)."""
+        with self._device_lock:
+            snap = self._synced()
+        return snap.vecs, snap.active, snap.version
+
+    @contextlib.contextmanager
+    def dispatching(self, track_vtv: bool = False
+                    ) -> Iterator[DeviceSnapshot]:
+        """The resident arrays, pending rows applied, with the dispatch
+        lock held while the caller ENQUEUES the programs that read them
+        (module docstring: the ordering rule).  Fetch results after the
+        block, not in it: the lock is what every other reader and every
+        sync waits on.  Reentrant on one thread."""
+        with self._device_lock:
+            yield self._synced(track_vtv)
+
+    def pending_rows(self) -> int:
+        """Rows written since the last sync that the next one will
+        scatter (unlocked: a hint); 0 while nothing is resident yet,
+        because the first upload is a load, not an update."""
+        return len(self._dirty) if self._device is not None else 0
+
+    def _synced(self, track_vtv: bool = False) -> DeviceSnapshot:
+        """Apply what is pending (dispatch lock held) and describe the
+        resident arrays."""
         with self._lock.write():
             cap = len(self._row_to_id)
-            if self._device is None or len(self._dirty) >= cap * _FULL_UPLOAD_FRACTION:
+            n_rows = n_bytes = 0
+            tags: tuple = ()
+            if self._device is None \
+                    or len(self._dirty) >= cap * _FULL_UPLOAD_FRACTION:
+                # drop the old copy first: both do not fit at 20M x 250
+                self._device = self._device_active = None
                 host = self._pad_cols(self._host)
                 if self._sharding is not None:
                     self._device = jax.device_put(host, self._sharding)
@@ -314,19 +454,89 @@ class FeatureVectorStore:
                     self._device = jnp.asarray(host)
                     self._device_active = jnp.asarray(self._active)
                 self._device_version += 1
+                self._sync_log.clear()  # no row list for a whole upload
+                n_rows, n_bytes = cap, int(host.nbytes)
             elif self._dirty:
-                # batched scatter of just the dirty rows; on a sharded
-                # snapshot GSPMD partitions this onto the row-sharded
-                # operand with replicated updates — no collectives, no
-                # full re-upload (verified against the compiled HLO)
-                rows = np.fromiter(self._dirty, dtype=np.int32)
-                self._device = self._device.at[rows].set(
-                    jnp.asarray(self._pad_cols(self._host[rows])))
-                self._device_active = self._device_active.at[rows].set(
-                    jnp.asarray(self._active[rows]))
+                rows = np.fromiter(self._dirty, dtype=np.int32,
+                                   count=len(self._dirty))
+                self._scatter(rows)
                 self._device_version += 1
+                self._sync_log.append((self._device_version, rows))
+                n_rows = len(rows)
+                n_bytes = n_rows * self.device_features \
+                    * self.dtype.itemsize
+            if n_rows:
+                self.device_syncs += 1
+                self.rows_synced += n_rows
+                tags = tuple(self._dirty_tags)
+                self._dirty_tags.clear()
             self._dirty.clear()
-            return self._device, self._device_active, self._device_version
+            self._active_dirty = False
+            if track_vtv:
+                # the Gramian about to be scanned is of the rows as they
+                # are now: corrections count from here
+                self._vtv_old = {}
+                self._vtv_tracking = True
+            return DeviceSnapshot(self._device, self._device_active,
+                                  self._device_version, n_rows, n_bytes,
+                                  tags)
+
+    def _scatter(self, rows: np.ndarray) -> None:
+        """The dirty ``rows`` of the host mirror into the resident
+        arrays, in place (both locks held).  On a sharded snapshot GSPMD
+        partitions the scatter onto the row-sharded operand with
+        replicated updates — no collectives, no full re-upload."""
+        for at in range(0, len(rows), _SYNC_MAX_ROWS):
+            part = rows[at:at + _SYNC_MAX_ROWS]
+            pad = _sync_bucket(len(part)) - len(part)
+            if pad:
+                part = np.concatenate([part, np.repeat(part[:1], pad)])
+            self._device = _scatter_rows(
+                self._device, part, self._pad_cols(self._host[part]))
+            if self._active_dirty:
+                # the mask only where a row came to life or was retired:
+                # its handle, and what is derived from it alone, outlive
+                # every sync that changes vectors only
+                self._device_active = _scatter_rows(
+                    self._device_active, part, self._active[part])
+
+    def warm_sync(self, max_rows: int = _SYNC_MAX_ROWS) -> int:
+        """Compile (and run, rewriting row 0 with itself) every scatter
+        program a sync of up to ``max_rows`` rows can need, so that the
+        first updates of a serving model compile nothing.  Returns how
+        many programs ran."""
+        n = 0
+        with self._device_lock:
+            self._synced()
+            with self._lock.write():
+                if self._device is None:
+                    return 0
+                bucket = 8
+                while bucket <= min(_sync_bucket(max(1, max_rows)),
+                                    _SYNC_MAX_ROWS):
+                    rows = np.zeros(bucket, dtype=np.int32)
+                    self._device = _scatter_rows(
+                        self._device, rows,
+                        self._pad_cols(self._host[rows]))
+                    self._device_active = _scatter_rows(
+                        self._device_active, rows, self._active[rows])
+                    bucket *= 2
+                    n += 1
+        return n
+
+    def rows_changed_since(self, version: int) -> np.ndarray | None:
+        """The rows that syncs wrote after device version ``version``
+        up to the current one, each once; None when that is not known
+        (a whole upload in between, or further back than the log):
+        whoever derives state from the matrix then rebuilds it."""
+        with self._lock.read():
+            if version == self._device_version:
+                return np.zeros(0, dtype=np.int32)
+            parts = [rows for v, rows in self._sync_log if v > version]
+            if not self._sync_log or self._sync_log[0][0] > version + 1 \
+                    or len(parts) != self._device_version - version:
+                return None
+        return np.unique(np.concatenate(parts))
 
     @property
     def device_version(self) -> int:
@@ -341,12 +551,22 @@ class FeatureVectorStore:
         Cached against the mutation counter: the serving hot path calls
         this once per device dispatch, and copying a 20M-entry table per
         request batch would cost more than the scoring itself."""
+        # No lock while no id was added or removed since the copy: this
+        # is the LAST thing a drain does before its requests are
+        # answered, and the lock prefers writers, so behind an update
+        # consumer applying a micro-batch of UP records (a write lock a
+        # record) a reader waited 4-12 ms here (PERF.md, PR 27).  A
+        # stale count means a writer is ahead of us, which is what a
+        # reader that took the lock a moment earlier would have seen.
+        cache = self._row_ids_cache
+        if cache is not None and cache[0] == self._mutations:
+            return cache[1]
         with self._lock.read():
-            if self._row_ids_cache is None \
-                    or self._row_ids_mutations != self._mutations:
-                self._row_ids_cache = list(self._row_to_id)
-                self._row_ids_mutations = self._mutations
-            return self._row_ids_cache
+            cache = self._row_ids_cache
+            if cache is None or cache[0] != self._mutations:
+                cache = (self._mutations, list(self._row_to_id))
+                self._row_ids_cache = cache
+            return cache[1]
 
     def host_arrays(self) -> tuple[np.ndarray, np.ndarray, list[str | None]]:
         """Copy of (vectors, active, row->id) for host-side iteration."""
@@ -361,13 +581,55 @@ class FeatureVectorStore:
         return out
 
     def vtv(self) -> np.ndarray:
-        """V^T V over live vectors — one device matmul (inactive rows are
-        zero and contribute nothing; device lane-padding columns are
-        zero and sliced off). Reference: FeatureVectors.getVTV."""
-        vecs, _ = self.device_arrays()
-        out = np.asarray(jnp.matmul(vecs.T, vecs,
-                                    preferred_element_type=jnp.float32))
-        return out[:self.features, :self.features]
+        """V^T V over live vectors (inactive rows are zero and
+        contribute nothing; device lane-padding columns are zero and
+        sliced off).  Reference: FeatureVectors.getVTV.
+
+        The first call reads the resident array once, in place, in one
+        device matmul.  From then on single-row writes are followed by
+        corrections on the host — plus the outer products of the rows'
+        new stored values, minus those of the values the scan saw — so
+        a micro-batch of updates costs a (rows x k) matmul, not another
+        read of the store; a bulk load or a model swap scans again."""
+        with self._lock.write():
+            base = self._vtv_base
+            rows = np.fromiter(self._vtv_old, dtype=np.int64,
+                               count=len(self._vtv_old))
+            if base is not None and len(rows):
+                old = np.stack([self._vtv_old[r] for r in rows.tolist()])
+                new = self._host[rows].astype(np.float32)
+                self._vtv_old = {}
+        if base is None:
+            with self.dispatching(track_vtv=True) as snap:
+                out = _gramian(snap.vecs)
+            k = self.features
+            base = np.asarray(out)[:k, :k].astype(np.float64)
+            with self._lock.write():
+                self.gramian_scans += 1
+                if self._vtv_tracking:  # no bulk load came in between
+                    self._vtv_base = base
+                    pending = bool(self._vtv_old)
+                else:
+                    pending = False
+            return self.vtv() if pending else base.astype(np.float32)
+        if len(rows):
+            # products of stored values are exact in float32
+            delta = (new.T @ new).astype(np.float64) \
+                - (old.T @ old).astype(np.float64)
+            with self._lock.write():
+                if self._vtv_base is not None:
+                    self._vtv_base = self._vtv_base + delta
+                    base = self._vtv_base
+                else:
+                    base = base + delta
+        return base.astype(np.float32)
+
+    def _vtv_forget(self) -> None:
+        """Many rows changed at once (write lock held): the next
+        ``vtv()`` scans the store again."""
+        self._vtv_base = None
+        self._vtv_tracking = False
+        self._vtv_old = {}
 
     def map_vectors(self, fn: Callable[[str, np.ndarray], None]) -> None:
         host, active, row_ids = self.host_arrays()

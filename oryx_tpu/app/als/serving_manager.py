@@ -10,6 +10,7 @@ loadRescorerProviders (:142-160).
 
 from __future__ import annotations
 
+import gc
 import logging
 import time
 
@@ -21,7 +22,7 @@ from ...cluster.sharding import is_local_item, parse_shard_spec
 from ...common import pmml as pmml_io
 from ...common import store
 from ...common.config import Config
-from ...common.lang import RateLimitCheck
+from ...common.lang import BackgroundShare, RateLimitCheck
 from ...kafka.api import KEY_MODEL, KEY_MODEL_REF, KEY_UP
 from ..pmml_utils import read_pmml_from_update_key_message
 from . import common as als_common
@@ -33,6 +34,19 @@ from .serving_model import ALSServingModel
 _log = logging.getLogger(__name__)
 
 __all__ = ["ALSServingModelManager"]
+
+
+# an UP record published at most this long ago is part of a live stream
+_LIVE_MS = 10_000
+
+
+def _is_live(headers: dict | None) -> bool:
+    """Whether a record's ``ts`` header (its publish time, epoch ms; the
+    speed layer stamps it) is recent; False without one."""
+    try:
+        return time.time() * 1000 - int(headers["ts"]) < _LIVE_MS
+    except (TypeError, KeyError, ValueError):
+        return False
 
 
 class ALSServingModelManager(AbstractServingModelManager):
@@ -74,6 +88,13 @@ class ALSServingModelManager(AbstractServingModelManager):
         # refused instead of absorbing into the serving model
         self.rejected_updates = 0
         self.rejected_models = 0
+        # UP records applied to the model
+        self.updates_applied = 0
+        # headers of the record being consumed (consume), else None
+        self._headers: dict | None = None
+        # the consumer thread's share of the interpreter once the model
+        # serves (consume)
+        self._pace = BackgroundShare()
         # -- serving-cluster state (oryx_tpu/cluster/) -------------------
         # catalog shard this replica materializes: Y vectors whose id
         # hashes elsewhere are skipped (the user store and known-items
@@ -134,6 +155,27 @@ class ALSServingModelManager(AbstractServingModelManager):
     def get_model(self) -> ALSServingModel | None:
         return self.model
 
+    def consume(self, updates) -> None:
+        """As the base class, with each record's headers at hand: an UP
+        record's ``batch`` header (the speed layer's micro-batch number)
+        tags the item rows it writes, and comes back from the store with
+        the device sync that makes them servable."""
+        for km in updates:
+            self._headers = km.headers
+            try:
+                if km.key == KEY_UP and self._triggered_solver \
+                        and _is_live(km.headers):
+                    # a live model taking a live stream: request
+                    # threads share the interpreter with this one.  A
+                    # load (before the trigger) and a backlog (a replay,
+                    # or a consumer that fell behind) run flat out
+                    with self._pace.work():
+                        self.consume_key_message(km.key, km.message)
+                else:
+                    self.consume_key_message(km.key, km.message)
+            finally:
+                self._headers = None
+
     def consume_key_message(self, key: str | None, message: str) -> None:
         if key == KEY_UP:
             model = self.model
@@ -167,7 +209,9 @@ class ALSServingModelManager(AbstractServingModelManager):
                 self.item_ordinals.setdefault(id_, self._ordinal_next)
                 self._ordinal_next += 1
                 if is_local_item(id_, self.shard_index, self.shard_count):
-                    model.set_item_vector(id_, vector)
+                    model.set_item_vector(
+                        id_, vector,
+                        tag=(self._headers or {}).get("batch"))
                     # a live Y write outdates the manifest's partial
                     # Gramian: /shard/yty scans again until next load
                     self._slice_yty = None
@@ -175,6 +219,7 @@ class ALSServingModelManager(AbstractServingModelManager):
                     self.skipped_remote_items += 1
             else:
                 raise ValueError(f"Bad message: {message}")
+            self.updates_applied += 1
             # load-fraction trigger OUTSIDE the log rate limiter: a
             # bulk replay that finishes inside one 60 s window must
             # not serve a minute of live traffic without solvers or a
@@ -184,6 +229,7 @@ class ALSServingModelManager(AbstractServingModelManager):
                     and model.get_fraction_loaded()
                     >= self.min_model_load_fraction):
                 self._triggered_solver = True
+                gc.freeze()  # the loaded model leaves the collector's sight (below)
                 # the replay path's load clock: MODEL receipt -> the UP
                 # stream crossing the serving gate (the slice path
                 # stamps its own, much earlier, moment)
@@ -303,6 +349,12 @@ class ALSServingModelManager(AbstractServingModelManager):
                 # no UP flood follows to fire the load-fraction
                 # trigger, so the solvers precompute here
                 self._triggered_solver = True
+                # a loaded model is long-lived: out of the collector's
+                # sight, or a full collection under traffic walks its
+                # 20M ids with the interpreter lock held (seconds;
+                # ServingLayer.start does the same for what was built
+                # before it)
+                gc.freeze()
                 self.model.precompute_solvers()
             _log.info("Model updated: %s", self.model)
         elif key == KEY_HEARTBEAT:

@@ -236,6 +236,19 @@ def _stream_plan(n_rows: int, b_pad: int) -> tuple[bool, int]:
 #    block that IS missed stays missed however wide ksel is.
 _BLOCK_ROWS = 128
 _BLOCK_KSEL = 32
+# the most rows whose LSH buckets are patched after a sync; past it the
+# whole matrix is hashed again
+_BUCKET_PATCH_ROWS = 1 << 16
+
+
+@jax.jit
+def _patch_rows(derived, rows, values):
+    """``derived`` with ``values`` at ``rows``: per-row state following
+    the rows a sync wrote.  Not donated — a drain on another thread may
+    still hold the version before it — so it costs one copy of the
+    derived array (4 bytes a row), never one of the store."""
+    return derived.at[rows].set(values)
+
 _APPROX_RECALL = 0.99999
 # Phase B gathers (rows, ksel, bs, F) of the store's dtype.  A window
 # whose gather would pass this many bytes runs its rows in equal
@@ -1103,8 +1116,12 @@ class ALSServingModel(FactorModelBase, ServingModel):
                     if sample_rate < 1.0 else None)
         self._item_buckets: jax.Array | None = None
         self._item_buckets_version: int = -1
+        # the penalties are functions of the active mask alone, whose
+        # handle outlives every sync that writes vectors only: cached
+        # against the mask they were built from (held, so that its
+        # identity cannot be reused), not against the store's version
         self._penalty: jax.Array | None = None
-        self._penalty_version: int = -1
+        self._penalty_src = None
         # int8 block-selection mirror (oryx.serving.api.int8-selection):
         # "auto" (the default) enables it at f <= 64, where it composes
         # with the fold mirror into the int8+fold phase A that streams
@@ -1143,7 +1160,7 @@ class ALSServingModel(FactorModelBase, ServingModel):
         self._fold_bkt_version: int = -1
         self._fold_version: int = -1
         self._penalty_i: jax.Array | None = None
-        self._penalty_i_version: int = -1
+        self._penalty_i_src = None
         # IVF ANN serving path (oryx.als.ann.*, ISSUE 18): the small
         # per-generation state (centroids + recall certificate) is
         # attached by the manager at model load; the big device mirror
@@ -1159,6 +1176,13 @@ class ALSServingModel(FactorModelBase, ServingModel):
         # observability: exact-scan recomputes forced by a failed
         # two-phase certificate (expected ~0; see _block_ksel's notes)
         self.twophase_fallbacks = 0
+        # whole-matrix builds of state derived from the item matrix (the
+        # phase-A mirrors, the LSH buckets, the IVF mirror): one a kind
+        # at load.  The penalties and the LSH buckets follow the rows a
+        # sync wrote; the mirrors and the IVF state do not yet, so under
+        # a write stream a route that uses one pays a pass over the
+        # store per device sync, and this count grows with the syncs
+        self.derived_rebuilds = 0
 
     # -- known items ---------------------------------------------------------
 
@@ -1219,6 +1243,12 @@ class ALSServingModel(FactorModelBase, ServingModel):
             # exact-scan recomputes forced by a failed streaming top-k
             # certificate; nonzero is worth an operator's attention
             "twophase_fallbacks": self.twophase_fallbacks,
+            # the update path: in-place syncs of the item store, the
+            # rows they carried (a load counts its whole upload), and
+            # the whole-matrix rebuilds of derived state beside them
+            "device_syncs": self.Y.device_syncs,
+            "rows_synced": self.Y.rows_synced,
+            "derived_rebuilds": self.derived_rebuilds,
         }
         # measured-cost route: which kernel path serves this shape and
         # the per-path device costs the choice was made from — the
@@ -1319,6 +1349,7 @@ class ALSServingModel(FactorModelBase, ServingModel):
                     or self._ivf_mirror_version != version:
                 cells = a.cells if a.cells is not None \
                     and len(a.cells) == int(vecs.shape[0]) else None
+                self.derived_rebuilds += 1
                 a.cells = None  # one-shot: stale after any store write
                 self._ivf_mirror = _ivf.build_mirror(
                     vecs, active, a, _BLOCK_ROWS, cells=cells)
@@ -1339,25 +1370,22 @@ class ALSServingModel(FactorModelBase, ServingModel):
             self.top_n_batch(how_many,
                              np.zeros((b, self.features), np.float32))
             b *= 2
+        # the in-place row sync's ladder of scatter programs: the first
+        # UP records of a live model compile nothing
+        self.Y.warm_sync()
         if self._item_shards > 1:
             return  # the loop above already warmed the SPMD merge kernel
-        vecs, active, version = self.Y.device_arrays_versioned()
-        n_rows = int(vecs.shape[0])
+        n_rows = len(self.Y.row_ids())
         k = min(_pad_k(how_many), n_rows)
         big, chunk = _stream_plan(n_rows, _CHUNKED_BATCH)
         if big and n_rows % chunk == 0 and k <= chunk:
-            lsh_on = self._lsh_active()
-            buckets = self._cached_buckets(vecs, version) if lsh_on \
-                else None
-            hp = self.lsh._device_hyperplanes() if lsh_on else None
-            mb = self.lsh.max_bits_differing if lsh_on else 0
             for w in _WINDOW_LADDER:
                 # exact-scan fallback per ladder window shape, so a rare
                 # certificate failure costs one extra dispatch, never an
                 # in-request XLA compile
-                jax.device_get(_batch_top_n_chunked_kernel(
-                    vecs, jnp.zeros((w, self.features), jnp.float32),
-                    active, buckets, hp, k, chunk, mb))
+                jax.device_get(self._enqueue_exact(
+                    jnp.zeros((w, self.features), jnp.float32), k, chunk,
+                    self._lsh_active()))
         # measure per-path costs for the live shape and install the
         # route while still pre-traffic: kernel choice is cost-driven,
         # not config-driven, from the first real request on
@@ -1366,13 +1394,15 @@ class ALSServingModel(FactorModelBase, ServingModel):
     def _cached_penalty(self, active, version) -> jax.Array:
         """Lane-aligned (N//128, 128) f32 additive mask (0 for live
         rows, -inf for retired) for the pallas phase-A kernel,
-        recomputed only when the Y snapshot version changes.  NEVER
+        recomputed only when the store hands out another mask (a row
+        came to life or was retired; ``version`` is not looked at: an
+        update of vectors leaves the penalty as it is).  NEVER
         shape this (N, 1): TPU tiling lane-pads that x128 (9.5 GB of
         padding at 20M rows — a measured compile OOM)."""
         with self._bucket_lock:
-            if self._penalty is None or self._penalty_version != version:
+            if self._penalty is None or self._penalty_src is not active:
                 self._penalty = _penalty_kernel(active, _BLOCK_ROWS)
-                self._penalty_version = version
+                self._penalty_src = active
             return self._penalty
 
     def _int8_enabled(self) -> bool:
@@ -1400,6 +1430,7 @@ class ALSServingModel(FactorModelBase, ServingModel):
         bucket side input folds lazily on first LSH use per version."""
         with self._bucket_lock:
             if self._fold is None or self._fold_version != version:
+                self.derived_rebuilds += 1
                 self._fold = _fold_items_kernel(vecs, active, fold, bs)
                 self._fold_version = version
             yf, pen_f = self._fold
@@ -1413,6 +1444,7 @@ class ALSServingModel(FactorModelBase, ServingModel):
         changes."""
         with self._bucket_lock:
             if self._i8 is None or self._i8_version != version:
+                self.derived_rebuilds += 1
                 self._i8 = _quantize_items_kernel(vecs, _BLOCK_ROWS)
                 self._i8_version = version
             return self._i8
@@ -1428,6 +1460,7 @@ class ALSServingModel(FactorModelBase, ServingModel):
         the folded mirror is the one that serves."""
         with self._bucket_lock:
             if self._i8_fold is None or self._i8_fold_version != version:
+                self.derived_rebuilds += 1
                 y8, sy_b, l1y_b = _quantize_items_kernel(vecs, bs)
                 y8f, pen_i_f = _fold_items_i8_kernel(y8, active, fold, bs)
                 self._i8_fold = (y8f, pen_i_f, sy_b, l1y_b)
@@ -1457,8 +1490,8 @@ class ALSServingModel(FactorModelBase, ServingModel):
                               ("_i8_fold", "_i8_fold_version"),
                               ("_fold", "_fold_version"),
                               ("_fold_bkt", "_fold_bkt_version"),
-                              ("_penalty", "_penalty_version"),
-                              ("_penalty_i", "_penalty_i_version"),
+                              ("_penalty", "_penalty_src"),
+                              ("_penalty_i", "_penalty_i_src"),
                               ("_ivf_mirror", "_ivf_mirror_version")):
                 if attr not in keep:
                     setattr(self, attr, None)
@@ -1469,6 +1502,7 @@ class ALSServingModel(FactorModelBase, ServingModel):
         """Folded LSH bucket side input, shared by the bf16-fold and
         int8-fold mirrors (caller holds ``_bucket_lock``)."""
         if self._fold_bkt is None or self._fold_bkt_version != version:
+            self.derived_rebuilds += 1
             self._fold_bkt = _fold_buckets_kernel(buckets, fold, bs)
             self._fold_bkt_version = version
         return self._fold_bkt
@@ -1476,18 +1510,35 @@ class ALSServingModel(FactorModelBase, ServingModel):
     def _cached_penalty_i(self, active, version) -> jax.Array:
         with self._bucket_lock:
             if self._penalty_i is None \
-                    or self._penalty_i_version != version:
+                    or self._penalty_i_src is not active:
                 self._penalty_i = _penalty_kernel_i32(active, _BLOCK_ROWS)
-                self._penalty_i_version = version
+                self._penalty_i_src = active
             return self._penalty_i
 
     def _cached_buckets(self, vecs, version) -> jax.Array:
-        """Per-item LSH bucket ids on device, recomputed only when the Y
-        snapshot version changes.  Computed device-to-device: at 20M
-        items the vectors never round-trip through the host."""
+        """Per-item LSH bucket ids on device, brought up to the Y
+        snapshot's version: the buckets of the rows that syncs wrote
+        since are recomputed and written in place; the whole matrix is
+        hashed again only where the store cannot name those rows (a
+        whole upload).  Computed device-to-device: at 20M items the
+        vectors never round-trip through the host."""
         with self._bucket_lock:
+            if self._item_buckets is not None \
+                    and self._item_buckets_version != version:
+                rows = self.Y.rows_changed_since(
+                    self._item_buckets_version)
+                if rows is not None and len(rows) \
+                        and len(rows) <= _BUCKET_PATCH_ROWS:
+                    pad = _pad_k(len(rows)) - len(rows)
+                    rows = np.concatenate([rows, np.repeat(rows[:1], pad)])
+                    self._item_buckets = _patch_rows(
+                        self._item_buckets, rows,
+                        self.lsh.device_buckets(jnp.take(
+                            vecs, jnp.asarray(rows), axis=0)))
+                    self._item_buckets_version = version
             if self._item_buckets is None \
                     or self._item_buckets_version != version:
+                self.derived_rebuilds += 1
                 self._item_buckets = self.lsh.device_buckets(vecs)
                 self._item_buckets_version = version
             return self._item_buckets
@@ -1514,22 +1565,28 @@ class ALSServingModel(FactorModelBase, ServingModel):
         CosineAverageFunction) selects the kernel.  ``use_lsh=False``
         forces an exact scan even on an LSH-configured model.
         """
-        vecs, active, version = self.Y.device_arrays_versioned()
-        if user_vector is not None:
-            q = np.asarray(user_vector, dtype=np.float32)
-            scores = _dot_scores(vecs, jnp.asarray(q))
-            lsh_query = q
-        else:
-            V = np.asarray(cosine_to, dtype=np.float32)
-            if V.ndim == 1:
-                V = V[:, None]
-            scores = _cosine_mean_scores(vecs, jnp.asarray(V))
-            lsh_query = V.mean(axis=1)
-        if lowest:
-            scores = -scores
-        use_lsh = use_lsh and self._route_use_lsh(int(vecs.shape[0]))
-        mask = self._lsh_mask(lsh_query if use_lsh else None, vecs, version,
-                              active)
+        # everything that reads the resident arrays is enqueued inside
+        # the store's dispatch lock; what is used after it (scores, the
+        # mask) is this request's own
+        with self.Y.dispatching() as snap:
+            vecs, active, version = snap.vecs, snap.active, snap.version
+            if user_vector is not None:
+                q = np.asarray(user_vector, dtype=np.float32)
+                scores = _dot_scores(vecs, jnp.asarray(q))
+                lsh_query = q
+            else:
+                V = np.asarray(cosine_to, dtype=np.float32)
+                if V.ndim == 1:
+                    V = V[:, None]
+                scores = _cosine_mean_scores(vecs, jnp.asarray(V))
+                lsh_query = V.mean(axis=1)
+            if lowest:
+                scores = -scores
+            use_lsh = use_lsh and self._route_use_lsh(int(vecs.shape[0]))
+            mask = self._lsh_mask(lsh_query if use_lsh else None, vecs,
+                                  version, active)
+            if mask is active:
+                mask = jnp.copy(active)
 
         exclude = set(exclude)
         if rescorer is not None or allowed is not None:
@@ -1610,94 +1667,123 @@ class ALSServingModel(FactorModelBase, ServingModel):
         # recorder (obs/trace.py); None, and one branch a site, for
         # every caller that opened none
         rec = obstrace.current_drain()
-        if rec is not None:
+        # rows the update consumer wrote since the last drain go to the
+        # device here, in place, before this drain's programs: a phase
+        # of its own, first, where there are any
+        syncing = rec is not None and self.Y.pending_rows() > 0
+        if syncing:
+            rec.mark("serving.apply_updates")
+        elif rec is not None:
             rec.mark("serving.prepare", rows=n_req)
-        vecs, active, version = self.Y.device_arrays_versioned()
-        n_rows = int(vecs.shape[0])
-        k = min(_pad_k(max(h + len(e) for h, e in zip(hm, excl))), n_rows)
-        # pow2 floor of 8 for the FLAT path sizing decision: a
-        # (1,F)x(F,N) matvec hits a much slower XLA path than a small
-        # batched matmul, and zero rows are free
-        b_pad = 1 << max(3, (n_req - 1).bit_length())
-        lsh_on = (use_lsh and self._lsh_active()
-                  and self._route_use_lsh(n_rows))
-        buckets = self._cached_buckets(vecs, version) if lsh_on else None
-        big, chunk = _stream_plan(n_rows, b_pad)
-        bs = _BLOCK_ROWS
-        ksel = _block_ksel(k, n_rows, bs)
-        if big and n_rows % chunk == 0 and k <= chunk:
-            # streaming path: static window shapes from the ladder
-            # (computed from the TRUE request count — a 257-query drain
-            # is [256, 8], not two full windows), dispatched async
-            # before ONE fetch
-            hp = self.lsh._device_hyperplanes() if lsh_on else None
-            mb = self.lsh.max_bits_differing if lsh_on else 0
-            sizes = _window_sizes(n_req)
-            padded = sum(sizes)
-            if n_req < padded:
-                Q = np.concatenate(
-                    [Q, np.zeros((padded - n_req, Q.shape[1]),
-                                 np.float32)])
-            windows, w = [], 0
-            for size in sizes:
-                windows.append(jnp.asarray(Q[w:w + size]))
-                w += size
-            twophase = _twophase_admits(k, ksel, vecs, bs)
-            if rec is not None:
-                # from the first program enqueued to the last result
-                # fetched: it waits on the device, and on whatever
-                # other drain the device is running.  ``ksel`` is the
-                # width phase B selects (the int8 builds double it), 0
-                # where the exact scan is the primary path
-                rec.mark("serving.scan", k=k,
-                         ksel=ksel if twophase else 0, windows=sizes)
-            if twophase:
-                fetched = self._dispatch_twophase(
-                    vecs, windows, active, version, buckets, hp, k,
-                    chunk, bs, ksel, mb)
-                for w, (ts, ti, cert) in enumerate(fetched):
-                    if not cert.all():
-                        # a genuine miss (the margin, or a head block
-                        # the approx selection dropped) for some row;
-                        # recompute on the exact scan.  Count
-                        # per certificate-failing row, under the lock —
-                        # batcher dispatcher threads race on this gauge.
-                        rows_failed = int((~cert).sum())
-                        with self._bucket_lock:
-                            self.twophase_fallbacks += rows_failed
-                        if rec is not None:
-                            rec.mark("serving.fallback", k=k,
-                                     width=sizes[w],
-                                     rows_failed=rows_failed)
-                        ts, ti = jax.device_get(
-                            _batch_top_n_chunked_kernel(
-                                vecs, windows[w], active, buckets, hp,
-                                k, chunk, mb))
-                        fetched[w] = (ts, ti, None)
+        # the store's ordering rule: the resident arrays are fetched
+        # and every program that reads them is enqueued inside the
+        # dispatch lock; results are fetched outside it
+        with self.Y.dispatching() as snap:
+            vecs, active, version = snap.vecs, snap.active, snap.version
+            if syncing:
+                rec.annotate(rows=snap.synced_rows, bytes=snap.synced_bytes,
+                             version=version, batches=sorted(snap.tags))
+                rec.mark("serving.prepare", rows=n_req)
+            n_rows = int(vecs.shape[0])
+            k = min(_pad_k(max(h + len(e) for h, e in zip(hm, excl))),
+                    n_rows)
+            # pow2 floor of 8 for the FLAT path sizing decision: a
+            # (1,F)x(F,N) matvec hits a much slower XLA path than a small
+            # batched matmul, and zero rows are free
+            b_pad = 1 << max(3, (n_req - 1).bit_length())
+            lsh_on = (use_lsh and self._lsh_active()
+                      and self._route_use_lsh(n_rows))
+            buckets = self._cached_buckets(vecs, version) if lsh_on \
+                else None
+            big, chunk = _stream_plan(n_rows, b_pad)
+            bs = _BLOCK_ROWS
+            ksel = _block_ksel(k, n_rows, bs)
+            streaming = big and n_rows % chunk == 0 and k <= chunk
+            twophase = streaming and _twophase_admits(k, ksel, vecs, bs)
+            attempted: list = []
+            if streaming:
+                # streaming path: static window shapes from the ladder
+                # (computed from the TRUE request count — a 257-query
+                # drain is [256, 8], not two full windows), dispatched
+                # async before ONE fetch
+                hp = self.lsh._device_hyperplanes() if lsh_on else None
+                mb = self.lsh.max_bits_differing if lsh_on else 0
+                sizes = _window_sizes(n_req)
+                padded = sum(sizes)
+                if n_req < padded:
+                    Q = np.concatenate(
+                        [Q, np.zeros((padded - n_req, Q.shape[1]),
+                                     np.float32)])
+                windows, w = [], 0
+                for size in sizes:
+                    windows.append(jnp.asarray(Q[w:w + size]))
+                    w += size
+                if rec is not None:
+                    # from the first program enqueued to the last result
+                    # fetched: it waits on the device, and on whatever
+                    # other drain the device is running.  ``ksel`` is the
+                    # width phase B selects (the int8 builds double it),
+                    # 0 where the exact scan is the primary path
+                    rec.mark("serving.scan", k=k,
+                             ksel=ksel if twophase else 0, windows=sizes)
+                if twophase:
+                    handles, attempted = self._dispatch_twophase(
+                        vecs, windows, active, version, buckets, hp, k,
+                        chunk, bs, ksel, mb)
+                else:
+                    handles = [
+                        _batch_top_n_chunked_kernel(vecs, qw, active,
+                                                    buckets, hp, k, chunk,
+                                                    mb)
+                        for qw in windows]
             else:
-                fetched = jax.device_get([
-                    _batch_top_n_chunked_kernel(vecs, qw, active,
-                                                buckets, hp, k, chunk, mb)
-                    for qw in windows])
+                if b_pad != n_req:
+                    Q = np.concatenate(
+                        [Q, np.zeros((b_pad - n_req, Q.shape[1]),
+                                     np.float32)])
+                Qd = jnp.asarray(Q)
+                if rec is not None:
+                    rec.mark("serving.scan", k=k, ksel=0, windows=[b_pad])
+                if lsh_on:
+                    handles = _batch_top_n_lsh_kernel(
+                        vecs, Qd, active, buckets,
+                        self.lsh._device_hyperplanes(), k,
+                        self.lsh.max_bits_differing)
+                else:
+                    handles = _batch_top_n_kernel(vecs, Qd, active, k)
+        # from here on the handles above are not touched again: a sync
+        # may have donated them.  What a fallback needs it fetches anew
+        # (_enqueue_exact), and answers from the version it finds
+        if twophase:
+            fetched = self._fetch_twophase(handles, attempted, windows, k,
+                                           chunk, bs, ksel, lsh_on)
+            for w, (ts, ti, cert) in enumerate(fetched):
+                if not cert.all():
+                    # a genuine miss (the margin, or a head block
+                    # the approx selection dropped) for some row;
+                    # recompute on the exact scan.  Count
+                    # per certificate-failing row, under the lock —
+                    # batcher dispatcher threads race on this gauge.
+                    rows_failed = int((~cert).sum())
+                    with self._bucket_lock:
+                        self.twophase_fallbacks += rows_failed
+                    if rec is not None:
+                        rec.mark("serving.fallback", k=k,
+                                 width=sizes[w],
+                                 rows_failed=rows_failed)
+                    ts, ti = jax.device_get(self._enqueue_exact(
+                        windows[w], k, chunk, lsh_on))
+                    fetched[w] = (ts, ti, None)
+            top_scores = np.concatenate([f[0] for f in fetched])
+            top_idx = np.concatenate([f[1] for f in fetched])
+        elif streaming:
+            fetched = jax.device_get(handles)
             top_scores = np.concatenate([f[0] for f in fetched])
             top_idx = np.concatenate([f[1] for f in fetched])
         else:
-            if b_pad != n_req:
-                Q = np.concatenate(
-                    [Q, np.zeros((b_pad - n_req, Q.shape[1]), np.float32)])
-            Qd = jnp.asarray(Q)
-            if rec is not None:
-                rec.mark("serving.scan", k=k, ksel=0, windows=[b_pad])
-            if lsh_on:
-                out_dev = _batch_top_n_lsh_kernel(
-                    vecs, Qd, active, buckets,
-                    self.lsh._device_hyperplanes(), k,
-                    self.lsh.max_bits_differing)
-            else:
-                out_dev = _batch_top_n_kernel(vecs, Qd, active, k)
             # fetch both outputs in ONE host round-trip (matters when the
             # device sits behind a high-latency transport)
-            top_scores, top_idx = jax.device_get(out_dev)
+            top_scores, top_idx = jax.device_get(handles)
         if rec is not None:
             rec.mark("serving.decode", rows=n_req)
         return self._decode_top_n(top_scores, top_idx, hm, excl, n_req,
@@ -1705,11 +1791,42 @@ class ALSServingModel(FactorModelBase, ServingModel):
                                                          np.float32),
                                   use_lsh)
 
+    def _enqueue_exact(self, qw, k: int, chunk: int, lsh_on: bool):
+        """Enqueue one window's exact chunked scan over the resident
+        arrays as they are NOW (its own acquisition of the dispatch
+        lock): the fallback of a drain whose first handles a sync may
+        have donated since."""
+        with self.Y.dispatching() as snap:
+            buckets, hp, mb = self._lsh_inputs(snap, lsh_on)
+            return _batch_top_n_chunked_kernel(
+                snap.vecs, qw, snap.active, buckets, hp, k, chunk, mb)
+
+    def _lsh_inputs(self, snap, lsh_on: bool) -> tuple:
+        """(buckets, hyperplanes, max bits differing) for a program
+        over ``snap``; (None, None, 0) with LSH off."""
+        if not lsh_on:
+            return None, None, 0
+        return (self._cached_buckets(snap.vecs, snap.version),
+                self.lsh._device_hyperplanes(),
+                self.lsh.max_bits_differing)
+
+    def _enqueue_scan_build(self, qw, k: int, chunk: int, bs: int,
+                            ksel: int, lsh_on: bool):
+        """Enqueue one window's ``lax.scan`` two-phase build over the
+        resident arrays as they are now (see ``_enqueue_exact``)."""
+        with self.Y.dispatching() as snap:
+            buckets, hp, mb = self._lsh_inputs(snap, lsh_on)
+            return _batch_top_n_twophase_kernel(
+                snap.vecs, qw, snap.active, buckets, hp, k, chunk, bs,
+                ksel, mb)
+
     def _dispatch_twophase(self, vecs, windows, active, version, buckets,
-                           hp, k: int, chunk: int, bs: int, ksel: int,
-                           mb: int) -> list:
-        """Dispatch every window's two-phase program (async) and fetch
-        once.  Prefers the pallas phase-A builds (scores never leave
+                          hp, k: int, chunk: int, bs: int, ksel: int,
+                          mb: int) -> tuple[list, list]:
+        """Enqueue every window's two-phase program (async; the caller
+        holds the store's dispatch lock) and return the handles with
+        the keys of the shapes attempted, for ``_fetch_twophase``.
+        Prefers the pallas phase-A builds (scores never leave
         VMEM); substitutes the lax.scan build per WINDOW SHAPE where a
         build cannot lower — routine on the CPU backend, a logged ERROR
         that lands in ``kernel_route.errors`` on a TPU
@@ -1722,11 +1839,6 @@ class ALSServingModel(FactorModelBase, ServingModel):
         def key_of(qw, kind):
             return (n_rows, int(vecs.shape[1]), int(qw.shape[0]),
                     str(vecs.dtype), buckets is not None, k, mb, kind)
-
-        def scan_handle(qw):
-            return _batch_top_n_twophase_kernel(vecs, qw, active, buckets,
-                                                hp, k, chunk, bs, ksel,
-                                                mb)
 
         ctx: dict = {}
         handles, attempted = [], []
@@ -1758,9 +1870,18 @@ class ALSServingModel(FactorModelBase, ServingModel):
                     # shape that worked before re-raises
                     _classify_pallas_failure([key], e)
             if not dispatched:
-                handles.append(scan_handle(qw))
+                handles.append(_batch_top_n_twophase_kernel(
+                    vecs, qw, active, buckets, hp, k, chunk, bs, ksel,
+                    mb))
+        return handles, attempted
+
+    def _fetch_twophase(self, handles: list, attempted: list, windows,
+                        k: int, chunk: int, bs: int, ksel: int,
+                        lsh_on: bool) -> list:
+        """ONE fetch for the drain's two-phase programs, outside the
+        dispatch lock."""
         try:
-            out = jax.device_get(handles)  # ONE fetch for the drain
+            out = jax.device_get(handles)
         except Exception as e:  # noqa: BLE001 — classified below
             fresh = [kk for kk in attempted
                      if _PALLAS_STATE.get(kk) != "ok"]
@@ -1771,7 +1892,9 @@ class ALSServingModel(FactorModelBase, ServingModel):
             # 3-strike counter protects an innocent shape from a single
             # misattribution) and serve the drain on the scan build
             _classify_pallas_failure(fresh, e)
-            return jax.device_get([scan_handle(qw) for qw in windows])
+            return jax.device_get([
+                self._enqueue_scan_build(qw, k, chunk, bs, ksel, lsh_on)
+                for qw in windows])
         for kk in attempted:
             _PALLAS_STATE[kk] = "ok"
         return out
@@ -1967,15 +2090,19 @@ class ALSServingModel(FactorModelBase, ServingModel):
         top-k, one all_gather, on-device merge (the SPMD kernel shared
         with parallel/serving_dist.ShardedItemScorer)."""
         n_req = Q.shape[0]
-        vecs, active, _ = self.Y.device_arrays_versioned()
-        n_rows = int(vecs.shape[0])
-        k = min(_pad_k(max(h + len(e) for h, e in zip(hm, excl))), n_rows)
-        b_pad = _pad_k(n_req)
-        if b_pad != n_req:
-            Q = np.concatenate(
-                [Q, np.zeros((b_pad - n_req, Q.shape[1]), np.float32)])
-        top_scores, top_idx = jax.device_get(self._shard_kernels.top_k(
-            vecs, active, self._shard_kernels.replicate(Q), k))
+        with self.Y.dispatching() as snap:  # enqueue inside, fetch after
+            vecs, active = snap.vecs, snap.active
+            n_rows = int(vecs.shape[0])
+            k = min(_pad_k(max(h + len(e) for h, e in zip(hm, excl))),
+                    n_rows)
+            b_pad = _pad_k(n_req)
+            if b_pad != n_req:
+                Q = np.concatenate(
+                    [Q, np.zeros((b_pad - n_req, Q.shape[1]),
+                                 np.float32)])
+            handles = self._shard_kernels.top_k(
+                vecs, active, self._shard_kernels.replicate(Q), k)
+        top_scores, top_idx = jax.device_get(handles)
         window = min(k, top_scores.shape[1])
         return self._decode_top_n(top_scores, top_idx, hm, excl, n_req,
                                   window < n_rows, Q, use_lsh)

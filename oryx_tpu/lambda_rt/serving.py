@@ -11,8 +11,10 @@ modelManager.consume, app-scope attributes), OryxApplication.java:41-98
 
 from __future__ import annotations
 
+import gc
 import importlib
 import logging
+import sys
 import threading
 
 from ..cluster.membership import HeartbeatPublisher, without_heartbeats
@@ -35,6 +37,56 @@ from .metrics import MetricsRegistry
 _log = logging.getLogger(__name__)
 
 __all__ = ["ServingLayer"]
+
+# the interpreter's switch interval while a serving layer runs (start())
+_SWITCH_INTERVAL_S = 0.0002
+
+
+def _single_threaded_host_blas():
+    """Host BLAS on one thread while a serving layer runs; returns what
+    undoes it, or None.  What a serving process hands BLAS is k x k (a
+    Gramian's factorisation, the corrections of
+    ``FeatureVectorStore.vtv``).  OpenBLAS answers with a pool of one
+    spinning thread a core: two solver rebuilds at once put twice the
+    machine's cores into spin loops, a 6 ms SVD took 10-100 ms, and for
+    as long every request thread waited for a core (45 stalls of 10-30
+    ms in a 40 s window at 100 updates a second, PERF.md, PR 27).  On
+    one thread the same calls take 1-6 ms and disturb nobody.  Without
+    ``threadpoolctl`` installed nothing changes."""
+    try:
+        import threadpoolctl
+    except ImportError:
+        return None
+    return threadpoolctl.threadpool_limits(limits=1, user_api="blas")
+
+
+# the interpreter-wide settings of a process that serves: taken by the
+# first layer to start, given back by the last to close
+_tuning_lock = threading.Lock()
+_tuned_layers = 0  # guarded-by: _tuning_lock
+_untune = None  # guarded-by: _tuning_lock
+
+
+def _tune_interpreter() -> None:
+    global _tuned_layers, _untune
+    with _tuning_lock:
+        _tuned_layers += 1
+        if _tuned_layers == 1:
+            interval = sys.getswitchinterval()
+            sys.setswitchinterval(min(interval, _SWITCH_INTERVAL_S))
+            _untune = (interval, _single_threaded_host_blas())
+
+
+def _untune_interpreter() -> None:
+    global _tuned_layers, _untune
+    with _tuning_lock:
+        _tuned_layers -= 1
+        if _tuned_layers == 0 and _untune is not None:
+            interval, blas = _untune
+            _untune = None
+            sys.setswitchinterval(interval)
+            if blas is not None:
+                blas.restore_original_limits()
 
 
 class ServingLayer:
@@ -87,6 +139,8 @@ class ServingLayer:
         self.model_manager = load_instance(manager_class, config)
 
         self._stop = threading.Event()
+        # this layer holds the interpreter-wide settings (start/close)
+        self._tuned = False
         self._consume_thread: threading.Thread | None = None
         self._server = None
         self._server_thread: threading.Thread | None = None
@@ -261,6 +315,25 @@ class ServingLayer:
     # -- lifecycle -----------------------------------------------------------
 
     def start(self) -> None:
+        # A request changes threads some six times (door, batcher,
+        # dispatcher and back), and each change has to take the
+        # interpreter lock.  At CPython's default switch interval a
+        # thread that computes — the update consumer parsing UP records,
+        # a co-located speed layer's micro-batch — keeps it 5 ms a time,
+        # so a request that meets such a burst waits up to 30 ms for
+        # nothing (measured on the chip host with two background threads:
+        # 38 of 1,256 wake-ups late by over 3 ms at 5 ms, 8 at 0.2 ms;
+        # PERF.md, PR 27).  Host BLAS goes to one thread with it.
+        if not self._tuned:
+            self._tuned = True
+            _tune_interpreter()
+        # What the model manager built before this point lives as long
+        # as the process: out of the collector's sight.  A full
+        # collection walks every container of a 20M-id model with the
+        # interpreter lock held, 1.3-3.6 s in which nothing is answered
+        # (one run in ten of the benchmark's 40 s windows, PERF.md, PR
+        # 24-27); frozen objects are still freed by reference count.
+        gc.freeze()
         # JVM-parity cold start: warm_serving_kernels' per-bucket scan
         # variants reload from the disk cache instead of recompiling
         compile_cache.enable_from_config(self.config)
@@ -371,6 +444,9 @@ class ServingLayer:
 
     def close(self) -> None:
         self._stop.set()
+        if self._tuned:
+            self._tuned = False
+            _untune_interpreter()
         if self.heartbeat is not None:
             self.heartbeat.close()
         if self._frame_server is not None:

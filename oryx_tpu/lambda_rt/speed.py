@@ -36,10 +36,23 @@ serving front end stamps — are served by the side-door ObsServer on
 (sampled ``/ingest``-family requests) get a retroactive
 ``speed.fold_in`` span attached to their originating trace, so a
 client request can be followed to the update that made it servable.
+
+Co-located with a serving layer (``SpeedLayer(config, serving=layer)``:
+one process holds the chip, README "Sharing a host") the two share what
+would not fit twice: the model manager folds in against the serving
+model's own stores (``attach_serving``: one copy of the catalog on the
+device, one host mirror) and spans go to the serving layer's tracer, so
+one ring holds a request and the micro-batch that changed its answer.
+Each micro-batch is a ``speed.micro_batch`` trace (``speed.gramian``,
+``speed.solve`` and ``speed.publish`` under it), and every UP record
+carries its micro-batch's number (``batch``) and the input offsets it
+ends at (``in``), so a reader of the update topic can tell which input
+each update came from.
 """
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import threading
 
@@ -53,6 +66,7 @@ from ..kafka.inproc import InProcTopicProducer, resolve_broker
 from ..obs import (events_from_config, flight_from_config, freshness,
                    tracer_from_config)
 from ..obs.server import ObsServer
+from ..obs import trace as obstrace
 from ..obs.trace import parse_traceparent
 from ..resilience import faults
 from ..resilience.policy import (ResilientTopicProducer, Retry,
@@ -66,9 +80,17 @@ _log = logging.getLogger(__name__)
 __all__ = ["SpeedLayer"]
 
 
+def _wall_ms(t_mono: float) -> int:
+    """The wall-clock millisecond of a monotonic stamp taken a moment
+    ago (the ``ts`` headers it is compared with are wall-clock)."""
+    return int((clockmod.now() - (clockmod.monotonic() - t_mono)) * 1000)
+
+
 class SpeedLayer:
 
-    def __init__(self, config: Config):
+    def __init__(self, config: Config, serving=None):
+        """``serving``: the ``ServingLayer`` of this process, when the
+        two are co-located (module docstring)."""
         self.config = config
         self.id = config.get_optional_string("oryx.id")
         self.input_broker = config.get_string("oryx.input-topic.broker")
@@ -115,6 +137,16 @@ class SpeedLayer:
         # ObsServer — the speed tier serves no public HTTP of its own
         self.metrics = MetricsRegistry()
         self.tracer = tracer_from_config(config, "speed")
+        if serving is not None:
+            self.tracer = serving.tracer
+            # a manager that can fold in against the served model does;
+            # another keeps its own copy, as in a process of its own
+            attach = getattr(self.model_manager, "attach_serving", None)
+            if attach is not None:
+                attach(serving.model_manager)
+        # micro-batches that found input, numbered from 1: the ``batch``
+        # header of the UP records each one publishes
+        self.batch_seq = 0
         self._update_tap = freshness.UpdateStreamTap()
         self.metrics.gauge_fn(
             "update_lag_records",
@@ -289,13 +321,20 @@ class SpeedLayer:
         """Publish one derived micro-batch and advance the fence.  With
         the checkpoint enabled this is the stage → publish → commit
         protocol; without it, the legacy publish → group-commit."""
-        up_headers = {"ts": str(int(clockmod.now() * 1000))}
+        up_headers = {"ts": str(int(clockmod.now() * 1000)),
+                      "batch": str(self.batch_seq),
+                      "in": ",".join(str(e) for e in ends)}
+        # co-located with a serving layer the publishing thread keeps to
+        # a share of the interpreter (the model manager's ``pace``)
+        pace = getattr(self.model_manager, "pace", None)
+        working = pace.work if pace is not None else contextlib.nullcontext
         if self.checkpoint is None:
             for update in updates:
                 # chaos seam: UP delta publish failure — offsets must
                 # not advance past an unpublished delta
                 faults.fire("speed-publish")
-                self._producer.send(KEY_UP, update, headers=up_headers)
+                with working():
+                    self._producer.send(KEY_UP, update, headers=up_headers)
             in_broker.set_offsets(self._group, self.input_topic, ends)
             return len(updates)
         # durable intent BEFORE the first publish: recovery replays
@@ -304,10 +343,11 @@ class SpeedLayer:
         batch = self.checkpoint.stage_batch(ends, updates, up_headers)
         for seq, update in enumerate(updates):
             faults.fire("speed-publish")
-            self._producer.send(
-                KEY_UP, update,
-                headers=speed_checkpoint.stamp_headers(
-                    up_headers, self.shard_tag, batch, seq))
+            with working():
+                self._producer.send(
+                    KEY_UP, update,
+                    headers=speed_checkpoint.stamp_headers(
+                        up_headers, self.shard_tag, batch, seq))
         # chaos seam: die AFTER the publishes, BEFORE the commit — the
         # exact window the staged batch + destination-log scan exists
         # for (docs/RESILIENCE.md)
@@ -364,12 +404,31 @@ class SpeedLayer:
         ends = broker.latest_offsets(self.input_topic)
         if all(e <= p for e, p in zip(ends, pos)):
             return pos
-        t_batch = clockmod.monotonic()
-        new_data = broker.read_ranges(self.input_topic, pos, ends)
-        updates = list(self.model_manager.build_updates(new_data))
-        n_updates = self._publish_batch(broker, updates, ends)
-        self._note_micro_batch(new_data, n_updates, t_batch)
+        self._derive_and_publish(broker, pos, ends)
         return ends
+
+    def _derive_and_publish(self, broker, pos: list[int],
+                            ends: list[int]) -> None:
+        """Input [pos, ends) -> updates -> the update topic, as one
+        ``speed.micro_batch`` trace where tracing is on."""
+        t_batch = clockmod.monotonic()
+        self.batch_seq += 1
+        with obstrace.phase("speed.micro_batch", self.tracer,
+                            batch=self.batch_seq) as span:
+            new_data = broker.read_ranges(self.input_topic, pos, ends)
+            updates = list(self.model_manager.build_updates(new_data))
+            with obstrace.phase("speed.publish", updates=len(updates)):
+                n_updates = self._publish_batch(broker, updates, ends)
+            if span.sampled:
+                oldest = freshness.oldest_ingest_ts_ms(new_data)
+                span.set_attr("events", len(new_data))
+                span.set_attr("updates", n_updates)
+                span.set_attr("oldest_wait_ms", None if oldest is None
+                              else _wall_ms(t_batch) - oldest)
+                for key, value in getattr(self.model_manager, "last_batch",
+                                          {}).items():
+                    span.set_attr(key, value)
+        self._note_micro_batch(new_data, n_updates, t_batch)
 
     def _micro_batch_loop(self) -> None:
         broker = resolve_broker(self.input_broker)
@@ -413,8 +472,4 @@ class SpeedLayer:
         ends = broker.latest_offsets(self.input_topic)
         if all(e <= p for e, p in zip(ends, pos)):
             return
-        t_batch = clockmod.monotonic()
-        new_data = broker.read_ranges(self.input_topic, pos, ends)
-        updates = list(self.model_manager.build_updates(new_data))
-        n_updates = self._publish_batch(broker, updates, ends)
-        self._note_micro_batch(new_data, n_updates, t_batch)
+        self._derive_and_publish(broker, pos, ends)

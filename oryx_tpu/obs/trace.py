@@ -50,7 +50,7 @@ from ..resilience import faults
 _log = logging.getLogger(__name__)
 
 __all__ = ["Span", "NOOP_SPAN", "Tracer", "DrainPhases", "current_drain",
-           "annotation", "parse_traceparent", "format_traceparent",
+           "annotation", "phase", "open_span", "parse_traceparent", "format_traceparent",
            "unsampled_traceparent", "tracer_from_config"]
 
 _FLAG_SAMPLED = 0x01
@@ -145,7 +145,7 @@ class Span:
     explicitly with :meth:`end`."""
 
     __slots__ = ("_tracer", "name", "trace_id", "span_id", "parent_id",
-                 "t_start", "attrs", "status", "_prev")
+                 "t_start", "attrs", "status", "_prev", "_note")
     sampled = True
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
@@ -159,6 +159,9 @@ class Span:
         self.attrs: dict = {}
         self.status = "ok"
         self._prev = None
+        # a profiler annotation that opens and closes with the span
+        # (phase()), or None
+        self._note = None
 
     def set_attr(self, key: str, value) -> None:
         self.attrs[key] = value
@@ -175,12 +178,16 @@ class Span:
 
     def __enter__(self):
         self._prev = self._tracer._swap(self)
+        if self._note is not None:
+            self._note.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
             self.status = "error"
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
         self.end()
         self._tracer._swap(self._prev)
         return False
@@ -195,6 +202,40 @@ def annotation(name: str):
     from jax.profiler import TraceAnnotation
 
     return TraceAnnotation(name)
+
+
+# the sampled span open on the calling thread, whichever tracer made it:
+# code that has no tracer of its own (a model, a store, a solver cache)
+# records under it through phase()
+_open = threading.local()
+
+
+def open_span():
+    """The sampled span open on the calling thread, or None: what a
+    caller hands to a helper thread as ``phase(..., parent=...)``."""
+    return getattr(_open, "span", None)
+
+
+def phase(name: str, tracer: "Tracer | None" = None, parent=None, **attrs):
+    """A piece of work on the calling thread, recorded once and read
+    twice like a drain's phases: a ring span and a profiler annotation
+    of the same name.  It is a child of whatever sampled span is open
+    on this thread, or of ``parent`` (``open_span()`` of the thread
+    that handed this work over); with neither it starts a trace of its
+    own in ``tracer`` (at that tracer's sampling ratio), and without
+    that it is NOOP_SPAN: one thread-local lookup and a branch.  Use as
+    a context manager; phases opened inside it nest under it."""
+    cur = getattr(_open, "span", None) or parent
+    if cur is not None:
+        span = Span(cur._tracer, name, cur.trace_id, cur.span_id)
+    elif tracer is not None and (tracer.sample_ratio >= 1.0
+                                 or random.random() < tracer.sample_ratio):
+        span = Span(tracer, name, _new_trace_id(), None)
+    else:
+        return NOOP_SPAN
+    span.attrs.update(attrs)
+    span._note = annotation(name)
+    return span
 
 
 # the drain being recorded on the calling thread, if any: the batcher's
@@ -259,6 +300,12 @@ class DrainPhases:
         self._note = note
         self._phases.append([name, now, None, attrs, "ok"])
 
+    def annotate(self, **attrs) -> None:
+        """Counts that are known only once the running phase's work is
+        done (what a sync carried) go onto it here."""
+        if self._phases:
+            self._phases[-1][3].update(attrs)
+
     def replay(self, tracer: "Tracer", trace_id: str,
                parent_id: str) -> None:
         """The drain's phases as ring spans under ``parent_id``, written
@@ -300,6 +347,7 @@ class Tracer:
     def _swap(self, span):
         prev = getattr(self._local, "span", None)
         self._local.span = span
+        _open.span = span
         return prev
 
     # -- span creation -------------------------------------------------------

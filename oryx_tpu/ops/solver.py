@@ -133,6 +133,32 @@ def unpack_packed(packed: np.ndarray) -> np.ndarray:
     return full
 
 
+def _smallest_singular_value_above(a: np.ndarray, threshold: float) -> bool:
+    """True only where the smallest singular value of ``a`` is certainly
+    above ``threshold``, found without the SVD; False means "not shown",
+    and the caller takes the SVD for the exact answer.
+
+    For the positive definite matrix S that ``a``'s lower triangle
+    spells (a float64 Cholesky factorisation says whether it is one):
+    the smallest eigenvalue is 1 / ||S^-1||_2 >= 1 / ||S^-1||_F, and the
+    singular values of ``a`` lie within ||a - a^T||_F of S's (Weyl).
+    A factorisation and an inverse: a third of the SVD's time at
+    250 x 250, which a process that rebuilds two Gramian solvers every
+    micro-batch beside its request threads pays 80 times in 40 s
+    (PERF.md, PR 27).  A Gramian whose condition number is under some
+    1e5 / sqrt(k) passes; anything else, and anything not positive
+    definite, goes the long way as before."""
+    if a.ndim != 2 or a.shape[0] != a.shape[1] or not a.size:
+        return False
+    try:
+        np.linalg.cholesky(a)
+        inv = np.linalg.inv(np.tril(a) + np.tril(a, -1).T)
+    except np.linalg.LinAlgError:
+        return False
+    bound = 1.0 / float(np.linalg.norm(inv)) - float(np.linalg.norm(a - a.T))
+    return bool(np.isfinite(bound) and bound > threshold)
+
+
 def get_solver(a) -> Solver:
     """Build a Solver for symmetric A, raising SingularMatrixSolverException
     when A is near-singular (threshold = inf-norm * 1e-5, matching
@@ -151,13 +177,16 @@ def get_solver(a) -> Solver:
     # inf-norm (max absolute row sum), as commons-math RealMatrix.getNorm()
     inf_norm = float(np.max(np.sum(np.abs(a), axis=1))) if a.size else 0.0
     threshold = inf_norm * _SINGULARITY_THRESHOLD_RATIO
-    svals = np.linalg.svd(a, compute_uv=False)
-    apparent_rank = int(np.sum(svals > 0.01 * (svals[0] if svals.size else 0.0)))
-    if svals.size == 0 or svals[-1] <= threshold:
-        raise SingularMatrixSolverException(
-            apparent_rank,
-            f"{a.shape[0]} x {a.shape[1]} matrix is near-singular "
-            f"(threshold {threshold}). Apparent rank: {apparent_rank}")
+    apparent_rank = a.shape[0]
+    if not _smallest_singular_value_above(a, threshold):
+        svals = np.linalg.svd(a, compute_uv=False)
+        apparent_rank = int(np.sum(
+            svals > 0.01 * (svals[0] if svals.size else 0.0)))
+        if svals.size == 0 or svals[-1] <= threshold:
+            raise SingularMatrixSolverException(
+                apparent_rank,
+                f"{a.shape[0]} x {a.shape[1]} matrix is near-singular "
+                f"(threshold {threshold}). Apparent rank: {apparent_rank}")
     chol = jnp.linalg.cholesky(jnp.asarray(a, dtype=jnp.float32))
     # chaos seam: discard the f32 factorization so tests can drive the
     # f64 rescue branch deterministically on a healthy matrix

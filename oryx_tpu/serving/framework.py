@@ -87,6 +87,11 @@ def send_input_many(req: Request, lines: list[str]) -> None:
     except Exception as e:  # noqa: BLE001 — any broker fault degrades,
         raise OryxServingException(                   # it doesn't error
             503, f"input send failed: {e}") from e
+    # every record counted here is in the input topic: what the speed
+    # layer's events_folded has to add up to
+    metrics = req.context.get("metrics")
+    if metrics is not None:
+        metrics.inc("events_acked", len(entries))
 
 
 def _produce(producer, entries: list[tuple[str, str, dict]]) -> None:

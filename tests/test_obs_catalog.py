@@ -39,7 +39,8 @@ _NAME_RE = re.compile(r"^[a-z][a-z0-9_]*$")
 # mark/annotation: obs/trace.py's drain phases and bare profiler
 # annotations; _await_locked: the batcher's annotated condition wait
 _SPAN_METHODS = {"span": 0, "child_span": 1, "record_span": 0,
-                 "mark": 0, "annotation": 0, "_await_locked": 0}
+                 "mark": 0, "annotation": 0, "_await_locked": 0,
+                 "phase": 0}
 _COUNTER_METHODS = {"inc": 0}
 _GAUGE_METHODS = {"set_gauge": 0, "gauge_fn": 0}
 
