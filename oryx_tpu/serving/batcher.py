@@ -9,20 +9,40 @@ inversion batches many concurrent requests into ONE MXU matmul
 Design: adaptive queue-drain batching bounded by a measured in-flight
 cap.  Handler threads enqueue a scoring job and block; dispatcher
 threads drain whatever is queued and issue one batched kernel call
-each.  The cap — ceil(round_trip / service_time) + 1, both learned
-from dispatch walls and completion gaps — is what makes batching
-adapt to model size: beyond it, extra dispatches only stack
-device-queue latency (observed before the cap existed: free
-dispatchers shredded a 5M-item model's queue into tiny batches that
-serialized on the device, 3% of achievable throughput with 3 s
-device-queue latency).  A blocked dispatcher wakes on the next
-completion and drains everything that queued during one service
-interval, so batch size tracks the arrival rate under load with no
-explicit pacing.  Below the cap, a request is held only a couple of
-milliseconds (zero on a locally attached chip) so a synchronized
-burst coalesces while an unloaded request keeps its latency at
-round-trip + exec — a service-interval hold here would cost more
-than the device time itself when the dispatch round trip is long.
+each.  WHEN pending requests are bound to a device program is the one
+decision made here, and it follows two measurements:
+
+- *How deep to keep the device's queue.*  The wall of a drain that ran
+  alone (round trip + one execution) against the gap between the
+  completions of drains that queued one behind the other (one
+  execution).  Where the two are far apart — device calls overlap, or a
+  transport round trip dominates — the cap is ceil(round_trip /
+  service_time) + 1: deeper only stacks device-queue latency (observed
+  before the cap existed: free dispatchers shredded a 5M-item model's
+  queue into tiny batches that serialized on the device, 3% of
+  achievable throughput with 3 s device-queue latency), and a blocked
+  dispatcher wakes on the next completion and drains everything that
+  queued during one service interval.  Where they are equal within a
+  quarter — a locally attached chip that runs one program after another
+  — a second program in flight hides nothing and costs every request
+  that arrives meanwhile a pass over the store of its own, so the cap
+  is ONE: a request that arrives while a program runs is bound late,
+  at that program's completion, together with everything else that is
+  waiting.  It loses nothing (its program could not have started
+  earlier) and shares its pass.  Each depth hides one of the two
+  measurements (at one nothing queues behind anything; a saturated
+  pipeline never runs a drain alone), so now and then one drain is
+  dispatched the other way to take the hidden one: :meth:`_depth` says
+  when and what it costs.
+- *Whether to hold a drain for the callers just answered.*  A
+  completion releases n callers; closed-loop callers are back within a
+  fraction of a service time, and the drain that could leave at once
+  would leave without them.  It waits for them — leaving as soon as
+  they are back — for at most an eighth of the service time, and keeps
+  score: where the awaited callers do not come back inside the hold
+  (open-loop arrivals) the hold switches itself off and tries again
+  every so many completions.  A lone closed-loop caller is never held:
+  when it is back, nobody else is out.
 """
 
 from __future__ import annotations
@@ -44,6 +64,35 @@ __all__ = ["TopNBatcher"]
 # dispatching for minutes
 _MIN_EXEC_S = 0.0005
 _MAX_EXEC_S = 5.0
+
+# The device runs drains one after another with nothing to hide behind a
+# second one where pipelining saves less than this share of a lone
+# drain's wall.  This chip: 15.1 ms completion gap at depth 2 against a
+# 15.5 ms lone wall, 3%, and single samples up to 16% where a narrow
+# program follows a wide one (the fetched k moves a program by +-7%);
+# an overlapping or remote device: 85-99%.
+_SERIAL_OVERLAP = 0.25
+# Each depth hides one of the two measurements it is chosen by: at depth
+# one no drain queues behind another, in a saturated pipeline none runs
+# alone.  After this many completions without the hidden one, ONE drain
+# is dispatched the other way to take it (a probe).  Every probe doubles
+# the count, up to the second number, and an answer that changes (serial
+# <-> pipelined) puts it back to the first: a device that keeps giving
+# the same answer is asked 7 times in its first 32,512 drains and once
+# in 16,384 from then on.
+_PROBE_EVERY = 256
+_PROBE_EVERY_MAX = 16384
+# A drain waits for the callers the last completion released at most
+# this fraction of the service time, and never longer than this.
+_HOLD_FRACTION = 8
+_MAX_HOLD_S = 0.002
+# The hold stays on while at least this share of the awaited callers
+# came back inside it (running mean over holds); off, one drain in this
+# many completions holds all the same, to see whether they do now, and
+# so does the drain after a hold in which they all did.
+_HIT_SHARE_ON = 0.5
+_HIT_SHARE_GAIN = 0.1
+_HOLD_PROBE_EVERY = 32
 
 
 class _Job:
@@ -75,23 +124,22 @@ class TopNBatcher:
     def __init__(self, max_batch: int = 1024, pipeline: int = 32,
                  idle_wait_s: float | None = None, tracer=None,
                  accountant=None):
-        """``pipeline`` dispatcher threads keep that many batched device
-        calls in flight at once: dispatch latency (dominated by the
-        host<->device round trip) overlaps instead of serializing, so
-        sustained throughput ~= mean_batch x pipeline / round_trip.
-        Depth must cover the transport's round trip x the dispatch rate;
-        32 was chosen where the dispatch round trip was long; idle
-        depth is just parked threads on a locally attached chip;
-        configurable via oryx.serving.api.scoring-pipeline-depth.
+        """``pipeline`` dispatcher threads are the most batched device
+        calls ever in flight at once; how many really are follows what
+        the batcher measures (:meth:`_depth`): one on a device that
+        runs them one after another, enough to cover the round trip
+        where dispatch latency overlaps (sustained throughput there ~=
+        mean_batch x depth / round_trip).  32 was chosen where the
+        dispatch round trip was long; idle depth is just parked
+        threads; configurable via
+        oryx.serving.api.scoring-pipeline-depth.
 
-        ``idle_wait_s`` caps how long a below-capacity server holds a
-        request hoping a burst coalesces.  None (default) adapts to
-        the measured round trip: where it is long the cap
-        is 2 ms (enough for a synchronized burst to land, invisible
-        next to the round trip), on a locally attached chip (measured
-        round trip under ~5 ms) it is 0 — immediate dispatch.
-        Configurable via oryx.serving.api.batch-idle-wait-ms
-        (-1 = adaptive).
+        ``idle_wait_s`` caps how long a drain that could leave waits
+        for the callers the last completion released (the hold of the
+        module's docstring).  None (default) is 2 ms; the hold in force
+        is the smaller of the cap and an eighth of the measured service
+        time, and 0 switches it off.  Configurable via
+        oryx.serving.api.batch-idle-wait-ms (-1 = the default cap).
 
         ``tracer`` (obs/trace.py, or None) splits each sampled
         request's batcher residence into a queue-wait span and a
@@ -101,10 +149,11 @@ class TopNBatcher:
         the model's prepare / scan / fallback / decode phases land
         under each sampled job's device-execute span and, as profiler
         annotations, on the dispatcher thread's line of a device trace.
-        The pool's two idle states are annotated there too, each on one
-        thread at a time (``serving.await_work``: nothing queued and
+        The pool's three idle states are annotated there too, each on
+        one thread at a time (``serving.await_work``: nothing queued and
         nothing in flight; ``serving.await_slot``: work queued behind
-        the in-flight cap).
+        the in-flight cap; ``serving.await_return``: a drain held for
+        the callers just answered).
 
         ``accountant`` (obs/device_time.py, or None) books every
         batched device-execute bracket as route-class ``serve`` time
@@ -117,23 +166,53 @@ class TopNBatcher:
         self._idle_wait = idle_wait_s
         lock = threading.RLock()
         self._cond = threading.Condition(lock)
-        # with a tracer, the ONE dispatcher whose wait is annotated as
-        # the pool's state parks here (same lock), so that whatever
-        # ends the state wakes that thread first (_await_locked,
-        # _wake_locked)
+        # the ONE dispatcher whose wait is the pool's state parks here
+        # (same lock), so that whatever ends the state wakes that thread
+        # first (_await_locked, _wake_locked); the token says whose turn
+        # it is, so a wait that times out clears no later thread's
         self._noted = threading.Condition(lock)
-        self._noted_waiting = False
+        self._noted_by: object | None = None
         self._pending: list[_Job] = []
         self._stopped = False
-        # service-rate pacing state (all under _cond)
+        # pacing state (all under _cond)
         self._in_flight = 0
-        self._last_dispatch = 0.0
         self._last_completion = 0.0
-        self._exec_ewma = _MIN_EXEC_S  # optimistic until measured
-        # min observed dispatch wall time ~= round_trip + one exec; the
-        # in-flight target ceil(round_trip / exec) + 1 keeps the device
-        # continuously fed without stacking a deep on-device queue
+        # service time: the gap between completions of drains that
+        # queued one behind the other, or, where the depth in force is
+        # one, the wall of a drain; optimistic until measured
+        self._exec_ewma = _MIN_EXEC_S
+        self._exec_measured = False
+        # least recent wall of a drain that was dispatched with nothing
+        # in flight ~= round trip + one exec.  A drain that queued
+        # behind another teaches nothing here: its wall holds the other
+        # one's device time, which is no round trip
         self._wall_min = float("inf")
+        # share of a lone drain's wall that a second drain in flight
+        # hides: 1 - completion gap / lone wall, running mean; None
+        # until two drains have queued one behind the other
+        self._overlap: float | None = None
+        # completions since a drain last ran alone / last queued behind
+        # another, how many of them arm a probe now (_PROBE_EVERY and
+        # its doubling), whether the one drain of a serial probe is out,
+        # and how many probes were taken
+        self._since_lone = 0
+        self._since_gap = 0
+        self._probe_every = _PROBE_EVERY
+        self._probe_out = False
+        self.probes = 0
+        # the hold for returning callers: how many the last completion
+        # released that are not back yet, the hold now running (start,
+        # limit, callers awaited at its start, arrivals inside it), and
+        # its score
+        self._awaited = 0
+        self._hold_t0: float | None = None
+        self._hold_limit = 0.0
+        self._hold_for = 0
+        self._hold_hits = 0
+        self._hit_share = 1.0
+        self._since_hold = 0
+        self.return_holds = 0
+        self.return_hits = 0
         self._threads = [
             threading.Thread(target=self._loop, daemon=True,
                              name=f"TopNBatcher-{i}")
@@ -191,6 +270,13 @@ class TopNBatcher:
             else:
                 stopped = False
                 self._pending.append(job)
+                if self._awaited:
+                    # any arrival counts as one of the callers just
+                    # answered coming back: they cannot be told apart
+                    self._awaited -= 1
+                    if self._hold_t0 is not None:
+                        self._hold_hits += 1
+                        self.return_hits += 1
                 self._wake_locked(1)
         if stopped:
             return model.top_n_batch([how_many], job.vector[None, :],
@@ -205,7 +291,10 @@ class TopNBatcher:
         of the recent-drain EWMA (decayed to 0 after 5 idle seconds)
         and the LIVE age of the oldest still-queued job — so a queue
         that stopped draining reports a growing wait, not the stale
-        average of better times."""
+        average of better times.  At a depth of one a request that
+        arrives while a program runs waits here, not on the device's
+        queue, so under load the signal reads up to one service time
+        where a deeper pipeline hid that wait inside the device call."""
         now = clockmod.monotonic()
         with self._cond:
             ew = self._qwait_ewma if now - self._qwait_at <= 5.0 else 0.0
@@ -218,6 +307,7 @@ class TopNBatcher:
         qw = self.recent_queue_wait_ms()
         with self._cond:
             sizes = self.batch_sizes[-1000:]
+            depth, why = self._depth()
             return {
                 "dispatches": self.total_dispatches,
                 "queue_wait_ms": round(qw, 2),
@@ -226,9 +316,20 @@ class TopNBatcher:
                 "service_time_ms": round(self._exec_ewma * 1e3, 2),
                 "round_trip_floor_ms": round(self._wall_min * 1e3, 1)
                 if self._wall_min != float("inf") else None,
+                "overlap_share": None if self._overlap is None
+                else round(self._overlap, 3),
                 "in_flight": self._in_flight,
-                "in_flight_target": self._in_flight_target(),
+                "in_flight_target": depth,
+                "depth_reason": why,
+                "probes": self.probes,
+                "probe_every": self._probe_every,
                 "pending": len(self._pending),
+                "return_hold_ms": round(self._hold_cap() * 1e3, 2),
+                "return_hold": "on" if self._hit_share >= _HIT_SHARE_ON
+                else "off",
+                "return_holds": self.return_holds,
+                "return_hits": self.return_hits,
+                "return_hit_share": round(self._hit_share, 3),
                 "deadline_rejects": self.deadline_rejects,
             }
 
@@ -236,28 +337,117 @@ class TopNBatcher:
         with self._cond:
             self._stopped = True
             self._cond.notify_all()
-            self._noted_waiting = False
+            self._noted_by = None
             self._noted.notify_all()
         for t in self._threads:
             t.join(5.0)
 
     # -- dispatcher ----------------------------------------------------------
 
-    def _in_flight_target(self) -> int:
-        """How many dispatches keep the device continuously busy: enough
-        to cover the transport round trip at the current service rate,
-        plus one.  More than this only deepens the on-device queue (each
-        extra dispatch adds a full service time to every later request's
-        latency).  Called inside the dispatchers' wait loops — plain
-        float math, no numpy scalars (they cost microseconds each)."""
-        wall_min = self._wall_min
-        if wall_min == float("inf"):
-            return len(self._threads)  # unmeasured: let it rip once
-        rtt = wall_min - self._exec_ewma
+    def _serial(self) -> bool:
+        """Whether the device was seen to run drains one after another
+        with (next to) nothing for a second one in flight to hide."""
+        return self._overlap is not None and self._overlap < _SERIAL_OVERLAP
+
+    def _depth(self) -> tuple[int, str]:
+        """How many dispatches to keep in flight, and why.  Called inside
+        the dispatchers' wait loops — plain float math, no numpy scalars
+        (they cost microseconds each).
+
+        Both measurements come for free only in part, so the hidden one
+        is taken by a probe, ``_probe_every`` completions after it was
+        last seen.  A pipeline that a closed loop keeps saturated never
+        runs a drain alone: the cap drops to one until the pipeline has
+        run dry and one drain has gone alone (``pipelined-probe``; costs
+        one round trip of throughput).  At a depth of one no drain
+        queues behind another: the next drain that finds a program
+        running is bound behind it (``serial-probe``), that ONE drain
+        and no other.  It costs the callers that come back while it runs
+        one program more each — with two callers one request, with n at
+        most n - 1 — and nothing until traffic splits by itself: callers
+        in step arrive while no program runs, and the probe stays armed.
+        """
+        if self._overlap is None:
+            # (no lone wall yet, or no queued pair yet)
+            # two, so that the first requests that coincide queue one
+            # behind the other and their completion gap is seen; no
+            # deeper, or a device that turns out to be serial starts
+            # with a queue of lone-request programs
+            return min(len(self._threads), 2), "unmeasured"
+        if self._serial():
+            if self._since_gap >= self._probe_every \
+                    and not self._probe_out and len(self._threads) > 1:
+                return 2, "serial-probe"
+            return 1, "serial"
+        if self._since_lone >= self._probe_every:
+            return 1, "pipelined-probe"
+        # enough to cover the transport round trip at the current
+        # service rate, plus one.  More than this only deepens the
+        # on-device queue (each extra dispatch adds a full service time
+        # to every later request's latency)
+        rtt = self._wall_min - self._exec_ewma
         if rtt <= 0.0:
-            return 2
+            return min(len(self._threads), 2), "pipelined"
         return min(len(self._threads),
-                   1 + max(1, -int(-rtt // self._exec_ewma)))
+                   1 + max(1, -int(-rtt // self._exec_ewma))), "pipelined"
+
+    def _in_flight_target(self) -> int:
+        return self._depth()[0]
+
+    def _hold_cap(self) -> float:
+        """The longest a drain may now wait for returning callers: no
+        time at all before the service time has been measured."""
+        if not self._exec_measured:
+            return 0.0
+        cap = _MAX_HOLD_S if self._idle_wait is None else self._idle_wait
+        return min(cap, self._exec_ewma / _HOLD_FRACTION)
+
+    def _hold_locked(self, now: float) -> float:
+        """Seconds the drain that could leave now should still wait for
+        the callers the last completion released; <= 0: go.  The clock
+        of a hold starts here, when a drain first could leave, not at
+        the oldest arrival: a caller that is not back yet has no age."""
+        if self._awaited <= 0:
+            return 0.0
+        if self._hold_t0 is None:
+            if self._hit_share < _HIT_SHARE_ON \
+                    and self._since_hold < _HOLD_PROBE_EVERY:
+                return 0.0
+            limit = self._hold_cap()
+            if limit <= 0.0:
+                return 0.0
+            self._hold_t0, self._hold_limit = now, limit
+            self._hold_for, self._hold_hits = self._awaited, 0
+            self._since_hold = 0
+            self.return_holds += 1
+        return self._hold_t0 + self._hold_limit - now
+
+    def _bind_locked(self, now: float) -> dict:
+        """A drain leaves: the hold it waited in, if any, is scored, and
+        whoever is still out is no longer waited for (they find a
+        program running and share the next).  Returns what the drain's
+        ``serving.queue_wait`` spans say of the batcher's state."""
+        depth, why = self._depth()
+        if why == "serial-probe" and self._in_flight:
+            # this is the probe's one drain: whoever comes next waits
+            # for a free device again
+            self._probe_out = True
+        held = 0.0
+        if self._hold_t0 is not None:
+            held = now - self._hold_t0
+            share = min(1.0, self._hold_hits / self._hold_for)
+            self._hit_share += _HIT_SHARE_GAIN * (share - self._hit_share)
+            if share >= 1.0:
+                # all of them came back: where the hold is off, the
+                # next completion tries again, not the 32nd (callers
+                # that turn closed-loop are in step within some forty
+                # programs, and an open loop's rare hit costs one hold)
+                self._since_hold = _HOLD_PROBE_EVERY
+            self._hold_t0 = None
+        self._awaited = 0
+        return {"depth": depth, "depth_reason": why,
+                "held_ms": round(held * 1e3, 3),
+                "return_hit_share": round(self._hit_share, 3)}
 
     def _loop(self) -> None:
         while True:
@@ -270,118 +460,154 @@ class TopNBatcher:
                         self._await_locked("serving.await_work",
                                            self._in_flight == 0)
                         continue
-                    # Hold-time is measured from the oldest pending
-                    # arrival's age, not time since the last dispatch —
-                    # a stale last-dispatch timestamp after an idle gap
-                    # must not extend the hold.
-                    age = clockmod.monotonic() - self._pending[0].t_enq
-                    full = len(self._pending) >= self.max_batch
                     if self._in_flight >= self._in_flight_target():
                         # at the in-flight cap: a full queue must NOT
                         # add dispatches — extra depth only stacks
                         # device-queue latency onto every later request.
-                        # Batching under load comes from HERE, not from
-                        # pacing: a blocked dispatcher wakes on the next
-                        # completion and drains everything that queued
-                        # during one service interval.
+                        # Batching under load comes from HERE: a blocked
+                        # dispatcher wakes on the next completion and
+                        # drains everything that queued during one
+                        # service interval.
                         self._await_locked("serving.await_slot", True)
                         continue
-                    # below the in-flight cap: hold only briefly so a
-                    # synchronized burst coalesces, then go.  A lone
-                    # request on an unloaded server must NOT pay a
-                    # service-interval hold — an exec EWMA learned
-                    # from completion gaps includes the dispatch round
-                    # trip and can run far above true device time, and
-                    # that hold was most of the unloaded p50 above the
-                    # transport floor.  With a locally
-                    # attached chip (tiny measured round trip) don't
-                    # hold at all.
-                    cap = self._idle_wait
-                    if cap is None:
-                        rtt = self._wall_min - self._exec_ewma
-                        cap = 0.002 if rtt > 0.005 else 0.0
-                    wait = min(cap, self._exec_ewma / 8) - age
-                    if full or wait <= 0:
+                    if len(self._pending) >= self.max_batch:
                         break
-                    self._cond.wait(wait)  # wall-clock: Condition poll on the real dispatch thread
-                if self._stopped:
+                    # a slot is free: go, unless callers the last
+                    # completion released are still out and likely back
+                    # within the hold.  A lone request on an unloaded
+                    # server never waits here: nobody is out.
+                    wait = self._hold_locked(clockmod.monotonic())
+                    if wait <= 0:
+                        break
+                    self._await_locked("serving.await_return", True, wait)
+                stopped = self._stopped
+                if stopped:
                     jobs, self._pending = self._pending, []
+                    note = None
                 else:
+                    t0 = clockmod.monotonic()
+                    note = self._bind_locked(t0)
                     jobs = self._pending[:self.max_batch]
                     del self._pending[:self.max_batch]
+                    lone = self._in_flight == 0
+                    probe = self._probe_out  # set by this bind, or not
                     self._in_flight += 1
-                    self._last_dispatch = clockmod.monotonic()
-                stopped = self._stopped
-            scored = 0
-            if jobs:
-                t0 = clockmod.monotonic()
-                scored = self._dispatch(jobs)
-                wall = clockmod.monotonic() - t0
-            if not stopped:
-                with self._cond:
-                    self._in_flight -= 1
-                    if not scored:
-                        # every job was deadline-shed: no device call
-                        # happened, and folding the near-zero wall into
-                        # the estimators would collapse _wall_min /
-                        # _exec_ewma and disable coalescing long after
-                        # the deadline burst ends
-                        self._wake_locked(2)
-                        continue
-                    now = clockmod.monotonic()
-                    # decay toward recent walls so a transient stall
-                    # (compile, GC) cannot pin the round-trip estimate
-                    self._wall_min = min(self._wall_min * 1.02, wall)
-                    if self._last_completion:
-                        gap = now - self._last_completion
-                        if self._in_flight > 0 and gap < _MAX_EXEC_S:
-                            # overlapped completions: the gap measures
-                            # the device's per-dispatch service time
-                            self._exec_ewma = min(_MAX_EXEC_S, max(
-                                _MIN_EXEC_S,
-                                0.7 * self._exec_ewma + 0.3 * gap))
-                    # a dispatch's whole wall (round trip + exec) upper-
-                    # bounds exec: clamping lets the estimate relearn
-                    # DOWNWARD after a hot-swap to a smaller model or an
-                    # anomalous gap, where gap-based learning alone
-                    # would lock pacing into serial dispatch forever
-                    self._exec_ewma = max(_MIN_EXEC_S,
-                                          min(self._exec_ewma, wall))
-                    self._last_completion = now
-                    # wake a couple of waiters, not the whole pipeline:
-                    # notify_all costs O(threads) lock churn per
-                    # completion, and pacing waiters self-wake on their
-                    # timeout anyway
-                    self._wake_locked(2)
+            scored = self._dispatch(jobs, note) if jobs else 0
             if stopped:
                 return
+            with self._cond:
+                self._in_flight -= 1
+                if probe:
+                    self._probe_out = False
+                # a drain whose every job was deadline-shed made no
+                # device call: folding its near-zero wall into the
+                # estimators would collapse them long after the
+                # deadline burst ends
+                if scored:
+                    self._learn_locked(t0, lone)
+                # this thread goes round and takes the next drain
+                # itself if one can leave.  It wakes another only for
+                # what it cannot do: end the wait of the thread that
+                # carries the pool's state, or fill a second slot where
+                # the depth has room for one.  Anyone else woken here
+                # finds nothing to do and takes the interpreter from
+                # the handlers that have answers to send
+                if self._noted_by is not None or (
+                        self._pending
+                        and self._in_flight + 1 < self._in_flight_target()):
+                    self._wake_locked(1)
 
-    def _await_locked(self, name: str, pool_state: bool) -> None:
-        """Wait on the condition (held by the caller) until notified.
-        With a tracer, and where the wait is the whole pool's state and
-        not just this thread's (``pool_state``), ONE thread at a time
-        takes it as a profiler annotation of that name: a device-idle
-        gap in which the host had nothing to dispatch then says so,
-        where it would otherwise carry no host event at all.  That
-        thread is the first one :meth:`_wake_locked` wakes, so the
-        annotation ends when the state does and covers no later gap.
-        No ring span: no request owns the wait."""
-        if self._tracer is None or not pool_state or self._noted_waiting:
-            self._cond.wait()  # wall-clock: Condition poll on the real dispatch thread
+    def _learn_locked(self, t0: float, lone: bool) -> None:
+        """A drain dispatched at ``t0`` (``lone``: with nothing in
+        flight) has completed: what its wall and the gap since the last
+        completion say of the device."""
+        now = clockmod.monotonic()
+        wall = now - t0
+        self._since_lone += 1
+        self._since_gap += 1
+        self._since_hold += 1
+        if lone:
+            self._probe_taken(self._since_lone)
+            # decay toward recent walls so a transient stall (compile,
+            # GC) cannot pin the round-trip estimate
+            self._wall_min = min(self._wall_min * 1.02, wall)
+            self._since_lone = 0
+        gap = now - self._last_completion
+        if t0 < self._last_completion and gap < _MAX_EXEC_S:
+            # it was already dispatched when the drain before it
+            # completed, so it waited on the device's queue and the gap
+            # between the two completions is the device's per-dispatch
+            # service time with its queue kept fed
+            self._probe_taken(self._since_gap)
+            self._learn_exec(gap)
+            if self._wall_min != float("inf"):
+                was_serial = self._serial()
+                hidden = max(0.0, 1.0 - gap / self._wall_min)
+                self._overlap = hidden if self._overlap is None \
+                    else 0.7 * self._overlap + 0.3 * hidden
+                if self._serial() != was_serial:
+                    # the answer changed: look again soon
+                    self._probe_every = _PROBE_EVERY
+            self._since_gap = 0
+        elif lone and (self._serial() or len(self._threads) == 1):
+            # at a depth of one no completion gap is ever seen; a lone
+            # drain's wall IS the service time there
+            self._learn_exec(wall)
+        # a dispatch's whole wall (round trip + exec) upper-bounds exec:
+        # clamping lets the estimate relearn DOWNWARD after a hot-swap
+        # to a smaller model or an anomalous gap
+        self._exec_ewma = max(_MIN_EXEC_S, min(self._exec_ewma, wall))
+        self._last_completion = now
+
+    def _probe_taken(self, since: int) -> None:
+        """One of the two measurements has come in, ``since``
+        completions after it was last seen.  Where that is more than the
+        count that arms a probe, the depth in force was hiding it and a
+        probe took it: ask again after twice as many."""
+        if since > self._probe_every and self._overlap is not None:
+            self.probes += 1
+            self._probe_every = min(_PROBE_EVERY_MAX,
+                                    2 * self._probe_every)
+
+    def _learn_exec(self, sample: float) -> None:
+        if self._exec_measured:
+            sample = 0.7 * self._exec_ewma + 0.3 * sample
+        self._exec_measured = True
+        self._exec_ewma = min(_MAX_EXEC_S, max(_MIN_EXEC_S, sample))
+
+    def _await_locked(self, name: str, pool_state: bool,
+                      timeout: float | None = None) -> None:
+        """Wait on the condition (held by the caller) until notified or
+        ``timeout`` seconds have passed.  Where the wait is the whole
+        pool's state and not just this thread's (``pool_state``), ONE
+        thread at a time takes it: that thread is the first one
+        :meth:`_wake_locked` wakes, so whatever ends the state — work
+        arrived, a dispatch completed, a caller came back — reaches the
+        thread that waits for it.  With a tracer the wait is also a
+        profiler annotation of that name: a device-idle gap in which
+        the host had nothing to dispatch then says so, where it would
+        otherwise carry no host event at all, and the annotation ends
+        when the state does and covers no later gap.  No ring span: no
+        request owns the wait."""
+        if not pool_state or self._noted_by is not None:
+            self._cond.wait(timeout)  # wall-clock: Condition poll on the real dispatch thread
             return
-        with obstrace.annotation(name):
-            # cleared by whoever wakes this thread (_wake_locked,
-            # close()): by the time it runs again another may hold
-            # the place
-            self._noted_waiting = True
-            self._noted.wait()  # wall-clock: Condition poll on the real dispatch thread
+        me = self._noted_by = object()
+        with obstrace.annotation(name) if self._tracer is not None \
+                else obstrace.NOOP_SPAN:
+            self._noted.wait(timeout)  # wall-clock: Condition poll on the real dispatch thread
+        # cleared by whoever woke this thread (_wake_locked, close());
+        # after a timeout it is still this thread's to clear, unless
+        # another has taken the place since
+        if self._noted_by is me:
+            self._noted_by = None
 
     def _wake_locked(self, n: int) -> None:
         """Wake ``n`` waiting dispatchers (the caller holds the
-        condition): the annotated one first, whose state has just
-        ended — work arrived, or a dispatch completed."""
-        if self._noted_waiting:
-            self._noted_waiting = False
+        condition): the one that carries the pool's state first, whose
+        state has just ended — work arrived, or a dispatch completed."""
+        if self._noted_by is not None:
+            self._noted_by = None
             self._noted.notify()
             n -= 1
         if n:
@@ -389,11 +615,15 @@ class TopNBatcher:
 
     def _record_spans(self, group: list[_Job], t_exec: float,
                       t_done: float, status: str,
-                      phases: obstrace.DrainPhases) -> None:
+                      phases: obstrace.DrainPhases,
+                      note: dict | None) -> None:
         """Queue-wait / device-execute spans for the sampled jobs of a
         drained group, and the drain's phases under each job's
         device-execute span (grandchildren of the request, so the
-        request's own children stay the two they were).  Recorded
+        request's own children stay the two they were).  The queue-wait
+        span carries ``note``: the depth in force and why, how long the
+        drain was held for returning callers, the hold's score
+        (:meth:`_bind_locked`; shared, read-only from here on).  Recorded
         retroactively from stored monotonic stamps (the dispatcher has
         no thread-local trace context), and strictly best-effort — the
         tracer absorbs recorder failures."""
@@ -408,15 +638,19 @@ class TopNBatcher:
             exec_attrs["kernel_route"] = route
         for j in traced:
             self._tracer.record_span("serving.queue_wait", j.trace_ctx,
-                                     j.t_enq, t_exec)
+                                     j.t_enq, t_exec, note)
             exec_id = self._tracer.record_span(
                 "serving.device_execute", j.trace_ctx, t_exec, t_done,
                 dict(exec_attrs), status)
             phases.replay(self._tracer, j.trace_ctx[0], exec_id)
 
-    def _dispatch(self, jobs: list[_Job]) -> int:
+    def _dispatch(self, jobs: list[_Job], note: dict | None = None) -> int:
         """Score a drained batch; returns how many jobs actually reached
-        the device (0 = all shed, caller must not learn pacing from it)."""
+        the device (0 = all shed, caller must not learn pacing from it).
+        The slot the drain holds frees when this returns, after the
+        decode of its results: 0.07 ms at two callers, and freeing it at
+        the fetch would take the model telling the batcher so with
+        tracing off, which only the phase recorder could carry."""
         # shed jobs whose budget expired while queued: their client has
         # already given up, and scoring them would tax every live job in
         # the same drain with their share of the device time
@@ -498,7 +732,7 @@ class TopNBatcher:
                     next_exec_start - t_exec)
             if phases is not None:
                 self._record_spans(group, t_exec, next_exec_start,
-                                   status, phases)
+                                   status, phases, note)
             with self._cond:
                 # under the lock: up to `pipeline` dispatcher threads
                 # land here concurrently, and a bare += loses updates
@@ -506,6 +740,11 @@ class TopNBatcher:
                 self.total_dispatches += 1
                 if len(self.batch_sizes) > 10000:
                     del self.batch_sizes[:5000]
+                # these callers are out with their answers from here
+                # on (before they are released: one that is back at
+                # once is counted), as far as the next drain has room
+                self._awaited = max(0, min(
+                    len(group), self.max_batch - len(self._pending)))
             for j in group:
                 j.done.set()
         return len(jobs)

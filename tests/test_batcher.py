@@ -422,3 +422,406 @@ def test_deadline_expiring_while_queued_is_shed_at_dispatch():
     assert len(outcome["first"]) == 3
     assert outcome["second"] == "shed"
     assert batcher.deadline_rejects >= 1
+
+
+# -- binding late, and holding a drain for the callers just answered ---------
+
+class _SerialDevice:
+    """What a locally attached chip looks like from the batcher: calls
+    run one after another, ``exec_s`` each, and answer at once.  The
+    first feature of every query is its arrival, in seconds since
+    ``t0``, so the log says how long each request waited while the
+    device was free."""
+
+    def __init__(self, exec_s: float, overlapping: bool = False):
+        self.exec_s, self.overlapping = exec_s, overlapping
+        self.lock = threading.Lock()
+        self.t0 = time.monotonic()
+        self.calls: list[tuple[float, float, list[float]]] = []
+
+    def vector(self) -> np.ndarray:
+        return np.asarray([time.monotonic() - self.t0, 0, 0, 0], np.float32)
+
+    def top_n_batch(self, how_many, vectors, exclude=None):
+        if self.overlapping:
+            time.sleep(self.exec_s)
+        else:
+            with self.lock:
+                start = time.monotonic() - self.t0
+                time.sleep(self.exec_s)
+                self.calls.append((start, time.monotonic() - self.t0,
+                                   [float(v[0]) for v in vectors]))
+        return [[("i0", 1.0)] * h for h in how_many]
+
+    def idle_waits(self) -> list[float]:
+        """Per request, the seconds it was queued while the device had
+        nothing to run: from its arrival, or the end of the program
+        before its own, to the start of its own."""
+        out, free_at = [], 0.0
+        for start, end, arrivals in self.calls:
+            out += [max(0.0, start - max(free_at, a)) for a in arrivals]
+            free_at = end
+        return out
+
+
+def _closed_loop(batcher, device, callers: int, seconds: float,
+                 turnaround=(0.0005, 0.003), delay=None) -> list[float]:
+    """``callers`` threads, each sending its next request a seeded
+    turnaround after its last answer; ``delay`` = (request numbers,
+    seconds) holds caller 0 back before each of them.  Returns every
+    request's wall."""
+    import random
+
+    walls: list[float] = []
+    stop = time.monotonic() + seconds
+
+    def caller(i):
+        rng, sent = random.Random(i), 0
+        while time.monotonic() < stop:
+            sent += 1
+            if delay and i == 0 and sent in delay[0]:
+                time.sleep(delay[1])
+            t = time.monotonic()
+            assert len(batcher.top_n(device, 3, device.vector())) == 3
+            walls.append(time.monotonic() - t)
+            time.sleep(rng.uniform(*turnaround))
+
+    threads = [threading.Thread(target=caller, args=(i,))
+               for i in range(callers)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(seconds + 10.0)
+        assert not t.is_alive()
+    return walls
+
+
+# sizes of the drains before the batcher has seen two programs queue one
+# behind the other and the callers have fallen into step
+_WARM_IN = 8
+
+
+@pytest.mark.parametrize("callers, mean_batch", [(2, 1.9), (8, 7.0)])
+def test_closed_loop_callers_share_one_pass(callers, mean_batch):
+    """On a serial device every caller that is waiting rides in the one
+    program: a request is one service time and the hold long, not a
+    program of its own behind everybody else's."""
+    device = _SerialDevice(0.05)
+    batcher = TopNBatcher(pipeline=8, idle_wait_s=0.02)
+    try:
+        walls = _closed_loop(batcher, device, callers, 2.0)
+        stats = batcher.stats()
+        sizes = batcher.batch_sizes[_WARM_IN:]
+    finally:
+        batcher.close()
+    assert stats["in_flight_target"] == 1, stats
+    assert stats["depth_reason"] == "serial", stats
+    assert stats["return_hold"] == "on" and stats["return_holds"] > 0, stats
+    assert stats["return_hit_share"] > 0.8, stats
+    assert sum(sizes) / len(sizes) >= mean_batch, sizes
+    assert sorted(sizes)[len(sizes) // 2] == callers, sizes
+    walls.sort()
+    hold = stats["return_hold_ms"] / 1e3
+    # one service time, the turnaround of the slowest caller and the
+    # hold; two programs would be 0.1 s
+    assert walls[len(walls) // 2] < 0.05 + 0.003 + hold + 0.01, \
+        walls[len(walls) // 2]
+
+
+def test_a_delayed_caller_is_back_in_step_within_two_programs():
+    device = _SerialDevice(0.05)
+    batcher = TopNBatcher(pipeline=8, idle_wait_s=0.02)
+    try:
+        # past the 6.25 ms hold, inside the other caller's program
+        _closed_loop(batcher, device, 2, 2.5, delay=((16,), 0.03))
+        sizes = batcher.batch_sizes[_WARM_IN:]
+    finally:
+        batcher.close()
+    assert 1 in sizes, sizes          # the pair did fall out of step
+    alone = 0
+    for n in sizes:
+        alone = alone + 1 if n == 1 else 0
+        assert alone <= 2, sizes      # and never stayed there
+    assert sizes[-3:-1] == [2, 2], sizes
+
+
+def test_a_lone_closed_loop_caller_is_never_held():
+    device = _SerialDevice(0.03)
+    batcher = TopNBatcher(pipeline=8, idle_wait_s=0.02)
+    try:
+        # two callers first, so that the device is known to be serial
+        # and the hold is armed
+        _closed_loop(batcher, device, 2, 0.6)
+        assert batcher.stats()["depth_reason"] == "serial"
+        assert batcher.stats()["return_hold_ms"] > 3.0
+        # the last pair's second caller never comes back: one miss
+        batcher.top_n(device, 3, device.vector())
+        holds = batcher.return_holds
+        walls = _closed_loop(batcher, device, 1, 0.5)
+        assert batcher.return_holds == holds
+        walls.sort()
+        assert len(walls) >= 10 and walls[len(walls) // 2] < 0.03 + 0.01, \
+            walls
+    finally:
+        batcher.close()
+
+
+def test_open_loop_arrivals_switch_the_hold_off():
+    """Seeded Poisson arrivals at a fifth of what the device serves one
+    by one: whoever was just answered does not come back, the hold's hit
+    share falls, the hold goes off but for its probes, and what it cost
+    a request is well under one hold."""
+    import random
+
+    device = _SerialDevice(0.01)
+    batcher = TopNBatcher(pipeline=8)
+    rng, due, t = random.Random(28), [], 0.0
+    while t < 4.0:
+        t += rng.expovariate(20.0)
+        due.append(t)
+
+    def one():
+        assert len(batcher.top_n(device, 3, device.vector())) == 3
+
+    try:
+        threads = []
+        for d in due:
+            time.sleep(max(0.0, device.t0 + d - time.monotonic()))
+            threads.append(threading.Thread(target=one))
+            threads[-1].start()
+        for th in threads:
+            th.join(10.0)
+            assert not th.is_alive()
+        stats = batcher.stats()
+    finally:
+        batcher.close()
+    assert stats["depth_reason"] == "serial", stats
+    assert stats["return_hold"] == "off", stats
+    assert stats["return_hit_share"] < 0.5, stats
+    # off, a drain holds once in 32 completions: far fewer than there
+    # were requests behind a running program
+    assert 7 <= stats["return_holds"] <= len(due) // 5, stats
+    waited = sorted(device.idle_waits())
+    assert len(waited) == len(due)
+    # less the three worst: a stall of the test's host is no hold
+    mean = sum(waited[:-3]) / len(waited[:-3])
+    assert mean < stats["return_hold_ms"] / 1e3, (mean, stats)
+
+
+def test_an_overlapping_device_keeps_its_deep_pipeline():
+    """Where device calls overlap, a second program in flight hides
+    nearly all of the first: no binding late."""
+    device = _SerialDevice(0.03, overlapping=True)
+    batcher = TopNBatcher(pipeline=8)
+    try:
+        _closed_loop(batcher, device, 6, 0.6, turnaround=(0.001, 0.02))
+        stats = batcher.stats()
+    finally:
+        batcher.close()
+    assert stats["depth_reason"] == "pipelined", stats
+    assert stats["in_flight_target"] > 1, stats
+    assert stats["overlap_share"] > 0.5, stats
+
+
+def test_the_queue_wait_span_says_what_the_batcher_did():
+    from oryx_tpu.obs.trace import Tracer
+
+    tracer = Tracer("serving", sample_ratio=1.0, max_traces=4096)
+    device = _SerialDevice(0.03)
+    batcher = TopNBatcher(pipeline=8, idle_wait_s=0.02, tracer=tracer)
+    stop = time.monotonic() + 1.0
+
+    def caller(i):
+        while time.monotonic() < stop:
+            req = tracer.begin_request("serving.request")
+            batcher.top_n(device, 3, device.vector())
+            tracer.end_request(req, 200)
+            time.sleep(0.001 + 0.002 * i)
+
+    threads = [threading.Thread(target=caller, args=(i,)) for i in range(2)]
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(10.0)
+            assert not t.is_alive()
+    finally:
+        batcher.close()
+    waits = [s for spans in tracer.traces_snapshot(limit=4096).values()
+             for s in spans if s["name"] == "serving.queue_wait"]
+    assert waits
+    assert all({"depth", "depth_reason", "held_ms", "return_hit_share"}
+               <= set(s["attrs"]) for s in waits)
+    late = [s["attrs"] for s in waits[len(waits) // 2:]]
+    assert {a["depth_reason"] for a in late} == {"serial"}
+    assert {a["depth"] for a in late} == {1}
+    assert any(a["held_ms"] > 0 for a in late)
+
+
+def test_no_wakeup_is_lost_under_a_crowd_of_callers():
+    """More callers than cores, a device fast enough that holds time out
+    and arrivals end them all the time, and a short switch interval: a
+    lost wake-up would leave a request queued with every dispatcher
+    parked, and a lost update would unbalance the books."""
+    import sys
+
+    device = _SerialDevice(0.0005)
+    batcher = TopNBatcher(pipeline=8, idle_wait_s=0.0005)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        walls = _closed_loop(batcher, device, 32, 1.5,
+                             turnaround=(0.0, 0.0004))
+        stats = batcher.stats()
+        answered = sum(batcher.batch_sizes)
+    finally:
+        sys.setswitchinterval(interval)
+        batcher.close()
+    assert len(walls) == answered == sum(len(c[2]) for c in device.calls)
+    assert stats["in_flight"] == 0 and stats["pending"] == 0, stats
+    assert stats["dispatches"] == len(device.calls)
+    assert 0 <= stats["return_hits"] and stats["return_holds"] > 0, stats
+    assert max(walls) < 1.0, max(walls)
+
+
+def test_a_probe_costs_one_program_and_asks_less_often(monkeypatch):
+    """At a depth of one the completion gap of a queued pair is hidden,
+    so once in a while ONE drain is bound behind a running program to
+    take it.  Callers in step never arrive while a program runs, so the
+    probe waits for a pair that has split by itself, costs one lone
+    program beside the split's own, and every probe that finds the
+    device still serial doubles the completions to the next."""
+    from oryx_tpu.serving import batcher as batcher_mod
+
+    monkeypatch.setattr(batcher_mod, "_PROBE_EVERY", 4)
+    device = _SerialDevice(0.02)
+    batcher = TopNBatcher(pipeline=8, idle_wait_s=0.005)
+    splits = (20, 45, 80)
+    try:
+        # past the 2.5 ms hold, inside the other caller's program
+        _closed_loop(batcher, device, 2, 2.6, turnaround=(0.0005, 0.002),
+                     delay=(splits, 0.012))
+        stats = batcher.stats()
+        sizes = batcher.batch_sizes[_WARM_IN:]
+    finally:
+        batcher.close()
+    # (armed again by the end, or not)
+    assert stats["depth_reason"] in ("serial", "serial-probe"), stats
+    # the splits let probes in (a loaded host adds splits of its own),
+    # and probe n waits for 4 * 2 ** n completions after the one before
+    probes = stats["probes"]
+    assert probes >= 1 and 4 * (2 ** probes - 1) <= stats["dispatches"], \
+        stats
+    assert stats["probe_every"] == 4 * 2 ** probes, stats
+    # (that a probe is ONE drain is pinned without threads, below)
+    assert sum(sizes) / len(sizes) >= 1.5, sizes
+
+
+def test_probes_back_off_while_the_answer_holds_and_return_when_it_changes():
+    """The count that arms a probe doubles with every probe up to its
+    cap, and an answer that changes puts it back: driven through the
+    estimator alone, with stamps of a device that is serial and then is
+    not."""
+    from oryx_tpu.serving import batcher as batcher_mod
+
+    batcher = TopNBatcher(pipeline=2)
+    try:
+        with batcher._cond:
+            # a drain that ran alone for 10 ms, then one that was
+            # dispatched 20 ms ago behind another that completed 10 ms
+            # ago: a serial device
+            batcher._learn_locked(time.monotonic() - 0.010, lone=True)
+            t = time.monotonic()
+            batcher._last_completion = t - 0.010
+            batcher._learn_locked(t - 0.020, lone=False)
+            assert batcher._depth() == (1, "serial")
+            every = batcher_mod._PROBE_EVERY
+            for _ in range(8):
+                batcher._since_gap = batcher._probe_every
+                assert batcher._depth() == (2, "serial-probe")
+                batcher._in_flight = 1
+                batcher._bind_locked(time.monotonic())
+                assert batcher._probe_out
+                # the probe's one drain is out: nobody else goes behind
+                assert batcher._depth() == (1, "serial")
+                batcher._in_flight = 0
+                batcher._probe_out = False
+                t = time.monotonic()
+                batcher._last_completion = t - 0.010
+                batcher._learn_locked(t - 0.020, lone=False)
+                every = min(batcher_mod._PROBE_EVERY_MAX, 2 * every)
+                assert batcher._probe_every == every
+                assert batcher._depth() == (1, "serial")
+            assert every == batcher_mod._PROBE_EVERY_MAX
+            assert batcher.probes == 8
+            # the device starts to overlap: the queued drain completes
+            # 1 ms after the one before it
+            for _ in range(6):
+                t = time.monotonic()
+                batcher._last_completion = t - 0.001
+                batcher._learn_locked(t - 0.011, lone=False)
+            assert batcher._depth()[1] == "pipelined"
+            assert batcher._probe_every == batcher_mod._PROBE_EVERY
+    finally:
+        batcher.close()
+
+
+def test_a_completion_wakes_nobody_who_has_nothing_to_do():
+    """The dispatcher whose drain completed goes round and takes the
+    next one itself; parked dispatchers woken at a completion would find
+    nothing queued and take the interpreter from the handlers that have
+    answers to send.  With two callers in step the only thread woken is
+    the one that carries the pool's state, by an arrival."""
+    device = _SerialDevice(0.02)
+    batcher = TopNBatcher(pipeline=8, idle_wait_s=0.01)
+    woken = []
+    notify = batcher._cond.notify
+    batcher._cond.notify = lambda n=1: (woken.append(n), notify(n))[1]
+    try:
+        _closed_loop(batcher, device, 2, 1.5)
+        drains = batcher.total_dispatches
+        sizes = batcher.batch_sizes[_WARM_IN:]
+    finally:
+        batcher.close()
+    assert drains > 40 and sum(sizes) / len(sizes) >= 1.5, sizes
+    # the parked pool is woken for the first requests, before the device
+    # is known to be serial, and when a caller falls out of step; two
+    # woken at every completion would be twice the drains
+    assert sum(woken) <= drains, (sum(woken), drains)
+
+
+def test_callers_that_turn_closed_loop_get_the_hold_back_soon():
+    """After open-loop traffic has switched the hold off, one drain in
+    32 completions holds all the same; a hold in which everyone came
+    back is followed by another at the next completion, so callers that
+    turn closed-loop have the hold back some forty programs later, not
+    two hundred.  Driven through the hold's own steps, without threads:
+    per completion one caller is out, and comes back inside any hold."""
+    batcher = TopNBatcher(pipeline=2)
+    try:
+        with batcher._cond:
+            batcher._exec_ewma, batcher._exec_measured = 0.016, True
+            batcher._hit_share = 0.05   # what an open loop leaves
+            completions = 0
+            while batcher._hit_share < 0.5:
+                completions += 1
+                assert completions < 100
+                batcher._since_hold += 1     # _learn_locked
+                batcher._awaited = 1         # _dispatch, after the fetch
+                if batcher._hold_locked(time.monotonic()) > 0:
+                    batcher._awaited -= 1    # top_n: the caller is back
+                    batcher._hold_hits += 1
+                batcher._bind_locked(time.monotonic())
+            assert 32 <= completions <= 40, completions
+            # an open loop's caller does not come back: the one hold in
+            # 32 is scored a miss and is not repeated
+            batcher._hit_share, batcher._since_hold = 0.05, 0
+            holds = batcher.return_holds
+            for _ in range(64):
+                batcher._since_hold += 1
+                batcher._awaited = 1
+                batcher._hold_locked(time.monotonic())
+                batcher._bind_locked(time.monotonic())
+            assert batcher.return_holds - holds == 2
+    finally:
+        batcher.close()
