@@ -104,9 +104,12 @@ class Checker:
         Each shape has two programs, the two-phase scan and the exact
         scan that answers when its certificate fails.  Real users'
         vectors are scored, new ones each time, until
-        ``twophase_fallbacks`` has moved: at once for a width the
-        certificate cannot hold, after a few tries for the others (some
-        3-4% of rows miss, my chip run PR 22)."""
+        ``twophase_fallbacks`` has moved or ``WARM_TRIES`` are spent.
+        Since PR 26 the scan selects max(32, 2k) blocks and no
+        certificate fails at any width (``twophase_fallbacks`` 0 over
+        ~146,000 requests), so every shape spends all its tries: the
+        loop warms the two-phase program and hunts in vain for the
+        other, about 5 s of ``setup_s`` (PERF.md section 7)."""
         model, widths, how_many = self.model, self.widths, self.how_many
         traffic = self.traffic
         deepest = int(traffic["clients"]) if traffic["loop"] == "closed" \

@@ -1,8 +1,13 @@
-"""The six per-layer metrics that open ``serving.device_execute``: three
-medians over the program's drain-phase spans (``span_median.py`` through
-new layer files) and three that read one program's own device time from
-the reduced trace (``trace_module_ms.py``, ``trace_module_roofline.py``),
-each checked against numbers worked out by hand."""
+"""The per-layer metrics that open ``serving.device_execute``: medians
+over the program's drain-phase spans (``span_median.py`` through layer
+files) and one program's own device time from the reduced trace
+(``trace_module_ms.py``, ``trace_module_roofline.py``), each checked
+against numbers worked out by hand.  PR 24 brought six; the two that
+read the exact-scan fallback (``dispatch.fallback_ms``,
+``kernel.exact_scan_ms``) were retired in PR 31, since no certificate
+has failed since PR 26.  Their readers stay, other metrics use them, so
+the fallback's span and program are still read here, through metrics
+built by hand (``RETIRED``)."""
 
 import json
 import os
@@ -25,29 +30,39 @@ NEW = {
     "dispatch.scan_ms": ("ms", "program_span", "dispatch",
                          "latency_p50_ms", "span_median.py",
                          {"span": "serving.scan"}),
-    "dispatch.fallback_ms": ("ms", "program_span", "dispatch",
-                             "latency_p99_ms", "span_median.py",
-                             {"span": "serving.fallback"}),
     "dispatch.decode_ms": ("ms", "program_span", "dispatch",
                            "latency_p50_ms", "span_median.py",
                            {"span": "serving.decode"}),
     "kernel.twophase_ms": ("ms", "device_trace", "kernels",
                            "latency_p50_ms", "trace_module_ms.py",
                            {"module": "twophase"}),
-    "kernel.exact_scan_ms": ("ms", "device_trace", "kernels",
-                             "latency_p99_ms", "trace_module_ms.py",
-                             {"module": "chunked_kernel"}),
     "kernel.twophase_roofline": ("%", "device_trace", "kernels",
                                  "latency_p50_ms",
                                  "trace_module_roofline.py",
                                  {"module": "twophase"}),
 }
+# what the two retired metrics read, as a later PR would name it again
+RETIRED = {
+    "dispatch.fallback_ms": manifest.LayerMetric(
+        name="dispatch.fallback_ms", unit="ms", source="program_span",
+        layer="dispatch", moves="latency_p99_ms", reader="span_median.py",
+        params={"span": "serving.fallback"}),
+    "kernel.exact_scan_ms": manifest.LayerMetric(
+        name="kernel.exact_scan_ms", unit="ms", source="device_trace",
+        layer="kernels", moves="latency_p99_ms",
+        reader="trace_module_ms.py", params={"module": "chunked_kernel"}),
+}
 STORE_250F = {"rows": 20_054_016, "device_features": 250, "itemsize": 2}
 
 
-def _metrics(cell: str = CELLS[0]) -> dict:
+def _listed(cell: str) -> dict:
+    """The per-layer metrics ``BENCHMARK.json`` lists for ``cell``."""
     resolved = manifest.resolve(ROOT, "BENCHMARK.json", cell)
     return {m.name: m for m in resolved.per_layer}
+
+
+def _metrics(cell: str = CELLS[0]) -> dict:
+    return dict(RETIRED, **_listed(cell))
 
 
 def _obs(spans=(), trace=None, batch_sizes=(), peaks=None) -> Observations:
@@ -62,24 +77,35 @@ def recorded():
 
 
 @pytest.mark.parametrize("cell", CELLS)
-def test_the_six_resolve_in_both_cells_as_the_table_has_them(cell):
-    metrics = _metrics(cell)
-    assert len(metrics) == 13
+def test_the_four_resolve_in_both_cells_as_the_table_has_them(cell):
+    metrics = _listed(cell)
+    # the static cells' twelve, all of which read since PR 31
+    assert len(metrics) == 12 and not set(RETIRED) & set(metrics)
     for name, (unit, source, layer, moves, reader, params) in NEW.items():
         m = metrics[name]
         assert (m.unit, m.source, m.layer, m.moves, m.reader, m.params) \
             == (unit, source, layer, moves, reader, params)
 
 
-def test_the_manifest_appends_them_and_touches_nothing_else():
+def test_the_manifest_keeps_them_in_order_for_every_cell():
+    """PR 24 appended them in one block; PR 27 and PR 29 appended
+    theirs behind, and PR 31 took the two retired ones out and their
+    layer files with them."""
     with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
         per_layer = json.load(f)["per_layer"]
-    assert [m["name"] for m in per_layer[-6:]] == list(NEW)
-    for m in per_layer[-6:]:
+    names = [m["name"] for m in per_layer]
+    first = names.index(next(iter(NEW)))
+    assert names[first:first + len(NEW)] == list(NEW)
+    for m in per_layer[first:first + len(NEW)]:
+        # no ``workloads`` key: every cell reports them
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves"}
         assert m["better"] == ("higher" if m["name"].endswith("_roofline")
                                else "lower")
+    for name in RETIRED:
+        assert name not in names
+        assert not os.path.exists(os.path.join(
+            ROOT, "benchmark", "layers", name + ".json"))
 
 
 def test_one_programs_own_time_on_the_recorded_v5e_trace(recorded):

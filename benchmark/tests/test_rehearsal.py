@@ -82,5 +82,6 @@ def test_rehearsal_reports_counts_and_correct_only(cell, trace):
         assert 0 < detail["spans_recorded"]
     else:
         assert last["metrics"] == {}
-        # users with more than 22 known items went through the fallback
-        assert detail["counters"]["end"]["twophase_fallbacks"] > 0
+        # since PR 26 the scan selects as many blocks as the request
+        # fetches: users with more than 22 known items are certified too
+        assert detail["counters"]["end"]["twophase_fallbacks"] == 0
