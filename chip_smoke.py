@@ -49,10 +49,10 @@ import urllib.request
 
 import numpy as np
 
-# The reference's published exact-scan configuration (BASELINE.md) and
-# bench.py's shape.  1M items pad to a 1,048,576-row store: past the
-# streaming threshold and a multiple of the Pallas tile, so all four
-# Pallas phase-A builds are eligible.
+# The reference's published exact-scan configuration (BASELINE.md).
+# 1M items pad to a 1,048,576-row store: past the streaming threshold
+# and a multiple of the Pallas tile, so all four Pallas phase-A builds
+# are eligible.
 FEATURES = 50
 ITEMS = 1_000_000
 PALLAS_KINDS = ("i8_fold", "fold", "i8", "pallas")

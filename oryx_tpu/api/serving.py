@@ -16,7 +16,7 @@ from ..kafka.api import KeyMessage
 
 __all__ = [
     "ServingModel", "ServingModelManager", "AbstractServingModelManager",
-    "OryxServingException", "HasCSV",
+    "StaticModelManager", "OryxServingException", "HasCSV",
 ]
 
 
@@ -68,6 +68,26 @@ class AbstractServingModelManager(ServingModelManager):
 
     @abc.abstractmethod
     def consume_key_message(self, key: str | None, message: str) -> None: ...
+
+
+class StaticModelManager(ServingModelManager):
+    """Read-only manager serving a prebuilt model, for endpoint tests and
+    dry runs (reference test scope: MockServingModelManager.java:27).
+    Subclass per use and set the ``model`` class attribute."""
+
+    model = None
+
+    def __init__(self, config=None):
+        pass
+
+    def consume(self, updates) -> None:
+        pass
+
+    def get_model(self):
+        return type(self).model
+
+    def is_read_only(self) -> bool:
+        return True
 
 
 class OryxServingException(Exception):

@@ -71,7 +71,7 @@ def planned_capacity(n_rows: int, initial_capacity: int = 1024) -> int:
     deploy-time AOT warmup (deploy/warmup.py) uses this to lower the
     kernel ladder with the EXACT shapes a later model load produces;
     keep it in lock-step with ``__init__``/``_grow`` (and tested
-    against a real bulk_load in tests/test_bench_tools.py)."""
+    against a real bulk_load in tests/test_warmup.py)."""
     cap = max(16, initial_capacity)
     if n_rows > cap:
         # one _grow(min_capacity=n_rows) from the fresh store

@@ -10,10 +10,10 @@ and backend, and a static preference list encodes yesterday's chip.
 
 This module replaces config-only selection with a stopwatch: at model
 load (and again on hot-swap, keyed to the store's padded capacity) it
-times each eligible path FOR THE LIVE SHAPE with the same m-deep
-dispatch-queue technique the kernel probe uses (one dispatch+fetch =
-rtt + exec; m queued dispatches fetched once = rtt + m*exec; the
-difference isolates device execution from the transport), then:
+times each eligible path FOR THE LIVE SHAPE with an m-deep
+dispatch-queue estimator (one dispatch+fetch = rtt + exec; m queued
+dispatches fetched once = rtt + m*exec; the difference isolates device
+execution from the transport), then:
 
   - orders the phase-A fallback chain by measured ascending cost, and
   - routes LSH-configured queries to the exact scan wherever the mask
@@ -36,6 +36,7 @@ logic is testable on CPU without a 20M-row model.
 from __future__ import annotations
 
 import logging
+import time
 
 import numpy as np
 
@@ -52,21 +53,45 @@ _log = logging.getLogger(__name__)
 _DEFAULT_BATCH = 256
 # timing repetitions: median of reps, each an m-queue pair
 _REPS = 2
+# the m-queue delta must clear this much transport jitter before it is
+# believed; the queue deepens x4 towards _MAX_M until it does
+_MIN_DELTA_MS = 30.0
+_MAX_M = 96
 
 
 def _time_exec_ms(dispatch, fetch, m: int) -> float:
     """Per-exec milliseconds of one queued device program, transport
-    excluded — THE probe's m-queue estimator (bench.kernel_probe.
-    time_exec: warm compile, then (m-queued minus single)/(m-1) with
-    adaptive queue-deepening until the delta clears the transport
-    jitter), so routing decisions and published kernel timings can
-    never diverge.  A delta the estimator could not resolve routes as
-    a tiny floor cost: indistinguishable kernels keep the static
-    order (ties never reorder)."""
-    from ...bench.kernel_probe import time_exec
+    excluded.  ``dispatch()`` must enqueue one device program and
+    return its output handle(s) without blocking; ``fetch(h)`` must
+    block until that handle's program completed.  One dispatch+fetch is
+    rtt + exec, ``m`` queued dispatches fetched once are rtt + m*exec
+    (the chip executes queued programs in order), so
 
-    t = time_exec(dispatch, fetch, m=m, reps=_REPS)
-    return max(1e-4, t["exec_ms"])
+        exec = (t_m - t_1) / (m - 1)
+
+    Small kernels (exec << round-trip jitter) would make the delta
+    indistinguishable from noise, and occasionally negative, so the
+    queue is deepened until it clears ``_MIN_DELTA_MS``.  A delta the
+    estimator still could not resolve routes as a tiny floor cost:
+    indistinguishable kernels keep the static order (ties never
+    reorder)."""
+    fetch(dispatch())  # ensure compiled
+    while True:
+        t1s, tms = [], []
+        for _ in range(_REPS):
+            t0 = time.perf_counter()
+            fetch(dispatch())
+            t1s.append(time.perf_counter() - t0)
+            t0 = time.perf_counter()
+            hs = [dispatch() for _ in range(m)]
+            fetch(hs[-1])
+            tms.append(time.perf_counter() - t0)
+        t1 = float(np.median(t1s))
+        tm = float(np.median(tms))
+        if (tm - t1) * 1e3 >= _MIN_DELTA_MS or m >= _MAX_M:
+            break
+        m = min(_MAX_M, m * 4)
+    return max(1e-4, round((tm - t1) / (m - 1) * 1e3, 3))
 
 
 def _lsh_parts(model, lsh_on: bool):

@@ -3,9 +3,8 @@
 The batch layer's monolithic publish (one MODEL-REF + a full-stream UP
 replay of every factor row) makes every serving replica's load time and
 host memory O(catalog): a ``--shard i/N`` replica replays ALL rows and
-discards the ~(N-1)/N whose ids hash elsewhere (BENCH_GATEWAY_r07:
-``model_load_s`` 24.2 s at just 131k items).  This module makes the
-*distribution itself* sharded:
+discards the ~(N-1)/N whose ids hash elsewhere.  This module makes
+the *distribution itself* sharded:
 
 - the item-factor rows are partitioned into ``ring`` **slices** by the
   SAME murmur2 contract the serving cluster routes by

@@ -17,7 +17,7 @@ shard never computes an answer nobody is waiting for.
 
 Transport is a hand-rolled keep-alive HTTP/1.1 client over a per-URL
 connection pool (the stdlib client's email-parser machinery costs real
-qps at gateway rates — same reasoning as bench/load.py's driver).  It
+qps at gateway rates).  It
 speaks the replicas' whole front-door surface: TLS to ``https``
 heartbeat URLs (unverified — the cluster-internal trust model for the
 replicas' self-signed serving certs) and the serving tier's DIGEST
@@ -327,6 +327,16 @@ class _CancelToken:
 # a replica that was merely slower than its hedge sibling
 _ABANDONED = object()
 
+# how far behind the request's deadline a hedged attempt's own socket
+# timer sits.  The query that launched it enforces the deadline (drain
+# until ``deadline.t_end``, then fire the cancel token); set to the
+# same instant, the attempt's timer would race that give-up, and a
+# timer that won would book a replica that was only slower than the
+# REQUEST's budget as a failure in its breaker instead of an
+# abandoned hedge.  The replica's own limit, ``shard-timeout-ms``,
+# is never extended.
+_GIVE_UP_GRACE_SEC = 1.0
+
 
 class _DigestAuth:
     """DIGEST client for the replicas' challenge (the serving tier's
@@ -504,7 +514,9 @@ class ScatterGather:
             remaining = deadline.remaining()
             if remaining <= 0.0:
                 raise ShardUnavailable("deadline exhausted")
-            timeout = min(timeout, remaining)
+            # a lone attempt (no token) has nobody else to end it
+            timeout = min(timeout, remaining if cancel is None
+                          else remaining + _GIVE_UP_GRACE_SEC)
             # remaining-budget propagation: the shard sheds work the
             # router would no longer wait for
             headers["X-Deadline-Ms"] = str(max(1, int(remaining * 1000)))

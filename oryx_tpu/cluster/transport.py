@@ -317,8 +317,7 @@ class FrameTransport:
             return sum(1 for c in self._conns.values() if not c.dead)
 
     def connection_snapshot(self) -> dict:
-        """addr -> in-flight stream count, for /metrics and the bench's
-        sockets-per-replica evidence."""
+        """addr -> in-flight stream count, for /metrics."""
         with self._lock:
             return {f"{a[0]}:{a[1]}": c.in_flight
                     for a, c in self._conns.items() if not c.dead}

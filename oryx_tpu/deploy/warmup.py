@@ -262,8 +262,8 @@ def _warm_training(ratings: int, rank: int, sample_rate: float,
     what its serving layer will load."""
     t0 = time.perf_counter()
     from ..app.als.common import ParsedRatings
+    from ..app.als.synthetic import synthesize_movielens
     from ..app.als.trainer import train_als
-    from ..bench.train import synthesize_movielens
 
     users, items_arr, implicit_vals, _, _ = synthesize_movielens(
         n_ratings=ratings, seed=11)

@@ -668,20 +668,18 @@ class TopNBatcher:
         if jobs:
             # queue wait of this drain = the oldest job's enqueue->pickup
             # age; EWMA'd so the admission signal tracks load, not one
-            # straggler.  Sampled BEFORE the dispatch seam below: the
-            # emulated device delay is service time, and folding it into
+            # straggler.  Sampled BEFORE the dispatch seam below: an
+            # injected device delay is service time, and folding it into
             # the wait would inflate the admission signal by one full
             # dispatch even with an empty queue
             qw = max(t_pickup - j.t_enq for j in jobs)
             with self._cond:
                 self._qwait_ewma = 0.7 * self._qwait_ewma + 0.3 * qw
                 self._qwait_at = t_pickup
-        # chaos / device-emulation seam: one fire per drained dispatch.
-        # mode=delay stands in for per-dispatch device time the host
-        # does not burn CPU on — bench/gateway.py stages it to model
-        # fixed-rate accelerators on a shared CPU box; mode=error fails
-        # the whole drain (surfaced per job, never killing the
-        # dispatcher thread)
+        # chaos seam: one fire per drained dispatch.  mode=delay stands
+        # in for per-dispatch device time the host does not burn CPU
+        # on (a slow or stalled device); mode=error fails the whole
+        # drain (surfaced per job, never killing the dispatcher thread)
         try:
             faults.fire("serving-scan-dispatch")
         except Exception as e:  # noqa: BLE001 — injected
@@ -692,8 +690,8 @@ class TopNBatcher:
         by_model: dict[int, list[_Job]] = {}
         for j in jobs:
             by_model.setdefault(id(j.model), []).append(j)
-        # the device window opens at drain PICKUP (before the emulation
-        # seam): like the admission EWMA above, the emulated device
+        # the device window opens at drain PICKUP (before the chaos
+        # seam): like the admission EWMA above, an injected device
         # delay is service time, so the recorded queue_wait/
         # device_execute split must put it on the device side — tail
         # attribution (obs/anatomy.py) otherwise blames the queue for

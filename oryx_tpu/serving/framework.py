@@ -163,8 +163,8 @@ def _metrics(req: Request):
     if counters:
         out["counters"] = counters
     # sharded-cluster replica: shard coordinates + generation, so an
-    # operator (and the gateway bench) can see per-replica catalog
-    # state without the router in between
+    # operator can see per-replica catalog state without the router in
+    # between
     mgr = req.context["model_manager"]
     if getattr(mgr, "shard_count", 1) > 1 or hasattr(mgr, "generation"):
         cluster = {"generation": getattr(mgr, "generation", 0)}

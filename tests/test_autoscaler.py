@@ -3,7 +3,7 @@ core — consecutive-poll streaks, cooldown, thinnest-group targeting,
 owned-only scale-down with the live floor — plus the interval-p99
 computation over merged bucket deltas and the policy config surface.
 The launcher and HTTP are faked; the real-process path is exercised by
-the elastic chaos IT and the gateway bench."""
+the elastic chaos IT."""
 
 from __future__ import annotations
 

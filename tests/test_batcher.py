@@ -9,8 +9,8 @@ import urllib.request
 import numpy as np
 import pytest
 
+from oryx_tpu.api.serving import StaticModelManager
 from oryx_tpu.app.als.serving_model import ALSServingModel
-from oryx_tpu.bench.load import StaticModelManager
 from oryx_tpu.common.config import from_dict
 from oryx_tpu.lambda_rt.serving import ServingLayer
 from oryx_tpu.serving.batcher import TopNBatcher

@@ -35,12 +35,12 @@ import urllib.request
 import numpy as np
 import pytest
 
-from oryx_tpu.bench.gateway import (_await, _free_port, _get_json,
-                                    _spawn, _write_conf)
 from oryx_tpu.cluster.sharding import shard_of
 from oryx_tpu.common import pmml as pmml_io
 from oryx_tpu.kafka.api import KEY_MODEL_REF
 from oryx_tpu.kafka.inproc import resolve_broker
+from tests.procs import (_await, _free_port, _get_json, _spawn,
+                         _write_conf)
 
 pytestmark = [pytest.mark.chaos, pytest.mark.slow]
 # slow: this module is the retained real-process smoke for scenarios
@@ -135,7 +135,7 @@ class _Cluster:
                    **(extra or {})}
         _write_conf(conf, self.broker_dir, port, overlay)
         proc = _spawn(["serving", "--shard", f"{shard}/{of}"], conf,
-                      None, os.path.join(self.work_dir, f"{name}.log"))
+                      os.path.join(self.work_dir, f"{name}.log"))
         self.procs[name] = (proc, port)
         return port
 
@@ -143,7 +143,7 @@ class _Cluster:
         port = _free_port()
         conf = os.path.join(self.work_dir, "router.conf")
         _write_conf(conf, self.broker_dir, port, dict(_FAST))
-        proc = _spawn(["router"], conf, None,
+        proc = _spawn(["router"], conf,
                       os.path.join(self.work_dir, "router.log"))
         self.procs["router"] = (proc, port)
         self.router_port = port

@@ -86,7 +86,7 @@ def test_hpack_encoder_is_decodable_and_uses_static_indexing():
 
 def _serving_app(**app_kwargs):
     from oryx_tpu.app.als.serving_model import ALSServingModel
-    from oryx_tpu.bench.load import StaticModelManager
+    from oryx_tpu.api.serving import StaticModelManager
     from oryx_tpu.lambda_rt.http import HttpApp, make_server
     from oryx_tpu.serving import als as als_resources
     from oryx_tpu.serving import framework as framework_resources
@@ -446,7 +446,7 @@ def test_curl_h2_digest_auth_and_errors(tmp_path):
     from oryx_tpu.lambda_rt.http import HttpApp, make_server
     from oryx_tpu.serving import als as als_resources
     from oryx_tpu.serving import framework as framework_resources
-    from oryx_tpu.bench.load import StaticModelManager
+    from oryx_tpu.api.serving import StaticModelManager
     from oryx_tpu.app.als.serving_model import ALSServingModel
     from oryx_tpu.serving.batcher import TopNBatcher
 

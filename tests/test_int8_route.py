@@ -360,3 +360,82 @@ def test_route_measurement_evicts_losing_mirrors():
         sm._PALLAS_STATE.update(old_state)
         (sm._FLAT_SCORES_LIMIT, sm._MAX_CHUNK_ROWS, sm._BLOCK_KSEL,
          sm._PA_TILE) = old
+
+
+# -- the router's stopwatch (the m-queue estimator) on a fake clock ----------
+
+class _FakeDevice:
+    """A device whose programs run one after another for ``exec_s``
+    each, ``rtt_s`` away: ``dispatch`` enqueues and returns at once,
+    ``fetch(h)`` returns when program ``h`` has run and its result has
+    travelled back.  ``perf_counter`` is the only clock."""
+
+    def __init__(self, exec_s: float, rtt_s: float = 0.012,
+                 compile_s: float = 0.0, lone_jitter_s: float = 0.0):
+        self.exec_s, self.rtt_s, self.compile_s = exec_s, rtt_s, compile_s
+        self.lone_jitter_s = lone_jitter_s   # extra on a 1-deep fetch
+        self.now = 0.0
+        self.free_at = 0.0     # when the device runs dry
+        self.dispatched = 0
+        self.fetch_depths: list[int] = []   # programs behind each fetch
+        self._since_fetch = 0
+
+    def perf_counter(self) -> float:
+        return self.now
+
+    def dispatch(self) -> float:
+        cost = self.exec_s + (self.compile_s if not self.dispatched else 0)
+        self.dispatched += 1
+        self._since_fetch += 1
+        self.free_at = max(self.free_at, self.now) + cost
+        return self.free_at
+
+    def fetch(self, done_at: float) -> None:
+        self.fetch_depths.append(self._since_fetch)
+        jitter = self.lone_jitter_s if self._since_fetch == 1 else 0.0
+        self._since_fetch = 0
+        self.now = max(self.now, done_at) + self.rtt_s + jitter
+
+
+def _estimate(monkeypatch, dev: _FakeDevice, m: int) -> float:
+    from oryx_tpu.app.als import kernel_router
+    monkeypatch.setattr(kernel_router, "time", dev)
+    return kernel_router._time_exec_ms(dev.dispatch, dev.fetch, m)
+
+
+@pytest.mark.parametrize("case", ["difference", "deepens", "max_m",
+                                  "floor", "compile"])
+def test_route_stopwatch_on_a_fake_clock(monkeypatch, case):
+    if case == "difference":
+        # exec = (t_m - t_1) / (m - 1): the round trip cancels, at the
+        # first depth when the delta already clears 30 ms
+        dev = _FakeDevice(exec_s=0.020, rtt_s=0.1)
+        assert _estimate(monkeypatch, dev, 3) == pytest.approx(20.0)
+        assert max(dev.fetch_depths) == 3
+    elif case == "deepens":
+        # 2 ms a program: 3 deep the delta is 4 ms, 12 deep 22 ms, 48
+        # deep 94 ms >= 30 — the queue went x4 twice and stopped there
+        dev = _FakeDevice(exec_s=0.002)
+        assert _estimate(monkeypatch, dev, 3) == pytest.approx(2.0)
+        assert sorted(set(dev.fetch_depths)) == [1, 3, 12, 48]
+    elif case == "max_m":
+        # 0.1 ms a program never clears 30 ms: it stops at 96 deep and
+        # still reads the cost
+        dev = _FakeDevice(exec_s=0.0001)
+        assert _estimate(monkeypatch, dev, 6) == pytest.approx(0.1)
+        assert max(dev.fetch_depths) == 96
+    elif case == "floor":
+        # a delta it cannot resolve (none; then a negative one, the
+        # lone fetch the slower) routes at the floor, so
+        # indistinguishable kernels keep the static chain's order
+        dev = _FakeDevice(exec_s=0.0)
+        assert _estimate(monkeypatch, dev, 3) == 1e-4
+        dev = _FakeDevice(exec_s=0.00001, lone_jitter_s=0.05)
+        assert _estimate(monkeypatch, dev, 3) == 1e-4
+        assert max(dev.fetch_depths) == 96
+    else:
+        # the first call is the compile and is not timed: a minute of
+        # it leaves the reading where it was
+        dev = _FakeDevice(exec_s=0.020, compile_s=60.0)
+        assert _estimate(monkeypatch, dev, 3) == pytest.approx(20.0)
+        assert dev.fetch_depths[0] == 1 and dev.now > 60.0

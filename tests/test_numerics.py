@@ -17,8 +17,8 @@ import pytest
 
 from oryx_tpu.app.als.common import ParsedRatings
 from oryx_tpu.app.als.evaluation import area_under_curve, rmse
+from oryx_tpu.app.als.synthetic import synthesize_movielens
 from oryx_tpu.app.als.trainer import train_als
-from oryx_tpu.bench.train import synthesize_movielens
 from oryx_tpu.common import pmml as pmml_io
 from oryx_tpu.common.config import from_dict
 from oryx_tpu.kafka.api import KeyMessage
@@ -301,13 +301,86 @@ def test_mlupdate_rejects_nonfinite_eval(tmp_path):
     assert len(msgs) == 1  # the finite candidate won; +Inf did not
 
 
-def test_sweep_records_rescue_and_gates_on_all_finite():
-    """The sweep artifact carries per-candidate rescue records and the
-    0-NaN gate, at test scale over the reference's grid (including the
-    lambda=5e-4 half that used to diverge)."""
-    from oryx_tpu.bench.sweep import run_sweep
+def _run_sweep(tmp_path, features_grid=(20, 60),
+               lambda_grid=(0.0005, 0.05)) -> dict:
+    """``ALSUpdate.run_update``'s own candidate search over the
+    reference's features x lambda grid (the lambda = 5e-4 half is the
+    one f32 alone used to lose), on 3,000 synthetic explicit ratings as
+    the CSV lines the batch layer hands it.  Returns every candidate's
+    eval with the rescue rung that trained it, and what was published."""
+    import json
 
-    r = run_sweep(ratings=3000, iterations=2, n_users=150, n_items=80)
+    from oryx_tpu.app.als.update import ALSUpdate
+    from oryx_tpu.ml.mlupdate import MODEL_FILE_NAME
+
+    users, items, _, stars, _ = synthesize_movielens(
+        n_users=150, n_items=80, n_ratings=3000, seed=7)
+    # increasing timestamps: the train/test split is by time
+    ts = 1_700_000_000_000
+    msgs = [KeyMessage(None, f"{u},{i},{v:.2f},{ts + j}")
+            for j, (u, i, v) in enumerate(zip(
+                users.tolist(), items.tolist(),
+                np.round(stars, 2).tolist()))]
+    candidates: list[dict] = []
+
+    def ext(doc, name):
+        return pmml_io.get_extension_value(doc, name)
+
+    class RecordingALSUpdate(ALSUpdate):
+        def evaluate(self, model, candidate_path, test_data, train_data):
+            e = super().evaluate(model, candidate_path, test_data,
+                                 train_data)
+            rescue = ext(model, "rescue")
+            candidates.append({
+                "features": int(ext(model, "features")),
+                "lambda": float(ext(model, "lambda")),
+                "eval": float(e),
+                # None = clean f32, else {precision, trigger_iteration,
+                # escalated_lambda}
+                "rescue": json.loads(rescue) if rescue else None})
+            return e
+
+    n_candidates = len(features_grid) * len(lambda_grid)
+    model_dir = str(tmp_path / "model")
+    RecordingALSUpdate(_als_cfg(**{
+        "oryx.als.hyperparams.features": list(features_grid),
+        "oryx.als.hyperparams.lambda": list(lambda_grid),
+        "oryx.ml.eval.candidates": n_candidates,
+        "oryx.ml.eval.parallelism": 2,
+    })).run_update(ts, msgs, [], model_dir, None)
+    published = [d for d in os.listdir(model_dir) if d.isdigit()]
+    assert len(published) == 1, published
+    doc = pmml_io.read(os.path.join(model_dir, published[0],
+                                    MODEL_FILE_NAME))
+    finite = [c for c in candidates if np.isfinite(c["eval"])]
+    best = max(finite, key=lambda c: c["eval"]) if finite else None
+    rescued = [c for c in candidates if c["rescue"]]
+    return {
+        "candidates": candidates,
+        "published_is_argmax": (
+            best is not None and len(candidates) == n_candidates
+            and int(ext(doc, "features")) == best["features"]
+            and float(ext(doc, "lambda")) == best["lambda"]),
+        "nan_candidates": len(candidates) - len(finite),
+        # a candidate that never reached evaluate() (diverged beyond
+        # rescue, or refused by the pre-publish gate) is as lost as a
+        # NaN one
+        "all_candidates_trained": len(finite) == n_candidates,
+        "rescued_candidates": len(rescued),
+        "rescues": {
+            "float64": sum(1 for c in rescued
+                           if c["rescue"].get("escalated_lambda") is None),
+            "escalated_lambda": sum(
+                1 for c in rescued
+                if c["rescue"].get("escalated_lambda") is not None)},
+    }
+
+
+def test_sweep_records_rescue_and_gates_on_all_finite(tmp_path):
+    """The candidate search carries per-candidate rescue records and
+    the 0-NaN gate, at test scale over the reference's grid (including
+    the lambda=5e-4 half that used to diverge)."""
+    r = _run_sweep(tmp_path)
     assert r["published_is_argmax"]
     assert r["nan_candidates"] == 0 and r["all_candidates_trained"]
     assert len(r["candidates"]) == 4
@@ -316,14 +389,12 @@ def test_sweep_records_rescue_and_gates_on_all_finite():
         1 for c in r["candidates"] if c["rescue"])
 
 
-def test_sweep_poisoned_candidate_is_rescued_and_recorded():
+def test_sweep_poisoned_candidate_is_rescued_and_recorded(tmp_path):
     """One injected f32 divergence mid-sweep: the candidate retrains on
-    the f64 rung, evaluates finite, and the artifact records exactly
-    one rescue — 0 NaN candidates either way."""
-    from oryx_tpu.bench.sweep import run_sweep
-
+    the f64 rung, evaluates finite, and exactly one rescue is recorded
+    — 0 NaN candidates either way."""
     faults.inject("trainer-f32-poison", mode="drop", times=1)
-    r = run_sweep(ratings=3000, iterations=2, n_users=150, n_items=80)
+    r = _run_sweep(tmp_path)
     assert faults.fired("trainer-f32-poison") == 1
     assert r["nan_candidates"] == 0 and r["all_candidates_trained"]
     assert r["rescued_candidates"] == 1

@@ -35,12 +35,11 @@ import urllib.request
 import numpy as np
 import pytest
 
-from oryx_tpu.bench.gateway import (_await, _free_port, _get_json,
-                                    _get_json_retry_cold, _spawn,
-                                    _write_conf)
 from oryx_tpu.common import pmml as pmml_io
 from oryx_tpu.kafka.api import KEY_MODEL, KEY_UP
 from oryx_tpu.kafka.inproc import resolve_broker
+from tests.procs import (_await, _free_port, _get_json,
+                         _get_json_retry_cold, _spawn, _write_conf)
 
 pytestmark = [pytest.mark.chaos, pytest.mark.slow]
 # slow: this module is the retained real-process smoke for scenarios
@@ -134,8 +133,7 @@ class _Region:
             "oryx.cluster.shard": "0/1",
             "oryx.cluster.replica-id": f"{self.name}-r0"})
         self.procs["replica"] = (_spawn(["serving", "--shard", "0/1"],
-                                        conf, None,
-                                        self._log("replica")), port)
+                                        conf, self._log("replica")), port)
 
     def spawn_router(self) -> None:
         port = _free_port()
@@ -145,7 +143,7 @@ class _Region:
             # post-heal byte-identity also proves invalidation
             "oryx.cluster.cache.enabled": True,
             "oryx.cluster.coalesce.enabled": True})
-        self.procs["router"] = (_spawn(["router"], conf, None,
+        self.procs["router"] = (_spawn(["router"], conf,
                                        self._log("router")), port)
         self.router_port = port
 
@@ -153,7 +151,7 @@ class _Region:
         conf = self._conf("speed", _free_port(), {
             "oryx.speed.model-manager-class":
                 "oryx_tpu.app.als.speed.ALSSpeedModelManager"})
-        self.procs["speed"] = (_spawn(["speed"], conf, None,
+        self.procs["speed"] = (_spawn(["speed"], conf,
                                       self._log("speed")), None)
 
     def spawn_mirror(self, source: "_Region",
@@ -180,7 +178,7 @@ class _Region:
                 "oryx.resilience.faults.mirror-link-partition.times":
                     -1})
         conf = self._conf("mirror", _free_port(), extra)
-        self.procs["mirror"] = (_spawn(["mirror"], conf, None,
+        self.procs["mirror"] = (_spawn(["mirror"], conf,
                                        self._log("mirror")),
                                 self.mirror_obs_port)
 

@@ -497,8 +497,9 @@ def test_the_exact_ladder_enqueues_only_the_named_programs():
     """Every build ``_dispatch_kind`` can enqueue for the exact ladder
     (the IVF kind, ``ivf.batch_top_n_ivf``, is outside the contract) is
     one of the two-phase programs, and the fallback is the exact scan:
-    ``benchmark/layers/kernel.twophase_ms.json`` and
-    ``kernel.exact_scan_ms.json`` match their names in the device trace."""
+    ``benchmark/layers/kernel.twophase_ms.json`` matches the former by
+    name in the device trace, and the latter's name is what a metric of
+    the exact scan would match."""
     assert _scan_programs_called_by(ALSServingModel._dispatch_kind) \
         == set(TWOPHASE_BUILDS)
     assert _scan_programs_called_by(ALSServingModel._dispatch_twophase) \

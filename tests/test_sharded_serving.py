@@ -108,7 +108,7 @@ def test_manager_rejects_non_pow2_shards():
 
 @pytest.fixture(scope="module")
 def sharded_server():
-    from oryx_tpu.bench.load import StaticModelManager
+    from oryx_tpu.api.serving import StaticModelManager
     from oryx_tpu.lambda_rt.http import HttpApp, make_server
     from oryx_tpu.serving import als as als_resources
     from oryx_tpu.serving import framework as framework_resources

@@ -27,7 +27,6 @@ import numpy as np
 import pytest
 
 from oryx_tpu.app.als.speed import ALSSpeedModelManager
-from oryx_tpu.bench.gateway import _await, _free_port, _get_json, _spawn
 from oryx_tpu.common.config import from_dict, keys_to_hocon
 from oryx_tpu.kafka.api import KEY_UP
 from oryx_tpu.kafka.inproc import resolve_broker
@@ -37,6 +36,7 @@ from oryx_tpu.lambda_rt.speed_checkpoint import (H_SPEED_BATCH,
                                                  H_SPEED_SEQ,
                                                  H_SPEED_SHARD,
                                                  SpeedCheckpoint)
+from tests.procs import _await, _free_port, _get_json, _spawn
 
 pytestmark = [pytest.mark.chaos, pytest.mark.slow]
 
@@ -151,7 +151,7 @@ def test_kill_restart_mid_micro_batch_zero_lost_zero_double(tmp_path):
         "oryx.resilience.faults.speed-crash-mid-batch.times": 1,
     }))
     log_path = os.path.join(work, "speed-it.log")
-    proc = _spawn(["speed", "--shard", "0/1"], conf1, None, log_path)
+    proc = _spawn(["speed", "--shard", "0/1"], conf1, log_path)
     try:
         # fold-in needs the replayed model first: gate new input on the
         # child's own freshness gauges (records folded against a
@@ -207,7 +207,7 @@ def test_kill_restart_mid_micro_batch_zero_lost_zero_double(tmp_path):
         "oryx.speed.streaming.generation-interval-sec": 2,
         "oryx.obs.metrics-port": obs_port2,
     }))
-    proc2 = _spawn(["speed", "--shard", "0/1"], conf2, None, log_path)
+    proc2 = _spawn(["speed", "--shard", "0/1"], conf2, log_path)
     try:
         # recovery resolves the stage before anything else: every
         # staged record found durable in the destination log — all

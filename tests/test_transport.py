@@ -264,11 +264,14 @@ def test_shard_timeout_fault_abandons_inflight_attempts():
     faults.inject("router-shard-timeout", mode="delay", times=1,
                   delay_sec=0.2)
     try:
+        # 0.8 s are left after the injected delay for the 40 ms hedge
+        # to launch the sibling (a loaded box can stall a thread for a
+        # few tenths), and the stubs' 3 s are past every timer
         with pytest.raises(ShardUnavailable):
             sg.query_shard(0, "GET", "/x",
-                           deadline=Deadline.after(0.6))
+                           deadline=Deadline.after(1.0))
         assert faults.fired("router-shard-timeout") == 1
-        deadline = time.monotonic() + 5.0
+        deadline = time.monotonic() + 10.0
         while sg.hedge_abandoned < 2 and time.monotonic() < deadline:
             time.sleep(0.02)
         # both stalled attempts were abandoned at give-up: the pool
@@ -280,6 +283,52 @@ def test_shard_timeout_fault_abandons_inflight_attempts():
         sg.close()
         slow.close()
         sibling.close()
+
+
+@pytest.mark.parametrize("hedged", [True, False])
+def test_a_hedged_attempts_own_timer_sits_behind_the_deadline(
+        monkeypatch, hedged):
+    """What made the test above red one run in a few under six xdist
+    workers: a hedged attempt's socket timer was set to the deadline's
+    remainder, the same instant at which its query gives up and fires
+    the cancel token, and when the timer won the attempt was a timeout
+    (a failure in the replica's breaker), not an abandoned hedge.  The
+    query enforces the deadline; the attempt's timer is the backstop
+    behind it, never past ``shard-timeout-ms``.  A lone attempt, which
+    nobody else would end, keeps the remainder itself."""
+    from oryx_tpu.cluster import scatter
+    from oryx_tpu.resilience.policy import Deadline
+    stub = _StubReplica()
+    reg = MembershipRegistry(ttl_sec=60.0)
+    hb = Heartbeat(replica="a", shard=0, of=1, url=stub.url,
+                   generation=1, ready=True)
+    reg.note(hb)
+    sg = ScatterGather(reg, _config(
+        **{"oryx.cluster.transport.enabled": False}))
+    seen = []
+    real = scatter._request
+
+    def recording(conn, rfile, method, path, body, headers, timeout):
+        seen.append((timeout, int(headers["X-Deadline-Ms"])))
+        return real(conn, rfile, method, path, body, headers, timeout)
+
+    monkeypatch.setattr(scatter, "_request", recording)
+    try:
+        for budget in (0.5, 60.0):
+            sg._attempt(hb, 0, "GET", "/x", None, Deadline.after(budget),
+                        cancel=scatter._CancelToken() if hedged else None)
+        (short, short_ms), (long, _) = seen
+        if hedged:
+            assert 0.5 + 0.9 * scatter._GIVE_UP_GRACE_SEC < short \
+                <= 0.5 + scatter._GIVE_UP_GRACE_SEC
+        else:
+            assert 0.4 < short <= 0.5
+        # what the replica is told is the request's budget, either way
+        assert 400 <= short_ms <= 500
+        assert long == sg.shard_timeout_sec == 5.0
+    finally:
+        sg.close()
+        stub.close()
 
 
 # -- frame client <-> server loopback ----------------------------------------
