@@ -69,10 +69,12 @@ _MAX_CHUNK_ROWS = 1 << 17
 # time as pow2 buckets would — and the 20M x 250 scan kernel compiles
 # once per LADDER size, not once per drain-size bucket.  The ladder's
 # small windows exist for latency: the per-window cost has a large
-# B-proportional VPU component (the block-max reduce), so an idle
-# server's lone request on an 8-window pays a few ms instead of the
-# full 256-window's tens (VERDICT r04: the 50f/20M LSH cell's unloaded
-# p50 lost to the baseline purely on window padding).
+# B-proportional component (phase B's gather and rescoring of ksel
+# blocks a query; the pass over the store costs the same at every
+# width, PERF.md section 5, PR 33), so an idle server's lone request
+# on an 8-window pays a few ms less than the full 256-window (VERDICT
+# r04: the 50f/20M LSH cell's unloaded p50 lost to the baseline purely
+# on window padding).
 _CHUNKED_BATCH = 256
 _WINDOW_LADDER = (8, 32, 256)
 
@@ -313,19 +315,22 @@ def _map_row_groups(fn, g: int, *xs):
 
 def _selects_row_major(b: int, ksel: int) -> bool:
     """Whether phase B pins the block maxima to the row-major layout
-    before it selects from them.  The pallas phase A writes them as
-    (blocks, B), and XLA hands that array to the selection's TopK as
-    it lies: the query rows on the 128 lanes, of which an 8-wide
-    window fills 8, at a cost in proportion to ``ksel``.  Whole 8-wide
-    programs at 250f x 20M on a v5e (PERF.md PR 26): 18.1 / 22.1 /
-    30.8 ms at k = 32 / 64 / 128 as it lies, 14.2 / 14.6 / 15.6 ms
-    pinned (XLA makes the copy itself at ksel 512, either way 16.0).
-    From 128 rows on the lanes are full and the pin changes nothing
-    (256 x k = 64, two groups of 128: 45.8 ms both ways).  The floor
-    width keeps the layout it has had since these programs were first
-    measured: the k = 16 program is the one PR 26 was to leave as it
-    was (its control), and the same pin there (15.97 -> 14.02 ms) is
-    PERF.md section 7's next step."""
+    before it selects from them.  The builds that write them as
+    (blocks, B) — the fold and int8 mirrors, and the pallas build from
+    128 queries on — hand over a transposed view, and XLA hands that
+    array to the selection's TopK as it lies: the query rows on the
+    128 lanes, of which an 8-wide window fills 8, at a cost in
+    proportion to ``ksel``.  Whole 8-wide programs at 250f x 20M on a
+    v5e (PERF.md PR 26): 18.1 / 22.1 / 30.8 ms at k = 32 / 64 / 128 as
+    it lies, 14.2 / 14.6 / 15.6 ms pinned (XLA makes the copy itself
+    at ksel 512, either way 16.0).  From 128 rows on the lanes are full
+    and the pin changes nothing (256 x k = 64, two groups of 128: 45.8
+    ms both ways).  The floor width is not pinned: for those builds it
+    keeps the layout it has had since these programs were first
+    measured.  The pallas build's narrow windows need none of this
+    since PR 33: their maxima arrive row-major (_scores_rows_on_lanes),
+    the pin finds nothing to move, and the k = 16 program, which the
+    missing pin held at 15.99 ms, runs in 14.0 (PERF.md section 5)."""
     return b < 128 and ksel > _BLOCK_KSEL
 
 
@@ -390,13 +395,16 @@ def _phase_b_rows(Y, Qc, active, buckets, target, M, k: int, bs: int,
 
 
 # Pallas phase A: rows per grid step.  The whole point is that the
-# (tile, B) score tile lives and dies in VMEM — the XLA scan writes a
-# (B, chunk) f32 score tensor to HBM every chunk and reads it back for
-# the block max, an F-independent ~270 MB/chunk tax that measured as
-# the bulk of the 20M-cell window time (155-176 ms regardless of F).
-# Measured on this chip: phase A at 250f drops ~10x (memory-roofline
-# ~860 GB/s); LSH variant pays the per-(item,query) popcount on the
-# VPU.  Tile 4096 fits VMEM with double-buffering at F=250 bf16.
+# score tile lives and dies in VMEM — the XLA scan writes a (B, chunk)
+# f32 score tensor to HBM every chunk and reads it back for the block
+# max, an F-independent ~270 MB/chunk tax that measured as the bulk of
+# the 20M-cell window time (155-176 ms regardless of F).  A narrow
+# window's pass (_scores_rows_on_lanes) now takes what streaming the
+# store takes, 7.0 / 13.8 ms at 128 lanes / 250f against 6.9 / 13.7
+# for the tiles alone (~740 GB/s; PERF.md section 5, PR 33); the LSH
+# variant pays the per-(item,query) popcount on the VPU.  Tile 4096
+# fits VMEM with double-buffering at F=250 bf16; steps of 8,192 and
+# 16,384 rows measured the same.
 _PA_TILE = 4096
 # runtime-fallback state for the pallas build, PER SHAPE: pallas cannot
 # lower on the CPU backend (tier-1 serves the lax.scan build there), and
@@ -475,66 +483,145 @@ def _classify_pallas_failure(keys: list, e: Exception) -> None:
             "shape(s) %s (3 strikes retires a shape): %s", fresh, e)
 
 
+def _scores_rows_on_lanes(b: int) -> bool:
+    """Whether the pallas phase A lays a ``b``-query window's score
+    tile (b, rows) — queries on the sublanes, the store's rows on the
+    lanes — instead of (rows, b): for every window narrower than a lane
+    tile, the ladder's 8 and 32.  (rows, 8) fills 8 of a vector
+    register's 128 lanes and (8, rows) all of them, and its block
+    maxima leave row-major, as phase B's selection reads them.  Phase A
+    alone over 20,054,016 bfloat16 rows on a v5e, ms a pass (PERF.md
+    section 5, PR 33), (rows, b) -> (b, rows), beside the 6.86 / 13.65
+    that streaming the tiles takes with no dot at all:
+
+        b      128 lanes (50f)     250f
+        8      11.38 ->  6.98      13.96 -> 13.76
+        32     11.39 ->  7.02      13.96 -> 13.76
+        128    11.35  (7.79)       14.17  (13.92)
+        256    11.35 (10.61)       15.38  (15.93)
+
+    (rows, b) costs the same at every width, so it is not the scores'
+    registers alone that hold it 4.5 ms over the stream; what does was
+    not split further.  From 128 queries on the rule keeps (rows, b)
+    (in brackets what (b, rows) read there: a loss at 256 x 250f), so
+    the route measurement's 256-wide program is what it was.  It reads
+    the window's width only: at 250f the gain is small and still a
+    gain."""
+    return b < 128
+
+
+def _pallas_block_maxima(Qc, Y, penalty, buckets, target, bs: int,
+                         max_bits: int, rows_on_lanes: bool,
+                         interpret: bool = False):
+    """Phase A of the pallas build: the (B, N // bs) maxima of every
+    ``bs``-row block's scores, from one pass over ``Y`` whose score
+    tiles live and die in VMEM.  ``penalty`` is the (N // bs, bs) 0 /
+    -inf active-row mask; ``buckets`` / ``target`` of None select the
+    exact scan.  The two layouts (_scores_rows_on_lanes) reduce the
+    same products and hand over the same maxima."""
+    from jax.experimental import pallas as pl
+
+    N, F = Y.shape
+    B = Qc.shape[0]
+    T = _PA_TILE
+    J = T // bs                     # blocks a step
+    lsh = buckets is not None
+    nt = (((1,), (1,)), ((), ()))   # contract both minor dimensions
+    # per-row side inputs ride in lane-aligned (rows//bs, bs) layout —
+    # an (N, 1) input would be lane-padded x128 by TPU tiling (9.5 GB
+    # of padding at 20M rows; measured compile OOM)
+    side = pl.BlockSpec((J, bs), lambda i: (i, 0))
+    ins = [Qc, Y, penalty]
+    in_specs = [pl.BlockSpec((B, F), lambda i: (0, 0)),
+                pl.BlockSpec((T, F), lambda i: (i, 0)), side]
+    if lsh:
+        ins.append(buckets.reshape(-1, bs))
+        in_specs.append(side)
+
+    if not rows_on_lanes:
+        # (rows, B): Mosaic requires the minor dim of a stored tile to
+        # be 128-aligned or full, so the maxima leave as (N // bs, B)
+        def kern(q_ref, y_ref, p_ref, *rest):
+            s = jax.lax.dot_general(y_ref[...], q_ref[...], nt,
+                                    preferred_element_type=jnp.float32)
+            s3 = s.reshape(J, bs, B) + p_ref[...][:, :, None]
+            if lsh:
+                b_ref, t_ref = rest[:2]
+                ok = jax.lax.population_count(
+                    jnp.bitwise_xor(b_ref[...][:, :, None],
+                                    t_ref[...][0][None, None, :])) <= max_bits
+                s3 = jnp.where(ok, s3, -jnp.inf)
+            rest[-1][...] = s3.max(1)
+
+        if lsh:
+            ins.append(target[None, :])
+            in_specs.append(pl.BlockSpec((1, B), lambda i: (0, 0)))
+        return pl.pallas_call(
+            kern, grid=(N // T,), in_specs=in_specs,
+            out_specs=pl.BlockSpec((J, B), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((N // bs, B), jnp.float32),
+            interpret=interpret)(*ins).T
+
+    # (B, rows): the dot is q . y^T, row j of a step's side inputs is
+    # the lanes of its block j and is broadcast down the sublanes, each
+    # block reduces along its lanes to one column, and the columns land
+    # in M as they lie: row-major, the blocks on the lanes.  A stored
+    # tile's minor dimension is a multiple of 128, so a step's J blocks
+    # are J lanes of a (B, 128) output tile that stays resident while
+    # 128 // J steps fill it (four, at 4,096 rows a step; larger steps
+    # measured the same, PERF.md section 5), and M is padded to whole
+    # tiles where the capacity is not
+    W = max(J, 128)                 # blocks an output tile
+    per_tile = W // J               # steps that fill one
+    if J * per_tile != W:
+        raise NotImplementedError(
+            f"{J} blocks a step do not tile {W} lanes")
+    n_blocks = N // bs
+
+    def kern(q_ref, y_ref, p_ref, *rest):
+        o_ref = rest[-1]
+        s = jax.lax.dot_general(q_ref[...], y_ref[...], nt,
+                                preferred_element_type=jnp.float32)
+        first = (pl.program_id(0) % per_tile) * J
+        m = jnp.where(first == 0, -jnp.inf, o_ref[...])
+        lane = jax.lax.broadcasted_iota(jnp.int32, (B, W), 1)
+        for j in range(J):
+            sj = s[:, j * bs:(j + 1) * bs] + p_ref[j:j + 1, :]
+            if lsh:
+                b_ref, t_ref = rest[:2]
+                ok = jax.lax.population_count(jnp.bitwise_xor(
+                    b_ref[j:j + 1, :], t_ref[...])) <= max_bits
+                sj = jnp.where(ok, sj, -jnp.inf)
+            m = jnp.where(lane == first + j, sj.max(1, keepdims=True), m)
+        o_ref[...] = m
+
+    if lsh:
+        ins.append(target[:, None])
+        in_specs.append(pl.BlockSpec((B, 1), lambda i: (0, 0)))
+    return pl.pallas_call(
+        kern, grid=(N // T,), in_specs=in_specs,
+        out_specs=pl.BlockSpec((B, W), lambda i: (0, i // per_tile)),
+        out_shape=jax.ShapeDtypeStruct((B, -(-n_blocks // W) * W),
+                                       jnp.float32),
+        interpret=interpret)(*ins)[:, :n_blocks]
+
+
 @partial(jax.jit, static_argnames=("k", "bs", "ksel", "max_bits",
                                    "interpret"))
 def _batch_top_n_twophase_pallas(Y, Q, penalty, active, buckets,
                                  hyperplanes, k: int, bs: int, ksel: int,
                                  max_bits: int, interpret: bool = False):
     """Two-phase streaming top-k with the phase-A block maxima computed
-    by a fused pallas dot+blockmax kernel (scores never touch HBM).
-    Output layout is transposed inside the kernel ((rows, B)) because
-    Mosaic requires the minor dim of a stored tile to be 128-aligned or
-    full; ``penalty`` is the (N, 1) 0/-inf active-row mask."""
-    from jax.experimental import pallas as pl
-
-    N, F = Y.shape
-    B = Q.shape[0]
-    T = _PA_TILE
+    by a fused pallas dot+blockmax kernel (scores never touch HBM), in
+    the layout the window's width asks for (_scores_rows_on_lanes);
+    ``penalty`` is the (N // bs, bs) 0/-inf active-row mask."""
     Qc = _q_cast(Q, Y)
     target = None
     if buckets is not None:
         target = _query_buckets(Q, hyperplanes)
-
-    # per-row side inputs ride in lane-aligned (rows//bs, bs) layout —
-    # an (N, 1) input would be lane-padded x128 by TPU tiling (9.5 GB
-    # of padding at 20M rows; measured compile OOM)
-    if buckets is None:
-        def kern(q_ref, y_ref, p_ref, o_ref):
-            s = jax.lax.dot_general(y_ref[...], q_ref[...],
-                                    (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            s3 = s.reshape(T // bs, bs, B) + p_ref[...][:, :, None]
-            o_ref[...] = s3.max(1)
-
-        ins = (Qc, Y, penalty)
-        in_specs = [pl.BlockSpec((B, F), lambda i: (0, 0)),
-                    pl.BlockSpec((T, F), lambda i: (i, 0)),
-                    pl.BlockSpec((T // bs, bs), lambda i: (i, 0))]
-    else:
-        def kern(q_ref, y_ref, p_ref, b_ref, t_ref, o_ref):
-            s = jax.lax.dot_general(y_ref[...], q_ref[...],
-                                    (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            s3 = s.reshape(T // bs, bs, B) + p_ref[...][:, :, None]
-            ok = jax.lax.population_count(
-                jnp.bitwise_xor(b_ref[...][:, :, None],
-                                t_ref[...][0][None, None, :])) <= max_bits
-            s3 = jnp.where(ok, s3, -jnp.inf)
-            o_ref[...] = s3.max(1)
-
-        ins = (Qc, Y, penalty, buckets.reshape(-1, bs), target[None, :])
-        in_specs = [pl.BlockSpec((B, F), lambda i: (0, 0)),
-                    pl.BlockSpec((T, F), lambda i: (i, 0)),
-                    pl.BlockSpec((T // bs, bs), lambda i: (i, 0)),
-                    pl.BlockSpec((T // bs, bs), lambda i: (i, 0)),
-                    pl.BlockSpec((1, B), lambda i: (0, 0))]
-
-    Mt = pl.pallas_call(
-        kern, grid=(N // T,), in_specs=in_specs,
-        out_specs=pl.BlockSpec((T // bs, B), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((N // bs, B), jnp.float32),
-        interpret=interpret)(*ins)
-    return _phase_b(Y, Qc, active, buckets, target, Mt.T, k, bs, ksel,
+    M = _pallas_block_maxima(Qc, Y, penalty, buckets, target, bs, max_bits,
+                             _scores_rows_on_lanes(Q.shape[0]), interpret)
+    return _phase_b(Y, Qc, active, buckets, target, M, k, bs, ksel,
                     max_bits)
 
 
@@ -1723,13 +1810,23 @@ class ALSServingModel(FactorModelBase, ServingModel):
                     # fetched: it waits on the device, and on whatever
                     # other drain the device is running.  ``ksel`` is the
                     # width phase B selects (the int8 builds double it),
-                    # 0 where the exact scan is the primary path
+                    # 0 where the exact scan is the primary path;
+                    # ``lane_rows`` how many of the windows phase A
+                    # scored with the store's rows on the lanes
                     rec.mark("serving.scan", k=k,
-                             ksel=ksel if twophase else 0, windows=sizes)
+                             ksel=ksel if twophase else 0, windows=sizes,
+                             lane_rows=0)
                 if twophase:
                     handles, attempted = self._dispatch_twophase(
                         vecs, windows, active, version, buckets, hp, k,
                         chunk, bs, ksel, mb)
+                    if rec is not None:
+                        # known once each window's build is: a shape
+                        # that did not lower ran the lax.scan build
+                        rec.annotate(lane_rows=sum(
+                            key[-1] == "pallas"
+                            and _scores_rows_on_lanes(key[2])
+                            for key in attempted))
                 else:
                     handles = [
                         _batch_top_n_chunked_kernel(vecs, qw, active,
@@ -1743,7 +1840,8 @@ class ALSServingModel(FactorModelBase, ServingModel):
                                      np.float32)])
                 Qd = jnp.asarray(Q)
                 if rec is not None:
-                    rec.mark("serving.scan", k=k, ksel=0, windows=[b_pad])
+                    rec.mark("serving.scan", k=k, ksel=0, windows=[b_pad],
+                             lane_rows=0)
                 if lsh_on:
                     handles = _batch_top_n_lsh_kernel(
                         vecs, Qd, active, buckets,

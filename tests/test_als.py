@@ -940,40 +940,112 @@ def test_phase_b_in_row_groups_returns_what_one_gather_returns(
     assert whole[2].all()
 
 
-def test_pallas_phase_a_interpret_agrees_with_scan_kernel():
-    """The pallas-built two-phase program (interpret mode, so it runs on
-    the CPU test platform) must produce the same top-k as the lax.scan
-    build — same phase B, same certificate semantics."""
-    import jax
+def _phase_a_case(n, f, b, lsh, seed=11, integers=False):
+    """A toy store for the pallas phase A in interpret mode: every fifth
+    row retired, and for the exact scan a row that is query 0's best by
+    far on the LAST row of the first tile, so that a block maximum
+    sits where one grid step ends."""
     import jax.numpy as jnp
 
     from oryx_tpu.app.als import serving_model as sm
 
-    rng = np.random.default_rng(11)
-    n, f, b, k = 8192, 16, 8, 8
-    Y = jnp.asarray(rng.standard_normal((n, f)).astype(np.float32))
-    Q = jnp.asarray(rng.standard_normal((b, f)).astype(np.float32))
+    rng = np.random.default_rng(seed)
+    if integers:
+        # small whole numbers: every product and every partial sum is
+        # exact in float32, whatever order a layout accumulates in
+        y = rng.integers(-4, 5, (n, f)).astype(np.float32)
+        q = rng.integers(-4, 5, (b, f)).astype(np.float32)
+    else:
+        y = rng.standard_normal((n, f)).astype(np.float32)
+        q = rng.standard_normal((b, f)).astype(np.float32)
+    last = sm._PA_TILE - 1
+    y[last] = 3.0 * q[0]
     act = np.ones(n, bool)
-    act[::5] = False
-    active = jnp.asarray(act)
-    bs, ksel = 128, 8
+    act[1::5] = False
+    assert act[last]
+    Y, Q, active = jnp.asarray(y), jnp.asarray(q), jnp.asarray(act)
+    buckets = hp = None
+    if lsh:
+        hp = jnp.asarray(rng.standard_normal((4, f)).astype(np.float32))
+        buckets = sm._query_buckets(Y, hp)
+    return Y, Q, active, buckets, hp, last
+
+
+@pytest.mark.parametrize("rows", ["whole_output_tiles",
+                                  "last_output_tile_partial"])
+@pytest.mark.parametrize("lsh", [False, True], ids=["exact", "lsh"])
+@pytest.mark.parametrize("b", [8, 32, 128])
+def test_pallas_phase_a_interpret_agrees_with_scan_kernel(b, lsh, rows):
+    """The pallas-built two-phase program (interpret mode, so it runs on
+    the CPU test platform) must produce the same top-k as the lax.scan
+    build — same phase B, same certificate semantics — in both of its
+    layouts: the store's rows on the lanes for a window narrower than a
+    lane tile (8, 32), the queries on the lanes from 128 on; over a
+    capacity whose block maxima fill whole 128-lane output tiles (four
+    grid steps each) and over one that is a multiple of the step only,
+    which leaves the last tile partly filled."""
+    import jax
+
+    from oryx_tpu.app.als import serving_model as sm
+
+    bs = 128
+    n = (8 if rows == "whole_output_tiles" else 5) * sm._PA_TILE
+    assert n % sm._PA_TILE == 0
+    assert (n // bs % 128 == 0) == (rows == "whole_output_tiles")
+    f, k, ksel, mb = 16, 8, 16, 2 if lsh else 0
+    assert sm._scores_rows_on_lanes(b) == (b < 128)
+    Y, Q, active, buckets, hp, last = _phase_a_case(n, f, b, lsh)
     penalty = sm._penalty_kernel(active, bs)
-    chunk = 2048
-    old_tile = sm._PA_TILE
-    sm._PA_TILE = 2048
-    try:
-        ts_p, ti_p, cert_p = jax.device_get(
-            sm._batch_top_n_twophase_pallas(
-                Y, Q, penalty, active, None, None, k, bs, ksel, 0,
-                interpret=True))
-    finally:
-        sm._PA_TILE = old_tile
+    ts_p, ti_p, cert_p = jax.device_get(
+        sm._batch_top_n_twophase_pallas(
+            Y, Q, penalty, active, buckets, hp, k, bs, ksel, mb,
+            interpret=True))
     ts_s, ti_s, cert_s = jax.device_get(
         sm._batch_top_n_twophase_kernel(
-            Y, Q, active, None, None, k, chunk, bs, ksel, 0))
+            Y, Q, active, buckets, hp, k, sm._PA_TILE, bs, ksel, mb))
     np.testing.assert_allclose(ts_p, ts_s, rtol=1e-5)
     assert (ti_p == ti_s).all()
     assert (cert_p == cert_s).all()
+    assert cert_p.all()
+    if not lsh:
+        assert ti_p[0, 0] == last
+    # no retired row is served
+    assert np.asarray(active)[ti_p[np.isfinite(ts_p)]].all()
+
+
+@pytest.mark.parametrize("lsh", [False, True], ids=["exact", "lsh"])
+def test_pallas_phase_a_layouts_hand_over_the_same_block_maxima(lsh):
+    """(B, rows) and (rows, B) reduce the same products: the block
+    maxima are equal element for element, -inf for -inf, where the
+    arithmetic is exact (the MXU's accumulation order differs between
+    the layouts as it does between any two builds; phase B's margin
+    covers that)."""
+    import jax
+
+    from oryx_tpu.app.als import serving_model as sm
+
+    n, f, b, bs = 5 * sm._PA_TILE, 16, 8, 128
+    Y, Q, active, buckets, hp, last = _phase_a_case(n, f, b, lsh,
+                                                    integers=True)
+    # a block with no live row reads -inf in both
+    active = active.at[2 * bs:3 * bs].set(False)
+    penalty = sm._penalty_kernel(active, bs)
+    Qc = sm._q_cast(Q, Y)
+    target = sm._query_buckets(Q, hp) if lsh else None
+    on_lanes, on_sublanes = (
+        np.asarray(jax.device_get(sm._pallas_block_maxima(
+            Qc, Y, penalty, buckets, target, bs, 2, layout,
+            interpret=True))) for layout in (True, False))
+    assert on_lanes.shape == on_sublanes.shape == (b, n // bs)
+    np.testing.assert_array_equal(on_lanes, on_sublanes)
+    assert np.isneginf(on_lanes[:, 2]).all()
+    assert np.isfinite(on_lanes).any()
+    want = np.where(np.asarray(active)[None],
+                    np.asarray(Q) @ np.asarray(Y).T, -np.inf)
+    if not lsh:
+        np.testing.assert_array_equal(
+            on_lanes, want.reshape(b, -1, bs).max(-1))
+        assert on_lanes[0, last // bs] == want[0, last]
 
 
 def test_pallas_fallback_on_unsupported_backend():

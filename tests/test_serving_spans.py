@@ -140,7 +140,7 @@ def test_phases_land_under_each_jobs_device_execute(branch, model, traced,
         # the ladder, 0 where no block is selected (the flat kernel)
         assert spans["serving.scan"][0]["attrs"] == {
             "k": 8, "ksel": 16 if branch == "ladder" else 0,
-            "windows": [8]}
+            "windows": [8], "lane_rows": 0}
         assert spans["serving.decode"][0]["attrs"] == {"rows": 3}
         stamps.add(tuple((spans[n][0]["start_ms"],
                           spans[n][0]["duration_ms"]) for n in PHASES))
@@ -173,8 +173,37 @@ def test_the_scan_span_says_which_width_ran(known, k, ksel, model, traced,
     spans = _by_name(tracer.spans_for(req.trace_id))
     assert "serving.fallback" not in spans
     assert spans["serving.scan"][0]["attrs"] == {"k": k, "ksel": ksel,
-                                                 "windows": [8]}
+                                                 "windows": [8],
+                                                 "lane_rows": 0}
     assert ksel == 0 or ksel == sm._block_ksel(k, ITEMS, 64)
+
+
+@pytest.mark.parametrize("jobs, windows, lane_rows", [
+    (2, [8], 1), (9, [32], 1), (33, [256], 0), (258, [256, 8], 1)])
+def test_the_scan_span_counts_the_windows_scored_rows_on_lanes(
+        jobs, windows, lane_rows, model, traced, ladder, monkeypatch):
+    """``lane_rows``: how many of the drain's windows the pallas phase A
+    scored with the store's rows on the lanes, which it does for a
+    window narrower than a lane tile.  The CPU lowers no pallas build
+    (every test above reads 0: the lax.scan build ran), so the build
+    runs in interpret mode here."""
+    real = sm._batch_top_n_twophase_pallas
+    monkeypatch.setattr(
+        sm, "_batch_top_n_twophase_pallas",
+        lambda *a, **kw: real(*a, **kw, interpret=True))
+    # the verdicts of the drains above, which could not lower it
+    monkeypatch.setattr(sm, "_PALLAS_STATE", {})
+    monkeypatch.setattr(sm, "_PALLAS_ERRORS", {})
+    batcher, tracer = traced
+    _, (req,) = _drain(batcher, tracer, model, jobs, sampled=1)
+    attrs = _by_name(tracer.spans_for(req.trace_id))[
+        "serving.scan"][0]["attrs"]
+    assert attrs == {"k": 8, "ksel": 16, "windows": windows,
+                     "lane_rows": lane_rows}
+    # ... and it ran, for every window: no shape fell to the scan build
+    ran = {key[2]: state for key, state in sm._PALLAS_STATE.items()
+           if key[-1] == "pallas"}
+    assert ran == {w: "ok" for w in windows}
 
 
 @pytest.mark.parametrize("failing, widths", [("tail", [8]),
@@ -520,6 +549,26 @@ def test_each_scan_program_is_jitted_under_its_own_name(name, pattern):
     assert hasattr(fn, "lower"), f"{name} is not a jitted function"
     assert fn.__name__ == name and pattern in name
     assert ("twophase" in name) != ("chunked_kernel" in name)
+
+
+@pytest.mark.parametrize("width", sm._WINDOW_LADDER)
+def test_the_pallas_build_is_one_named_program_at_every_ladder_width(
+        width):
+    """Whichever way phase A lays the window's scores (the store's rows
+    on the lanes for the ladder's 8 and 32, the queries there for 256),
+    kernel, transposition if any and phase B are ONE jitted program
+    under the name the device metrics match."""
+    rows, bs, k = 4 * sm._PA_TILE, 128, 8
+    ksel = sm._block_ksel(k, rows, bs)
+    Y = jnp.zeros((rows, FEATURES), jnp.float32)
+    Q = jnp.zeros((width, FEATURES), jnp.float32)
+    active = jnp.ones((rows,), bool)
+    text = sm._batch_top_n_twophase_pallas.lower(
+        Y, Q, sm._penalty_kernel(active, bs), active, None, None, k, bs,
+        ksel, 0, interpret=True).as_text()
+    assert "@jit__batch_top_n_twophase_pallas" in text[:200]
+    assert text.count("func.func public") == 1
+    assert sm._scores_rows_on_lanes(width) == (width < 128)
 
 
 @pytest.mark.parametrize("k, rows_at_once", [(8, 8), (64, 8), (64, 2)])
