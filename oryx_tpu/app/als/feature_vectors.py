@@ -146,10 +146,14 @@ class FeatureVectorStore:
         first axis row-shards) places the device snapshot across a mesh
         instead of one device — serving mode for item matrices past one
         chip's HBM.  Capacity is always grown to a multiple of the
-        device count so the leading dim splits evenly; single-row UP
-        syncs use the same batched scatter as the single-device path
-        (GSPMD partitions a replicated-update scatter onto the sharded
-        operand with no collectives)."""
+        device count so the leading dim splits evenly, and past
+        ``_LARGE_ALIGN`` rows to a multiple of devices x that chunk, so
+        that every SHARD splits into whole streaming chunks and blocks
+        as a one-chip store does (20M rows over 4 devices: 5,013,504
+        rows a shard would be 38.25 chunks; 5,111,808 are 39);
+        single-row UP syncs use the same batched scatter as the
+        single-device path (GSPMD partitions a replicated-update scatter
+        onto the sharded operand with no collectives)."""
         self.features = features
         # Device snapshots lane-pad the feature dim to 128: a factor
         # tile whose minor dim is under the TPU's 128-lane width runs
@@ -175,10 +179,7 @@ class FeatureVectorStore:
             self._active_sharding = NamedSharding(
                 device_sharding.mesh,
                 PartitionSpec(*device_sharding.spec[:1]))
-        cap = max(16, initial_capacity, self._cap_multiple)
-        if cap > _LARGE_ALIGN:
-            cap = -(-cap // _LARGE_ALIGN) * _LARGE_ALIGN
-        cap = -(-cap // self._cap_multiple) * self._cap_multiple
+        cap = self._aligned(max(16, initial_capacity))
         self._id_to_row: dict[str, int] = {}
         self._row_to_id: list[str | None] = [None] * cap
         self._free: list[int] = list(range(cap - 1, -1, -1))
@@ -365,6 +366,20 @@ class FeatureVectorStore:
             if len(self._row_to_id) < n_rows:
                 self._grow(n_rows)
 
+    def _aligned(self, cap: int) -> int:
+        """``cap`` rounded up to what the serving kernels can split: a
+        multiple of the device count (exact-fit bulk_load growth can
+        land on any size), and a large store a whole number of
+        ``_LARGE_ALIGN``-row chunks on EVERY device."""
+        m = self._cap_multiple
+        if cap > _LARGE_ALIGN * m:
+            m *= _LARGE_ALIGN
+        elif cap > _LARGE_ALIGN:
+            # between one chunk and one chunk a device: whole chunks,
+            # as ever, and an even split
+            cap = -(-cap // _LARGE_ALIGN) * _LARGE_ALIGN
+        return -(-cap // m) * m
+
     def _grow(self, min_capacity: int | None = None) -> None:
         old_cap = len(self._row_to_id)
         if old_cap >= 4 * _LARGE_ALIGN:
@@ -376,13 +391,7 @@ class FeatureVectorStore:
             new_cap = old_cap * 2
         if min_capacity is not None and min_capacity > new_cap:
             new_cap = min_capacity
-        if new_cap > _LARGE_ALIGN:
-            new_cap = -(-new_cap // _LARGE_ALIGN) * _LARGE_ALIGN
-        # sharded stores: the leading dim must split evenly over the
-        # mesh (exact-fit bulk_load growth can land on any size)
-        m = self._cap_multiple
-        if m > 1:
-            new_cap = -(-new_cap // m) * m
+        new_cap = self._aligned(new_cap)
         host = np.zeros((new_cap, self.features), dtype=self.dtype)
         host[:old_cap] = self._host
         self._host = host

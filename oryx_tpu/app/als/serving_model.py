@@ -24,11 +24,12 @@ speed.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import math
 import threading
 from functools import partial
-from typing import Callable, Iterable, Sequence
+from typing import Callable, Iterable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -42,7 +43,8 @@ from .factor_model import FactorModelBase, SolverCache  # noqa: F401 (re-export)
 from .lsh import LocalitySensitiveHash, _popcount
 from .rescorer import Rescorer
 
-__all__ = ["ALSServingModel", "SolverCache"]
+__all__ = ["ALSServingModel", "ShardPlan", "SolverCache", "shard_candidates",
+           "shard_plan"]
 
 _log = logging.getLogger(__name__)
 
@@ -113,8 +115,15 @@ def _score_precision(Y):
     than a float32 store states; float32 stores therefore score at
     HIGHEST.  bfloat16 stores multiply exactly in one pass with float32
     accumulation either way, and the CPU backend ignores the setting.
-    Phase A of the two-phase scan only SELECTS blocks (phase B rescores
-    what is served), so it stays on the fast default pass."""
+    Phase A of the two-phase scan only SELECTS blocks, but its maxima
+    are what the certificate holds the k-th served score against
+    (_phase_b_rows' ``m_guard``, 1e-4 relative), so on a float32 store
+    it multiplies at HIGHEST too: a one-pass product's maxima lie up to
+    5.4e-3 off (PERF.md section 5, PR 34), and a certificate that
+    passed on those would prove nothing about an unselected block.
+    The pass costs what the stream costs either way at the ladder's
+    narrow windows (7.06 against 7.05 ms over 5.1M x 250 float32
+    rows)."""
     return jax.lax.Precision.HIGHEST if Y.dtype == jnp.float32 else None
 
 
@@ -527,6 +536,15 @@ def _pallas_block_maxima(Qc, Y, penalty, buckets, target, bs: int,
     J = T // bs                     # blocks a step
     lsh = buckets is not None
     nt = (((1,), (1,)), ((), ()))   # contract both minor dimensions
+    precision = _score_precision(Y)  # the certificate rests on these
+    extra = {}
+    if precision is not None:
+        # HIGHEST splits a float32 tile into bfloat16 parts in VMEM: a
+        # 256-wide window at 250f asks for 26.7 MB, the compiler's
+        # scoped default is 16 of a v5e's 128
+        from jax.experimental.pallas import tpu as pltpu
+        extra["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=64 << 20)
     # per-row side inputs ride in lane-aligned (rows//bs, bs) layout —
     # an (N, 1) input would be lane-padded x128 by TPU tiling (9.5 GB
     # of padding at 20M rows; measured compile OOM)
@@ -543,7 +561,8 @@ def _pallas_block_maxima(Qc, Y, penalty, buckets, target, bs: int,
         # be 128-aligned or full, so the maxima leave as (N // bs, B)
         def kern(q_ref, y_ref, p_ref, *rest):
             s = jax.lax.dot_general(y_ref[...], q_ref[...], nt,
-                                    preferred_element_type=jnp.float32)
+                                    preferred_element_type=jnp.float32,
+                                    precision=precision)
             s3 = s.reshape(J, bs, B) + p_ref[...][:, :, None]
             if lsh:
                 b_ref, t_ref = rest[:2]
@@ -560,7 +579,7 @@ def _pallas_block_maxima(Qc, Y, penalty, buckets, target, bs: int,
             kern, grid=(N // T,), in_specs=in_specs,
             out_specs=pl.BlockSpec((J, B), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((N // bs, B), jnp.float32),
-            interpret=interpret)(*ins).T
+            interpret=interpret, **extra)(*ins).T
 
     # (B, rows): the dot is q . y^T, row j of a step's side inputs is
     # the lanes of its block j and is broadcast down the sublanes, each
@@ -581,7 +600,8 @@ def _pallas_block_maxima(Qc, Y, penalty, buckets, target, bs: int,
     def kern(q_ref, y_ref, p_ref, *rest):
         o_ref = rest[-1]
         s = jax.lax.dot_general(q_ref[...], y_ref[...], nt,
-                                preferred_element_type=jnp.float32)
+                                preferred_element_type=jnp.float32,
+                                precision=precision)
         first = (pl.program_id(0) % per_tile) * J
         m = jnp.where(first == 0, -jnp.inf, o_ref[...])
         lane = jax.lax.broadcasted_iota(jnp.int32, (B, W), 1)
@@ -603,7 +623,7 @@ def _pallas_block_maxima(Qc, Y, penalty, buckets, target, bs: int,
         out_specs=pl.BlockSpec((B, W), lambda i: (0, i // per_tile)),
         out_shape=jax.ShapeDtypeStruct((B, -(-n_blocks // W) * W),
                                        jnp.float32),
-        interpret=interpret)(*ins)[:, :n_blocks]
+        interpret=interpret, **extra)(*ins)[:, :n_blocks]
 
 
 @partial(jax.jit, static_argnames=("k", "bs", "ksel", "max_bits",
@@ -704,6 +724,7 @@ def _batch_top_n_twophase_pallas_fold(Y, Yf, Q, pen_f, active, bkt_f,
     bsf = bs // fold
     Tf = _PA_TILE // fold
     Qc = _q_cast(Q, Y)
+    precision = _score_precision(Y)  # as _pallas_block_maxima
     # slot-shifted query copies: slot j's features live in lanes
     # [j*w, j*w + w), zeros elsewhere — the zero lanes kill the other
     # slots' features in the shared dot
@@ -720,7 +741,8 @@ def _batch_top_n_twophase_pallas_fold(Y, Yf, Q, pen_f, active, bkt_f,
             for j in range(fold):
                 s = jax.lax.dot_general(y_ref[...], q_ref[j],
                                         (((1,), (1,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
+                                        preferred_element_type=jnp.float32,
+                                        precision=precision)
                 s3 = s.reshape(Tf // bsf, bsf, B) + p_ref[j][:, :, None]
                 mj = s3.max(1)
                 m = mj if m is None else jnp.maximum(m, mj)
@@ -737,7 +759,8 @@ def _batch_top_n_twophase_pallas_fold(Y, Yf, Q, pen_f, active, bkt_f,
             for j in range(fold):
                 s = jax.lax.dot_general(y_ref[...], q_ref[j],
                                         (((1,), (1,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
+                                        preferred_element_type=jnp.float32,
+                                        precision=precision)
                 s3 = s.reshape(Tf // bsf, bsf, B) + p_ref[j][:, :, None]
                 ok = jax.lax.population_count(
                     jnp.bitwise_xor(b_ref[j][:, :, None],
@@ -766,6 +789,32 @@ def _batch_top_n_twophase_pallas_fold(Y, Yf, Q, pen_f, active, bkt_f,
                     max_bits)
 
 
+def _scan_block_maxima(Qc, Y, active, buckets, target, chunk: int,
+                       bs: int, max_bits: int):
+    """Phase A of the lax.scan build: the (B, N // bs) maxima of every
+    ``bs``-row block's scores, one (B, chunk) score tile live at a
+    time.  The build every backend lowers (the CPU has no other)."""
+    b = Qc.shape[0]
+    n_chunks = Y.shape[0] // chunk
+    xs = (Y.reshape(n_chunks, chunk, Y.shape[1]),
+          active.reshape(n_chunks, chunk))
+    if target is not None:
+        xs = xs + (buckets.reshape(n_chunks, chunk),)
+
+    def step_a(_, x):
+        scores = jnp.matmul(Qc, x[0].T,
+                            preferred_element_type=jnp.float32,
+                            precision=_score_precision(Y))
+        ok = x[1][None, :]
+        if target is not None:
+            ok = _lsh_ok(ok, x[2][None, :], target[:, None], max_bits)
+        scores = jnp.where(ok, scores, -jnp.inf)
+        return None, scores.reshape(b, chunk // bs, bs).max(-1)
+
+    _, Ms = jax.lax.scan(step_a, None, xs)
+    return jnp.transpose(Ms, (1, 0, 2)).reshape(b, -1)   # (B, n_blocks)
+
+
 @partial(jax.jit, static_argnames=("k", "chunk", "bs", "ksel", "max_bits"))
 def _batch_top_n_twophase_kernel(Y, Q, active, buckets, hyperplanes,
                                  k: int, chunk: int, bs: int, ksel: int,
@@ -787,29 +836,12 @@ def _batch_top_n_twophase_kernel(Y, Q, active, buckets, hyperplanes,
     caller on the exact lax.top_k scan path.  ``buckets`` /
     ``hyperplanes`` of None select the exact scan; with LSH they fuse
     the Hamming-ball mask into both phases."""
-    b = Q.shape[0]
-    n_chunks = Y.shape[0] // chunk
-    Yr = Y.reshape(n_chunks, chunk, Y.shape[1])
-    Ar = active.reshape(n_chunks, chunk)
-    xs = (Yr, Ar)
     target = None
     if buckets is not None:
-        xs = xs + (buckets.reshape(n_chunks, chunk),)
         target = _query_buckets(Q, hyperplanes)
-
     Qc = _q_cast(Q, Y)
-
-    def step_a(_, x):
-        scores = jnp.matmul(Qc, x[0].T,
-                            preferred_element_type=jnp.float32)
-        ok = x[1][None, :]
-        if target is not None:
-            ok = _lsh_ok(ok, x[2][None, :], target[:, None], max_bits)
-        scores = jnp.where(ok, scores, -jnp.inf)
-        return None, scores.reshape(b, chunk // bs, bs).max(-1)
-
-    _, Ms = jax.lax.scan(step_a, None, xs)
-    M = jnp.transpose(Ms, (1, 0, 2)).reshape(b, -1)   # (B, n_blocks)
+    M = _scan_block_maxima(Qc, Y, active, buckets, target, chunk, bs,
+                           max_bits)
     return _phase_b(Y, Qc, active, buckets, target, M, k, bs, ksel,
                     max_bits)
 
@@ -853,6 +885,59 @@ def _batch_top_n_chunked_kernel(Y, Q, active, buckets, hyperplanes,
             jnp.zeros((b, k), jnp.int32))
     (best_s, best_i), _ = jax.lax.scan(step, init, xs)
     return best_s, best_i
+
+
+class ShardPlan(NamedTuple):
+    """How every shard of a row-sharded store scores one window on the
+    two-phase path (parallel/serving_dist.py)."""
+
+    ksel: int    # blocks phase B selects on a shard
+    chunk: int   # rows a step of the scan builds and of the exact scan
+    bs: int      # rows a block
+
+
+def shard_plan(Y, n_shards: int, k: int, width: int) -> ShardPlan | None:
+    """The two-phase plan for a fetch of ``k`` rows by a ``width``-query
+    window over the row-sharded ``Y`` (an array or its aval), or None
+    where the flat body answers: the one-chip dispatch's own conditions
+    (``top_n_batch``), read on the rows ONE shard holds."""
+    rows = int(Y.shape[0]) // n_shards
+    local = jax.ShapeDtypeStruct((rows, int(Y.shape[1])), Y.dtype)
+    big, chunk = _stream_plan(rows, width)
+    bs = _BLOCK_ROWS
+    ksel = _block_ksel(k, rows, bs)
+    if big and rows % chunk == 0 and k <= chunk \
+            and _twophase_admits(k, ksel, local, bs):
+        return ShardPlan(ksel, chunk, bs)
+    return None
+
+
+def shard_candidates(Y, active, Q, penalty, k: int,
+                     plan: ShardPlan | None):
+    """The per-shard body of the sharded program: one shard's best ``k``
+    rows (scores, LOCAL row ids) over its own ``Y``.  ``plan`` of None
+    is the flat body, one matmul over all of them and ``top_k``.
+    Otherwise the one-chip two-phase scan, and a third result, each
+    query row's certificate; ``penalty`` of None selects the lax.scan
+    phase A.  Where a certificate fails the shard scores its rows again
+    with the exact scan: its neighbours' answers stand, and the merge
+    waits for it."""
+    if plan is None:
+        return _batch_top_n_kernel.__wrapped__(Y, Q, active, k)
+    Qc = _q_cast(Q, Y)
+    if penalty is None:
+        M = _scan_block_maxima(Qc, Y, active, None, None, plan.chunk,
+                               plan.bs, 0)
+    else:
+        M = _pallas_block_maxima(Qc, Y, penalty, None, None, plan.bs, 0,
+                                 _scores_rows_on_lanes(Q.shape[0]))
+    ts, ti, cert = _phase_b(Y, Qc, active, None, None, M, k, plan.bs,
+                            plan.ksel, 0)
+    ts, ti = jax.lax.cond(
+        cert.all(), lambda: (ts, ti),
+        lambda: _batch_top_n_chunked_kernel(Y, Q, active, None, None, k,
+                                            plan.chunk, 0))
+    return ts, ti, cert
 
 
 @partial(jax.jit, static_argnames=("k", "bs", "ksel", "max_bits"))
@@ -1159,9 +1244,12 @@ class ALSServingModel(FactorModelBase, ServingModel):
                  fold_scan: str | bool = "auto", ann_config=None):
         """``item_shards`` > 1 row-shards the item matrix over that many
         devices (``oryx.serving.api.item-shards``) and routes the
-        dot-product top-N scan through one SPMD program with an
-        on-device top-k merge — the serving mode for item matrices past
-        one chip's HBM (reference's partitioned scan,
+        dot-product top-N scan through one SPMD program a window: on
+        every shard the two-phase scan of the one-chip path over that
+        shard's rows (the flat matmul + top_k where the shard is too
+        small for it), then one all_gather and an on-device top-k
+        merge (parallel/serving_dist.py) — the serving mode for item
+        matrices past one chip's HBM (reference's partitioned scan,
         PartitionedFeatureVectors.java:84-148 via
         ALSServingModel.java:265-280).  LSH pruning is bypassed in
         sharded mode (it is a single-chip optimization); cosine and
@@ -1263,6 +1351,13 @@ class ALSServingModel(FactorModelBase, ServingModel):
         # observability: exact-scan recomputes forced by a failed
         # two-phase certificate (expected ~0; see _block_ksel's notes)
         self.twophase_fallbacks = 0
+        # the sharded path's own: two-phase windows scored by the SPMD
+        # program, and the (query row, shard) pairs whose certificate
+        # failed there, each of which that shard answered by its exact
+        # scan (a query row counts once in twophase_fallbacks however
+        # many of its shards failed)
+        self.sharded_windows = 0
+        self.shard_fallback_rows = 0
         # whole-matrix builds of state derived from the item matrix (the
         # phase-A mirrors, the LSH buckets, the IVF mirror): one a kind
         # at load.  The penalties and the LSH buckets follow the rows a
@@ -1330,6 +1425,8 @@ class ALSServingModel(FactorModelBase, ServingModel):
             # exact-scan recomputes forced by a failed streaming top-k
             # certificate; nonzero is worth an operator's attention
             "twophase_fallbacks": self.twophase_fallbacks,
+            "sharded_windows": self.sharded_windows,
+            "shard_fallback_rows": self.shard_fallback_rows,
             # the update path: in-place syncs of the item store, the
             # rows they carried (a load counts its whole upload), and
             # the whole-matrix rebuilds of derived state beside them
@@ -1365,7 +1462,8 @@ class ALSServingModel(FactorModelBase, ServingModel):
         r = self._route
         if not r:
             return None
-        chosen = r.get("chosen")
+        # a sharded model has one path and says which body it runs
+        chosen = r.get("chosen") or r.get("kind")
         if chosen is None:
             return None
         return f"{chosen}+lsh" if r.get("use_lsh") else str(chosen)
@@ -1461,7 +1559,11 @@ class ALSServingModel(FactorModelBase, ServingModel):
         # UP records of a live model compile nothing
         self.Y.warm_sync()
         if self._item_shards > 1:
-            return  # the loop above already warmed the SPMD merge kernel
+            # the loop above ran every window of the ladder through the
+            # SPMD program on the live mesh, and that program holds its
+            # own exact-scan fallback: nothing is left to compile
+            self.refresh_route()
+            return
         n_rows = len(self.Y.row_ids())
         k = min(_pad_k(how_many), n_rows)
         big, chunk = _stream_plan(n_rows, _CHUNKED_BATCH)
@@ -1754,23 +1856,8 @@ class ALSServingModel(FactorModelBase, ServingModel):
         # recorder (obs/trace.py); None, and one branch a site, for
         # every caller that opened none
         rec = obstrace.current_drain()
-        # rows the update consumer wrote since the last drain go to the
-        # device here, in place, before this drain's programs: a phase
-        # of its own, first, where there are any
-        syncing = rec is not None and self.Y.pending_rows() > 0
-        if syncing:
-            rec.mark("serving.apply_updates")
-        elif rec is not None:
-            rec.mark("serving.prepare", rows=n_req)
-        # the store's ordering rule: the resident arrays are fetched
-        # and every program that reads them is enqueued inside the
-        # dispatch lock; results are fetched outside it
-        with self.Y.dispatching() as snap:
+        with self._drain_snapshot(rec, n_req) as snap:
             vecs, active, version = snap.vecs, snap.active, snap.version
-            if syncing:
-                rec.annotate(rows=snap.synced_rows, bytes=snap.synced_bytes,
-                             version=version, batches=sorted(snap.tags))
-                rec.mark("serving.prepare", rows=n_req)
             n_rows = int(vecs.shape[0])
             k = min(_pad_k(max(h + len(e) for h, e in zip(hm, excl))),
                     n_rows)
@@ -1889,6 +1976,28 @@ class ALSServingModel(FactorModelBase, ServingModel):
                                                          np.float32),
                                   use_lsh)
 
+    @contextlib.contextmanager
+    def _drain_snapshot(self, rec, n_req: int):
+        """The resident arrays for one drain's programs, under the
+        store's ordering rule: they are fetched and every program that
+        reads them is enqueued inside the dispatch lock; results are
+        fetched outside it.  Rows the update consumer wrote since the
+        last drain go to the device here, in place, before this drain's
+        programs: a phase of its own on the recorder ``rec``, first,
+        where there are any; then ``serving.prepare``."""
+        syncing = rec is not None and self.Y.pending_rows() > 0
+        if syncing:
+            rec.mark("serving.apply_updates")
+        elif rec is not None:
+            rec.mark("serving.prepare", rows=n_req)
+        with self.Y.dispatching() as snap:
+            if syncing:
+                rec.annotate(rows=snap.synced_rows, bytes=snap.synced_bytes,
+                             version=snap.version,
+                             batches=sorted(snap.tags))
+                rec.mark("serving.prepare", rows=n_req)
+            yield snap
+
     def _enqueue_exact(self, qw, k: int, chunk: int, lsh_on: bool):
         """Enqueue one window's exact chunked scan over the resident
         arrays as they are NOW (its own acquisition of the dispatch
@@ -1914,9 +2023,9 @@ class ALSServingModel(FactorModelBase, ServingModel):
         resident arrays as they are now (see ``_enqueue_exact``)."""
         with self.Y.dispatching() as snap:
             buckets, hp, mb = self._lsh_inputs(snap, lsh_on)
-            return _batch_top_n_twophase_kernel(
-                snap.vecs, qw, snap.active, buckets, hp, k, chunk, bs,
-                ksel, mb)
+            return self._dispatch_kind(
+                "scan", qw, snap.vecs, snap.active, snap.version, buckets,
+                hp, k, bs, ksel, mb, 1, {}, chunk=chunk)
 
     def _dispatch_twophase(self, vecs, windows, active, version, buckets,
                           hp, k: int, chunk: int, bs: int, ksel: int,
@@ -1935,8 +2044,13 @@ class ALSServingModel(FactorModelBase, ServingModel):
                                                  int(vecs.shape[1]), bs)
 
         def key_of(qw, kind):
+            # a sharded model's verdicts are its own: the same kernel
+            # under shard_map is another program (the shard count rides
+            # before the kind, which stays last)
+            mesh = (self._item_shards,) if self._item_shards > 1 else ()
             return (n_rows, int(vecs.shape[1]), int(qw.shape[0]),
-                    str(vecs.dtype), buckets is not None, k, mb, kind)
+                    str(vecs.dtype), buckets is not None, k, mb,
+                    *mesh, kind)
 
         ctx: dict = {}
         handles, attempted = [], []
@@ -1968,9 +2082,9 @@ class ALSServingModel(FactorModelBase, ServingModel):
                     # shape that worked before re-raises
                     _classify_pallas_failure([key], e)
             if not dispatched:
-                handles.append(_batch_top_n_twophase_kernel(
-                    vecs, qw, active, buckets, hp, k, chunk, bs, ksel,
-                    mb))
+                handles.append(self._dispatch_kind(
+                    "scan", qw, vecs, active, version, buckets, hp, k, bs,
+                    ksel, mb, fold, ctx, chunk=chunk))
         return handles, attempted
 
     def _fetch_twophase(self, handles: list, attempted: list, windows,
@@ -2006,6 +2120,16 @@ class ALSServingModel(FactorModelBase, ServingModel):
         across the router's timing repetitions).  Shared by the serving
         dispatch, the measured-cost router, and the kernel probe — the
         timed program must BE the served program."""
+        if self._item_shards > 1:
+            # the same two builds of the canonical store's phase A, on
+            # every shard's own rows inside the SPMD program
+            if kind not in ("pallas", "scan"):
+                raise ValueError(f"no sharded phase-A kind {kind!r}")
+            if kind == "pallas" and "penalty" not in ctx:
+                ctx["penalty"] = self._cached_penalty(active, version)
+            return self._shard_kernels.twophase(
+                vecs, active, qw, k, (ksel, chunk, bs),
+                ctx["penalty"] if kind == "pallas" else None)
         if kind == "i8_fold":
             if "i8_fold" not in ctx:
                 ctx["i8_fold"] = self._cached_i8_fold(
@@ -2065,7 +2189,11 @@ class ALSServingModel(FactorModelBase, ServingModel):
         operator opted into the quantized mirror's HBM profile).  The
         lax.scan build is a first-class routable kind: where it
         MEASURES cheapest, routing chooses it rather than merely
-        falling back to it."""
+        falling back to it.  A sharded model has the canonical store's
+        two builds, by the rows ONE shard holds: no mirror is sharded."""
+        if self._item_shards > 1:
+            tiles = (n_rows // self._item_shards) % _PA_TILE == 0
+            return (["pallas"] if tiles else []) + ["scan"], 1
         eligible = n_rows % _PA_TILE == 0
         want_i8 = self._int8_enabled()
         fold = _fold_eligible(width, self.features, bs) \
@@ -2134,7 +2262,7 @@ class ALSServingModel(FactorModelBase, ServingModel):
         of UP-stream version bumps); ``force`` re-measures anyway."""
         from .kernel_router import measure_routes
         if self._item_shards > 1:
-            return None  # SPMD merge kernel is the only sharded path
+            return self._refresh_sharded_route()
         with self._route_lock:
             n_rows = len(self.Y.row_ids())
             r = self._route
@@ -2181,26 +2309,87 @@ class ALSServingModel(FactorModelBase, ServingModel):
                      and r.get("ann_key") == self._ann_route_key()) \
             else None
 
+    def _refresh_sharded_route(self) -> dict | None:
+        """What a sharded model reports where the one-chip model reports
+        its measured route: the body the SPMD program runs on every
+        shard at the live capacity, read off the mesh and the store.
+        Nothing is measured and nothing configured: a shard has one
+        path."""
+        n_rows = len(self.Y.row_ids())
+        store = jax.ShapeDtypeStruct((n_rows, self.Y.device_features),
+                                     self.Y.dtype)
+        plan = shard_plan(store, self._item_shards,
+                          min(_pad_k(1), n_rows), _WINDOW_LADDER[0])
+        route = {"kind": "sharded_twophase" if plan else "sharded_flat",
+                 "shards": self._item_shards, "capacity": n_rows}
+        with self._route_lock:
+            self._route, self._route_capacity = route, n_rows
+        return route
+
     def _sharded_top_n_batch(self, hm: list[int], Q: np.ndarray,
                              excl: list[set[str]],
                              use_lsh: bool) -> list[list[tuple[str, float]]]:
-        """Batched top-N over the mesh-sharded item matrix: per-shard
-        top-k, one all_gather, on-device merge (the SPMD kernel shared
-        with parallel/serving_dist.ShardedItemScorer)."""
+        """Batched top-N over the mesh-sharded item matrix, one SPMD
+        program a window (parallel/serving_dist.py, the builder shared
+        with ShardedItemScorer): every shard scans its own rows, one
+        all_gather, an on-device merge.  Where a shard is large enough
+        (``shard_plan``: the one-chip conditions on one shard's rows)
+        the windows are the one-chip ladder's and the scan the
+        two-phase one, its builds tried and substituted as on one chip
+        (``_dispatch_twophase``); a failed certificate is answered
+        inside the program, by that shard's exact scan, and only
+        counted here.  The drain's phases are the one-chip drain's."""
         n_req = Q.shape[0]
-        with self.Y.dispatching() as snap:  # enqueue inside, fetch after
+        rec = obstrace.current_drain()
+        kernels, shards = self._shard_kernels, self._item_shards
+        with self._drain_snapshot(rec, n_req) as snap:
             vecs, active = snap.vecs, snap.active
             n_rows = int(vecs.shape[0])
             k = min(_pad_k(max(h + len(e) for h, e in zip(hm, excl))),
                     n_rows)
             b_pad = _pad_k(n_req)
-            if b_pad != n_req:
+            plan = shard_plan(vecs, shards, k, b_pad)
+            sizes = _window_sizes(n_req) if plan is not None else [b_pad]
+            if n_req < sum(sizes):
                 Q = np.concatenate(
-                    [Q, np.zeros((b_pad - n_req, Q.shape[1]),
+                    [Q, np.zeros((sum(sizes) - n_req, Q.shape[1]),
                                  np.float32)])
-            handles = self._shard_kernels.top_k(
-                vecs, active, self._shard_kernels.replicate(Q), k)
-        top_scores, top_idx = jax.device_get(handles)
+            if rec is not None:
+                rec.mark("serving.scan", shards=shards, k=k,
+                         ksel=plan.ksel if plan is not None else 0,
+                         windows=sizes, lane_rows=0)
+            if plan is None:
+                handles = kernels.flat(vecs, active, kernels.replicate(Q),
+                                       k)
+            else:
+                windows, w = [], 0
+                for size in sizes:
+                    windows.append(kernels.replicate(Q[w:w + size]))
+                    w += size
+                handles, attempted = self._dispatch_twophase(
+                    vecs, windows, active, snap.version, None, None, k,
+                    plan.chunk, plan.bs, plan.ksel, 0)
+                if rec is not None:
+                    rec.annotate(lane_rows=sum(
+                        _scores_rows_on_lanes(key[2]) for key in attempted
+                        if key[-1] == "pallas"))
+        if plan is None:
+            top_scores, top_idx = jax.device_get(handles)
+        else:
+            fetched = self._fetch_twophase(
+                handles, attempted, windows, k, plan.chunk, plan.bs,
+                plan.ksel, False)
+            failed = [~f[2] for f in fetched]       # (shards, B) a window
+            with self._bucket_lock:
+                self.sharded_windows += len(fetched)
+                self.shard_fallback_rows += sum(int(f.sum())
+                                                for f in failed)
+                self.twophase_fallbacks += sum(int(f.any(0).sum())
+                                               for f in failed)
+            top_scores = np.concatenate([f[0] for f in fetched])
+            top_idx = np.concatenate([f[1] for f in fetched])
+        if rec is not None:
+            rec.mark("serving.decode", rows=n_req)
         window = min(k, top_scores.shape[1])
         return self._decode_top_n(top_scores, top_idx, hm, excl, n_req,
                                   window < n_rows, Q, use_lsh)

@@ -308,12 +308,16 @@ def run_warmup(config, items_list: list[int], features_list: list[int],
         # the sharded SPMD scan compiles against a live device mesh —
         # not AOT-able from avals here.  Say so loudly instead of
         # reporting a successful warm of single-chip kernels the
-        # sharded serving layer will never dispatch.
+        # sharded serving layer will never dispatch, and name what
+        # does warm them.
         _log.warning(
-            "item-shards=%d: the sharded merge kernels are NOT warmed "
-            "(mesh-bound; first sharded start still compiles them). "
-            "Warming the single-chip ladder anyway for tools/benches.",
-            item_shards)
+            "item-shards=%d: the sharded programs are NOT warmed by "
+            "this tool (it has no mesh). The serving process warms "
+            "them itself: ALSServingModel.warm_serving_kernels runs "
+            "every window of the ladder through the SPMD program on "
+            "the live mesh, exact-scan fallback included, before "
+            "traffic. Warming the single-chip ladder anyway for "
+            "tools/benches.", item_shards)
         report["sharded_not_warmed"] = item_shards
     t0 = time.perf_counter()
     import jax

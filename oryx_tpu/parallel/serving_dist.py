@@ -7,13 +7,27 @@ partitions scanned by a thread pool with a streaming top-N merge
 The TPU-native analog scales the same way across CHIPS: rows of Y live
 sharded over a 1-D mesh, every query's partial top-k is computed on the
 shard that owns the rows, partials ride one all_gather over ICI, and
-the merge happens on device — one jitted SPMD program, no host fan-in.
+the merge happens on device — one jitted SPMD program a window, no host
+fan-in.
 
-This is the capacity story past a single chip's HBM: a 40M x 250 bf16
-item matrix (20 GB) serves from 2 chips, 160M items from 8.  The
-single-chip serving model (app/als/serving_model.py) remains the
-production path up to ~20M items; this scorer is the P4/P5 scale-out
-the driver dry-runs on a virtual mesh.
+What a shard does with its rows is the serving model's to say
+(``serving_model.shard_candidates``; this module holds the mesh, the
+gather and the merge): what the one-chip model does with all of them,
+by the one-chip model's own rules read on ONE shard's row count
+(``serving_model.shard_plan``): where the two-phase scan is admitted,
+phase A's block maxima (the pallas kernel, or the ``lax.scan`` build
+where that cannot lower), phase B's selection and rescoring, and the
+exactness certificate; a shard whose certificate fails answers by the
+exact scan over its own rows, inside the same program, while the others
+wait at the gather.  Small stores (the tests' meshes) keep the flat
+body: one matmul, a mask and ``top_k``.
+
+This is the capacity story past a single chip's HBM, and the only way
+to serve the reference's largest published catalog at the reference's
+own float32: 20M x 250 x 4 B = 20 GB, 5.1 GB a chip over the four chips
+of one v5e host (``oryx.serving.api.item-shards = 4``; the benchmark's
+``als250-20m-f32-x4.two-callers`` cell).  What that layout measured on
+the chips is in PERF.md, sections 5 and 6 (PR 34).
 """
 
 from __future__ import annotations
@@ -27,69 +41,105 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..app.als import serving_model as sm
 from ..app.als.feature_vectors import resolve_dtype
-from ..app.als.serving_model import _pad_k, _q_cast, _score_precision
 
-__all__ = ["ShardedItemScorer"]
+__all__ = ["ShardedItemScorer", "ShardKernelCache", "build_program"]
 
 
-def _make_kernel(mesh: Mesh, k_shard: int, k_final: int, axis: str):
-    """``k_shard`` candidates leave each shard; ``k_final`` survive the
-    merge.  They are independent: a shard can never contribute more
-    than its own row count, but the MERGED result may be wider than any
-    one shard's candidate list (how_many > rows-per-shard)."""
-    @partial(shard_map, mesh=mesh,
-             in_specs=(P(axis, None), P(axis), P(None, None)),
-             out_specs=(P(None, None), P(None, None)),
-             # the all_gather-merged outputs ARE replicated, but the
-             # static replication checker cannot infer that
+def build_program(mesh: Mesh, axis: str, k_shard: int, k_final: int,
+                  plan: sm.ShardPlan | None = None, pallas: bool = False):
+    """THE builder of the sharded top-k program, for the serving model
+    and for :class:`ShardedItemScorer`: ``k_shard`` candidates leave
+    each shard, by the flat body (``plan`` None) or by the two-phase
+    scan, ride one all_gather, and ``k_final`` survive the merge, with
+    GLOBAL row ids.  They are independent: a shard can never contribute
+    more than its own row count, but the MERGED result may be wider
+    than any one shard's candidate list (how_many > rows-per-shard).
+
+    Returns a jitted ``(Y, active, Q[, penalty]) -> (scores, rows[,
+    certificates (shards, B)])``.  The two-phase program's name
+    contains ``twophase``: the device metrics find it by that
+    (tests/test_serving_spans.py)."""
+    rows = P(axis, None)
+    in_specs = (rows, P(axis), P(None, None)) + ((rows,) if pallas else ())
+    out_specs = (P(None, None),) * (2 if plan is None else 3)
+
+    # the all_gather-merged outputs ARE replicated, but the static
+    # replication checker cannot infer that
+    @partial(shard_map, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
              check_vma=False)
-    def scorer(Y_local, active_local, Q):
-        n_local = Y_local.shape[0]
-        # bf16 stores: keep the scan on the native bf16 MXU path
-        # (serving_model._q_cast rationale)
-        scores = jnp.matmul(_q_cast(Q, Y_local), Y_local.T,
-                            preferred_element_type=jnp.float32,
-                            precision=_score_precision(Y_local))
-        scores = jnp.where(active_local[None, :], scores, -jnp.inf)
-        ls, li = jax.lax.top_k(scores, k_shard)        # (B, ks) local
-        gi = li + jax.lax.axis_index(axis) * n_local   # global row ids
+    def scorer(Y_local, active_local, Q, penalty=None):
+        found = sm.shard_candidates(Y_local, active_local, Q, penalty,
+                                    k_shard, plan)
+        ls, li = found[:2]
+        gi = li + jax.lax.axis_index(axis) * Y_local.shape[0]
         # partials from every shard: (n_dev, B, ks) -> (B, n_dev*ks)
-        gs = jax.lax.all_gather(ls, axis)
-        gidx = jax.lax.all_gather(gi, axis)
+        gathered = jax.lax.all_gather((ls, gi) + tuple(found[2:]), axis)
         b = Q.shape[0]
-        gs = jnp.moveaxis(gs, 0, 1).reshape(b, -1)
-        gidx = jnp.moveaxis(gidx, 0, 1).reshape(b, -1)
+        gs = jnp.moveaxis(gathered[0], 0, 1).reshape(b, -1)
+        gidx = jnp.moveaxis(gathered[1], 0, 1).reshape(b, -1)
         ms, sel = jax.lax.top_k(gs, k_final)
         mi = jnp.take_along_axis(gidx, sel, axis=1)
-        return ms, mi
+        return (ms, mi) + tuple(gathered[2:])
 
-    return jax.jit(scorer)
+    def program(*args):
+        return scorer(*args)
+
+    # the device trace names a program after the jitted function
+    program.__name__ = "sharded_flat_top_k" if plan is None \
+        else "sharded_twophase_top_k"
+    return jax.jit(program)
 
 
 class ShardKernelCache:
-    """Per-(k_shard, k_final) compiled SPMD merge kernels for one mesh —
-    the shard plan shared by :class:`ShardedItemScorer` and the serving
-    model's configured sharded mode (``oryx.serving.api.item-shards``)."""
+    """The compiled SPMD programs of one mesh — the shard plan shared by
+    :class:`ShardedItemScorer` and the serving model's configured
+    sharded mode (``oryx.serving.api.item-shards``)."""
 
     def __init__(self, mesh: Mesh, axis: str = "d"):
         self.mesh = mesh
         self.axis = axis
-        self._kernels: dict[tuple[int, int], object] = {}
+        self.shards = int(mesh.devices.size)
+        self._programs: dict[tuple, object] = {}
+
+    def _program(self, key: tuple, **build):
+        prog = self._programs.get(key)
+        if prog is None:
+            prog = self._programs[key] = build_program(
+                self.mesh, self.axis, **build)
+        return prog
+
+    def flat(self, Y, active, Q_dev, k: int):
+        """(scores, global rows) of the merged per-shard top-k by the
+        flat body; ``k`` is clamped to the global row count and each
+        shard's contribution to its local rows."""
+        k_shard = min(k, int(Y.shape[0]) // self.shards)
+        k_final = min(k, k_shard * self.shards)
+        return self._program(("flat", k_shard, k_final), k_shard=k_shard,
+                             k_final=k_final)(Y, active, Q_dev)
+
+    def twophase(self, Y, active, Q_dev, k: int, plan: tuple,
+                 penalty=None):
+        """(scores, global rows, certificates (shards, B)) by the
+        two-phase scan on every shard, by ``plan`` (a ShardPlan or its
+        (ksel, chunk, bs)); phase A is the pallas kernel where its
+        ``penalty`` (the row-sharded (N // bs, bs) mask) is given, else
+        the lax.scan build."""
+        plan, pallas = sm.ShardPlan(*plan), penalty is not None
+        prog = self._program(("twophase", k, plan, pallas), k_shard=k,
+                             k_final=k, plan=plan, pallas=pallas)
+        return prog(Y, active, Q_dev, *((penalty,) if pallas else ()))
 
     def top_k(self, Y, active, Q_dev, k: int):
-        """(scores, global_row_idx) of the merged per-shard top-k for a
-        replicated query batch; ``k`` is clamped to the global row
-        count and each shard's contribution to its local rows."""
-        n_rows = int(Y.shape[0])
-        n_local = n_rows // self.mesh.devices.size
-        k_shard = min(k, n_local)
-        k_final = min(k, k_shard * self.mesh.devices.size)
-        kern = self._kernels.get((k_shard, k_final))
-        if kern is None:
-            kern = self._kernels[(k_shard, k_final)] = _make_kernel(
-                self.mesh, k_shard, k_final, self.axis)
-        return kern(Y, active, Q_dev)
+        """The merged top-``k`` by whichever body ``shard_plan`` admits
+        (the lax.scan phase A where it is the two-phase one): (scores,
+        global rows), the certificates dropped — a shard that failed
+        one has answered by its exact scan."""
+        plan = sm.shard_plan(Y, self.shards, k, int(Q_dev.shape[0]))
+        if plan is None:
+            return self.flat(Y, active, Q_dev, k)
+        return self.twophase(Y, active, Q_dev, k, plan)[:2]
 
     def replicate(self, Q: np.ndarray):
         return jax.device_put(
@@ -145,13 +195,13 @@ class ShardedItemScorer:
         n_req = Q.shape[0]
         if n_req == 0:
             return []
-        b_pad = _pad_k(n_req)
+        b_pad = sm._pad_k(n_req)
         if b_pad != n_req:
             Q = np.concatenate(
                 [Q, np.zeros((b_pad - n_req, Q.shape[1]), np.float32)])
         scores, idx = jax.device_get(self._kernels.top_k(
             self._Y, self._active, self._kernels.replicate(Q),
-            min(_pad_k(how_many), int(self._Y.shape[0]))))
+            min(sm._pad_k(how_many), int(self._Y.shape[0]))))
         out: list[list[tuple[str, float]]] = []
         for b in range(n_req):
             row: list[tuple[str, float]] = []

@@ -249,7 +249,8 @@ def test_served_scores_keep_the_stores_precision():
     carry HIGHEST for a float32 store — visible in the lowered program
     on any backend — and must not for a bfloat16 store, whose products
     are exact in one pass.  Phase A of the two-phase scan only selects
-    blocks and stays on the default pass."""
+    blocks, but the certificate holds the served scores against its
+    maxima, so it carries HIGHEST too (PR 34)."""
     import jax
     import jax.numpy as jnp
 
@@ -276,7 +277,7 @@ def test_served_scores_keep_the_stores_precision():
 
     f32, bf16 = dots(jnp.float32), dots(jnp.bfloat16)
     assert all(count == 0 for count in bf16.values()), bf16
-    # one dot per served-score kernel; the two-phase program's only
-    # HIGHEST dot is phase B's rescore (phase A stays default)
+    # one dot per served-score kernel; the two-phase program has two,
+    # phase A's maxima and phase B's rescore
     assert all(count >= 1 for count in f32.values()), f32
-    assert f32["twophase_scan"] == f32["flat"], f32
+    assert f32["twophase_scan"] == 2 * f32["flat"], f32

@@ -531,8 +531,13 @@ def test_the_exact_ladder_enqueues_only_the_named_programs():
     the exact scan would match."""
     assert _scan_programs_called_by(ALSServingModel._dispatch_kind) \
         == set(TWOPHASE_BUILDS)
+    # ... and nothing is enqueued but through it (a sharded model's
+    # builds are the SPMD program of parallel/serving_dist.py, named
+    # ``sharded_twophase_top_k``: tests/test_sharded_twophase.py)
     assert _scan_programs_called_by(ALSServingModel._dispatch_twophase) \
-        == {"_batch_top_n_twophase_kernel"}
+        == set()
+    assert _scan_programs_called_by(ALSServingModel._sharded_top_n_batch) \
+        == set()
     assert _scan_programs_called_by(ALSServingModel.top_n_batch) \
         >= {EXACT_SCAN}
     assert not any("twophase" in name for name in
