@@ -36,13 +36,19 @@ decision made here, and it follows two measurements:
   when and what it costs.
 - *Whether to hold a drain for the callers just answered.*  A
   completion releases n callers; closed-loop callers are back within a
-  fraction of a service time, and the drain that could leave at once
-  would leave without them.  It waits for them — leaving as soon as
-  they are back — for at most an eighth of the service time, and keeps
-  score: where the awaited callers do not come back inside the hold
-  (open-loop arrivals) the hold switches itself off and tries again
-  every so many completions.  A lone closed-loop caller is never held:
-  when it is back, nobody else is out.
+  fraction of a service time, one after another through the door, and
+  the drain that could leave at once would leave without them.  It
+  waits for them, leaving as soon as they are back, for as long as they
+  keep coming: the clock runs from the LAST caller's return, and the
+  drain leaves when nobody has come back for one patience (an eighth of
+  the service time at most, less where many wait for few), or a quarter
+  of the service time after the first return, whoever is still out.
+  A caller left behind finds a program running and rides the next: a
+  whole service time, against the fraction of one that those waiting
+  paid for it.  The hold keeps score: where the awaited callers do not
+  come back inside it (open-loop arrivals) it switches itself off and
+  tries again every so many completions.  A lone closed-loop caller is
+  never held: when it is back, nobody else is out.
 """
 
 from __future__ import annotations
@@ -82,10 +88,20 @@ _SERIAL_OVERLAP = 0.25
 # in 16,384 from then on.
 _PROBE_EVERY = 256
 _PROBE_EVERY_MAX = 16384
-# A drain waits for the callers the last completion released at most
-# this fraction of the service time, and never longer than this.
+# A drain that waits for the callers the last completion released
+# leaves when none of them has come back for this fraction of the
+# service time (one patience), and never waits longer than this for the
+# next one.
 _HOLD_FRACTION = 8
 _MAX_HOLD_S = 0.002
+# However they trickle back, a hold lasts at most this fraction of the
+# service time from the first return on (and as long before it, where a
+# drain could leave with nobody back): what a caller in step can lose to
+# it, where a caller left behind loses a whole one.  It is also what
+# keeps the score honest: Poisson arrivals cannot be told from returning
+# callers, and S / 4 meets a quarter as many of them as the program
+# before it released, well under _HIT_SHARE_ON.
+_HOLD_TOTAL_FRACTION = 4
 # The hold stays on while at least this share of the awaited callers
 # came back inside it (running mean over holds); off, one drain in this
 # many completions holds all the same, to see whether they do now, and
@@ -134,11 +150,13 @@ class TopNBatcher:
         threads; configurable via
         oryx.serving.api.scoring-pipeline-depth.
 
-        ``idle_wait_s`` caps how long a drain that could leave waits
-        for the callers the last completion released (the hold of the
-        module's docstring).  None (default) is 2 ms; the hold in force
-        is the smaller of the cap and an eighth of the measured service
-        time, and 0 switches it off.  Configurable via
+        ``idle_wait_s`` caps one patience of the hold of the module's
+        docstring: how long a drain that could leave goes on waiting
+        after the last of the callers the previous completion released
+        came back.  None (default) is 2 ms; the patience in force is
+        the smaller of the cap and an eighth of the measured service
+        time, the hold ends a quarter of it after the first return at
+        the latest, and 0 switches the hold off.  Configurable via
         oryx.serving.api.batch-idle-wait-ms (-1 = the default cap).
 
         ``tracer`` (obs/trace.py, or None) splits each sampled
@@ -201,18 +219,23 @@ class TopNBatcher:
         self._probe_out = False
         self.probes = 0
         # the hold for returning callers: how many the last completion
-        # released that are not back yet, the hold now running (start,
-        # limit, callers awaited at its start, arrivals inside it), and
-        # its score
+        # released that are not back yet and when the last of them came
+        # back (None: none has), the hold now running (start, where
+        # its limit counts from, callers awaited once the first was
+        # back, how many of them came, arrivals that left somebody
+        # out), and its score
         self._awaited = 0
+        self._last_return: float | None = None
         self._hold_t0: float | None = None
-        self._hold_limit = 0.0
+        self._hold_from = 0.0
         self._hold_for = 0
         self._hold_hits = 0
+        self._hold_renewals = 0
         self._hit_share = 1.0
         self._since_hold = 0
         self.return_holds = 0
         self.return_hits = 0
+        self.return_left_behind = 0
         self._threads = [
             threading.Thread(target=self._loop, daemon=True,
                              name=f"TopNBatcher-{i}")
@@ -270,13 +293,7 @@ class TopNBatcher:
             else:
                 stopped = False
                 self._pending.append(job)
-                if self._awaited:
-                    # any arrival counts as one of the callers just
-                    # answered coming back: they cannot be told apart
-                    self._awaited -= 1
-                    if self._hold_t0 is not None:
-                        self._hold_hits += 1
-                        self.return_hits += 1
+                self._return_locked(job.t_enq)
                 self._wake_locked(1)
         if stopped:
             return model.top_n_batch([how_many], job.vector[None, :],
@@ -329,6 +346,7 @@ class TopNBatcher:
                 else "off",
                 "return_holds": self.return_holds,
                 "return_hits": self.return_hits,
+                "return_left_behind": self.return_left_behind,
                 "return_hit_share": round(self._hit_share, 3),
                 "deadline_rejects": self.deadline_rejects,
             }
@@ -395,58 +413,105 @@ class TopNBatcher:
         return self._depth()[0]
 
     def _hold_cap(self) -> float:
-        """The longest a drain may now wait for returning callers: no
-        time at all before the service time has been measured."""
+        """One patience: the longest a held drain may now go on waiting
+        after the last returning caller's arrival.  No time at all
+        before the service time has been measured."""
         if not self._exec_measured:
             return 0.0
         cap = _MAX_HOLD_S if self._idle_wait is None else self._idle_wait
         return min(cap, self._exec_ewma / _HOLD_FRACTION)
 
+    def _return_locked(self, at: float) -> None:
+        """A request arrived at ``at``.  While callers the last
+        completion released are out, any arrival counts as one of them
+        coming back: they cannot be told apart."""
+        if self._awaited <= 0:
+            return
+        self._awaited -= 1
+        if self._hold_t0 is not None:
+            self.return_hits += 1
+            if self._last_return is None:
+                # the first one back, in a hold that began with nobody
+                # back: the stream of returns begins here, and the hold
+                # is scored like any other, on those who are out now
+                # (an open loop's next arrival is no caller returning)
+                self._hold_from, self._hold_for = at, self._awaited
+            else:
+                self._hold_hits += 1
+            if self._awaited:
+                # somebody is still out: the hold goes on from here
+                self._hold_renewals += 1
+        self._last_return = at
+
     def _hold_locked(self, now: float) -> float:
         """Seconds the drain that could leave now should still wait for
-        the callers the last completion released; <= 0: go.  The clock
-        of a hold starts here, when a drain first could leave, not at
-        the oldest arrival: a caller that is not back yet has no age."""
+        the callers the last completion released; <= 0: go.  A hold
+        starts here, when a drain first could leave; inside it the
+        clock that counts is the one since the last awaited caller came
+        back.  Nobody back for one patience: the stream of returns has
+        ended, and the drain goes without whoever is still out.  One
+        patience is worth no more than what the wait costs those who
+        pay it: the callers waiting lose ``waiting x t`` to a wait of
+        t, those left behind a service time each.  The limit of the
+        whole hold counts from the first return.  Most cycles that is
+        where the hold starts; where the drain could leave with nobody
+        back (a caller left behind waited for the completion) only the
+        limit runs, a caller that is not back having no age, and it
+        counts anew when the first is back (:meth:`_return_locked`):
+        cut at that distance from the completion, the one would leave
+        alone and the others come back to a running program."""
         if self._awaited <= 0:
             return 0.0
         if self._hold_t0 is None:
             if self._hit_share < _HIT_SHARE_ON \
                     and self._since_hold < _HOLD_PROBE_EVERY:
                 return 0.0
-            limit = self._hold_cap()
-            if limit <= 0.0:
+            if self._hold_cap() <= 0.0:
                 return 0.0
-            self._hold_t0, self._hold_limit = now, limit
+            self._hold_t0 = self._hold_from = now
             self._hold_for, self._hold_hits = self._awaited, 0
+            self._hold_renewals = 0
             self._since_hold = 0
             self.return_holds += 1
-        return self._hold_t0 + self._hold_limit - now
+        end = self._hold_from + self._exec_ewma / _HOLD_TOTAL_FRACTION
+        if self._last_return is not None:
+            patience = min(self._hold_cap(), self._exec_ewma
+                           * self._awaited / max(1, len(self._pending)))
+            end = min(end, self._last_return + patience)
+        return end - now
 
     def _bind_locked(self, now: float) -> dict:
         """A drain leaves: the hold it waited in, if any, is scored, and
-        whoever is still out is no longer waited for (they find a
-        program running and share the next).  Returns what the drain's
-        ``serving.queue_wait`` spans say of the batcher's state."""
+        whoever is still out is left behind and no longer waited for
+        (they find a program running and share the next).  Returns what
+        the drain's ``serving.queue_wait`` spans say of the batcher's
+        state."""
         depth, why = self._depth()
         if why == "serial-probe" and self._in_flight:
             # this is the probe's one drain: whoever comes next waits
             # for a free device again
             self._probe_out = True
-        held = 0.0
+        held, renewals = 0.0, 0
         if self._hold_t0 is not None:
-            held = now - self._hold_t0
-            share = min(1.0, self._hold_hits / self._hold_for)
-            self._hit_share += _HIT_SHARE_GAIN * (share - self._hit_share)
-            if share >= 1.0:
-                # all of them came back: where the hold is off, the
-                # next completion tries again, not the 32nd (callers
-                # that turn closed-loop are in step within some forty
-                # programs, and an open loop's rare hit costs one hold)
+            held, renewals = now - self._hold_t0, self._hold_renewals
+            if self._hold_for:
+                share = min(1.0, self._hold_hits / self._hold_for)
+                self._hit_share += _HIT_SHARE_GAIN * (
+                    share - self._hit_share)
+            if self._hold_hits >= self._hold_for:
+                # all of them came back (or the first one back was the
+                # only one out, which scores nothing): where the hold
+                # is off, the next completion tries again, not the 32nd
+                # (callers that turn closed-loop are in step within
+                # some forty programs, and an open loop's rare hit
+                # costs one hold)
                 self._since_hold = _HOLD_PROBE_EVERY
             self._hold_t0 = None
-        self._awaited = 0
+        left_behind, self._awaited = self._awaited, 0
+        self.return_left_behind += left_behind
         return {"depth": depth, "depth_reason": why,
                 "held_ms": round(held * 1e3, 3),
+                "renewals": renewals, "left_behind": left_behind,
                 "return_hit_share": round(self._hit_share, 3)}
 
     def _loop(self) -> None:
@@ -473,8 +538,8 @@ class TopNBatcher:
                     if len(self._pending) >= self.max_batch:
                         break
                     # a slot is free: go, unless callers the last
-                    # completion released are still out and likely back
-                    # within the hold.  A lone request on an unloaded
+                    # completion released are still out and still
+                    # coming back.  A lone request on an unloaded
                     # server never waits here: nobody is out.
                     wait = self._hold_locked(clockmod.monotonic())
                     if wait <= 0:
@@ -622,7 +687,8 @@ class TopNBatcher:
         device-execute span (grandchildren of the request, so the
         request's own children stay the two they were).  The queue-wait
         span carries ``note``: the depth in force and why, how long the
-        drain was held for returning callers, the hold's score
+        drain was held for returning callers, how often one of them
+        extended the hold, how many it left behind, the hold's score
         (:meth:`_bind_locked`; shared, read-only from here on).  Recorded
         retroactively from stored monotonic stamps (the dispatcher has
         no thread-local trace context), and strictly best-effort — the
@@ -743,6 +809,7 @@ class TopNBatcher:
                 # once is counted), as far as the next drain has room
                 self._awaited = max(0, min(
                     len(group), self.max_batch - len(self._pending)))
+                self._last_return = None
             for j in group:
                 j.done.set()
         return len(jobs)

@@ -528,6 +528,155 @@ def test_closed_loop_callers_share_one_pass(callers, mean_batch):
         walls[len(walls) // 2]
 
 
+@pytest.mark.parametrize("turnaround, mean_batch, whole", [
+    ((0.0005, 0.009), 7.5, 0.85), ((0.008, 0.016), 7.0, 0.75)])
+def test_eight_callers_that_return_one_after_another_all_ride(
+        turnaround, mean_batch, whole):
+    """Eight callers come back through a door that serves them one after
+    another: spread over more than one patience (6.25 ms here), each
+    close behind the one before.  The hold's clock runs from the last
+    return, so the drain leaves when the stream has ended, with all
+    eight; a clock from the first return cut its tail in most cycles,
+    and the caller it cut paid a second program.  In the second case
+    even the first is back later than one patience after the
+    completion: a drain that could leave at the completion, because a
+    caller left behind is waiting, counts its hold from the first
+    return and not from the completion, or the one would leave alone
+    and the seven come back to a running program, cycle after cycle."""
+    device = _SerialDevice(0.05)
+    batcher = TopNBatcher(pipeline=8, idle_wait_s=0.02)
+    try:
+        walls = _closed_loop(batcher, device, 8, 4.0, turnaround=turnaround)
+        stats = batcher.stats()
+        sizes = batcher.batch_sizes[_WARM_IN:]
+    finally:
+        batcher.close()
+    assert stats["return_hold"] == "on", stats
+    # 8.0 / 7.9 and next to none left behind on a quiet host (6.1 / 4.0
+    # with the clock run from the first return: four drains in five
+    # short of a caller, or two groups of four for good); a stall of a
+    # loaded one cuts a caller off now and then, and the drain after it
+    # waits for the seven and is whole again
+    assert sum(sizes) / len(sizes) >= mean_batch, sizes
+    assert sizes.count(8) >= whole * len(sizes), sizes
+    assert stats["return_left_behind"] <= len(sizes) // 10 + 8, stats
+    # in the order they completed: less the requests of the warm-in.
+    # One cycle; with the first return's clock one request in five
+    # took two
+    walls = sorted(walls[8 * _WARM_IN:])
+    assert walls[int(len(walls) * 0.95)] < 1.5 * 0.05, walls[-24:]
+
+
+def _hold_by_hand(batcher, returns, out=8, waiting=1, first_back=True):
+    """Drive one hold through its own steps, without threads, on stamps
+    relative to the moment a drain first could leave: ``out`` callers
+    are out, ``waiting`` requests wait, and one caller comes back at
+    each of ``returns``.  ``first_back``: the drain can leave because
+    the first caller is back (it is the one waiting); False: because a
+    caller left behind waited for the completion, and nobody is back.
+    Returns when the drain left and the note it left with."""
+    t0, at = time.monotonic(), 0.0
+    batcher._pending = [None] * waiting
+    try:
+        batcher._awaited = out
+        batcher._last_return = t0 if first_back else None
+        assert batcher._hold_locked(t0) > 0
+        for back in returns:
+            # the dispatcher sleeps until the hold runs out or a caller
+            # is back, whichever comes first
+            ends = at + batcher._hold_locked(t0 + at)
+            if ends <= back:
+                at = ends
+                break
+            at = back
+            batcher._pending.append(None)
+            batcher._return_locked(t0 + at)
+        else:
+            at = max(at, at + batcher._hold_locked(t0 + at))
+        return at, batcher._bind_locked(t0 + at)
+    finally:
+        # the lock is the caller's: no dispatcher has seen these
+        batcher._pending = []
+
+
+def test_a_hold_ends_with_the_stream_of_returns_and_within_its_bound():
+    """S = 16 ms: one patience is 2 ms, and the hold ends 4 ms after the
+    first return at the latest."""
+    from oryx_tpu.serving import batcher as batcher_mod
+
+    batcher = TopNBatcher(pipeline=2)
+    try:
+        with batcher._cond:
+            batcher._exec_ewma, batcher._exec_measured = 0.016, True
+            # six of seven come back 0.3 ms apart, the last never: the
+            # drain leaves one patience after the sixth, past the 2 ms
+            # of a clock that ran from the first
+            at, note = _hold_by_hand(
+                batcher, [0.0003 * i for i in range(1, 7)], out=7)
+            assert at == pytest.approx(0.0018 + 0.002)
+            assert (note["renewals"], note["left_behind"]) == (6, 1)
+            assert note["held_ms"] == pytest.approx(3.8, abs=0.01)
+            # all back: gone at once
+            at, note = _hold_by_hand(
+                batcher, [0.0003 * i for i in range(1, 8)], out=7)
+            assert at == pytest.approx(0.0021)
+            assert (note["renewals"], note["left_behind"]) == (6, 0)
+            # they trickle back just under one patience apart: every
+            # return renews the clock, and the hold still ends a quarter
+            # of the service time after it began
+            at, note = _hold_by_hand(
+                batcher, [0.0019 * i for i in range(1, 8)], out=7)
+            assert at == pytest.approx(0.004)
+            assert (note["renewals"], note["left_behind"]) == (2, 5)
+            # nobody comes back at all: one patience
+            at, note = _hold_by_hand(batcher, [], out=7)
+            assert at == pytest.approx(0.002)
+            assert (note["renewals"], note["left_behind"]) == (0, 7)
+            # a caller left behind waits at the completion and nobody is
+            # back: until one is there is no stream to see the end of,
+            # and only the bound runs ...
+            at, note = _hold_by_hand(batcher, [], out=7, first_back=False)
+            assert at == pytest.approx(0.004)
+            assert (note["renewals"], note["left_behind"]) == (0, 7)
+            # ... and counts anew from the first return, as it does for
+            # a drain that can leave because the first is back
+            at, note = _hold_by_hand(
+                batcher, [0.003 + 0.0004 * i for i in range(7)], out=7,
+                first_back=False)
+            assert at == pytest.approx(0.0054)
+            assert (note["renewals"], note["left_behind"]) == (6, 0)
+            at, note = _hold_by_hand(
+                batcher, [0.003 + 0.0019 * i for i in range(7)], out=7,
+                first_back=False)
+            assert at == pytest.approx(0.003 + 0.004)
+            assert (note["renewals"], note["left_behind"]) == (3, 4)
+            # many wait for one: a patience is worth no more than the
+            # service time over the callers that pay it
+            at, note = _hold_by_hand(batcher, [0.0001], out=2, waiting=31)
+            assert at == pytest.approx(0.0001 + 0.016 / 32)
+            assert batcher.stats()["return_left_behind"] \
+                == 1 + 5 + 7 + 7 + 4 + 1
+            # the score: a hold is scored on those who were out when
+            # the first was back.  Where that one was the only one out
+            # (an open loop's next arrival, or the other of two callers)
+            # there is nothing to score, and the next completion holds
+            # again to see; where nobody came at all it is a miss
+            probe = batcher_mod._HOLD_PROBE_EVERY
+            batcher._hit_share, batcher._since_hold = 0.4, probe
+            _hold_by_hand(batcher, [0.001], out=1, first_back=False)
+            assert (batcher._hit_share, batcher._since_hold) == (0.4, probe)
+            _hold_by_hand(batcher, [], out=1, first_back=False)
+            assert batcher._hit_share == pytest.approx(0.36)
+            assert batcher._since_hold == 0
+            batcher._since_hold = probe
+            _hold_by_hand(batcher, [0.001, 0.0015], out=2,
+                          first_back=False)
+            assert batcher._hit_share == pytest.approx(0.36 + 0.064)
+            assert batcher._since_hold == probe
+    finally:
+        batcher.close()
+
+
 def test_a_delayed_caller_is_back_in_step_within_two_programs():
     device = _SerialDevice(0.05)
     batcher = TopNBatcher(pipeline=8, idle_wait_s=0.02)
@@ -566,22 +715,35 @@ def test_a_lone_closed_loop_caller_is_never_held():
         batcher.close()
 
 
-def test_open_loop_arrivals_switch_the_hold_off():
-    """Seeded Poisson arrivals at a fifth of what the device serves one
-    by one: whoever was just answered does not come back, the hold's hit
-    share falls, the hold goes off but for its probes, and what it cost
-    a request is well under one hold."""
+@pytest.mark.parametrize("exec_s, rate, seconds", [
+    (0.01, 20.0, 4.0), (0.015, 100.0, 3.0), (0.015, 200.0, 3.0),
+    (0.015, 400.0, 2.5)])
+def test_open_loop_arrivals_switch_the_hold_off(exec_s, rate, seconds):
+    """Seeded Poisson arrivals, from a fifth of what the device serves
+    one by one to six a program: whoever was just answered does not come
+    back, and the strangers that arrive inside a hold (they renew its
+    clock like a returning caller would) are too few beside those the
+    program before released, because the hold is bounded by a quarter
+    of the service time from the first of them on, and as long before
+    it.  The hit share falls, the hold goes off but for its probes, what
+    it cost a request is well under one patience, and no hold outlasts
+    its bound."""
     import random
 
-    device = _SerialDevice(0.01)
-    batcher = TopNBatcher(pipeline=8)
+    from oryx_tpu.obs.trace import Tracer
+
+    tracer = Tracer("serving", sample_ratio=1.0, max_traces=8192)
+    device = _SerialDevice(exec_s)
+    batcher = TopNBatcher(pipeline=8, tracer=tracer)
     rng, due, t = random.Random(28), [], 0.0
-    while t < 4.0:
-        t += rng.expovariate(20.0)
+    while t < seconds:
+        t += rng.expovariate(rate)
         due.append(t)
 
     def one():
+        req = tracer.begin_request("serving.request")
         assert len(batcher.top_n(device, 3, device.vector())) == 3
+        tracer.end_request(req, 200)
 
     try:
         threads = []
@@ -606,6 +768,18 @@ def test_open_loop_arrivals_switch_the_hold_off():
     # less the three worst: a stall of the test's host is no hold
     mean = sum(waited[:-3]) / len(waited[:-3])
     assert mean < stats["return_hold_ms"] / 1e3, (mean, stats)
+    # one value a held drain (every request of a drain carries its note)
+    held = sorted({s["attrs"]["held_ms"]
+                   for spans in tracer.traces_snapshot(limit=8192).values()
+                   for s in spans if s["name"] == "serving.queue_wait"
+                   and s["attrs"]["held_ms"] > 0})
+    assert len(held) >= 7, held
+    # a quarter of the service time (read at the end, a little over the
+    # program) for the first arrival, as much again from it on, and the
+    # wake-up of a loaded host; the bound itself is pinned to the
+    # microsecond, without threads, above
+    bound_ms = stats["service_time_ms"] / 2 + 3.0
+    assert held[len(held) * 9 // 10 - 1] <= bound_ms, (held, stats)
 
 
 def test_an_overlapping_device_keeps_its_deep_pipeline():
@@ -650,12 +824,17 @@ def test_the_queue_wait_span_says_what_the_batcher_did():
     waits = [s for spans in tracer.traces_snapshot(limit=4096).values()
              for s in spans if s["name"] == "serving.queue_wait"]
     assert waits
-    assert all({"depth", "depth_reason", "held_ms", "return_hit_share"}
+    assert all({"depth", "depth_reason", "held_ms", "renewals",
+                "left_behind", "return_hit_share"}
                <= set(s["attrs"]) for s in waits)
     late = [s["attrs"] for s in waits[len(waits) // 2:]]
     assert {a["depth_reason"] for a in late} == {"serial"}
     assert {a["depth"] for a in late} == {1}
     assert any(a["held_ms"] > 0 for a in late)
+    # two callers: when the second is back nobody is out, so a hold is
+    # never renewed, and a drain leaves nobody behind once in step
+    assert {a["renewals"] for a in late} == {0}
+    assert sum(a["left_behind"] for a in late) <= len(late) // 10
 
 
 def test_no_wakeup_is_lost_under_a_crowd_of_callers():
