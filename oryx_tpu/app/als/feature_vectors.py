@@ -17,6 +17,14 @@ changed — so serving reads always see a consistent device snapshot and
 per-event device dispatch never happens.  Removed rows are zeroed and
 recycled via a free list; capacity grows by doubling.
 
+A store may be PARTITIONED (``partition_by``; the serving model's item
+store under LSH): a row then lives in the region of its vector's hash
+bucket, a region being whole ``step``-row steps of the array, so that
+every step holds rows of one bucket and a scan can visit the steps of
+some buckets only.  Free rows inside a region are inactive exactly as
+removed rows are; a write whose vector hashes elsewhere moves the row.
+There is still ONE copy of the factors.
+
 The ordering rule between readers and syncs (``dispatching``): a sync
 donates the resident arrays, which deletes the Python handles of the
 version before it.  Syncs and readers are therefore ordered under one
@@ -64,14 +72,24 @@ _SYNC_LOG = 256
 _LARGE_ALIGN = 1 << 17
 
 
-def planned_capacity(n_rows: int, initial_capacity: int = 1024) -> int:
+def planned_capacity(n_rows: int, initial_capacity: int = 1024,
+                     buckets: int = 0, step: int = 0) -> int:
     """The padded row capacity a fresh store ends up with after a
     single ``bulk_load`` of ``n_rows`` vectors — the compiled leading
     dimension every serving kernel sees for a model of that size.  The
     deploy-time AOT warmup (deploy/warmup.py) uses this to lower the
     kernel ladder with the EXACT shapes a later model load produces;
     keep it in lock-step with ``__init__``/``_grow`` (and tested
-    against a real bulk_load in tests/test_warmup.py)."""
+    against a real bulk_load in tests/test_warmup.py).  For a store
+    partitioned into ``buckets`` regions of ``step``-row steps it is
+    what buckets of EQUAL size come to (a region is whole steps, so it
+    holds half a step of slack in the mean): exact for factors that
+    hash evenly, an estimate for a trained catalog's."""
+    if buckets:
+        steps = buckets * -(-n_rows // (buckets * step))
+        cap = max(step, steps * step)
+        return -(-cap // _LARGE_ALIGN) * _LARGE_ALIGN \
+            if cap > _LARGE_ALIGN else cap
     cap = max(16, initial_capacity)
     if n_rows > cap:
         # one _grow(min_capacity=n_rows) from the fresh store
@@ -137,6 +155,31 @@ def _sync_bucket(n: int) -> int:
     return max(8, 1 << (n - 1).bit_length())
 
 
+class _Partition:
+    """The layout of a partitioned store (``partition_by``): which
+    bucket each ``step``-row step of the array belongs to, and where
+    the free rows are.  Guarded by the store's write lock."""
+
+    def __init__(self, bucket_of, n_buckets: int, step: int, cap: int):
+        self.bucket_of = bucket_of      # (n, features) array -> (n,) ints
+        self.n_buckets = n_buckets
+        self.step = step
+        # bucket of every step; -1: not given to a bucket yet
+        self.step_bucket = np.full(cap // step, -1, dtype=np.int32)
+        # steps no bucket has yet (popped from the end: lowest first)
+        self.free_steps: list[int] = list(range(cap // step - 1, -1, -1))
+        # free rows of each bucket's region (popped from the end)
+        self.free: list[list[int]] = [[] for _ in range(n_buckets)]
+        # bumped when a step is given to a bucket: the cache key of
+        # what readers derive from ``step_bucket``
+        self.version = 0
+        # rows that changed region because a write changed their bucket
+        self.row_moves = 0
+
+    def bucket_of_row(self, row: int) -> int:
+        return int(self.step_bucket[row // self.step])
+
+
 class FeatureVectorStore:
     """Mutable {id -> float32[k]} map materialized as a device array."""
 
@@ -167,6 +210,8 @@ class FeatureVectorStore:
         self.dtype = resolve_dtype(dtype)
         self._sharding = device_sharding
         self._cap_multiple = 1
+        # rows a step of a partitioned store (partition_by); 1: none
+        self._step_multiple = 1
         self._active_sharding = None
         if device_sharding is not None:
             n_dev = device_sharding.mesh.devices.size
@@ -220,6 +265,93 @@ class FeatureVectorStore:
         # (mutation count it was copied at, the copy): one tuple, so
         # that row_ids() can read both without the lock
         self._row_ids_cache: tuple[int, list[str | None]] | None = None
+        # None: rows live wherever they were appended (the layout every
+        # store has but an item store under LSH)
+        self._part: _Partition | None = None
+
+    # -- the partitioned layout ---------------------------------------------
+
+    def partition_by(self, bucket_of: Callable[[np.ndarray], np.ndarray],
+                     n_buckets: int, step: int) -> None:
+        """Lay this (still empty, one-device) store out by bucket:
+        ``bucket_of`` maps an (n, features) array of vectors in the
+        store's dtype to their n bucket ids in [0, ``n_buckets``), and
+        from now on every ``step``-row step of the array holds rows of
+        ONE bucket (module docstring).  The capacity becomes a whole
+        number of steps; a bucket takes a step when its first row
+        arrives and another when its region is full, from the
+        array's unassigned steps or, when there is none, after
+        ``_grow`` (which costs what it costs any store: a new capacity
+        and a whole re-upload)."""
+        if step <= 0 or step & (step - 1):
+            raise ValueError(f"a step is a power of two of rows, not {step}")
+        with self._lock.write():
+            if self._id_to_row or self._sharding is not None:
+                raise ValueError("only an empty one-device store can "
+                                 "be partitioned")
+            self._step_multiple = step
+            cap = self._aligned(len(self._row_to_id))
+            self._row_to_id = [None] * cap
+            self._free = []
+            self._host = np.zeros((cap, self.features), dtype=self.dtype)
+            self._active = np.zeros(cap, dtype=bool)
+            self._part = _Partition(bucket_of, n_buckets, step, cap)
+            self._mutations += 1
+
+    @property
+    def partitioned(self) -> bool:
+        return self._part is not None
+
+    def partition_layout(self) -> tuple[np.ndarray, int, int]:
+        """(bucket of every step (a copy; -1 where no bucket has the
+        step), rows a step, version of that table) of a partitioned
+        store."""
+        with self._lock.read():
+            p = self._part
+            return p.step_bucket.copy(), p.step, p.version
+
+    @property
+    def partition_version(self) -> int:
+        return self._part.version
+
+    @property
+    def row_moves(self) -> int:
+        """Rows that changed region because a write changed the bucket
+        of their vector (0 for a store that is not partitioned)."""
+        return self._part.row_moves if self._part is not None else 0
+
+    def _bucket_row(self, bucket: int) -> int:
+        """A free row of ``bucket``'s region (write lock held)."""
+        free = self._part.free[bucket]
+        if not free:
+            self._give_step(bucket)
+        return free.pop()
+
+    def _give_step(self, bucket: int) -> None:
+        """One more step for ``bucket``'s region (write lock held),
+        from the steps no bucket has; the array grows first where there
+        is none.  A region's rows are handed out in ascending order:
+        the new step goes UNDER what is still free."""
+        p = self._part
+        if not p.free_steps:
+            self._grow()
+        s = p.free_steps.pop()
+        p.step_bucket[s] = bucket
+        p.version += 1
+        p.free[bucket][:0] = range((s + 1) * p.step - 1, s * p.step - 1, -1)
+
+    def _release(self, row: int) -> None:
+        """Retire ``row`` (write lock held): zeroed, inactive, dirty,
+        and free again, in its region where the store has regions."""
+        self._row_to_id[row] = None
+        self._host[row] = 0.0
+        self._active[row] = False
+        self._active_dirty = True
+        self._dirty.add(row)
+        if self._part is None:
+            self._free.append(row)
+        else:
+            self._part.free[self._part.bucket_of_row(row)].append(row)
 
     # -- basic map ops ------------------------------------------------------
 
@@ -256,12 +388,29 @@ class FeatureVectorStore:
         """``tag``, if given, comes back in ``DeviceSnapshot.tags`` of
         the sync that carries this row to the device."""
         vector = np.asarray(vector, dtype=np.float32)
+        part = self._part
+        if part is not None:
+            # hashed as it will be STORED, outside the lock (a device
+            # call); the layout is only read under it
+            stored = vector.astype(self.dtype)[None, :]
+            bucket = int(part.bucket_of(stored)[0])
         with self._lock.write():
             row = self._id_to_row.get(id_)
+            if part is not None and row is not None \
+                    and part.bucket_of_row(row) != bucket:
+                # the vector now hashes elsewhere: the row is freed and
+                # the id takes a row in its new region
+                self._overwriting(row)
+                self._release(row)
+                part.row_moves += 1
+                row = None
             if row is None:
-                if not self._free:
-                    self._grow()
-                row = self._free.pop()
+                if part is not None:
+                    row = self._bucket_row(bucket)
+                else:
+                    if not self._free:
+                        self._grow()
+                    row = self._free.pop()
                 self._id_to_row[id_] = row
                 self._row_to_id[row] = id_
                 self._mutations += 1
@@ -293,6 +442,8 @@ class FeatureVectorStore:
             raise ValueError(
                 f"matrix must be ({len(ids)}, {self.features}), "
                 f"got {matrix.shape}")
+        if self._part is not None:
+            return self._bulk_load_partitioned(ids, matrix)
         with self._lock.write():
             new_ids = [i for i in ids if i not in self._id_to_row]
             if len(self._free) < len(new_ids):
@@ -316,18 +467,70 @@ class FeatureVectorStore:
             self._recent.update(ids)
             self._vtv_forget()
 
+    def _bulk_load_partitioned(self, ids: list[str],
+                               matrix: np.ndarray) -> None:
+        """``bulk_load`` into regions: the buckets in one pass over the
+        matrix, the rows of every bucket taken at once, one permuted
+        host write."""
+        part = self._part
+        if len(set(ids)) != len(ids):
+            # an id twice in one load: row by row, the last one wins
+            for id_, vector in zip(ids, matrix):
+                self.set_vector(id_, vector)
+            with self._lock.write():
+                self._vtv_forget()
+            return
+        buckets = np.asarray(part.bucket_of(matrix), dtype=np.int64)
+        with self._lock.write():
+            rows = np.full(len(ids), -1, dtype=np.int64)
+            for j, id_ in enumerate(ids):
+                row = self._id_to_row.get(id_)
+                if row is None:
+                    continue
+                if part.bucket_of_row(row) == buckets[j]:
+                    rows[j] = row
+                else:
+                    del self._id_to_row[id_]
+                    self._release(row)
+                    part.row_moves += 1
+            fresh = np.flatnonzero(rows < 0)
+            counts = np.bincount(buckets[fresh], minlength=part.n_buckets)
+            short = sum(-(-max(0, int(c) - len(part.free[b])) // part.step)
+                        for b, c in enumerate(counts.tolist()))
+            if short > len(part.free_steps):
+                # size once, exactly (as bulk_load does): the steps the
+                # buckets lack, no doubling
+                self._grow(len(self._row_to_id)
+                           + (short - len(part.free_steps)) * part.step)
+            order = fresh[np.argsort(buckets[fresh], kind="stable")]
+            at = 0
+            for b, c in enumerate(counts.tolist()):
+                free = part.free[b]
+                while len(free) < c:
+                    self._give_step(b)
+                if c:
+                    rows[order[at:at + c]] = free[:-c - 1:-1]
+                    del free[-c:]
+                    at += c
+            for j in fresh.tolist():
+                row = int(rows[j])
+                self._id_to_row[ids[j]] = row
+                self._row_to_id[row] = ids[j]
+            self._mutations += 1
+            self._host[rows] = matrix
+            self._active[rows] = True
+            self._active_dirty = True
+            self._dirty.update(rows.tolist())
+            self._recent.update(ids)
+            self._vtv_forget()
+
     def remove(self, id_: str) -> None:
         with self._lock.write():
             row = self._id_to_row.pop(id_, None)
             if row is not None:
-                self._row_to_id[row] = None
                 self._mutations += 1
                 self._overwriting(row)
-                self._host[row] = 0.0
-                self._active[row] = False
-                self._active_dirty = True
-                self._dirty.add(row)
-                self._free.append(row)
+                self._release(row)
 
     def recent_ids(self) -> set[str]:
         """IDs set since the last retain (reference: FeatureVectors.addAllRecentTo)."""
@@ -342,14 +545,8 @@ class FeatureVectorStore:
         with self._lock.write():
             keep |= self._recent
             for id_ in [i for i in self._id_to_row if i not in keep]:
-                row = self._id_to_row.pop(id_)
-                self._row_to_id[row] = None
+                self._release(self._id_to_row.pop(id_))
                 self._mutations += 1
-                self._host[row] = 0.0
-                self._active[row] = False
-                self._active_dirty = True
-                self._dirty.add(row)
-                self._free.append(row)
             # a model swap drops rows by the thousand: scan again
             self._vtv_forget()
             self._recent.clear()
@@ -363,6 +560,11 @@ class FeatureVectorStore:
         re-uploads the whole device snapshot, and every intermediate
         pow2 capacity would be a compiled-shape cache miss)."""
         with self._lock.write():
+            if self._part is not None:
+                # which buckets the rows will fall into is not known
+                # yet: room for them plus the last, part-filled step
+                # of every region
+                n_rows += self._part.n_buckets * self._part.step
             if len(self._row_to_id) < n_rows:
                 self._grow(n_rows)
 
@@ -378,7 +580,10 @@ class FeatureVectorStore:
             # between one chunk and one chunk a device: whole chunks,
             # as ever, and an even split
             cap = -(-cap // _LARGE_ALIGN) * _LARGE_ALIGN
-        return -(-cap // m) * m
+        cap = -(-cap // m) * m
+        # a partitioned store: whole steps (a power of two, as the
+        # chunk is, so a large capacity stays whole chunks)
+        return -(-cap // self._step_multiple) * self._step_multiple
 
     def _grow(self, min_capacity: int | None = None) -> None:
         old_cap = len(self._row_to_id)
@@ -400,7 +605,19 @@ class FeatureVectorStore:
         self._active = active
         self._row_to_id.extend([None] * (new_cap - old_cap))
         self._mutations += 1
-        self._free.extend(range(new_cap - 1, old_cap - 1, -1))
+        part = self._part
+        if part is None:
+            self._free.extend(range(new_cap - 1, old_cap - 1, -1))
+        else:
+            # the new rows are whole steps no bucket has yet; no row
+            # moves, every region keeps the steps it has
+            old_steps = len(part.step_bucket)
+            part.step_bucket = np.concatenate([
+                part.step_bucket,
+                np.full(new_cap // part.step - old_steps, -1, np.int32)])
+            part.free_steps[:0] = range(new_cap // part.step - 1,
+                                        old_steps - 1, -1)
+            part.version += 1
         self._device = None  # force full re-upload at next sync
         self._device_active = None
 

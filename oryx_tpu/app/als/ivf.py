@@ -409,7 +409,7 @@ def measure_recall(model, mirror: IVFMirror, cfg: AnnConfig) -> float:
     big, chunk = sm._stream_plan(n_rows, len(Q))
     if big and n_rows % chunk == 0 and k <= chunk:
         ex_s, ex_i = jax.device_get(sm._batch_top_n_chunked_kernel(
-            vecs, Qd, active, None, None, k, chunk, 0))
+            vecs, Qd, active, k, chunk))
     else:
         ex_s, ex_i = jax.device_get(sm._batch_top_n_kernel(
             vecs, Qd, active, k))

@@ -5,14 +5,26 @@ serving/als/model/LocalitySensitiveHash.java — hash/bits-differing
 selection from target sample rate and core count (:41-124), sign-bit
 hyperplane hash (:142-150), Hamming-ball candidate partitions (:156-177).
 
-TPU-native twist: the reference partitions the item matrix by bucket and
-scans selected partitions on a thread pool.  Here all items stay in one
-device array alongside a precomputed bucket id per item; a query builds
-its candidate set as a DEVICE-SIDE mask — popcount(bucket XOR target)
-<= max_bits_differing — fused into the scoring matmul, so LSH costs one
-extra elementwise op instead of a data layout.  (On TPU the brute-force
-matmul often wins anyway; LSH is kept as the capability the reference
-has, and for memory-partitioned deployments.)
+On the device LSH is a DATA LAYOUT, as it is in the reference
+(PartitionedFeatureVectors: the item matrix partitioned by bucket, the
+partitions inside the Hamming ball scanned).  The serving model lays its
+one item store out by bucket (FeatureVectorStore.partition_by: every
+phase-A step of the store holds rows of one bucket) and the streaming
+scan visits only the steps of the buckets a window's queries can reach,
+so a 0.3 deployment streams the bytes of its candidates, not the
+catalog's.  Until PR 36 the candidate set was a per-row mask over the
+whole store (popcount(bucket XOR target) fused into every tile): the
+same bytes as the exact scan and more work, which the measured-cost
+router therefore never served.  What the chip said of the layout is in
+PERF.md (section 6, PR 36).
+
+Every bucket product — an item's at load and on a write, a query row's
+in a window — is computed by ``_bucket_kernel`` at
+``Precision.HIGHEST``: at the default precision the MXU rounds the
+float32 hyperplanes to bfloat16, an error of ~1e-3 in a product that is
+N(0, 1) for unit-variance factors, which puts an item in fifty on the
+wrong side of some hyperplane.  At HIGHEST a sign can differ from the
+float32 reference's only within summation-order rounding of zero.
 """
 
 from __future__ import annotations
@@ -26,9 +38,23 @@ import numpy as np
 
 from ...common.rand import RandomManager
 
-__all__ = ["LocalitySensitiveHash", "choose_hash_count"]
+__all__ = ["LocalitySensitiveHash", "PUBLISHED_CORES", "choose_hash_count"]
 
 MAX_HASHES = 20
+# rows ``bucket_of`` hashes in one device call
+_BUCKET_CHUNK = 1 << 20
+
+# The "cores" the reference's rule (choose_hash_count) partitions for.
+# On a chip the rule's keep-the-cores-busy half means nothing and only
+# its sample-rate half binds, so the one number that makes a sample
+# rate mean what the reference's published rows meant by it is the core
+# count those rows ran on: the 32-core Haswell Xeon of
+# https://oryx.io/docs/performance.html.  At 0.3 it gives 8 hyperplanes
+# and a Hamming radius of 2: 37 of 256 buckets, 14.45% of the catalog
+# (and 668 / 134 ms = 5.0x is what 1 / 0.1445 = 6.9x less fixed costs
+# bears out).  The former hidden default of 8 made 0.3 mean 7 hashes,
+# radius 1: 6.25%, under the 0.1 the reference warns against.
+PUBLISHED_CORES = 32
 
 
 def _binom(n: int, k: int) -> int:
@@ -58,9 +84,11 @@ def choose_hash_count(sample_rate: float, num_cores: int) -> tuple[int, int]:
 
 @partial(jax.jit, static_argnames=("num_hashes",))
 def _bucket_kernel(vectors, hyperplanes, num_hashes: int):
-    """Sign-bit bucket ids for a block of vectors: one matmul + packbits."""
-    signs = jnp.matmul(vectors, hyperplanes.T,
-                       preferred_element_type=jnp.float32) > 0.0
+    """Sign-bit bucket ids for a block of vectors: one matmul + packbits,
+    the products in float32 at HIGHEST (module docstring)."""
+    signs = jnp.matmul(vectors.astype(jnp.float32), hyperplanes.T,
+                       preferred_element_type=jnp.float32,
+                       precision=jax.lax.Precision.HIGHEST) > 0.0
     weights = jnp.asarray([1 << i for i in range(num_hashes)], dtype=jnp.int32)
     return jnp.sum(signs.astype(jnp.int32) * weights[None, :], axis=1)
 
@@ -78,7 +106,7 @@ class LocalitySensitiveHash:
     """Hyperplane LSH over factor vectors."""
 
     def __init__(self, sample_rate: float, num_features: int,
-                 num_cores: int = 8):
+                 num_cores: int = PUBLISHED_CORES):
         self.sample_rate = sample_rate
         self.num_features = num_features
         self._hp_dev: jax.Array | None = None
@@ -110,20 +138,25 @@ class LocalitySensitiveHash:
         """Bucket index for each row vector (reference getIndexFor :142)."""
         if self.num_hashes == 0:
             return np.zeros(len(vectors), dtype=np.int32)
-        return np.asarray(self.device_buckets(jnp.asarray(vectors,
-                                                          jnp.float32)))
+        # in row chunks, in the dtype they are stored in: a 20M x 250
+        # bfloat16 matrix is hashed 1M rows (0.5 GB) at a time
+        out = np.empty(len(vectors), dtype=np.int32)
+        for at in range(0, len(vectors), _BUCKET_CHUNK):
+            part = vectors[at:at + _BUCKET_CHUNK]
+            n = len(part)
+            if at and n < _BUCKET_CHUNK:    # one compiled shape for the tail
+                part = np.concatenate([part, np.zeros(
+                    (_BUCKET_CHUNK - n, part.shape[1]), part.dtype)])
+            out[at:at + n] = np.asarray(
+                self.device_buckets(jnp.asarray(part)))[:n]
+        return out
 
     def device_buckets(self, vectors: jax.Array) -> jax.Array:
-        """Bucket ids computed device-to-device (no host round trip; the
-        input may be the serving model's whole resident item matrix)."""
+        """Bucket ids of device-resident vectors (at their true width)."""
         if self.num_hashes == 0:
             return jnp.zeros(vectors.shape[0], dtype=jnp.int32)
-        hp = self._device_hyperplanes()
-        if hp.shape[1] != vectors.shape[1]:
-            # lane-padded device snapshot: zero hyperplane columns keep
-            # every sign bit identical
-            hp = jnp.pad(hp, [(0, 0), (0, vectors.shape[1] - hp.shape[1])])
-        return _bucket_kernel(vectors, hp, self.num_hashes)
+        return _bucket_kernel(vectors, self._device_hyperplanes(),
+                              self.num_hashes)
 
     def candidate_mask(self, query_vector: np.ndarray,
                        item_buckets: jax.Array) -> jax.Array:

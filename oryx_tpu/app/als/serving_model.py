@@ -9,17 +9,27 @@ getFractionLoaded; retainRecentAndUserIDs/ItemIDs MODEL-swap logic
 (:318-383); TopNConsumer.java:30 (streaming top-N heap).
 
 TPU-native redesign of the scan (P4/P5/P6 in SURVEY §2.14): instead of
-a thread-pool scan over LSH partitions, the WHOLE item matrix lives in
-one device array alongside per-item LSH bucket ids; top-N is
+a thread-pool scan over partitions, the WHOLE item matrix lives in one
+device array and top-N is one program a window of requests: a flat
+``matmul + top_k`` for a small catalog, for a large one the streaming
+two-phase scan (phase A: one pass over the store that keeps only the
+maxima of 128-row blocks; phase B: exact rescoring of the best blocks,
+with a certificate).
 
-    scores = Y @ x  (MXU matmul)
-    scores = where(active & lsh_mask, scores, -inf)
-    top_k(scores, k)
+With ``oryx.als.sample-rate`` < 1 the item store is LAID OUT by LSH
+bucket, as the reference's PartitionedFeatureVectors is
+(``FeatureVectorStore.partition_by``: every phase-A step of the store
+holds rows of one bucket), and phase A's grid runs over the steps of the
+buckets inside the Hamming balls of the window's queries only
+(``_visit_plan``): pruning prunes bytes.  It is the configuration's
+semantics, not a route the measurement may withdraw.  Until PR 36 the
+candidates were a per-row mask over the whole store, which streamed the
+exact scan's bytes and computed more, so the measured-cost router never
+served it (PERF.md section 6, PR 36, has the chip's numbers).
 
-— one XLA program, microseconds at reference scale.  When a rescorer
-plugin or an allowed-predicate is present the full score vector is
-pulled to host and rescored exactly, preserving reference semantics over
-speed.
+When a rescorer plugin or an allowed-predicate is present the full score
+vector is pulled to host and rescored exactly, preserving reference
+semantics over speed.
 """
 
 from __future__ import annotations
@@ -151,14 +161,10 @@ def _cosine_mean_scores(Y, V):
                     / denom, axis=1)
 
 
-def _lsh_ok(ok, buckets, target, max_bits: int):
-    """Fuse the LSH Hamming-ball candidate test into a mask: ok AND
-    popcount(bucket XOR target) <= max_bits.  The single definition all
-    four scoring kernels share — the candidate-set invariant must not
-    be able to diverge between the exact, streaming, and two-phase
-    paths (the exactness certificate assumes phase A and phase B agree
-    bit-for-bit)."""
-    return ok & (_popcount(jnp.bitwise_xor(buckets, target)) <= max_bits)
+def _in_ball(buckets, target, max_bits: int):
+    """The LSH candidate test: popcount(bucket XOR target) <= max_bits.
+    The single definition every pruned window shares."""
+    return _popcount(jnp.bitwise_xor(buckets, target)) <= max_bits
 
 
 def _query_buckets(Q, hyperplanes):
@@ -168,6 +174,49 @@ def _query_buckets(Q, hyperplanes):
     item bucket ids can never drift apart."""
     from .lsh import _bucket_kernel
     return _bucket_kernel(Q, hyperplanes, int(hyperplanes.shape[0]))
+
+
+class Pruning(NamedTuple):
+    """What a pruned window's program needs beside the store (arrays;
+    the Hamming radius rides as a static argument)."""
+
+    step_bucket: jax.Array   # (steps,) int32: bucket of each store step
+    step_live: jax.Array     # (steps,) int32: live rows of each step
+    hyperplanes: jax.Array   # (hashes, features) float32
+    n_real: jax.Array        # () int32: rows of the window that are
+    #                          requests (the rest is padding)
+
+
+def _visit_plan(Q, prune: Pruning, max_bits: int):
+    """Which steps of the store a window visits: the steps of every
+    bucket inside the Hamming ball of some REAL query row's bucket
+    (padding rows add nothing).  Returns ``steps`` (every step of the
+    store, the visited ones first, in store order), ``n_visit`` (how
+    many are visited: phase A's grid bound), ``step_ok`` ((B, steps) in
+    visit order: whether the step's bucket is a candidate of the row)
+    and ``stats`` (int32 [steps visited, buckets in the union, live
+    rows in them, live rows in the store])."""
+    b = Q.shape[0]
+    target = _query_buckets(Q, prune.hyperplanes)
+    real = jnp.arange(b) < prune.n_real
+    # (B, buckets): the buckets inside each real row's ball ...
+    n_buckets = 1 << int(prune.hyperplanes.shape[0])
+    ball = _in_ball(jnp.arange(n_buckets, dtype=jnp.int32)[None, :],
+                    target[:, None], max_bits) & real[:, None]
+    # ... and (B, steps): the steps of those buckets (a step nobody has
+    # yet carries bucket -1 and is nobody's candidate)
+    sb = prune.step_bucket
+    ok = jnp.take(ball, jnp.maximum(sb, 0), axis=1) & (sb >= 0)[None, :]
+    visit = ok.any(0)
+    n_visit = visit.sum(dtype=jnp.int32)
+    steps = jnp.argsort(~visit, stable=True).astype(jnp.int32)
+    step_ok = jnp.take(ok, steps, axis=1) \
+        & (jnp.arange(steps.shape[0]) < n_visit)[None, :]
+    stats = jnp.stack([n_visit, ball.any(0).sum(dtype=jnp.int32),
+                       jnp.sum(jnp.where(visit, prune.step_live, 0),
+                               dtype=jnp.int32),
+                       prune.step_live.sum(dtype=jnp.int32)])
+    return steps, n_visit, step_ok, stats
 
 
 @partial(jax.jit, static_argnames=("k",))
@@ -181,23 +230,6 @@ def _batch_top_n_kernel(Y, Q, active, k: int):
                         precision=_score_precision(Y))
     scores = jnp.where(active[None, :], scores, -jnp.inf)
     return jax.lax.top_k(scores, k)
-
-
-@partial(jax.jit, static_argnames=("k", "max_bits"))
-def _batch_top_n_lsh_kernel(Y, Q, active, buckets, hyperplanes,
-                            k: int, max_bits: int):
-    """Batched top-k with the LSH Hamming-ball candidate mask fused in:
-    each query's target bucket is computed on device and compared to the
-    per-item bucket ids — the whole approximate query stays one dispatch
-    (reference scans selected partitions on a thread pool instead,
-    ALSServingModel.java:265-280)."""
-    target = _query_buckets(Q, hyperplanes)
-    scores = jnp.matmul(_q_cast(Q, Y), Y.T,
-                        preferred_element_type=jnp.float32,
-                        precision=_score_precision(Y))
-    ok = _lsh_ok(active[None, :], buckets[None, :], target[:, None],
-                 max_bits)
-    return jax.lax.top_k(jnp.where(ok, scores, -jnp.inf), k)
 
 
 def _stream_plan(n_rows: int, b_pad: int) -> tuple[bool, int]:
@@ -247,19 +279,6 @@ def _stream_plan(n_rows: int, b_pad: int) -> tuple[bool, int]:
 #    block that IS missed stays missed however wide ksel is.
 _BLOCK_ROWS = 128
 _BLOCK_KSEL = 32
-# the most rows whose LSH buckets are patched after a sync; past it the
-# whole matrix is hashed again
-_BUCKET_PATCH_ROWS = 1 << 16
-
-
-@jax.jit
-def _patch_rows(derived, rows, values):
-    """``derived`` with ``values`` at ``rows``: per-row state following
-    the rows a sync wrote.  Not donated — a drain on another thread may
-    still hold the version before it — so it costs one copy of the
-    derived array (4 bytes a row), never one of the store."""
-    return derived.at[rows].set(values)
-
 _APPROX_RECALL = 0.99999
 # Phase B gathers (rows, ksel, bs, F) of the store's dtype.  A window
 # whose gather would pass this many bytes runs its rows in equal
@@ -343,31 +362,39 @@ def _selects_row_major(b: int, ksel: int) -> bool:
     return b < 128 and ksel > _BLOCK_KSEL
 
 
-def _phase_b(Y, Qc, active, buckets, target, M, k: int, bs: int,
-             ksel: int, max_bits: int):
+def _phase_b(Y, Qc, active, M, k: int, bs: int, ksel: int, steps=None):
     """Phase B shared by the scan- and pallas-built phase A: pick the
     ``ksel`` best 128-row blocks per query from the block maxima ``M``
     with approx_max_k, exactly rescore the gathered rows, and emit
     top-k plus the exactness certificate kth_score >= max(unselected
     block maxima).  A window too wide for one gather
     (_phase_b_group_rows) runs in equal row groups under lax.map:
-    static shapes, one program, rows independent of each other."""
+    static shapes, one program, rows independent of each other.
+
+    ``steps`` of None: ``M`` holds every block of the store in store
+    order.  A pruned window (_visit_plan) hands its maxima over in
+    VISIT order, a step's blocks side by side, -inf wherever a block is
+    no candidate of the row, and ``steps`` maps them back to the store:
+    selection, rescoring and the certificate then run over the visited
+    blocks only."""
     g = _phase_b_group_rows(Qc.shape[0], ksel, bs, _row_bytes(Y))
-    xs = (Qc, M) if target is None else (Qc, M, target)
     return _map_row_groups(
-        lambda q, m, t=None: _phase_b_rows(Y, q, active, buckets, t, m, k,
-                                           bs, ksel, max_bits), g, *xs)
+        lambda q, m: _phase_b_rows(Y, q, active, m, k, bs, ksel, steps),
+        g, Qc, M)
 
 
-def _phase_b_rows(Y, Qc, active, buckets, target, M, k: int, bs: int,
-                  ksel: int, max_bits: int):
+def _phase_b_rows(Y, Qc, active, M, k: int, bs: int, ksel: int, steps):
     """Phase B for one group of query rows (the whole window where its
     gather fits)."""
     b = Qc.shape[0]
     if _selects_row_major(b, ksel):
         M = with_layout_constraint(M, Layout(major_to_minor=(0, 1)))
-    _, bi = jax.lax.approx_max_k(M, ksel, recall_target=_APPROX_RECALL)
+    m_sel, bi = jax.lax.approx_max_k(M, ksel, recall_target=_APPROX_RECALL)
     m_rest = M.at[jnp.arange(b)[:, None], bi].set(-jnp.inf).max(-1)
+    if steps is not None:
+        # visited block -> the store's block
+        per_step = M.shape[1] // steps.shape[0]
+        bi = jnp.take(steps, bi // per_step) * per_step + bi % per_step
     # gathered blocks stay in the store dtype: phase B must reduce the
     # SAME bf16 products phase A did or the exactness certificate's
     # phase-A-bounds-phase-B argument breaks at the rounding margin
@@ -377,12 +404,12 @@ def _phase_b_rows(Y, Qc, active, buckets, target, M, k: int, bs: int,
                         preferred_element_type=jnp.float32,
                         precision=_score_precision(Y)
                         ).reshape(b, ksel * bs)
-    ok = jnp.take(active.reshape(-1, bs), bi, axis=0).reshape(b, ksel * bs)
-    if target is not None:
-        bg = jnp.take(buckets.reshape(-1, bs), bi,
-                      axis=0).reshape(b, ksel * bs)
-        ok = _lsh_ok(ok, bg, target[:, None], max_bits)
-    scores = jnp.where(ok, scores, -jnp.inf)
+    ok = jnp.take(active.reshape(-1, bs), bi, axis=0)
+    if steps is not None:
+        # fewer candidate blocks than ``ksel``: the selection filled up
+        # with blocks outside the row's ball, whose rows are no answer
+        ok = ok & (m_sel > -jnp.inf)[:, :, None]
+    scores = jnp.where(ok.reshape(b, ksel * bs), scores, -jnp.inf)
     ts, ti = jax.lax.top_k(scores, k)
     rows = (bi[:, :, None] * bs
             + jnp.arange(bs, dtype=jnp.int32)[None, None, :]).reshape(
@@ -410,10 +437,11 @@ def _phase_b_rows(Y, Qc, active, buckets, target, M, k: int, bs: int,
 # the 20M-cell window time (155-176 ms regardless of F).  A narrow
 # window's pass (_scores_rows_on_lanes) now takes what streaming the
 # store takes, 7.0 / 13.8 ms at 128 lanes / 250f against 6.9 / 13.7
-# for the tiles alone (~740 GB/s; PERF.md section 5, PR 33); the LSH
-# variant pays the per-(item,query) popcount on the VPU.  Tile 4096
+# for the tiles alone (~740 GB/s; PERF.md section 5, PR 33).  Tile 4096
 # fits VMEM with double-buffering at F=250 bf16; steps of 8,192 and
-# 16,384 rows measured the same.
+# 16,384 rows measured the same.  It is also the step of an item store
+# laid out by LSH bucket (one bucket a step; a model reads it when it
+# is built), whose pruned pass is this kernel over a list of steps.
 _PA_TILE = 4096
 # runtime-fallback state for the pallas build, PER SHAPE: pallas cannot
 # lower on the CPU backend (tier-1 serves the lax.scan build there), and
@@ -519,22 +547,32 @@ def _scores_rows_on_lanes(b: int) -> bool:
     return b < 128
 
 
-def _pallas_block_maxima(Qc, Y, penalty, buckets, target, bs: int,
-                         max_bits: int, rows_on_lanes: bool,
-                         interpret: bool = False):
+def _pallas_block_maxima(Qc, Y, penalty, bs: int, rows_on_lanes: bool,
+                         interpret: bool = False, steps=None,
+                         n_visit=None):
     """Phase A of the pallas build: the (B, N // bs) maxima of every
     ``bs``-row block's scores, from one pass over ``Y`` whose score
     tiles live and die in VMEM.  ``penalty`` is the (N // bs, bs) 0 /
-    -inf active-row mask; ``buckets`` / ``target`` of None select the
-    exact scan.  The two layouts (_scores_rows_on_lanes) reduce the
-    same products and hand over the same maxima."""
+    -inf active-row mask.  The two layouts (_scores_rows_on_lanes)
+    reduce the same products and hand over the same maxima.
+
+    ``steps`` of None is the exact scan: the grid is every step of the
+    store.  With ``steps`` (a scalar-prefetched (N // T,) table, the
+    visited steps first) the grid is its first ``n_visit`` entries, a
+    bound known only on the device, and grid step i streams store step
+    ``steps[i]``: the kernel body is the exact scan's, only the index
+    maps differ, and the maxima leave in VISIT order (step i's blocks
+    at columns [i * J, (i + 1) * J); -inf past the last visited step).
+    Which of a visited step's maxima a query row may use is the
+    caller's one comparison a (row, step)."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     N, F = Y.shape
     B = Qc.shape[0]
     T = _PA_TILE
     J = T // bs                     # blocks a step
-    lsh = buckets is not None
+    pruned = steps is not None
     nt = (((1,), (1,)), ((), ()))   # contract both minor dimensions
     precision = _score_precision(Y)  # the certificate rests on these
     extra = {}
@@ -542,44 +580,56 @@ def _pallas_block_maxima(Qc, Y, penalty, buckets, target, bs: int,
         # HIGHEST splits a float32 tile into bfloat16 parts in VMEM: a
         # 256-wide window at 250f asks for 26.7 MB, the compiler's
         # scoped default is 16 of a v5e's 128
-        from jax.experimental.pallas import tpu as pltpu
         extra["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=64 << 20)
     # per-row side inputs ride in lane-aligned (rows//bs, bs) layout —
     # an (N, 1) input would be lane-padded x128 by TPU tiling (9.5 GB
     # of padding at 20M rows; measured compile OOM)
-    side = pl.BlockSpec((J, bs), lambda i: (i, 0))
-    ins = [Qc, Y, penalty]
-    in_specs = [pl.BlockSpec((B, F), lambda i: (0, 0)),
-                pl.BlockSpec((T, F), lambda i: (i, 0)), side]
-    if lsh:
-        ins.append(buckets.reshape(-1, bs))
-        in_specs.append(side)
+    if pruned:
+        # index maps take the prefetched table after the grid index,
+        # and the kernel takes its ref first
+        first, at, stay = (steps,), lambda i, st: (st[i], 0), \
+            lambda i, st: (0, 0)
+
+        def call(kern, out_spec, out_shape):
+            body = lambda st_ref, *refs: kern(*refs)  # noqa: E731
+            return pl.pallas_call(
+                body, grid_spec=pltpu.PrefetchScalarGridSpec(
+                    num_scalar_prefetch=1, grid=(n_visit,),
+                    in_specs=in_specs, out_specs=out_spec),
+                out_shape=out_shape, interpret=interpret, **extra)
+    else:
+        first, at, stay = (), lambda i: (i, 0), lambda i: (0, 0)
+
+        def call(kern, out_spec, out_shape):
+            return pl.pallas_call(
+                kern, grid=(N // T,), in_specs=in_specs,
+                out_specs=out_spec, out_shape=out_shape,
+                interpret=interpret, **extra)
+
+    ins = [*first, Qc, Y, penalty]
+    in_specs = [pl.BlockSpec((B, F), stay), pl.BlockSpec((T, F), at),
+                pl.BlockSpec((J, bs), at)]
 
     if not rows_on_lanes:
         # (rows, B): Mosaic requires the minor dim of a stored tile to
         # be 128-aligned or full, so the maxima leave as (N // bs, B)
-        def kern(q_ref, y_ref, p_ref, *rest):
+        def kern(q_ref, y_ref, p_ref, o_ref):
             s = jax.lax.dot_general(y_ref[...], q_ref[...], nt,
                                     preferred_element_type=jnp.float32,
                                     precision=precision)
             s3 = s.reshape(J, bs, B) + p_ref[...][:, :, None]
-            if lsh:
-                b_ref, t_ref = rest[:2]
-                ok = jax.lax.population_count(
-                    jnp.bitwise_xor(b_ref[...][:, :, None],
-                                    t_ref[...][0][None, None, :])) <= max_bits
-                s3 = jnp.where(ok, s3, -jnp.inf)
-            rest[-1][...] = s3.max(1)
+            o_ref[...] = s3.max(1)
 
-        if lsh:
-            ins.append(target[None, :])
-            in_specs.append(pl.BlockSpec((1, B), lambda i: (0, 0)))
-        return pl.pallas_call(
-            kern, grid=(N // T,), in_specs=in_specs,
-            out_specs=pl.BlockSpec((J, B), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((N // bs, B), jnp.float32),
-            interpret=interpret, **extra)(*ins).T
+        # the output follows the GRID index on both paths: a pruned
+        # pass writes its maxima in visit order
+        rows = (lambda i, st: (i, 0)) if pruned else (lambda i: (i, 0))
+        Mt = call(kern, pl.BlockSpec((J, B), rows),
+                  jax.ShapeDtypeStruct((N // bs, B), jnp.float32))(*ins)
+        if pruned:
+            Mt = jnp.where((jnp.arange(N // bs) < n_visit * J)[:, None],
+                           Mt, -jnp.inf)
+        return Mt.T
 
     # (B, rows): the dot is q . y^T, row j of a step's side inputs is
     # the lanes of its block j and is broadcast down the sublanes, each
@@ -597,8 +647,7 @@ def _pallas_block_maxima(Qc, Y, penalty, buckets, target, bs: int,
             f"{J} blocks a step do not tile {W} lanes")
     n_blocks = N // bs
 
-    def kern(q_ref, y_ref, p_ref, *rest):
-        o_ref = rest[-1]
+    def kern(q_ref, y_ref, p_ref, o_ref):
         s = jax.lax.dot_general(q_ref[...], y_ref[...], nt,
                                 preferred_element_type=jnp.float32,
                                 precision=precision)
@@ -607,42 +656,53 @@ def _pallas_block_maxima(Qc, Y, penalty, buckets, target, bs: int,
         lane = jax.lax.broadcasted_iota(jnp.int32, (B, W), 1)
         for j in range(J):
             sj = s[:, j * bs:(j + 1) * bs] + p_ref[j:j + 1, :]
-            if lsh:
-                b_ref, t_ref = rest[:2]
-                ok = jax.lax.population_count(jnp.bitwise_xor(
-                    b_ref[j:j + 1, :], t_ref[...])) <= max_bits
-                sj = jnp.where(ok, sj, -jnp.inf)
             m = jnp.where(lane == first + j, sj.max(1, keepdims=True), m)
         o_ref[...] = m
 
-    if lsh:
-        ins.append(target[:, None])
-        in_specs.append(pl.BlockSpec((B, 1), lambda i: (0, 0)))
-    return pl.pallas_call(
-        kern, grid=(N // T,), in_specs=in_specs,
-        out_specs=pl.BlockSpec((B, W), lambda i: (0, i // per_tile)),
-        out_shape=jax.ShapeDtypeStruct((B, -(-n_blocks // W) * W),
-                                       jnp.float32),
-        interpret=interpret, **extra)(*ins)[:, :n_blocks]
+    tiles = (lambda i, st: (0, i // per_tile)) if pruned \
+        else (lambda i: (0, i // per_tile))
+    M = call(kern, pl.BlockSpec((B, W), tiles),
+             jax.ShapeDtypeStruct((B, -(-n_blocks // W) * W),
+                                  jnp.float32))(*ins)[:, :n_blocks]
+    if pruned:
+        # a tile the grid never reached was never written; the last
+        # one it reached holds -inf past the last visited step already
+        M = jnp.where((jnp.arange(n_blocks) < n_visit * J)[None, :], M,
+                      -jnp.inf)
+    return M
+
+
+def _candidate_maxima(M, step_ok):
+    """A pruned pass's block maxima (visit order) with every block of a
+    step that is no candidate of the row at -inf: the LSH test, one
+    comparison a (query row, step)."""
+    b, n_steps = step_ok.shape
+    return jnp.where(step_ok[:, :, None], M.reshape(b, n_steps, -1),
+                     -jnp.inf).reshape(b, -1)
 
 
 @partial(jax.jit, static_argnames=("k", "bs", "ksel", "max_bits",
                                    "interpret"))
-def _batch_top_n_twophase_pallas(Y, Q, penalty, active, buckets,
-                                 hyperplanes, k: int, bs: int, ksel: int,
-                                 max_bits: int, interpret: bool = False):
+def _batch_top_n_twophase_pallas(Y, Q, penalty, active, prune, k: int,
+                                 bs: int, ksel: int, max_bits: int = 0,
+                                 interpret: bool = False):
     """Two-phase streaming top-k with the phase-A block maxima computed
     by a fused pallas dot+blockmax kernel (scores never touch HBM), in
     the layout the window's width asks for (_scores_rows_on_lanes);
-    ``penalty`` is the (N // bs, bs) 0/-inf active-row mask."""
+    ``penalty`` is the (N // bs, bs) 0/-inf active-row mask.  ``prune``
+    of None is the exact scan.  With a ``Pruning`` the pass streams
+    only the steps the window's rows can reach (_visit_plan) and the
+    program returns a fourth result, the plan's ``stats``."""
     Qc = _q_cast(Q, Y)
-    target = None
-    if buckets is not None:
-        target = _query_buckets(Q, hyperplanes)
-    M = _pallas_block_maxima(Qc, Y, penalty, buckets, target, bs, max_bits,
-                             _scores_rows_on_lanes(Q.shape[0]), interpret)
-    return _phase_b(Y, Qc, active, buckets, target, M, k, bs, ksel,
-                    max_bits)
+    lanes = _scores_rows_on_lanes(Q.shape[0])
+    if prune is None:
+        M = _pallas_block_maxima(Qc, Y, penalty, bs, lanes, interpret)
+        return _phase_b(Y, Qc, active, M, k, bs, ksel)
+    steps, n_visit, step_ok, stats = _visit_plan(Q, prune, max_bits)
+    M = _pallas_block_maxima(Qc, Y, penalty, bs, lanes, interpret, steps,
+                             n_visit)
+    return (*_phase_b(Y, Qc, active, _candidate_maxima(M, step_ok), k, bs,
+                      ksel, steps), stats)
 
 
 def _fold_factor(width: int, features: int) -> int:
@@ -682,9 +742,7 @@ def _fold_items_kernel(vecs, active, fold: int, bs: int):
     block ``b`` — block maxima land in the same (N//bs, B) layout the
     unfolded kernel produces.  Returns (Yf, penalty_fold) with the
     per-slot penalty in the (fold, N//bs, bs//fold) layout the
-    kernel's block specs expect; the LSH bucket side input is folded
-    separately (_fold_buckets_kernel) so LSH/non-LSH drains share this
-    mirror."""
+    kernel's block specs expect."""
     N, W = vecs.shape
     w = W // fold
     bsf = bs // fold
@@ -694,19 +752,10 @@ def _fold_items_kernel(vecs, active, fold: int, bs: int):
     return Yf, pen_f
 
 
-@partial(jax.jit, static_argnames=("fold", "bs"))
-def _fold_buckets_kernel(buckets, fold: int, bs: int):
-    """Per-slot LSH bucket ids in the fold kernel's side-input
-    layout."""
-    return buckets.reshape(-1, fold).T.reshape(fold, -1, bs // fold)
-
-
-@partial(jax.jit, static_argnames=("k", "bs", "ksel", "max_bits", "fold",
+@partial(jax.jit, static_argnames=("k", "bs", "ksel", "fold",
                                    "interpret"))
-def _batch_top_n_twophase_pallas_fold(Y, Yf, Q, pen_f, active, bkt_f,
-                                      buckets, hyperplanes, k: int,
-                                      bs: int, ksel: int, max_bits: int,
-                                      fold: int,
+def _batch_top_n_twophase_pallas_fold(Y, Yf, Q, pen_f, active, k: int,
+                                      bs: int, ksel: int, fold: int,
                                       interpret: bool = False):
     """Two-phase streaming top-k whose phase A scans the FOLDED mirror:
     one dot per fold slot against a slot-shifted query copy, per-block
@@ -731,66 +780,34 @@ def _batch_top_n_twophase_pallas_fold(Y, Yf, Q, pen_f, active, bkt_f,
     qw = Qc[:, :w]
     Qs = jnp.stack([jnp.pad(qw, ((0, 0), (j * w, W - (j + 1) * w)))
                     for j in range(fold)])
-    target = None
-    if buckets is not None:
-        target = _query_buckets(Q, hyperplanes)
 
-    if bkt_f is None:
-        def kern(q_ref, y_ref, p_ref, o_ref):
-            m = None
-            for j in range(fold):
-                s = jax.lax.dot_general(y_ref[...], q_ref[j],
-                                        (((1,), (1,)), ((), ())),
-                                        preferred_element_type=jnp.float32,
-                                        precision=precision)
-                s3 = s.reshape(Tf // bsf, bsf, B) + p_ref[j][:, :, None]
-                mj = s3.max(1)
-                m = mj if m is None else jnp.maximum(m, mj)
-            o_ref[...] = m
+    def kern(q_ref, y_ref, p_ref, o_ref):
+        m = None
+        for j in range(fold):
+            s = jax.lax.dot_general(y_ref[...], q_ref[j],
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32,
+                                    precision=precision)
+            s3 = s.reshape(Tf // bsf, bsf, B) + p_ref[j][:, :, None]
+            mj = s3.max(1)
+            m = mj if m is None else jnp.maximum(m, mj)
+        o_ref[...] = m
 
-        ins = (Qs, Yf, pen_f)
-        in_specs = [pl.BlockSpec((fold, B, W), lambda i: (0, 0, 0)),
-                    pl.BlockSpec((Tf, W), lambda i: (i, 0)),
-                    pl.BlockSpec((fold, Tf // bsf, bsf),
-                                 lambda i: (0, i, 0))]
-    else:
-        def kern(q_ref, y_ref, p_ref, b_ref, t_ref, o_ref):
-            m = None
-            for j in range(fold):
-                s = jax.lax.dot_general(y_ref[...], q_ref[j],
-                                        (((1,), (1,)), ((), ())),
-                                        preferred_element_type=jnp.float32,
-                                        precision=precision)
-                s3 = s.reshape(Tf // bsf, bsf, B) + p_ref[j][:, :, None]
-                ok = jax.lax.population_count(
-                    jnp.bitwise_xor(b_ref[j][:, :, None],
-                                    t_ref[...][0][None, None, :])) \
-                    <= max_bits
-                s3 = jnp.where(ok, s3, -jnp.inf)
-                mj = s3.max(1)
-                m = mj if m is None else jnp.maximum(m, mj)
-            o_ref[...] = m
-
-        ins = (Qs, Yf, pen_f, bkt_f, target[None, :])
-        in_specs = [pl.BlockSpec((fold, B, W), lambda i: (0, 0, 0)),
-                    pl.BlockSpec((Tf, W), lambda i: (i, 0)),
-                    pl.BlockSpec((fold, Tf // bsf, bsf),
-                                 lambda i: (0, i, 0)),
-                    pl.BlockSpec((fold, Tf // bsf, bsf),
-                                 lambda i: (0, i, 0)),
-                    pl.BlockSpec((1, B), lambda i: (0, 0))]
+    ins = (Qs, Yf, pen_f)
+    in_specs = [pl.BlockSpec((fold, B, W), lambda i: (0, 0, 0)),
+                pl.BlockSpec((Tf, W), lambda i: (i, 0)),
+                pl.BlockSpec((fold, Tf // bsf, bsf),
+                             lambda i: (0, i, 0))]
 
     Mt = pl.pallas_call(
         kern, grid=(N // _PA_TILE,), in_specs=in_specs,
         out_specs=pl.BlockSpec((Tf // bsf, B), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N // bs, B), jnp.float32),
         interpret=interpret)(*ins)
-    return _phase_b(Y, Qc, active, buckets, target, Mt.T, k, bs, ksel,
-                    max_bits)
+    return _phase_b(Y, Qc, active, Mt.T, k, bs, ksel)
 
 
-def _scan_block_maxima(Qc, Y, active, buckets, target, chunk: int,
-                       bs: int, max_bits: int):
+def _scan_block_maxima(Qc, Y, active, chunk: int, bs: int):
     """Phase A of the lax.scan build: the (B, N // bs) maxima of every
     ``bs``-row block's scores, one (B, chunk) score tile live at a
     time.  The build every backend lowers (the CPU has no other)."""
@@ -798,27 +815,46 @@ def _scan_block_maxima(Qc, Y, active, buckets, target, chunk: int,
     n_chunks = Y.shape[0] // chunk
     xs = (Y.reshape(n_chunks, chunk, Y.shape[1]),
           active.reshape(n_chunks, chunk))
-    if target is not None:
-        xs = xs + (buckets.reshape(n_chunks, chunk),)
 
     def step_a(_, x):
         scores = jnp.matmul(Qc, x[0].T,
                             preferred_element_type=jnp.float32,
                             precision=_score_precision(Y))
-        ok = x[1][None, :]
-        if target is not None:
-            ok = _lsh_ok(ok, x[2][None, :], target[:, None], max_bits)
-        scores = jnp.where(ok, scores, -jnp.inf)
+        scores = jnp.where(x[1][None, :], scores, -jnp.inf)
         return None, scores.reshape(b, chunk // bs, bs).max(-1)
 
     _, Ms = jax.lax.scan(step_a, None, xs)
     return jnp.transpose(Ms, (1, 0, 2)).reshape(b, -1)   # (B, n_blocks)
 
 
+def _scan_step_maxima(Qc, Y, active, steps, n_visit, bs: int):
+    """Phase A of the lax.scan build over a pruned window's steps
+    (_visit_plan): the loop runs ``n_visit`` times, a bound known only
+    on the device, slices store step ``steps[i]`` and writes its block
+    maxima at visit position i; what it never reaches stays -inf."""
+    b = Qc.shape[0]
+    n_steps = steps.shape[0]
+    tile = Y.shape[0] // n_steps
+
+    def step_a(i, M):
+        at = steps[i] * tile
+        y = jax.lax.dynamic_slice_in_dim(Y, at, tile)
+        a = jax.lax.dynamic_slice_in_dim(active, at, tile)
+        scores = jnp.matmul(Qc, y.T, preferred_element_type=jnp.float32,
+                            precision=_score_precision(Y))
+        scores = jnp.where(a[None, :], scores, -jnp.inf)
+        return jax.lax.dynamic_update_slice_in_dim(
+            M, scores.reshape(b, 1, tile // bs, bs).max(-1), i, 1)
+
+    M = jax.lax.fori_loop(
+        0, n_visit, step_a,
+        jnp.full((b, n_steps, tile // bs), -jnp.inf, jnp.float32))
+    return M.reshape(b, -1)
+
+
 @partial(jax.jit, static_argnames=("k", "chunk", "bs", "ksel", "max_bits"))
-def _batch_top_n_twophase_kernel(Y, Q, active, buckets, hyperplanes,
-                                 k: int, chunk: int, bs: int, ksel: int,
-                                 max_bits: int):
+def _batch_top_n_twophase_kernel(Y, Q, active, prune, k: int, chunk: int,
+                                 bs: int, ksel: int, max_bits: int = 0):
     """Streaming batched top-k, two-phase MIPS style, EXACT with a
     per-row certificate.
 
@@ -833,58 +869,85 @@ def _batch_top_n_twophase_kernel(Y, Q, active, buckets, hyperplanes,
     kth_score >= max(every unselected block's maximum) proves no
     unscanned block can hold a better item.  Rows whose certificate
     fails (approx selection missed a head block) are recomputed by the
-    caller on the exact lax.top_k scan path.  ``buckets`` /
-    ``hyperplanes`` of None select the exact scan; with LSH they fuse
-    the Hamming-ball mask into both phases."""
-    target = None
-    if buckets is not None:
-        target = _query_buckets(Q, hyperplanes)
+    caller on the exact lax.top_k scan path.  ``prune`` of None is
+    the exact scan; with a ``Pruning`` phase A loops over the steps the
+    window's rows can reach only (_visit_plan; ``chunk`` is then not
+    looked at) and the plan's ``stats`` are a fourth result."""
     Qc = _q_cast(Q, Y)
-    M = _scan_block_maxima(Qc, Y, active, buckets, target, chunk, bs,
-                           max_bits)
-    return _phase_b(Y, Qc, active, buckets, target, M, k, bs, ksel,
-                    max_bits)
+    if prune is None:
+        M = _scan_block_maxima(Qc, Y, active, chunk, bs)
+        return _phase_b(Y, Qc, active, M, k, bs, ksel)
+    steps, n_visit, step_ok, stats = _visit_plan(Q, prune, max_bits)
+    M = _scan_step_maxima(Qc, Y, active, steps, n_visit, bs)
+    return (*_phase_b(Y, Qc, active, _candidate_maxima(M, step_ok), k, bs,
+                      ksel, steps), stats)
 
 
-@partial(jax.jit, static_argnames=("k", "chunk", "max_bits"))
-def _batch_top_n_chunked_kernel(Y, Q, active, buckets, hyperplanes,
-                                k: int, chunk: int, max_bits: int):
+def _merge_top_k(best_s, best_i, scores, base, k: int):
+    """The running best ``k`` (scores, rows) with the best of one more
+    tile of ``scores``, whose first row is store row ``base``."""
+    cs, ci = jax.lax.top_k(scores, min(k, scores.shape[1]))
+    ns, sel = jax.lax.top_k(jnp.concatenate([best_s, cs], axis=1), k)
+    ni = jnp.take_along_axis(
+        jnp.concatenate([best_i, ci + base], axis=1), sel, axis=1)
+    return ns, ni
+
+
+@partial(jax.jit, static_argnames=("k", "chunk"))
+def _batch_top_n_chunked_kernel(Y, Q, active, k: int, chunk: int):
     """Streaming batched top-k with exact per-chunk lax.top_k — the
     certainty fallback for two-phase certificate failures (and the
     reference semantics oracle in tests).  Carries the running (B, k)
-    best scores/indices across item-row chunks.  ``buckets`` /
-    ``hyperplanes`` of None select the exact scan."""
+    best scores/indices across item-row chunks."""
     n_chunks = Y.shape[0] // chunk
-    Yr = Y.reshape(n_chunks, chunk, Y.shape[1])
-    Ar = active.reshape(n_chunks, chunk)
-    xs = (Yr, Ar, jnp.arange(n_chunks, dtype=jnp.int32) * chunk)
-    target = None
-    if buckets is not None:
-        xs = xs + (buckets.reshape(n_chunks, chunk),)
-        target = _query_buckets(Q, hyperplanes)
-
+    xs = (Y.reshape(n_chunks, chunk, Y.shape[1]),
+          active.reshape(n_chunks, chunk),
+          jnp.arange(n_chunks, dtype=jnp.int32) * chunk)
     Qc = _q_cast(Q, Y)
 
     def step(carry, x):
-        best_s, best_i = carry
-        Yc, Ac, base = x[:3]
+        Yc, Ac, base = x
         scores = jnp.matmul(Qc, Yc.T,
                             preferred_element_type=jnp.float32,
                             precision=_score_precision(Y))
-        ok = Ac[None, :]
-        if target is not None:
-            ok = _lsh_ok(ok, x[3][None, :], target[:, None], max_bits)
-        cs, ci = jax.lax.top_k(jnp.where(ok, scores, -jnp.inf), k)
-        ns, sel = jax.lax.top_k(jnp.concatenate([best_s, cs], axis=1), k)
-        ni = jnp.take_along_axis(
-            jnp.concatenate([best_i, ci + base], axis=1), sel, axis=1)
-        return (ns, ni), None
+        return _merge_top_k(*carry, jnp.where(Ac[None, :], scores,
+                                              -jnp.inf), base, k), None
 
     b = Q.shape[0]
     init = (jnp.full((b, k), -jnp.inf, jnp.float32),
             jnp.zeros((b, k), jnp.int32))
     (best_s, best_i), _ = jax.lax.scan(step, init, xs)
     return best_s, best_i
+
+
+@partial(jax.jit, static_argnames=("k", "max_bits"))
+def _batch_top_n_pruned_exact_kernel(Y, Q, active, prune, k: int,
+                                     max_bits: int):
+    """The exact scan held to a pruned window's candidates: the answer
+    of a window whose two-phase certificate failed, and the primary
+    path where the two-phase program is not admitted.  One store step
+    at a time over the steps the window's rows can reach (_visit_plan),
+    a row scoring only the steps of its own ball, with the running
+    (B, k) best carried along.  Returns (scores, rows, stats)."""
+    steps, n_visit, step_ok, stats = _visit_plan(Q, prune, max_bits)
+    tile = Y.shape[0] // steps.shape[0]
+    Qc = _q_cast(Q, Y)
+
+    def step(i, carry):
+        at = steps[i] * tile
+        y = jax.lax.dynamic_slice_in_dim(Y, at, tile)
+        a = jax.lax.dynamic_slice_in_dim(active, at, tile)
+        scores = jnp.matmul(Qc, y.T, preferred_element_type=jnp.float32,
+                            precision=_score_precision(Y))
+        ok = a[None, :] & jax.lax.dynamic_slice_in_dim(step_ok, i, 1, 1)
+        return _merge_top_k(*carry, jnp.where(ok, scores, -jnp.inf), at,
+                            k)
+
+    b = Q.shape[0]
+    init = (jnp.full((b, k), -jnp.inf, jnp.float32),
+            jnp.zeros((b, k), jnp.int32))
+    best_s, best_i = jax.lax.fori_loop(0, n_visit, step, init)
+    return best_s, best_i, stats
 
 
 class ShardPlan(NamedTuple):
@@ -926,39 +989,27 @@ def shard_candidates(Y, active, Q, penalty, k: int,
         return _batch_top_n_kernel.__wrapped__(Y, Q, active, k)
     Qc = _q_cast(Q, Y)
     if penalty is None:
-        M = _scan_block_maxima(Qc, Y, active, None, None, plan.chunk,
-                               plan.bs, 0)
+        M = _scan_block_maxima(Qc, Y, active, plan.chunk, plan.bs)
     else:
-        M = _pallas_block_maxima(Qc, Y, penalty, None, None, plan.bs, 0,
+        M = _pallas_block_maxima(Qc, Y, penalty, plan.bs,
                                  _scores_rows_on_lanes(Q.shape[0]))
-    ts, ti, cert = _phase_b(Y, Qc, active, None, None, M, k, plan.bs,
-                            plan.ksel, 0)
+    ts, ti, cert = _phase_b(Y, Qc, active, M, k, plan.bs, plan.ksel)
     ts, ti = jax.lax.cond(
         cert.all(), lambda: (ts, ti),
-        lambda: _batch_top_n_chunked_kernel(Y, Q, active, None, None, k,
-                                            plan.chunk, 0))
+        lambda: _batch_top_n_chunked_kernel(Y, Q, active, k, plan.chunk))
     return ts, ti, cert
-
-
-@partial(jax.jit, static_argnames=("k", "bs", "ksel", "max_bits"))
-def _phase_b_only(Y, Q, active, buckets, hyperplanes, M, k: int,
-                  bs: int, ksel: int, max_bits: int):
-    """Phase B as a standalone program over precomputed block maxima
-    ``M`` — the kernel probe times this against the full two-phase
-    program to decompose per-pass cost (phase A = full - phase B).
-    Never on the serving path."""
-    Qc = _q_cast(Q, Y)
-    target = None
-    if buckets is not None:
-        target = _query_buckets(Q, hyperplanes)
-    return _phase_b(Y, Qc, active, buckets, target, M, k, bs, ksel,
-                    max_bits)
 
 
 @partial(jax.jit, static_argnames=("k",))
 def _masked_top_k(scores, mask, k: int):
     masked = jnp.where(mask, scores, -jnp.inf)
     return jax.lax.top_k(masked, k)
+
+
+@partial(jax.jit, static_argnames=("n_steps",))
+def _step_live_kernel(active, n_steps: int):
+    """Live rows of each of a partitioned store's ``n_steps`` steps."""
+    return active.reshape(n_steps, -1).sum(1, dtype=jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("bs",))
@@ -1041,13 +1092,11 @@ def _fold_items_i8_kernel(y8, active, fold: int, bs: int):
     return y8f, pen_f
 
 
-@partial(jax.jit, static_argnames=("k", "bs", "ksel", "max_bits", "fold",
+@partial(jax.jit, static_argnames=("k", "bs", "ksel", "fold",
                                    "interpret"))
 def _batch_top_n_twophase_pallas_i8_fold(Y, Y8f, sy_b, l1y_b, Q,
-                                         pen_i_f, active, bkt_f, buckets,
-                                         hyperplanes, k: int, bs: int,
-                                         ksel: int, max_bits: int,
-                                         fold: int,
+                                         pen_i_f, active, k: int, bs: int,
+                                         ksel: int, fold: int,
                                          interpret: bool = False):
     """The deepest phase-A mirror: int8 quantized AND row-folded, so a
     50-feature scan streams ~items x features BYTES (one int8 per
@@ -1078,52 +1127,23 @@ def _batch_top_n_twophase_pallas_i8_fold(Y, Y8f, sy_b, l1y_b, Q,
     q8w = q8[:, :w]
     q8s = jnp.stack([jnp.pad(q8w, ((0, 0), (j * w, W - (j + 1) * w)))
                      for j in range(fold)])
-    target = None
-    if buckets is not None:
-        target = _query_buckets(Q, hyperplanes)
 
-    if bkt_f is None:
-        def kern(q_ref, y_ref, p_ref, o_ref):
-            m = None
-            for j in range(fold):
-                s = jax.lax.dot_general(y_ref[...], q_ref[j],
-                                        (((1,), (1,)), ((), ())),
-                                        preferred_element_type=jnp.int32)
-                s3 = s.reshape(Tf // bsf, bsf, B) + p_ref[j][:, :, None]
-                mj = s3.max(1)
-                m = mj if m is None else jnp.maximum(m, mj)
-            o_ref[...] = m
+    def kern(q_ref, y_ref, p_ref, o_ref):
+        m = None
+        for j in range(fold):
+            s = jax.lax.dot_general(y_ref[...], q_ref[j],
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.int32)
+            s3 = s.reshape(Tf // bsf, bsf, B) + p_ref[j][:, :, None]
+            mj = s3.max(1)
+            m = mj if m is None else jnp.maximum(m, mj)
+        o_ref[...] = m
 
-        ins = (q8s, Y8f, pen_i_f)
-        in_specs = [pl.BlockSpec((fold, B, W), lambda i: (0, 0, 0)),
-                    pl.BlockSpec((Tf, W), lambda i: (i, 0)),
-                    pl.BlockSpec((fold, Tf // bsf, bsf),
-                                 lambda i: (0, i, 0))]
-    else:
-        def kern(q_ref, y_ref, p_ref, b_ref, t_ref, o_ref):
-            m = None
-            for j in range(fold):
-                s = jax.lax.dot_general(y_ref[...], q_ref[j],
-                                        (((1,), (1,)), ((), ())),
-                                        preferred_element_type=jnp.int32)
-                s3 = s.reshape(Tf // bsf, bsf, B) + p_ref[j][:, :, None]
-                ok = jax.lax.population_count(
-                    jnp.bitwise_xor(b_ref[j][:, :, None],
-                                    t_ref[...][0][None, None, :])) \
-                    <= max_bits
-                s3 = jnp.where(ok, s3, _I8_PENALTY)
-                mj = s3.max(1)
-                m = mj if m is None else jnp.maximum(m, mj)
-            o_ref[...] = m
-
-        ins = (q8s, Y8f, pen_i_f, bkt_f, target[None, :])
-        in_specs = [pl.BlockSpec((fold, B, W), lambda i: (0, 0, 0)),
-                    pl.BlockSpec((Tf, W), lambda i: (i, 0)),
-                    pl.BlockSpec((fold, Tf // bsf, bsf),
-                                 lambda i: (0, i, 0)),
-                    pl.BlockSpec((fold, Tf // bsf, bsf),
-                                 lambda i: (0, i, 0)),
-                    pl.BlockSpec((1, B), lambda i: (0, 0))]
+    ins = (q8s, Y8f, pen_i_f)
+    in_specs = [pl.BlockSpec((fold, B, W), lambda i: (0, 0, 0)),
+                pl.BlockSpec((Tf, W), lambda i: (i, 0)),
+                pl.BlockSpec((fold, Tf // bsf, bsf),
+                             lambda i: (0, i, 0))]
 
     Mt_int = pl.pallas_call(
         kern, grid=(N // _PA_TILE,), in_specs=in_specs,
@@ -1139,16 +1159,12 @@ def _batch_top_n_twophase_pallas_i8_fold(Y, Y8f, sy_b, l1y_b, Q,
              + 0.5 * sy_b[:, None] * l1q[None, :]
              + 0.25 * W * sy_b[:, None] * sq[None, :])
     bound = jnp.where(masked | (l1q[None, :] == 0.0), -jnp.inf, bound)
-    return _phase_b(Y, Qc, active, buckets, target, bound.T, k, bs,
-                    ksel, max_bits)
+    return _phase_b(Y, Qc, active, bound.T, k, bs, ksel)
 
 
-@partial(jax.jit, static_argnames=("k", "bs", "ksel", "max_bits",
-                                   "interpret"))
+@partial(jax.jit, static_argnames=("k", "bs", "ksel", "interpret"))
 def _batch_top_n_twophase_pallas_i8(Y, Y8, sy_b, l1y_b, Q, penalty_i,
-                                    active, buckets, hyperplanes,
-                                    k: int, bs: int, ksel: int,
-                                    max_bits: int,
+                                    active, k: int, bs: int, ksel: int,
                                     interpret: bool = False):
     """Two-phase streaming top-k with an INT8 phase A: block selection
     runs on a quantized mirror of the item matrix (half the HBM bytes
@@ -1173,41 +1189,18 @@ def _batch_top_n_twophase_pallas_i8(Y, Y8, sy_b, l1y_b, Q, penalty_i,
     Qf = Qc.astype(jnp.float32)
     sq = jnp.maximum(jnp.max(jnp.abs(Qf), axis=1), 1e-30) / 127.0
     q8 = jnp.clip(jnp.round(Qf / sq[:, None]), -127, 127).astype(jnp.int8)
-    target = None
-    if buckets is not None:
-        target = _query_buckets(Q, hyperplanes)
 
-    if buckets is None:
-        def kern(q_ref, y_ref, p_ref, o_ref):
-            s = jax.lax.dot_general(y_ref[...], q_ref[...],
-                                    (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.int32)
-            s3 = s.reshape(T // bs, bs, B) + p_ref[...][:, :, None]
-            o_ref[...] = s3.max(1)
+    def kern(q_ref, y_ref, p_ref, o_ref):
+        s = jax.lax.dot_general(y_ref[...], q_ref[...],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.int32)
+        s3 = s.reshape(T // bs, bs, B) + p_ref[...][:, :, None]
+        o_ref[...] = s3.max(1)
 
-        ins = (q8, Y8, penalty_i)
-        in_specs = [pl.BlockSpec((B, F), lambda i: (0, 0)),
-                    pl.BlockSpec((T, F), lambda i: (i, 0)),
-                    pl.BlockSpec((T // bs, bs), lambda i: (i, 0))]
-    else:
-        def kern(q_ref, y_ref, p_ref, b_ref, t_ref, o_ref):
-            s = jax.lax.dot_general(y_ref[...], q_ref[...],
-                                    (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.int32)
-            s3 = s.reshape(T // bs, bs, B) + p_ref[...][:, :, None]
-            ok = jax.lax.population_count(
-                jnp.bitwise_xor(b_ref[...][:, :, None],
-                                t_ref[...][0][None, None, :])) <= max_bits
-            s3 = jnp.where(ok, s3, _I8_PENALTY)
-            o_ref[...] = s3.max(1)
-
-        ins = (q8, Y8, penalty_i, buckets.reshape(-1, bs),
-               target[None, :])
-        in_specs = [pl.BlockSpec((B, F), lambda i: (0, 0)),
-                    pl.BlockSpec((T, F), lambda i: (i, 0)),
-                    pl.BlockSpec((T // bs, bs), lambda i: (i, 0)),
-                    pl.BlockSpec((T // bs, bs), lambda i: (i, 0)),
-                    pl.BlockSpec((1, B), lambda i: (0, 0))]
+    ins = (q8, Y8, penalty_i)
+    in_specs = [pl.BlockSpec((B, F), lambda i: (0, 0)),
+                pl.BlockSpec((T, F), lambda i: (i, 0)),
+                pl.BlockSpec((T // bs, bs), lambda i: (i, 0))]
 
     Mt_int = pl.pallas_call(
         kern, grid=(N // T,), in_specs=in_specs,
@@ -1218,8 +1211,8 @@ def _batch_top_n_twophase_pallas_i8(Y, Y8, sy_b, l1y_b, Q, penalty_i,
     #   s = sy*sq*s_int + err, |err| <= sq/2*L1(y) + sy/2*L1(q) + F*sy*sq/4
     # (y = y8*sy + ey with |ey| <= sy/2, q likewise; cross terms
     # bounded by the L1 norms, quadratic term by F/4 scale products).
-    # Masked entries stay -inf so a fully-retired/out-of-ball block can
-    # never fail a certificate.
+    # Masked entries stay -inf so a fully-retired block can never fail
+    # a certificate.
     l1q = jnp.sum(jnp.abs(Qf), axis=1)                      # (B,)
     masked = Mt_int <= _I8_PENALTY // 2
     bound = (Mt_int.astype(jnp.float32) * sy_b[:, None] * sq[None, :]
@@ -1230,8 +1223,7 @@ def _batch_top_n_twophase_pallas_i8(Y, Y8, sy_b, l1y_b, Q, penalty_i,
     # both phases; a small positive margin bound would fail its
     # certificate on EVERY padded drain — its true bound is 0^- = -inf
     bound = jnp.where(masked | (l1q[None, :] == 0.0), -jnp.inf, bound)
-    return _phase_b(Y, Qc, active, buckets, target, bound.T, k, bs,
-                    ksel, max_bits)
+    return _phase_b(Y, Qc, active, bound.T, k, bs, ksel)
 
 
 class ALSServingModel(FactorModelBase, ServingModel):
@@ -1252,10 +1244,15 @@ class ALSServingModel(FactorModelBase, ServingModel):
         matrices past one chip's HBM (reference's partitioned scan,
         PartitionedFeatureVectors.java:84-148 via
         ALSServingModel.java:265-280).  LSH pruning is bypassed in
-        sharded mode (it is a single-chip optimization); cosine and
-        rescorer paths run on the sharded arrays through XLA's
-        sharding propagation.  ``mesh`` overrides the auto-built 1-D
-        mesh (tests)."""
+        sharded mode (a sharded store is not laid out by bucket:
+        PERF.md section 7); cosine and rescorer paths run on the
+        sharded arrays through XLA's sharding propagation.  ``mesh``
+        overrides the auto-built 1-D mesh (tests).
+
+        ``sample_rate`` < 1 (``oryx.als.sample-rate``) on one chip lays
+        the item store out by LSH bucket, one bucket a ``_PA_TILE``-row
+        step, and every dot-product top-N then scans the buckets inside
+        the query's Hamming ball only (module docstring)."""
         self._item_shards = int(item_shards)
         self._mesh = None
         item_sharding = None
@@ -1289,8 +1286,24 @@ class ALSServingModel(FactorModelBase, ServingModel):
         self._known_lock = AutoReadWriteLock()
         self.lsh = (LocalitySensitiveHash(sample_rate, features)
                     if sample_rate < 1.0 else None)
-        self._item_buckets: jax.Array | None = None
-        self._item_buckets_version: int = -1
+        # rows a step of the item store under LSH (0: not laid out)
+        self._lsh_step = _PA_TILE if self._lsh_active() else 0
+        if self._lsh_step:
+            self.Y.partition_by(self.lsh.bucket_of,
+                                self.lsh.num_partitions, self._lsh_step)
+        # what a pruned window's program reads beside the store: the
+        # bucket of every step (cached against the layout's version)
+        # and the live rows of every step (against the active mask)
+        self._step_bucket: jax.Array | None = None
+        self._step_bucket_version: int = -1
+        self._step_live: jax.Array | None = None
+        self._step_live_src = None
+        # pruned windows scored, the live rows inside their queries'
+        # Hamming balls (the work the semantics oblige) and the rows
+        # phase A streamed for them (whole steps)
+        self.lsh_windows = 0
+        self.lsh_candidate_rows = 0
+        self.lsh_streamed_rows = 0
         # the penalties are functions of the active mask alone, whose
         # handle outlives every sync that writes vectors only: cached
         # against the mask they were built from (held, so that its
@@ -1314,10 +1327,10 @@ class ALSServingModel(FactorModelBase, ServingModel):
         self._int8_selection = int8_selection
         self._i8: tuple | None = None
         self._i8_version: int = -1
-        # int8 x fold combined mirror: (Y8f, penalty_i_fold, buckets_f)
+        # int8 x fold combined mirror: (Y8f, penalty_i_fold, scale, L1)
         self._i8_fold: tuple | None = None
         self._i8_fold_version: int = -1
-        # measured-cost route: {kinds, use_lsh, costs_ms, ...} chosen by
+        # measured-cost route: {kinds, costs_ms, ...} chosen by
         # kernel_router.measure_routes at model load / hot-swap, keyed
         # on the Y store's padded capacity (the compiled-shape key —
         # UP-stream version bumps must NOT trigger re-measurement)
@@ -1331,8 +1344,6 @@ class ALSServingModel(FactorModelBase, ServingModel):
         # costs 1/fold of the canonical snapshot's HBM
         self._fold_scan = fold_scan
         self._fold: tuple | None = None
-        self._fold_bkt: jax.Array | None = None
-        self._fold_bkt_version: int = -1
         self._fold_version: int = -1
         self._penalty_i: jax.Array | None = None
         self._penalty_i_src = None
@@ -1359,9 +1370,10 @@ class ALSServingModel(FactorModelBase, ServingModel):
         self.sharded_windows = 0
         self.shard_fallback_rows = 0
         # whole-matrix builds of state derived from the item matrix (the
-        # phase-A mirrors, the LSH buckets, the IVF mirror): one a kind
-        # at load.  The penalties and the LSH buckets follow the rows a
-        # sync wrote; the mirrors and the IVF state do not yet, so under
+        # phase-A mirrors, the IVF mirror): one a kind at load.  The
+        # penalties follow the rows a sync wrote (and a store laid out
+        # by bucket needs no bucket per row: its steps' table follows
+        # the writes); the mirrors and the IVF state do not yet, so under
         # a write stream a route that uses one pays a pass over the
         # store per device sync, and this count grows with the syncs
         self.derived_rebuilds = 0
@@ -1434,9 +1446,18 @@ class ALSServingModel(FactorModelBase, ServingModel):
             "rows_synced": self.Y.rows_synced,
             "derived_rebuilds": self.derived_rebuilds,
         }
+        part = self.partitioning()
+        if part is not None:
+            # the layout LSH pruning rests on, and what it saved: rows
+            # streamed against the candidates' and the store's
+            out["lsh"] = dict(
+                part, windows=self.lsh_windows,
+                candidate_rows=self.lsh_candidate_rows,
+                streamed_rows=self.lsh_streamed_rows,
+                row_moves=self.lsh_row_moves)
         # measured-cost route: which kernel path serves this shape and
         # the per-path device costs the choice was made from — the
-        # operator-visible answer to "why is LSH off / which build ran"
+        # operator-visible answer to "which build ran, at what cost"
         r = self._route
         if r is not None:
             # plus every build that failed at DISPATCH for this shape
@@ -1448,14 +1469,39 @@ class ALSServingModel(FactorModelBase, ServingModel):
                     and _PALLAS_STATE.get(key) != "ok"}
             if late:
                 r = dict(r, errors={**late, **r.get("errors", {})})
+            if part is not None:
+                r = dict(r, partitioning=part)
             out["kernel_route"] = r
         return out
+
+    def partitioning(self) -> dict | None:
+        """How the item store is laid out under LSH (None without):
+        hyperplanes, Hamming radius, buckets (37 of 256 inside a ball
+        at the reference's 0.3), rows a step, and the steps the store
+        has and has given to buckets."""
+        if not self._lsh_active():
+            return None
+        step_bucket, step, _ = self.Y.partition_layout()
+        return {"hashes": self.lsh.num_hashes,
+                "radius": self.lsh.max_bits_differing,
+                "buckets": self.lsh.num_partitions,
+                "buckets_a_ball": sum(
+                    math.comb(self.lsh.num_hashes, d)
+                    for d in range(self.lsh.max_bits_differing + 1)),
+                "step_rows": step, "steps": int(len(step_bucket)),
+                "steps_assigned": int((step_bucket >= 0).sum())}
+
+    @property
+    def lsh_row_moves(self) -> int:
+        """Item rows that changed region because a write changed their
+        vector's bucket."""
+        return self.Y.row_moves
 
     @property
     def kernel_route_label(self) -> str | None:
         """Compact label of the measured-cost route serving this shape
-        (kernel_router.measure_routes' ``chosen`` kind, ``+lsh`` when
-        the Hamming-ball mask is honored) — attached to every sampled
+        (kernel_router.measure_routes' ``chosen`` kind, ``+lsh`` on a
+        model whose scans are pruned) — attached to every sampled
         device-execute span by the scoring batcher so a slow trace
         names the phase-A variant that ran.  None before routing has
         measured (or on paths routing cannot time)."""
@@ -1470,9 +1516,11 @@ class ALSServingModel(FactorModelBase, ServingModel):
 
     def _lsh_active(self) -> bool:
         """True when this model's LSH configuration actually prunes
-        (hashes exist and the Hamming ball is a strict subset).  Always
-        False in sharded mode: LSH is a single-chip optimization, and
-        the sharded exact scan already splits the bandwidth bill."""
+        (hashes exist and the Hamming ball is a strict subset): the
+        item store is then laid out by bucket.  Always False in sharded
+        mode: a row-sharded store keeps rows where they were appended
+        (PERF.md section 7), and the sharded exact scan already splits
+        the bandwidth bill."""
         return (self._item_shards == 1 and self.lsh is not None
                 and self.lsh.num_hashes > 0
                 and self.lsh.max_bits_differing < self.lsh.num_hashes)
@@ -1567,14 +1615,17 @@ class ALSServingModel(FactorModelBase, ServingModel):
         n_rows = len(self.Y.row_ids())
         k = min(_pad_k(how_many), n_rows)
         big, chunk = _stream_plan(n_rows, _CHUNKED_BATCH)
-        if big and n_rows % chunk == 0 and k <= chunk:
+        pruned = self._lsh_active()
+        if pruned or (big and n_rows % chunk == 0 and k <= chunk):
             for w in _WINDOW_LADDER:
                 # exact-scan fallback per ladder window shape, so a rare
                 # certificate failure costs one extra dispatch, never an
-                # in-request XLA compile
+                # in-request XLA compile (a pruned window's loops run to
+                # a bound the device computes: one program a shape,
+                # however many steps a window visits)
                 jax.device_get(self._enqueue_exact(
                     jnp.zeros((w, self.features), jnp.float32), k, chunk,
-                    self._lsh_active()))
+                    w if pruned else None))
         # measure per-path costs for the live shape and install the
         # route while still pre-traffic: kernel choice is cost-driven,
         # not config-driven, from the first real request on
@@ -1610,22 +1661,16 @@ class ALSServingModel(FactorModelBase, ServingModel):
     def _fold_enabled(self) -> bool:
         return bool(self._fold_scan) and self._fold_scan != "false"
 
-    def _cached_fold(self, vecs, active, buckets, version, fold: int,
+    def _cached_fold(self, vecs, active, version, fold: int,
                      bs: int) -> tuple:
-        """(Yf, penalty_fold, buckets_fold|None) phase-A fold mirror,
-        recomputed device-to-device when the Y snapshot version
-        changes.  The mirror is shared between LSH and non-LSH drains
-        (mixed traffic must not thrash a full-matrix rebuild); the
-        bucket side input folds lazily on first LSH use per version."""
+        """(Yf, penalty_fold) phase-A fold mirror, recomputed
+        device-to-device when the Y snapshot version changes."""
         with self._bucket_lock:
             if self._fold is None or self._fold_version != version:
                 self.derived_rebuilds += 1
                 self._fold = _fold_items_kernel(vecs, active, fold, bs)
                 self._fold_version = version
-            yf, pen_f = self._fold
-            bkt_f = self._fold_bkt_locked(buckets, version, fold, bs) \
-                if buckets is not None else None
-            return yf, pen_f, bkt_f
+            return self._fold
 
     def _cached_i8(self, vecs, version):
         """(Y8, per-block scale, per-block L1) quantization mirror,
@@ -1638,9 +1683,9 @@ class ALSServingModel(FactorModelBase, ServingModel):
                 self._i8_version = version
             return self._i8
 
-    def _cached_i8_fold(self, vecs, active, buckets, version, fold: int,
+    def _cached_i8_fold(self, vecs, active, version, fold: int,
                         bs: int) -> tuple:
-        """(Y8f, penalty_i_fold, buckets_fold|None, scale, L1) int8+fold
+        """(Y8f, penalty_i_fold, scale, L1) int8+fold
         phase-A mirror.  Quantizes with the SAME kernel as the unfolded
         path (identical scales/L1 norms — the bound algebra must agree)
         but deliberately does NOT go through ``_cached_i8``: the
@@ -1654,10 +1699,7 @@ class ALSServingModel(FactorModelBase, ServingModel):
                 y8f, pen_i_f = _fold_items_i8_kernel(y8, active, fold, bs)
                 self._i8_fold = (y8f, pen_i_f, sy_b, l1y_b)
                 self._i8_fold_version = version
-            y8f, pen_i_f, sy_b, l1y_b = self._i8_fold
-            bkt_f = self._fold_bkt_locked(buckets, version, fold, bs) \
-                if buckets is not None else None
-            return y8f, pen_i_f, bkt_f, sy_b, l1y_b
+            return self._i8_fold
 
     def _evict_unused_mirrors(self, keep_kind: str | None) -> None:
         """Drop the phase-A mirror caches the routed kind does not use.
@@ -1668,9 +1710,9 @@ class ALSServingModel(FactorModelBase, ServingModel):
         store for the model's lifetime.  Version-keyed caches rebuild
         on demand if a fallback ever routes back to an evicted kind."""
         keep = {
-            "i8_fold": {"_i8_fold", "_fold_bkt"},
+            "i8_fold": {"_i8_fold"},
             "i8": {"_i8", "_penalty_i"},
-            "fold": {"_fold", "_fold_bkt"},
+            "fold": {"_fold"},
             "pallas": {"_penalty"},
             "ivf": {"_ivf_mirror"},
         }.get(keep_kind, set())
@@ -1678,23 +1720,12 @@ class ALSServingModel(FactorModelBase, ServingModel):
             for attr, ver in (("_i8", "_i8_version"),
                               ("_i8_fold", "_i8_fold_version"),
                               ("_fold", "_fold_version"),
-                              ("_fold_bkt", "_fold_bkt_version"),
                               ("_penalty", "_penalty_src"),
                               ("_penalty_i", "_penalty_i_src"),
                               ("_ivf_mirror", "_ivf_mirror_version")):
                 if attr not in keep:
                     setattr(self, attr, None)
                     setattr(self, ver, -1)
-
-    def _fold_bkt_locked(self, buckets, version, fold: int,
-                         bs: int) -> jax.Array:
-        """Folded LSH bucket side input, shared by the bf16-fold and
-        int8-fold mirrors (caller holds ``_bucket_lock``)."""
-        if self._fold_bkt is None or self._fold_bkt_version != version:
-            self.derived_rebuilds += 1
-            self._fold_bkt = _fold_buckets_kernel(buckets, fold, bs)
-            self._fold_bkt_version = version
-        return self._fold_bkt
 
     def _cached_penalty_i(self, active, version) -> jax.Array:
         with self._bucket_lock:
@@ -1704,40 +1735,38 @@ class ALSServingModel(FactorModelBase, ServingModel):
                 self._penalty_i_src = active
             return self._penalty_i
 
-    def _cached_buckets(self, vecs, version) -> jax.Array:
-        """Per-item LSH bucket ids on device, brought up to the Y
-        snapshot's version: the buckets of the rows that syncs wrote
-        since are recomputed and written in place; the whole matrix is
-        hashed again only where the store cannot name those rows (a
-        whole upload).  Computed device-to-device: at 20M items the
-        vectors never round-trip through the host."""
+    def _pruning(self, active, n_real: int) -> Pruning:
+        """What a pruned program over the resident arrays reads beside
+        them (the caller holds the store's dispatch lock, so the table
+        of the steps' buckets, read after the sync, is at least as new
+        as the rows on the device: a step a bucket took since holds no
+        live row there yet).  ``n_real`` of the window's rows are
+        requests."""
         with self._bucket_lock:
-            if self._item_buckets is not None \
-                    and self._item_buckets_version != version:
-                rows = self.Y.rows_changed_since(
-                    self._item_buckets_version)
-                if rows is not None and len(rows) \
-                        and len(rows) <= _BUCKET_PATCH_ROWS:
-                    pad = _pad_k(len(rows)) - len(rows)
-                    rows = np.concatenate([rows, np.repeat(rows[:1], pad)])
-                    self._item_buckets = _patch_rows(
-                        self._item_buckets, rows,
-                        self.lsh.device_buckets(jnp.take(
-                            vecs, jnp.asarray(rows), axis=0)))
-                    self._item_buckets_version = version
-            if self._item_buckets is None \
-                    or self._item_buckets_version != version:
-                self.derived_rebuilds += 1
-                self._item_buckets = self.lsh.device_buckets(vecs)
-                self._item_buckets_version = version
-            return self._item_buckets
+            if self._step_bucket is None \
+                    or self._step_bucket_version != self.Y.partition_version \
+                    or self._step_live_src is not active:
+                table, step, version = self.Y.partition_layout()
+                # by the RESIDENT arrays' steps: a store that grew since
+                # the sync has steps the device does not hold yet
+                n_steps = int(active.shape[0]) // step
+                self._step_bucket = jnp.asarray(table[:n_steps])
+                self._step_bucket_version = version
+                if self._step_live_src is not active:
+                    self._step_live = _step_live_kernel(active, n_steps)
+                    self._step_live_src = active
+            return Pruning(self._step_bucket, self._step_live,
+                           self.lsh._device_hyperplanes(),
+                           np.int32(n_real))
 
-    def _lsh_mask(self, query_vec: np.ndarray | None, vecs, version, active):
-        if self._item_shards > 1 or self.lsh is None or query_vec is None \
-                or self.lsh.num_hashes == 0:
+    def _lsh_mask(self, query_vec: np.ndarray | None, active):
+        """``active`` held to the rows of the buckets inside the
+        query's Hamming ball: a row's bucket is its step's."""
+        if query_vec is None or not self._lsh_active():
             return active
-        buckets = self._cached_buckets(vecs, version)
-        return active & self.lsh.candidate_mask(query_vec, buckets)
+        table = self._pruning(active, 1).step_bucket   # -1: in no ball
+        ok = self.lsh.candidate_mask(query_vec, table)
+        return active & jnp.repeat(ok, active.shape[0] // table.shape[0])
 
     def top_n(self, how_many: int,
               user_vector: np.ndarray | None = None,
@@ -1771,9 +1800,7 @@ class ALSServingModel(FactorModelBase, ServingModel):
                 lsh_query = V.mean(axis=1)
             if lowest:
                 scores = -scores
-            use_lsh = use_lsh and self._route_use_lsh(int(vecs.shape[0]))
-            mask = self._lsh_mask(lsh_query if use_lsh else None, vecs,
-                                  version, active)
+            mask = self._lsh_mask(lsh_query if use_lsh else None, active)
             if mask is active:
                 mask = jnp.copy(active)
 
@@ -1828,9 +1855,10 @@ class ALSServingModel(FactorModelBase, ServingModel):
         ``exclude`` optionally gives per-request excluded item IDs.
         Rescorers/allowed-predicates take the single-request path.
 
-        On an LSH-configured model each query's Hamming-ball candidate
-        mask is fused into the same dispatch (per-query target buckets
-        computed on device).  ``use_lsh=False`` forces the exact scan.
+        On an LSH-configured model the same dispatch plans, on the
+        device, which steps of the bucket-laid-out store the window's
+        queries can reach, and scans those only.  ``use_lsh=False``
+        forces the exact scan.
 
         The batch dimension is zero-padded up to a power of two so the
         request micro-batcher's varying drain sizes hit a handful of
@@ -1865,23 +1893,23 @@ class ALSServingModel(FactorModelBase, ServingModel):
             # (1,F)x(F,N) matvec hits a much slower XLA path than a small
             # batched matmul, and zero rows are free
             b_pad = 1 << max(3, (n_req - 1).bit_length())
-            lsh_on = (use_lsh and self._lsh_active()
-                      and self._route_use_lsh(n_rows))
-            buckets = self._cached_buckets(vecs, version) if lsh_on \
-                else None
+            # a model laid out by bucket scans its candidates only, at
+            # every size: there is no flat pruned path
+            pruned = use_lsh and self._lsh_active()
             big, chunk = _stream_plan(n_rows, b_pad)
             bs = _BLOCK_ROWS
             ksel = _block_ksel(k, n_rows, bs)
-            streaming = big and n_rows % chunk == 0 and k <= chunk
-            twophase = streaming and _twophase_admits(k, ksel, vecs, bs)
+            streaming = pruned or (big and n_rows % chunk == 0
+                                   and k <= chunk)
+            twophase = streaming and _twophase_admits(k, ksel, vecs, bs) \
+                and (not pruned or self._lsh_step % bs == 0)
             attempted: list = []
+            reals: list | None = None
             if streaming:
                 # streaming path: static window shapes from the ladder
                 # (computed from the TRUE request count — a 257-query
                 # drain is [256, 8], not two full windows), dispatched
                 # async before ONE fetch
-                hp = self.lsh._device_hyperplanes() if lsh_on else None
-                mb = self.lsh.max_bits_differing if lsh_on else 0
                 sizes = _window_sizes(n_req)
                 padded = sum(sizes)
                 if n_req < padded:
@@ -1892,6 +1920,11 @@ class ALSServingModel(FactorModelBase, ServingModel):
                 for size in sizes:
                     windows.append(jnp.asarray(Q[w:w + size]))
                     w += size
+                if pruned:
+                    # the requests of each window: its padding rows
+                    # reach no bucket
+                    reals = [min(size, max(0, n_req - at)) for size, at
+                             in zip(sizes, np.cumsum([0] + sizes))]
                 if rec is not None:
                     # from the first program enqueued to the last result
                     # fetched: it waits on the device, and on whatever
@@ -1905,8 +1938,8 @@ class ALSServingModel(FactorModelBase, ServingModel):
                              lane_rows=0)
                 if twophase:
                     handles, attempted = self._dispatch_twophase(
-                        vecs, windows, active, version, buckets, hp, k,
-                        chunk, bs, ksel, mb)
+                        vecs, windows, active, version, reals, k, chunk,
+                        bs, ksel)
                     if rec is not None:
                         # known once each window's build is: a shape
                         # that did not lower ran the lax.scan build
@@ -1916,10 +1949,9 @@ class ALSServingModel(FactorModelBase, ServingModel):
                             for key in attempted))
                 else:
                     handles = [
-                        _batch_top_n_chunked_kernel(vecs, qw, active,
-                                                    buckets, hp, k, chunk,
-                                                    mb)
-                        for qw in windows]
+                        self._exact_scan(vecs, qw, active, k, chunk,
+                                         reals[w] if pruned else None)
+                        for w, qw in enumerate(windows)]
             else:
                 if b_pad != n_req:
                     Q = np.concatenate(
@@ -1929,24 +1961,21 @@ class ALSServingModel(FactorModelBase, ServingModel):
                 if rec is not None:
                     rec.mark("serving.scan", k=k, ksel=0, windows=[b_pad],
                              lane_rows=0)
-                if lsh_on:
-                    handles = _batch_top_n_lsh_kernel(
-                        vecs, Qd, active, buckets,
-                        self.lsh._device_hyperplanes(), k,
-                        self.lsh.max_bits_differing)
-                else:
-                    handles = _batch_top_n_kernel(vecs, Qd, active, k)
+                handles = _batch_top_n_kernel(vecs, Qd, active, k)
         # from here on the handles above are not touched again: a sync
         # may have donated them.  What a fallback needs it fetches anew
         # (_enqueue_exact), and answers from the version it finds
         if twophase:
             fetched = self._fetch_twophase(handles, attempted, windows, k,
-                                           chunk, bs, ksel, lsh_on)
-            for w, (ts, ti, cert) in enumerate(fetched):
+                                           chunk, bs, ksel, reals)
+            if pruned:
+                self._note_pruned(rec, [f[3] for f in fetched])
+            for w, (ts, ti, cert, *_) in enumerate(fetched):
                 if not cert.all():
                     # a genuine miss (the margin, or a head block
                     # the approx selection dropped) for some row;
-                    # recompute on the exact scan.  Count
+                    # recompute on the exact scan (a pruned window's:
+                    # over the same candidates).  Count
                     # per certificate-failing row, under the lock —
                     # batcher dispatcher threads race on this gauge.
                     rows_failed = int((~cert).sum())
@@ -1956,13 +1985,16 @@ class ALSServingModel(FactorModelBase, ServingModel):
                         rec.mark("serving.fallback", k=k,
                                  width=sizes[w],
                                  rows_failed=rows_failed)
-                    ts, ti = jax.device_get(self._enqueue_exact(
-                        windows[w], k, chunk, lsh_on))
+                    ts, ti, *_ = jax.device_get(self._enqueue_exact(
+                        windows[w], k, chunk,
+                        reals[w] if pruned else None))
                     fetched[w] = (ts, ti, None)
             top_scores = np.concatenate([f[0] for f in fetched])
             top_idx = np.concatenate([f[1] for f in fetched])
         elif streaming:
             fetched = jax.device_get(handles)
+            if pruned:
+                self._note_pruned(rec, [f[2] for f in fetched])
             top_scores = np.concatenate([f[0] for f in fetched])
             top_idx = np.concatenate([f[1] for f in fetched])
         else:
@@ -1975,6 +2007,24 @@ class ALSServingModel(FactorModelBase, ServingModel):
                                   k < n_rows, np.asarray(user_vectors,
                                                          np.float32),
                                   use_lsh)
+
+    def _note_pruned(self, rec, stats: list) -> None:
+        """Book a drain's pruned windows from their programs' ``stats``
+        ([steps visited, buckets in the union, live rows in them, live
+        rows in the store] each): the model's counters, and on the
+        recorder's open ``serving.scan`` phase the drain's own numbers,
+        summed over its windows."""
+        steps, buckets, rows, live = (int(v) for v in np.sum(stats, axis=0))
+        streamed = steps * self._lsh_step
+        with self._bucket_lock:
+            self.lsh_windows += len(stats)
+            self.lsh_candidate_rows += rows
+            self.lsh_streamed_rows += streamed
+        if rec is not None:
+            rec.annotate(lsh_buckets=buckets, lsh_candidate_rows=rows,
+                         lsh_steps=steps,
+                         lsh_streamed_share=round(
+                             100.0 * streamed / max(1, live), 3))
 
     @contextlib.contextmanager
     def _drain_snapshot(self, rec, n_req: int):
@@ -1998,38 +2048,40 @@ class ALSServingModel(FactorModelBase, ServingModel):
                 rec.mark("serving.prepare", rows=n_req)
             yield snap
 
-    def _enqueue_exact(self, qw, k: int, chunk: int, lsh_on: bool):
-        """Enqueue one window's exact chunked scan over the resident
-        arrays as they are NOW (its own acquisition of the dispatch
-        lock): the fallback of a drain whose first handles a sync may
-        have donated since."""
-        with self.Y.dispatching() as snap:
-            buckets, hp, mb = self._lsh_inputs(snap, lsh_on)
-            return _batch_top_n_chunked_kernel(
-                snap.vecs, qw, snap.active, buckets, hp, k, chunk, mb)
+    def _exact_scan(self, vecs, qw, active, k: int, chunk: int,
+                    n_real: int | None):
+        """Enqueue one window's exact scan (the dispatch lock held):
+        over the whole store, or with ``n_real`` (how many of a pruned
+        window's rows are requests) over its rows' candidates."""
+        if n_real is None:
+            return _batch_top_n_chunked_kernel(vecs, qw, active, k, chunk)
+        return _batch_top_n_pruned_exact_kernel(
+            vecs, qw, active, self._pruning(active, n_real), k,
+            self.lsh.max_bits_differing)
 
-    def _lsh_inputs(self, snap, lsh_on: bool) -> tuple:
-        """(buckets, hyperplanes, max bits differing) for a program
-        over ``snap``; (None, None, 0) with LSH off."""
-        if not lsh_on:
-            return None, None, 0
-        return (self._cached_buckets(snap.vecs, snap.version),
-                self.lsh._device_hyperplanes(),
-                self.lsh.max_bits_differing)
+    def _enqueue_exact(self, qw, k: int, chunk: int, n_real: int | None):
+        """Enqueue one window's exact scan (``_exact_scan``) over the
+        resident arrays as they are NOW (its own acquisition of the
+        dispatch lock): the fallback of a drain whose first handles a
+        sync may have donated since."""
+        with self.Y.dispatching() as snap:
+            return self._exact_scan(snap.vecs, qw, snap.active, k, chunk,
+                                    n_real)
 
     def _enqueue_scan_build(self, qw, k: int, chunk: int, bs: int,
-                            ksel: int, lsh_on: bool):
+                            ksel: int, n_real: int | None):
         """Enqueue one window's ``lax.scan`` two-phase build over the
         resident arrays as they are now (see ``_enqueue_exact``)."""
         with self.Y.dispatching() as snap:
-            buckets, hp, mb = self._lsh_inputs(snap, lsh_on)
             return self._dispatch_kind(
-                "scan", qw, snap.vecs, snap.active, snap.version, buckets,
-                hp, k, bs, ksel, mb, 1, {}, chunk=chunk)
+                "scan", qw, snap.vecs, snap.active, snap.version,
+                None if n_real is None
+                else self._pruning(snap.active, n_real),
+                k, bs, ksel, 1, {}, chunk=chunk)
 
-    def _dispatch_twophase(self, vecs, windows, active, version, buckets,
-                          hp, k: int, chunk: int, bs: int, ksel: int,
-                          mb: int) -> tuple[list, list]:
+    def _dispatch_twophase(self, vecs, windows, active, version,
+                          reals: list | None, k: int, chunk: int, bs: int,
+                          ksel: int) -> tuple[list, list]:
         """Enqueue every window's two-phase program (async; the caller
         holds the store's dispatch lock) and return the handles with
         the keys of the shapes attempted, for ``_fetch_twophase``.
@@ -2038,8 +2090,12 @@ class ALSServingModel(FactorModelBase, ServingModel):
         build cannot lower — routine on the CPU backend, a logged ERROR
         that lands in ``kernel_route.errors`` on a TPU
         (``pallas_failure_level``).  A drain may mix full windows and
-        one small tail window, and each shape stands or falls alone."""
+        one small tail window, and each shape stands or falls alone.
+        ``reals`` (how many rows of each window are requests) makes
+        every window a pruned one; None is the exact scan."""
         n_rows = int(vecs.shape[0])
+        pruned = reals is not None
+        mb = self.lsh.max_bits_differing if pruned else 0
         static_kinds, fold = self._phase_a_kinds(n_rows,
                                                  int(vecs.shape[1]), bs)
 
@@ -2049,8 +2105,7 @@ class ALSServingModel(FactorModelBase, ServingModel):
             # before the kind, which stays last)
             mesh = (self._item_shards,) if self._item_shards > 1 else ()
             return (n_rows, int(vecs.shape[1]), int(qw.shape[0]),
-                    str(vecs.dtype), buckets is not None, k, mb,
-                    *mesh, kind)
+                    str(vecs.dtype), pruned, k, mb, *mesh, kind)
 
         ctx: dict = {}
         handles, attempted = [], []
@@ -2059,20 +2114,18 @@ class ALSServingModel(FactorModelBase, ServingModel):
         # reordered by MEASURED ascending cost once measure_routes has
         # timed the live shape (config stops deciding, the stopwatch
         # does); invariant across a drain's windows
-        kinds = self._route_order(
-            [kk for kk in static_kinds
-             if kk != "ivf" or buckets is None],
-            n_rows, lsh_on=buckets is not None)
-        for qw in windows:
+        kinds = self._route_order(static_kinds, n_rows, lsh_on=pruned)
+        for w, qw in enumerate(windows):
             dispatched = False
+            prune = self._pruning(active, reals[w]) if pruned else None
             for kind in kinds:
                 key = key_of(qw, kind)
                 if _PALLAS_STATE.get(key) == "broken":
                     continue
                 try:
                     handles.append(self._dispatch_kind(
-                        kind, qw, vecs, active, version, buckets, hp,
-                        k, bs, ksel, mb, fold, ctx, chunk=chunk))
+                        kind, qw, vecs, active, version, prune, k, bs,
+                        ksel, fold, ctx, chunk=chunk))
                     attempted.append(key)
                     dispatched = True
                     break
@@ -2083,13 +2136,13 @@ class ALSServingModel(FactorModelBase, ServingModel):
                     _classify_pallas_failure([key], e)
             if not dispatched:
                 handles.append(self._dispatch_kind(
-                    "scan", qw, vecs, active, version, buckets, hp, k, bs,
-                    ksel, mb, fold, ctx, chunk=chunk))
+                    "scan", qw, vecs, active, version, prune, k, bs,
+                    ksel, fold, ctx, chunk=chunk))
         return handles, attempted
 
     def _fetch_twophase(self, handles: list, attempted: list, windows,
                         k: int, chunk: int, bs: int, ksel: int,
-                        lsh_on: bool) -> list:
+                        reals: list | None) -> list:
         """ONE fetch for the drain's two-phase programs, outside the
         dispatch lock."""
         try:
@@ -2105,21 +2158,28 @@ class ALSServingModel(FactorModelBase, ServingModel):
             # misattribution) and serve the drain on the scan build
             _classify_pallas_failure(fresh, e)
             return jax.device_get([
-                self._enqueue_scan_build(qw, k, chunk, bs, ksel, lsh_on)
-                for qw in windows])
+                self._enqueue_scan_build(
+                    qw, k, chunk, bs, ksel,
+                    None if reals is None else reals[w])
+                for w, qw in enumerate(windows)])
         for kk in attempted:
             _PALLAS_STATE[kk] = "ok"
         return out
 
     def _dispatch_kind(self, kind: str, qw, vecs, active, version,
-                       buckets, hp, k: int, bs: int, ksel: int, mb: int,
+                       prune: Pruning | None, k: int, bs: int, ksel: int,
                        fold: int, ctx: dict, chunk: int = 0):
         """Enqueue ONE window's phase-A build of the given kind and
         return its output handle(s) without blocking.  ``ctx`` caches
         the lazily-built device mirrors across windows of a drain (and
         across the router's timing repetitions).  Shared by the serving
-        dispatch, the measured-cost router, and the kernel probe — the
-        timed program must BE the served program."""
+        dispatch and the measured-cost router — the timed program must
+        BE the served program.  ``prune`` makes the window a pruned one:
+        the two builds that can skip steps take it (_phase_a_kinds
+        offers a model under LSH no other)."""
+        mb = self.lsh.max_bits_differing if prune is not None else 0
+        if prune is not None and kind not in ("pallas", "scan"):
+            raise ValueError(f"no pruned phase-A kind {kind!r}")
         if self._item_shards > 1:
             # the same two builds of the canonical store's phase A, on
             # every shard's own rows inside the SPMD program
@@ -2133,34 +2193,31 @@ class ALSServingModel(FactorModelBase, ServingModel):
         if kind == "i8_fold":
             if "i8_fold" not in ctx:
                 ctx["i8_fold"] = self._cached_i8_fold(
-                    vecs, active, buckets, version, fold, bs)
-            y8f, pen_i_f, bkt_f, sy_b, l1y_b = ctx["i8_fold"]
+                    vecs, active, version, fold, bs)
+            y8f, pen_i_f, sy_b, l1y_b = ctx["i8_fold"]
             return _batch_top_n_twophase_pallas_i8_fold(
-                vecs, y8f, sy_b, l1y_b, qw, pen_i_f, active, bkt_f,
-                buckets, hp, k, bs,
-                _i8_ksel(ksel, int(vecs.shape[0]), bs), mb, fold)
+                vecs, y8f, sy_b, l1y_b, qw, pen_i_f, active, k, bs,
+                _i8_ksel(ksel, int(vecs.shape[0]), bs), fold)
         if kind == "fold":
             if "fold" not in ctx:
                 ctx["fold"] = self._cached_fold(
-                    vecs, active, buckets, version, fold, bs)
-            yf, pen_f, bkt_f = ctx["fold"]
+                    vecs, active, version, fold, bs)
+            yf, pen_f = ctx["fold"]
             return _batch_top_n_twophase_pallas_fold(
-                vecs, yf, qw, pen_f, active, bkt_f, buckets, hp, k, bs,
-                ksel, mb, fold)
+                vecs, yf, qw, pen_f, active, k, bs, ksel, fold)
         if kind == "i8":
             if "i8" not in ctx:
                 ctx["i8"] = (self._cached_i8(vecs, version),
                              self._cached_penalty_i(active, version))
             (y8, sy_b, l1y_b), penalty_i = ctx["i8"]
             return _batch_top_n_twophase_pallas_i8(
-                vecs, y8, sy_b, l1y_b, qw, penalty_i, active, buckets,
-                hp, k, bs, _i8_ksel(ksel, int(vecs.shape[0]), bs), mb)
+                vecs, y8, sy_b, l1y_b, qw, penalty_i, active, k, bs,
+                _i8_ksel(ksel, int(vecs.shape[0]), bs))
         if kind == "pallas":
             if "penalty" not in ctx:
                 ctx["penalty"] = self._cached_penalty(active, version)
             return _batch_top_n_twophase_pallas(
-                vecs, qw, ctx["penalty"], active, buckets, hp, k, bs,
-                ksel, mb)
+                vecs, qw, ctx["penalty"], active, prune, k, bs, ksel, mb)
         if kind == "ivf":
             from . import ivf as _ivf
             if "ivf" not in ctx:
@@ -2171,7 +2228,7 @@ class ALSServingModel(FactorModelBase, ServingModel):
                 self._ann.cfg.nprobe)
         if kind == "scan":
             return _batch_top_n_twophase_kernel(
-                vecs, qw, active, buckets, hp, k, chunk, bs, ksel, mb)
+                vecs, qw, active, prune, k, chunk, bs, ksel, mb)
         raise ValueError(f"unknown phase-A kind {kind!r}")
 
     # -- measured-cost routing (kernel_router) -------------------------------
@@ -2195,6 +2252,13 @@ class ALSServingModel(FactorModelBase, ServingModel):
             tiles = (n_rows // self._item_shards) % _PA_TILE == 0
             return (["pallas"] if tiles else []) + ["scan"], 1
         eligible = n_rows % _PA_TILE == 0
+        if self._lsh_active():
+            # a store laid out by bucket: the two builds whose grid can
+            # be a list of steps.  No mirror is laid out by bucket, so
+            # none is offered, measured or built (the layout's step is
+            # the tile the model was built with)
+            tiles = eligible and self._lsh_step == _PA_TILE
+            return (["pallas"] if tiles else []) + ["scan"], 1
         want_i8 = self._int8_enabled()
         fold = _fold_eligible(width, self.features, bs) \
             if self._fold_enabled() else 1
@@ -2223,8 +2287,8 @@ class ALSServingModel(FactorModelBase, ServingModel):
                      lsh_on: bool = False) -> list[str]:
         """Reorder the eligible phase-A kinds by MEASURED ascending
         cost for the live shape — using THE DRAIN'S OWN variant's cost
-        table (the Hamming mask can invert the ranking between builds,
-        so an exact drain must not be ordered by masked costs).  Kinds
+        table (pruning can invert the ranking between builds, so an
+        exact drain must not be ordered by pruned costs).  Kinds
         without a measurement keep their static order after the
         measured ones.  No route yet (or a route for a different
         capacity) leaves the static order untouched."""
@@ -2239,17 +2303,6 @@ class ALSServingModel(FactorModelBase, ServingModel):
             return kinds
         measured.sort(key=lambda kk: costs[kk])
         return measured + [kk for kk in kinds if costs.get(kk) is None]
-
-    def _route_use_lsh(self, n_rows: int) -> bool:
-        """False when the measured route found the Hamming-mask build
-        slower than the exact scan for the live shape (VERDICT r5 Weak
-        #3: at 50f/20M the masked window cost ~1.6x the exact one, so
-        honoring the config made the configured-faster mode the slower
-        one).  Config semantics are preserved where LSH wins."""
-        r = self._route_current(n_rows)
-        if not r or r.get("use_lsh") is None:
-            return True
-        return bool(r["use_lsh"])
 
     def refresh_route(self, batch: int | None = None, m: int = 3,
                       force: bool = False) -> dict | None:
@@ -2367,8 +2420,8 @@ class ALSServingModel(FactorModelBase, ServingModel):
                     windows.append(kernels.replicate(Q[w:w + size]))
                     w += size
                 handles, attempted = self._dispatch_twophase(
-                    vecs, windows, active, snap.version, None, None, k,
-                    plan.chunk, plan.bs, plan.ksel, 0)
+                    vecs, windows, active, snap.version, None, k,
+                    plan.chunk, plan.bs, plan.ksel)
                 if rec is not None:
                     rec.annotate(lane_rows=sum(
                         _scores_rows_on_lanes(key[2]) for key in attempted
@@ -2378,7 +2431,7 @@ class ALSServingModel(FactorModelBase, ServingModel):
         else:
             fetched = self._fetch_twophase(
                 handles, attempted, windows, k, plan.chunk, plan.bs,
-                plan.ksel, False)
+                plan.ksel, None)
             failed = [~f[2] for f in fetched]       # (shards, B) a window
             with self._bucket_lock:
                 self.sharded_windows += len(fetched)

@@ -84,37 +84,54 @@ def warm_serving_shapes(features: int, items: int, dtype: str,
     ``ivf.AnnConfig``) additionally warms the IVF phase-A ladder —
     index shapes derive from ``ivf.mirror_shapes`` over the SAME
     ``planned_capacity`` that ``bulk_load`` obeys, so warmed shapes
-    stay lock-stepped with what a model load will build."""
+    stay lock-stepped with what a model load will build.
+
+    A ``sample_rate`` < 1 model's item store is laid out by LSH bucket
+    (its capacity is ``planned_capacity``'s for that layout: exact for
+    factors that hash evenly) and every window is a pruned one: the
+    ladder is the two builds that can skip steps (``pallas``, ``scan``)
+    and the exact scan over a window's candidates, each ONE program a
+    window shape however many steps a window visits (the grid's bound
+    is computed on the device), plus the exact scan over the whole
+    store that ``use_lsh=False`` callers take.  No mirror is built for
+    such a model, so none is warmed."""
     import jax.numpy as jnp
 
     from ..app.als import ivf as ivf_mod
     from ..app.als import serving_model as sm
     from ..app.als.feature_vectors import planned_capacity, resolve_dtype
-    from ..app.als.lsh import LocalitySensitiveHash, _bucket_kernel
+    from ..app.als.lsh import (LocalitySensitiveHash, _BUCKET_CHUNK,
+                               _bucket_kernel)
 
-    cap = planned_capacity(items)
+    lsh = (LocalitySensitiveHash(sample_rate, features)
+           if sample_rate < 1.0 else None)
+    lsh_on = lsh is not None and lsh.num_hashes > 0 \
+        and lsh.max_bits_differing < lsh.num_hashes
+    cap = planned_capacity(items, buckets=lsh.num_partitions,
+                           step=sm._PA_TILE) if lsh_on \
+        else planned_capacity(items)
     W = features if features >= 128 else 128
     dt = jnp.dtype(resolve_dtype(dtype))
     F = features
     k = min(sm._pad_k(how_many), cap)
     Y = _aval((cap, W), dt)
     A = _aval((cap,), jnp.bool_)
-    lsh = (LocalitySensitiveHash(sample_rate, F)
-           if sample_rate < 1.0 else None)
-    lsh_on = lsh is not None and lsh.num_hashes > 0 \
-        and lsh.max_bits_differing < lsh.num_hashes
-    variants: list[tuple] = [(None, None, 0)]
+    variants: list[tuple] = [(None, 0)]
     if lsh_on:
-        variants.append((_aval((cap,), jnp.int32),
-                         _aval((lsh.num_hashes, F), jnp.float32),
-                         lsh.max_bits_differing))
-        # item-matrix bucketing (model-load path: device_buckets pads
-        # the hyperplanes to the snapshot's lane width); the per-drain
-        # QUERY bucketing compiles inside each serving kernel above
+        n_steps = cap // sm._PA_TILE
+        variants.append((sm.Pruning(
+            _aval((n_steps,), jnp.int32), _aval((n_steps,), jnp.int32),
+            _aval((lsh.num_hashes, F), jnp.float32),
+            _aval((), jnp.int32)), lsh.max_bits_differing))
+        # item bucketing (model-load path: bulk_load hashes the host
+        # matrix a chunk of rows at a time, at its true width); the
+        # per-drain QUERY bucketing compiles inside each serving kernel
         _compile(report, f"{F}f/{items}: lsh_buckets", _bucket_kernel,
-                 _aval((cap, W), dt),
-                 _aval((lsh.num_hashes, W), jnp.float32),
+                 _aval((min(items, _BUCKET_CHUNK), F), dt),
+                 _aval((lsh.num_hashes, F), jnp.float32),
                  num_hashes=lsh.num_hashes)
+        _compile(report, f"{F}f/{items}: lsh_step_live",
+                 sm._step_live_kernel, A, n_steps=n_steps)
 
     big, chunk = sm._stream_plan(cap, sm._CHUNKED_BATCH)
     bs = sm._BLOCK_ROWS
@@ -130,6 +147,7 @@ def warm_serving_shapes(features: int, items: int, dtype: str,
     if twophase_ok:
         _compile(report, f"{tag}: penalty", sm._penalty_kernel, A,
                  bs=bs)
+    if twophase_ok and not lsh_on:
         _compile(report, f"{tag}: penalty_i8", sm._penalty_kernel_i32,
                  A, bs=bs)
         _compile(report, f"{tag}: quantize", sm._quantize_items_kernel,
@@ -140,10 +158,6 @@ def warm_serving_shapes(features: int, items: int, dtype: str,
             _compile(report, f"{tag}: fold_items_i8",
                      sm._fold_items_i8_kernel,
                      _aval((cap, W), jnp.int8), A, fold=fold, bs=bs)
-            if lsh_on:
-                _compile(report, f"{tag}: fold_buckets",
-                         sm._fold_buckets_kernel,
-                         _aval((cap,), jnp.int32), fold=fold, bs=bs)
 
     # single-request path (top_n): dot scores + masked top-k
     _compile(report, f"{tag}: dot_scores", sm._dot_scores, Y,
@@ -151,7 +165,7 @@ def warm_serving_shapes(features: int, items: int, dtype: str,
     _compile(report, f"{tag}: masked_top_k", sm._masked_top_k,
              _aval((cap,), jnp.float32), A, k=k)
 
-    if big:
+    if big or lsh_on:
         windows = sm._WINDOW_LADDER
     else:
         windows, b = [], 8
@@ -160,30 +174,49 @@ def warm_serving_shapes(features: int, items: int, dtype: str,
             b *= 2
     for w in windows:
         Q = _aval((w, F), jnp.float32)
-        for buckets, hp, mb in variants:
-            suffix = f" B={w}" + ("/lsh" if buckets is not None else "")
+        for prune, mb in variants:
+            suffix = f" B={w}" + ("/lsh" if prune is not None else "")
+            if prune is not None:
+                # the pruned ladder: the exact scan over the window's
+                # candidates, and the two builds that can skip steps
+                _compile(report, f"{tag}: pruned_exact{suffix}",
+                         sm._batch_top_n_pruned_exact_kernel, Y, Q, A,
+                         prune, k=k, max_bits=mb)
+                if not sm._twophase_admits(k, ksel, Y, bs):
+                    continue
+                _compile(report, f"{tag}: twophase_scan{suffix}",
+                         sm._batch_top_n_twophase_kernel, Y, Q, A, prune,
+                         k=k, chunk=chunk, bs=bs, ksel=ksel, max_bits=mb)
+                if cap % sm._PA_TILE == 0:
+                    _compile(report, f"{tag}: pallas{suffix}",
+                             sm._batch_top_n_twophase_pallas, Y, Q,
+                             _aval((cap // bs, bs), jnp.float32), A,
+                             prune, k=k, bs=bs, ksel=ksel, max_bits=mb)
+                continue
             if not big:
-                if buckets is None:
-                    _compile(report, f"{tag}: flat{suffix}",
-                             sm._batch_top_n_kernel, Y, Q, A, k=k)
-                else:
-                    _compile(report, f"{tag}: flat_lsh{suffix}",
-                             sm._batch_top_n_lsh_kernel, Y, Q, A,
-                             buckets, hp, k=k,
-                             max_bits=mb)
+                _compile(report, f"{tag}: flat{suffix}",
+                         sm._batch_top_n_kernel, Y, Q, A, k=k)
                 continue
             # streaming ladder: exact-scan fallback + scan build +
             # every pallas phase-A build the dispatch can route to
             _compile(report, f"{tag}: chunked_exact{suffix}",
-                     sm._batch_top_n_chunked_kernel, Y, Q, A, buckets,
-                     hp, k=k, chunk=chunk, max_bits=mb)
+                     sm._batch_top_n_chunked_kernel, Y, Q, A, k=k,
+                     chunk=chunk)
             if not twophase_ok:
                 continue
             _compile(report, f"{tag}: twophase_scan{suffix}",
-                     sm._batch_top_n_twophase_kernel, Y, Q, A, buckets,
-                     hp, k=k, chunk=chunk, bs=bs, ksel=ksel,
-                     max_bits=mb)
-            if (buckets is None and ann is not None and ann.enabled
+                     sm._batch_top_n_twophase_kernel, Y, Q, A, None,
+                     k=k, chunk=chunk, bs=bs, ksel=ksel)
+            if lsh_on:
+                # a model under LSH is offered pallas and scan only
+                # (serving_model._phase_a_kinds): no mirror to warm
+                if pallas_ok:
+                    _compile(report, f"{tag}: pallas{suffix}",
+                             sm._batch_top_n_twophase_pallas, Y, Q,
+                             _aval((cap // bs, bs), jnp.float32), A,
+                             None, k=k, bs=bs, ksel=ksel)
+                continue
+            if (ann is not None and ann.enabled
                     and cap // bs >= ann.cells):
                 # IVF phase-A ladder (exact variant only — the kind is
                 # never dispatched on masked drains).  The permuted
@@ -221,34 +254,31 @@ def warm_serving_shapes(features: int, items: int, dtype: str,
                 continue
             P = _aval((cap // bs, bs), jnp.float32)
             _compile(report, f"{tag}: pallas{suffix}",
-                     sm._batch_top_n_twophase_pallas, Y, Q, P, A,
-                     buckets, hp, k=k, bs=bs, ksel=ksel, max_bits=mb)
+                     sm._batch_top_n_twophase_pallas, Y, Q, P, A, None,
+                     k=k, bs=bs, ksel=ksel)
             ksel_i8 = sm._i8_ksel(ksel, cap, bs)
             _compile(report, f"{tag}: pallas_i8{suffix}",
                      sm._batch_top_n_twophase_pallas_i8, Y,
                      _aval((cap, W), jnp.int8),
                      _aval((cap // bs,), jnp.float32),
                      _aval((cap // bs,), jnp.float32), Q,
-                     _aval((cap // bs, bs), jnp.int32), A, buckets, hp,
-                     k=k, bs=bs, ksel=ksel_i8, max_bits=mb)
+                     _aval((cap // bs, bs), jnp.int32), A,
+                     k=k, bs=bs, ksel=ksel_i8)
             if fold > 1:
-                bkt_f = None if buckets is None else \
-                    _aval((fold, cap // bs, bs // fold), jnp.int32)
                 _compile(report, f"{tag}: pallas_fold{suffix}",
                          sm._batch_top_n_twophase_pallas_fold, Y,
                          _aval((cap // fold, W), dt), Q,
                          _aval((fold, cap // bs, bs // fold),
-                               jnp.float32), A, bkt_f, buckets, hp,
-                         k=k, bs=bs, ksel=ksel, max_bits=mb, fold=fold)
+                               jnp.float32), A,
+                         k=k, bs=bs, ksel=ksel, fold=fold)
                 _compile(report, f"{tag}: pallas_i8_fold{suffix}",
                          sm._batch_top_n_twophase_pallas_i8_fold, Y,
                          _aval((cap // fold, W), jnp.int8),
                          _aval((cap // bs,), jnp.float32),
                          _aval((cap // bs,), jnp.float32), Q,
                          _aval((fold, cap // bs, bs // fold),
-                               jnp.int32), A, bkt_f, buckets, hp,
-                         k=k, bs=bs, ksel=ksel_i8, max_bits=mb,
-                         fold=fold)
+                               jnp.int32), A,
+                         k=k, bs=bs, ksel=ksel_i8, fold=fold)
 
 
 def _warm_training(ratings: int, rank: int, sample_rate: float,

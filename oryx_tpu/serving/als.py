@@ -155,9 +155,9 @@ def _dot_top_n(req: Request, model: ALSServingModel, how_many: int,
                rescorer) -> list[tuple[str, float]]:
     """Dot-product top-N, coalesced with concurrent requests through the
     app-scope TopNBatcher unless a rescorer plugin forces the exact
-    single-request path.  LSH-configured models batch too: per-query
-    Hamming-ball masks are fused into the shared dispatch
-    (ALSServingModel.top_n_batch)."""
+    single-request path.  LSH-configured models batch too: the shared
+    dispatch scans the union of its queries' Hamming balls, each query
+    held to its own (ALSServingModel.top_n_batch)."""
     batcher = req.context.get("top_n_batcher")
     if batcher is not None and rescorer is None:
         # the front-end deadline rides into the batcher queue: expired

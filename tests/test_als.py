@@ -651,19 +651,21 @@ def test_top_n_batch_lsh_matches_single():
 
 
 def test_top_n_batch_chunked_lsh(monkeypatch):
+    """Where the two-phase program is not admitted, a model under LSH
+    answers by the exact scan over the window's candidates: the same
+    answers."""
     from oryx_tpu.app.als import serving_model as sm
     rng = np.random.default_rng(6)
     ni, k = 1800, 8
+    monkeypatch.setattr(sm, "_PA_TILE", 128)
     model = ALSServingModel(k, implicit=True, sample_rate=0.3)
     model.Y.bulk_load([f"i{j}" for j in range(ni)],
                       rng.standard_normal((ni, k)).astype(np.float32))
     Q = rng.standard_normal((3, k)).astype(np.float32)
-    flat = model.top_n_batch(5, Q)
-    monkeypatch.setattr(sm, "_FLAT_SCORES_LIMIT", 1)
-    monkeypatch.setattr(sm, "_MAX_CHUNK_ROWS", 256)
+    two = model.top_n_batch(5, Q)
     _exact_scan_only(monkeypatch)
     chunked = model.top_n_batch(5, Q)
-    for f, c in zip(flat, chunked):
+    for f, c in zip(two, chunked):
         assert [i for i, _ in f] == [i for i, _ in c]
         np.testing.assert_allclose([s for _, s in f], [s for _, s in c],
                                    rtol=1e-5)
@@ -690,14 +692,16 @@ def test_top_n_batch_twophase_matches_flat(monkeypatch):
         assert [i for i, _ in f] == [i for i, _ in c]
         np.testing.assert_allclose([s for _, s in f], [s for _, s in c],
                                    rtol=1e-5)
-    # LSH masks fuse into both phases
+    # a model under LSH: the two-phase scan over the candidates' steps
+    # against the single-request path's mask over the whole store
+    monkeypatch.setattr(sm, "_PA_TILE", 64)
     model2 = ALSServingModel(k, implicit=True, sample_rate=0.3)
     model2.Y.bulk_load([f"i{j}" for j in range(ni)], Y)
     lsh_two = model2.top_n_batch(6, Q)
-    monkeypatch.undo()
-    lsh_flat = model2.top_n_batch(6, Q)
-    for f, c in zip(lsh_flat, lsh_two):
-        assert [i for i, _ in f] == [i for i, _ in c]
+    assert model2.lsh_windows == 1 and model2.twophase_fallbacks == 0
+    for b, c in enumerate(lsh_two):
+        single = model2.top_n(6, user_vector=Q[b])
+        assert [i for i, _ in single] == [i for i, _ in c]
 
 
 def test_top_n_batch_twophase_cert_fallback(monkeypatch):
@@ -917,16 +921,13 @@ def test_phase_b_in_row_groups_returns_what_one_gather_returns(
     act = np.ones(n, bool)
     act[::7] = False
     active = jnp.asarray(act)
-    buckets = hp = None
-    if lsh:
-        hp = jnp.asarray(rng.standard_normal((4, f)).astype(np.float32))
-        buckets = sm._query_buckets(Y, hp)
+    prune = _toy_pruning(rng, active, n // 512, f, b) if lsh else None
 
     def program():
         # a fresh jit each time: the budget is read at trace time
         return jax.device_get(jax.jit(
             lambda: sm._batch_top_n_twophase_kernel.__wrapped__(
-                Y, Q, active, buckets, hp, k, 1024, bs, ksel, 2))())
+                Y, Q, active, prune, k, 1024, bs, ksel, 2)[:3])())
 
     whole = program()
     assert sm._phase_b_group_rows(b, ksel, bs, f * 4) == b
@@ -938,6 +939,25 @@ def test_phase_b_in_row_groups_returns_what_one_gather_returns(
         assert w.shape == g.shape and w.dtype == g.dtype
         np.testing.assert_array_equal(w, g)
     assert whole[2].all()
+
+
+def _toy_pruning(rng, active, n_steps: int, f: int, n_real: int):
+    """What a pruned window's program takes beside the store, for a toy
+    store of ``n_steps`` steps: 4 hyperplanes, every step given one of
+    the 16 buckets at random (step 1 to nobody).  The kernels only read
+    WHICH steps a window's rows can reach, so the rows of a step need
+    not hash to its bucket here."""
+    import jax.numpy as jnp
+
+    from oryx_tpu.app.als import serving_model as sm
+
+    table = rng.integers(0, 16, n_steps).astype(np.int32)
+    table[1] = -1
+    return sm.Pruning(
+        jnp.asarray(table),
+        sm._step_live_kernel(active, n_steps),
+        jnp.asarray(rng.standard_normal((4, f)).astype(np.float32)),
+        np.int32(n_real))
 
 
 def _phase_a_case(n, f, b, lsh, seed=11, integers=False):
@@ -964,11 +984,9 @@ def _phase_a_case(n, f, b, lsh, seed=11, integers=False):
     act[1::5] = False
     assert act[last]
     Y, Q, active = jnp.asarray(y), jnp.asarray(q), jnp.asarray(act)
-    buckets = hp = None
-    if lsh:
-        hp = jnp.asarray(rng.standard_normal((4, f)).astype(np.float32))
-        buckets = sm._query_buckets(Y, hp)
-    return Y, Q, active, buckets, hp, last
+    prune = _toy_pruning(rng, active, n // sm._PA_TILE, f, b - 1) \
+        if lsh else None
+    return Y, Q, active, prune, last
 
 
 @pytest.mark.parametrize("rows", ["whole_output_tiles",
@@ -994,15 +1012,20 @@ def test_pallas_phase_a_interpret_agrees_with_scan_kernel(b, lsh, rows):
     assert (n // bs % 128 == 0) == (rows == "whole_output_tiles")
     f, k, ksel, mb = 16, 8, 16, 2 if lsh else 0
     assert sm._scores_rows_on_lanes(b) == (b < 128)
-    Y, Q, active, buckets, hp, last = _phase_a_case(n, f, b, lsh)
+    Y, Q, active, prune, last = _phase_a_case(n, f, b, lsh)
     penalty = sm._penalty_kernel(active, bs)
-    ts_p, ti_p, cert_p = jax.device_get(
+    ts_p, ti_p, cert_p, *stats_p = jax.device_get(
         sm._batch_top_n_twophase_pallas(
-            Y, Q, penalty, active, buckets, hp, k, bs, ksel, mb,
+            Y, Q, penalty, active, prune, k, bs, ksel, mb,
             interpret=True))
-    ts_s, ti_s, cert_s = jax.device_get(
+    ts_s, ti_s, cert_s, *stats_s = jax.device_get(
         sm._batch_top_n_twophase_kernel(
-            Y, Q, active, buckets, hp, k, sm._PA_TILE, bs, ksel, mb))
+            Y, Q, active, prune, k, sm._PA_TILE, bs, ksel, mb))
+    if lsh:
+        # the plan's numbers, and the padding row reaches nothing
+        np.testing.assert_array_equal(stats_p[0], stats_s[0])
+        assert 0 < stats_p[0][0] < n // sm._PA_TILE
+        assert np.isneginf(ts_p[-1]).all()
     np.testing.assert_allclose(ts_p, ts_s, rtol=1e-5)
     assert (ti_p == ti_s).all()
     assert (cert_p == cert_s).all()
@@ -1021,31 +1044,46 @@ def test_pallas_phase_a_layouts_hand_over_the_same_block_maxima(lsh):
     the layouts as it does between any two builds; phase B's margin
     covers that)."""
     import jax
+    import jax.numpy as jnp
 
     from oryx_tpu.app.als import serving_model as sm
 
     n, f, b, bs = 5 * sm._PA_TILE, 16, 8, 128
-    Y, Q, active, buckets, hp, last = _phase_a_case(n, f, b, lsh,
-                                                    integers=True)
+    Y, Q, active, prune, last = _phase_a_case(n, f, b, lsh,
+                                              integers=True)
     # a block with no live row reads -inf in both
     active = active.at[2 * bs:3 * bs].set(False)
     penalty = sm._penalty_kernel(active, bs)
     Qc = sm._q_cast(Q, Y)
-    target = sm._query_buckets(Q, hp) if lsh else None
+    # a pruned pass: steps 3, 0 and 2, in that order, of the five
+    steps, n_visit = (jnp.asarray([3, 0, 2, 1, 4], jnp.int32),
+                      jnp.int32(3)) if lsh else (None, None)
     on_lanes, on_sublanes = (
         np.asarray(jax.device_get(sm._pallas_block_maxima(
-            Qc, Y, penalty, buckets, target, bs, 2, layout,
-            interpret=True))) for layout in (True, False))
+            Qc, Y, penalty, bs, layout, True, steps, n_visit)))
+        for layout in (True, False))
     assert on_lanes.shape == on_sublanes.shape == (b, n // bs)
     np.testing.assert_array_equal(on_lanes, on_sublanes)
-    assert np.isneginf(on_lanes[:, 2]).all()
     assert np.isfinite(on_lanes).any()
     want = np.where(np.asarray(active)[None],
-                    np.asarray(Q) @ np.asarray(Y).T, -np.inf)
+                    np.asarray(Q) @ np.asarray(Y).T, -np.inf
+                    ).reshape(b, -1, bs).max(-1)
+    per_step = sm._PA_TILE // bs
     if not lsh:
+        assert np.isneginf(on_lanes[:, 2]).all()
+        np.testing.assert_array_equal(on_lanes, want)
+        assert on_lanes[0, last // bs] == want[0, last // bs]
+    else:
+        # the maxima leave in VISIT order, -inf past the last visited
+        # step; the scan build hands over the same
+        by_step = want.reshape(b, -1, per_step)
         np.testing.assert_array_equal(
-            on_lanes, want.reshape(b, -1, bs).max(-1))
-        assert on_lanes[0, last // bs] == want[0, last]
+            on_lanes.reshape(b, -1, per_step)[:, :3],
+            by_step[:, [3, 0, 2]])
+        assert np.isneginf(on_lanes[:, 3 * per_step:]).all()
+        np.testing.assert_array_equal(
+            on_lanes, np.asarray(sm._scan_step_maxima(
+                Qc, Y, active, steps, n_visit, bs)))
 
 
 def test_pallas_fallback_on_unsupported_backend():
@@ -1100,7 +1138,7 @@ def test_certificate_passes_when_all_unselected_blocks_masked():
     act = np.zeros(n, bool)
     act[:ksel * bs] = True
     ts, ti, cert = jax.device_get(sm._batch_top_n_twophase_kernel(
-        Y, Q, jnp.asarray(act), None, None, k, 256, bs, ksel, 0))
+        Y, Q, jnp.asarray(act), None, k, 256, bs, ksel))
     assert cert.all(), cert
 
 
@@ -1230,8 +1268,8 @@ def test_int8_twophase_matches_oracle_interpret():
     sm._PA_TILE = 1024
     try:
         ts, ti, cert = sm._batch_top_n_twophase_pallas_i8(
-            Y, y8, sy_b, l1y_b, Q, pen_i, active, None, None,
-            k=k, bs=bs, ksel=ksel, max_bits=0, interpret=True)
+            Y, y8, sy_b, l1y_b, Q, pen_i, active,
+            k=k, bs=bs, ksel=ksel, interpret=True)
     finally:
         sm._PA_TILE = old_tile
     want_s, want_i = sm._batch_top_n_kernel(Y, Q, active, k)
@@ -1335,8 +1373,8 @@ def test_int8_certificate_passes_on_zero_padded_rows():
     sm._PA_TILE = 1024
     try:
         ts, ti, cert = sm._batch_top_n_twophase_pallas_i8(
-            Y, y8, sy_b, l1y_b, jnp.asarray(Q), pen_i, active, None,
-            None, k=k, bs=bs, ksel=ksel, max_bits=0, interpret=True)
+            Y, y8, sy_b, l1y_b, jnp.asarray(Q), pen_i, active,
+            k=k, bs=bs, ksel=ksel, interpret=True)
     finally:
         sm._PA_TILE = old_tile
     assert np.asarray(cert)[3:].all()  # padding rows always certify
@@ -1355,11 +1393,8 @@ def test_fold_mirror_layout_matches_numpy():
     Y = np.zeros((N, W), np.float32)
     Y[:, :F] = rng.standard_normal((N, F)).astype(np.float32)
     act = rng.random(N) > 0.2
-    bkt = rng.integers(0, 16, N).astype(np.int32)
     yf, pen_f = jax.device_get(sm._fold_items_kernel(
         jnp.asarray(Y), jnp.asarray(act), fold, bs))
-    bkt_f = jax.device_get(sm._fold_buckets_kernel(
-        jnp.asarray(bkt), fold, bs))
     assert yf.shape == (N // fold, W)
     for i in range(0, N // fold, 37):
         for j in range(fold):
@@ -1367,19 +1402,16 @@ def test_fold_mirror_layout_matches_numpy():
                                           Y[i * fold + j, :w])
     pen = np.where(act, 0.0, -np.inf).astype(np.float32)
     assert pen_f.shape == (fold, N // bs, bs // fold)
-    assert bkt_f.shape == (fold, N // bs, bs // fold)
     for j in range(fold):
         np.testing.assert_array_equal(
             pen_f[j].reshape(-1), pen.reshape(-1, fold)[:, j])
-        np.testing.assert_array_equal(
-            bkt_f[j].reshape(-1), bkt.reshape(-1, fold)[:, j])
 
 
 def test_fold_pallas_interpret_agrees_with_scan_kernel():
     """The folded phase-A program (pallas interpret mode) must produce
-    the same top-k and certificates as the lax.scan build, with and
-    without the LSH mask — phase B is shared, so this pins the folded
-    block maxima to the canonical ones."""
+    the same top-k and certificates as the lax.scan build — phase B is
+    shared, so this pins the folded block maxima to the canonical
+    ones."""
     import jax
     import jax.numpy as jnp
     from oryx_tpu.app.als import serving_model as sm
@@ -1397,25 +1429,20 @@ def test_fold_pallas_interpret_agrees_with_scan_kernel():
     act = np.ones(N, bool)
     act[::7] = False
     active = jnp.asarray(act)
-    bkt = jnp.asarray(rng.integers(0, 8, N).astype(np.int32))
-    hp = jnp.asarray(rng.standard_normal((3, W)).astype(np.float32))
     old_tile = sm._PA_TILE
     sm._PA_TILE = 2048
     try:
-        for buckets, hyp, mb in ((None, None, 0), (bkt, hp, 1)):
-            yf, pen_f = sm._fold_items_kernel(Yj, active, fold, bs)
-            bkt_f = sm._fold_buckets_kernel(buckets, fold, bs) \
-                if buckets is not None else None
-            ts_f, ti_f, cert_f = jax.device_get(
-                sm._batch_top_n_twophase_pallas_fold(
-                    Yj, yf, Q, pen_f, active, bkt_f, buckets, hyp,
-                    k, bs, ksel, mb, fold, interpret=True))
-            ts_s, ti_s, cert_s = jax.device_get(
-                sm._batch_top_n_twophase_kernel(
-                    Yj, Q, active, buckets, hyp, k, 2048, bs, ksel, mb))
-            np.testing.assert_allclose(ts_f, ts_s, rtol=1e-5)
-            np.testing.assert_array_equal(ti_f, ti_s)
-            np.testing.assert_array_equal(cert_f, cert_s)
+        yf, pen_f = sm._fold_items_kernel(Yj, active, fold, bs)
+        ts_f, ti_f, cert_f = jax.device_get(
+            sm._batch_top_n_twophase_pallas_fold(
+                Yj, yf, Q, pen_f, active, k, bs, ksel, fold,
+                interpret=True))
+        ts_s, ti_s, cert_s = jax.device_get(
+            sm._batch_top_n_twophase_kernel(
+                Yj, Q, active, None, k, 2048, bs, ksel))
+        np.testing.assert_allclose(ts_f, ts_s, rtol=1e-5)
+        np.testing.assert_array_equal(ti_f, ti_s)
+        np.testing.assert_array_equal(cert_f, cert_s)
     finally:
         sm._PA_TILE = old_tile
 

@@ -267,10 +267,9 @@ def test_served_scores_keep_the_stores_precision():
                 Y, jax.ShapeDtypeStruct((50, 2), jnp.float32)),
             "flat": sm._batch_top_n_kernel.lower(Y, Q, A, k=k),
             "chunked_exact": sm._batch_top_n_chunked_kernel.lower(
-                Y, Q, A, None, None, k=k, chunk=512, max_bits=0),
+                Y, Q, A, k=k, chunk=512),
             "twophase_scan": sm._batch_top_n_twophase_kernel.lower(
-                Y, Q, A, None, None, k=k, chunk=512, bs=128, ksel=4,
-                max_bits=0),
+                Y, Q, A, None, k=k, chunk=512, bs=128, ksel=4),
         }
         return {name: low.as_text().count("HIGHEST")
                 for name, low in texts.items()}

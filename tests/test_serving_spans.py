@@ -513,6 +513,7 @@ TWOPHASE_BUILDS = ["_batch_top_n_twophase_pallas",
                    "_batch_top_n_twophase_pallas_i8_fold",
                    "_batch_top_n_twophase_kernel"]
 EXACT_SCAN = "_batch_top_n_chunked_kernel"
+PRUNED_EXACT_SCAN = "_batch_top_n_pruned_exact_kernel"
 
 
 def _scan_programs_called_by(method) -> set[str]:
@@ -538,42 +539,67 @@ def test_the_exact_ladder_enqueues_only_the_named_programs():
         == set()
     assert _scan_programs_called_by(ALSServingModel._sharded_top_n_batch) \
         == set()
-    assert _scan_programs_called_by(ALSServingModel.top_n_batch) \
-        >= {EXACT_SCAN}
+    # the exact scan, over the whole store or (a model under LSH) over
+    # a window's candidates, is enqueued in one place
+    assert _scan_programs_called_by(ALSServingModel._exact_scan) \
+        == {EXACT_SCAN, PRUNED_EXACT_SCAN}
     assert not any("twophase" in name for name in
-                   _scan_programs_called_by(ALSServingModel.top_n_batch))
+                   _scan_programs_called_by(ALSServingModel.top_n_batch)
+                   | _scan_programs_called_by(ALSServingModel._exact_scan))
 
 
 @pytest.mark.parametrize("name, pattern",
                          [(n, "twophase") for n in TWOPHASE_BUILDS]
-                         + [(EXACT_SCAN, "chunked_kernel")])
+                         + [(EXACT_SCAN, "chunked_kernel"),
+                            (PRUNED_EXACT_SCAN, "pruned_exact_kernel")])
 def test_each_scan_program_is_jitted_under_its_own_name(name, pattern):
     """The device trace names a program ``jit_<function name>(<hash>)``:
     the wrapper must be ONE jitted function carrying that name."""
     fn = getattr(sm, name)
     assert hasattr(fn, "lower"), f"{name} is not a jitted function"
     assert fn.__name__ == name and pattern in name
-    assert ("twophase" in name) != ("chunked_kernel" in name)
+    assert ("twophase" in name) != ("chunked_kernel" in name
+                                    or "exact_kernel" in name)
 
 
+def _toy_pruning(rows: int, n_real: int):
+    """A pruned window's side inputs over ``rows`` zero rows: 3
+    hyperplanes, every step in bucket 0."""
+    n_steps = rows // sm._PA_TILE
+    return sm.Pruning(jnp.zeros((n_steps,), jnp.int32),
+                      jnp.full((n_steps,), sm._PA_TILE, jnp.int32),
+                      jnp.ones((3, FEATURES), jnp.float32),
+                      np.int32(n_real))
+
+
+@pytest.mark.parametrize("pruned", [False, True], ids=["exact", "pruned"])
 @pytest.mark.parametrize("width", sm._WINDOW_LADDER)
 def test_the_pallas_build_is_one_named_program_at_every_ladder_width(
-        width):
+        width, pruned):
     """Whichever way phase A lays the window's scores (the store's rows
     on the lanes for the ladder's 8 and 32, the queries there for 256),
     kernel, transposition if any and phase B are ONE jitted program
-    under the name the device metrics match."""
+    under the name the device metrics match; and a model under LSH
+    enqueues the same two names (ISSUE 36: the plan of the steps to
+    visit, the pass over them and phase B are one program whose name
+    holds ``twophase``)."""
     rows, bs, k = 4 * sm._PA_TILE, 128, 8
     ksel = sm._block_ksel(k, rows, bs)
     Y = jnp.zeros((rows, FEATURES), jnp.float32)
     Q = jnp.zeros((width, FEATURES), jnp.float32)
     active = jnp.ones((rows,), bool)
+    prune = _toy_pruning(rows, width) if pruned else None
     text = sm._batch_top_n_twophase_pallas.lower(
-        Y, Q, sm._penalty_kernel(active, bs), active, None, None, k, bs,
-        ksel, 0, interpret=True).as_text()
+        Y, Q, sm._penalty_kernel(active, bs), active, prune, k, bs,
+        ksel, 1 if pruned else 0, interpret=True).as_text()
     assert "@jit__batch_top_n_twophase_pallas" in text[:200]
     assert text.count("func.func public") == 1
     assert sm._scores_rows_on_lanes(width) == (width < 128)
+    if pruned:
+        scan = sm._batch_top_n_twophase_kernel.lower(
+            Y, Q, active, prune, k, 0, bs, ksel, 1).as_text()
+        assert "@jit__batch_top_n_twophase_kernel" in scan[:200]
+        assert scan.count("func.func public") == 1
 
 
 @pytest.mark.parametrize("k, rows_at_once", [(8, 8), (64, 8), (64, 2)])
@@ -593,7 +619,7 @@ def test_the_lowered_programs_carry_the_names_the_trace_shows(
     Q = jnp.zeros((8, FEATURES), jnp.float32)
     active = jnp.ones((rows,), bool)
     two = sm._batch_top_n_twophase_kernel.lower(
-        Y, Q, active, None, None, k, 256, bs, ksel, 0)
+        Y, Q, active, None, k, 256, bs, ksel)
     assert "@jit__batch_top_n_twophase_kernel" in two.as_text()[:200]
     # the floor width's program is as it was; a wider selection reads
     # the block maxima row-major (``_selects_row_major``)
@@ -602,5 +628,5 @@ def test_the_lowered_programs_carry_the_names_the_trace_shows(
     assert two.as_text().count("stablehlo.while") \
         == (1 if rows_at_once == 8 else 2)
     exact = sm._batch_top_n_chunked_kernel.lower(
-        Y, Q, active, None, None, k, 256, 0)
+        Y, Q, active, k, 256)
     assert "@jit__batch_top_n_chunked_kernel" in exact.as_text()[:200]

@@ -288,17 +288,17 @@ def _phase_a_builds(dtype):
         Q = jnp.zeros((b, f), jnp.float32)
         out[f"pallas-{b}"] = jax.make_jaxpr(
             lambda Y, Q: sm._batch_top_n_twophase_pallas(
-                Y, Q, pen, act, None, None, k, bs, ksel, 0))(Y, Q)
+                Y, Q, pen, act, None, k, bs, ksel))(Y, Q)
     Q = jnp.zeros((8, f), jnp.float32)
     out["scan"] = jax.make_jaxpr(
         lambda Y, Q: sm._batch_top_n_twophase_kernel(
-            Y, Q, act, None, None, k, 1024, bs, ksel, 0))(Y, Q)
+            Y, Q, act, None, k, 1024, bs, ksel))(Y, Q)
     fold = 2
     Yf, pen_f = sm._fold_items_kernel(Y, act, fold, bs)
     out["fold"] = jax.make_jaxpr(
         lambda Y, Yf, Q: sm._batch_top_n_twophase_pallas_fold(
-            Y, Yf, Q[:, :f // fold], pen_f, act, None, None, None, k, bs,
-            ksel, 0, fold))(Y, Yf, Q)
+            Y, Yf, Q[:, :f // fold], pen_f, act, k, bs, ksel,
+            fold))(Y, Yf, Q)
     from jax.sharding import Mesh
     mesh = Mesh(np.array(jax.devices()[:2]), ("items",))
     plan = sm.ShardPlan(ksel, 1024, bs)
