@@ -99,9 +99,16 @@ def _time_exec_ms(dispatch, fetch, m: int) -> float:
 
 def _pruning(model, active, lsh_on: bool):
     """What the pruned variant's program takes beside the store (None
-    for the exact one): the measured window holds ONE request, so the
-    pass it times streams one Hamming ball, the least a window can."""
-    return model._pruning(active, 1) if lsh_on else None
+    for the exact one)."""
+    return model._pruning(active) if lsh_on else None
+
+
+def _n_real(batch: int, lsh_on: bool) -> int:
+    """How many rows of the measured window are requests.  The pruned
+    variant's holds ONE, so the pass it times streams one Hamming ball,
+    the least a window can (and phase B rescores one row); every row of
+    the exact variant's is one, so its cost is a full window's."""
+    return 1 if lsh_on else batch
 
 
 def measure_routes(model, batch: int | None = None,
@@ -214,7 +221,8 @@ def measure_routes(model, batch: int | None = None,
                         lambda: (faults.fire(point),
                                  model._dispatch_kind(
                                      kind, Q, vecs, active, version,
-                                     prune, k, bs, ksel, fold, ctx,
+                                     prune, _n_real(batch, lsh_on), k,
+                                     bs, ksel, fold, ctx,
                                      chunk=chunk))[1],
                         jax.device_get, m), 3)
                     sm._PALLAS_STATE[key] = "ok"
@@ -290,8 +298,9 @@ def measure_routes(model, batch: int | None = None,
         try:
             jax.device_get(model._dispatch_kind(
                 route["chosen"], Q, vecs, active, version,
-                _pruning(model, active, serving_lsh), k, bs, ksel, fold,
-                {}, chunk=chunk))
+                _pruning(model, active, serving_lsh),
+                _n_real(batch, serving_lsh), k, bs, ksel, fold, {},
+                chunk=chunk))
         except Exception as e:  # noqa: BLE001 — never a load gate,
             # but the build that just measured fastest failing to run
             # again must not pass silently

@@ -80,13 +80,16 @@ _MAX_CHUNK_ROWS = 1 << 17
 # memory_bw (~240 on v5e), so the full window costs the same device
 # time as pow2 buckets would — and the 20M x 250 scan kernel compiles
 # once per LADDER size, not once per drain-size bucket.  The ladder's
-# small windows exist for latency: the per-window cost has a large
-# B-proportional component (phase B's gather and rescoring of ksel
-# blocks a query; the pass over the store costs the same at every
-# width, PERF.md section 5, PR 33), so an idle server's lone request
-# on an 8-window pays a few ms less than the full 256-window (VERDICT
-# r04: the 50f/20M LSH cell's unloaded p50 lost to the baseline purely
-# on window padding).
+# small windows exist for latency: the pass over the store costs the
+# same at every width (PERF.md section 5, PR 33), but phase B selects,
+# for every row of the WINDOW, ksel blocks out of the block maxima and
+# sorts their scores, so an idle server's lone request on an 8-window
+# pays a few ms less than on the full 256-window (VERDICT r04: the
+# 50f/20M LSH cell's unloaded p50 lost to the baseline purely on window
+# padding).  What moves bytes in proportion to rows, phase B's gather
+# and rescoring of those blocks, runs over the rows that are REQUESTS
+# (_rescores_requests): the zero rows a narrow window is padded with
+# are free there.
 _CHUNKED_BATCH = 256
 _WINDOW_LADDER = (8, 32, 256)
 
@@ -127,7 +130,7 @@ def _score_precision(Y):
     accumulation either way, and the CPU backend ignores the setting.
     Phase A of the two-phase scan only SELECTS blocks, but its maxima
     are what the certificate holds the k-th served score against
-    (_phase_b_rows' ``m_guard``, 1e-4 relative), so on a float32 store
+    (_phase_b's ``m_guard``, 1e-4 relative), so on a float32 store
     it multiplies at HIGHEST too: a one-pass product's maxima lie up to
     5.4e-3 off (PERF.md section 5, PR 34), and a certificate that
     passed on those would prove nothing about an unselected block.
@@ -183,22 +186,21 @@ class Pruning(NamedTuple):
     step_bucket: jax.Array   # (steps,) int32: bucket of each store step
     step_live: jax.Array     # (steps,) int32: live rows of each step
     hyperplanes: jax.Array   # (hashes, features) float32
-    n_real: jax.Array        # () int32: rows of the window that are
-    #                          requests (the rest is padding)
 
 
-def _visit_plan(Q, prune: Pruning, max_bits: int):
+def _visit_plan(Q, prune: Pruning, n_real, max_bits: int):
     """Which steps of the store a window visits: the steps of every
-    bucket inside the Hamming ball of some REAL query row's bucket
-    (padding rows add nothing).  Returns ``steps`` (every step of the
-    store, the visited ones first, in store order), ``n_visit`` (how
-    many are visited: phase A's grid bound), ``step_ok`` ((B, steps) in
-    visit order: whether the step's bucket is a candidate of the row)
+    bucket inside the Hamming ball of some REAL query row's bucket (the
+    first ``n_real`` rows, the scalar phase B reads too; padding rows
+    add nothing).  Returns ``steps`` (every step of the store, the
+    visited ones first, in store order), ``n_visit`` (how many are
+    visited: phase A's grid bound), ``step_ok`` ((B, steps) in visit
+    order: whether the step's bucket is a candidate of the row)
     and ``stats`` (int32 [steps visited, buckets in the union, live
     rows in them, live rows in the store])."""
     b = Q.shape[0]
     target = _query_buckets(Q, prune.hyperplanes)
-    real = jnp.arange(b) < prune.n_real
+    real = jnp.arange(b) < n_real
     # (B, buckets): the buckets inside each real row's ball ...
     n_buckets = 1 << int(prune.hyperplanes.shape[0])
     ball = _in_ball(jnp.arange(n_buckets, dtype=jnp.int32)[None, :],
@@ -281,11 +283,13 @@ _BLOCK_ROWS = 128
 _BLOCK_KSEL = 32
 _APPROX_RECALL = 0.99999
 # Phase B gathers (rows, ksel, bs, F) of the store's dtype.  A window
-# whose gather would pass this many bytes runs its rows in equal
-# groups, one after the other inside the same program (_phase_b): a
-# 256-wide window fetching k = 256 at 250f bfloat16 would otherwise
-# ask for 8.4 GB beside a 10 GB store.  Of the order of
-# _FLAT_SCORES_LIMIT, the other transient this module bounds.
+# from 128 rows on whose gather would pass this many bytes runs its
+# rows in equal groups, one after the other inside the same program
+# (_phase_b): a 256-wide window fetching k = 256 at 250f bfloat16 would
+# otherwise ask for 8.4 GB beside a 10 GB store.  A narrower window
+# gathers a request at a time (_rescores_requests), and a fetch whose
+# ONE row would pass it is the exact scan's (_twophase_admits).  Of the
+# order of _FLAT_SCORES_LIMIT, the other transient this module bounds.
 _PHASE_B_GATHER_BYTES = 1 << 30
 
 
@@ -320,10 +324,9 @@ def _twophase_admits(k: int, ksel: int, Y, bs: int) -> bool:
 
 def _phase_b_group_rows(b: int, ksel: int, bs: int,
                         row_bytes: int) -> int:
-    """Query rows phase B rescores at once: the largest divisor of the
-    window's ``b`` rows whose (rows, ksel, bs, F) gather stays inside
-    ``_PHASE_B_GATHER_BYTES`` (all of them for every 8- and 32-wide
-    window up to k = 256 at 250f bfloat16; one row at least)."""
+    """Query rows a window from 128 rows on rescores at once: the
+    largest divisor of its ``b`` rows whose (rows, ksel, bs, F) gather
+    stays inside ``_PHASE_B_GATHER_BYTES`` (one row at least)."""
     fit = max(1, _PHASE_B_GATHER_BYTES // (ksel * bs * row_bytes))
     return next(g for g in range(min(b, fit), 0, -1) if b % g == 0)
 
@@ -339,6 +342,22 @@ def _map_row_groups(fn, g: int, *xs):
     out = jax.lax.map(lambda x: fn(*x), tuple(
         x.reshape(b // g, g, *x.shape[1:]) for x in xs))
     return jax.tree.map(lambda o: o.reshape(b, *o.shape[2:]), out)
+
+
+def _rescores_requests(b: int) -> bool:
+    """Whether phase B gathers and rescores blocks for the REQUESTS of
+    a ``b``-row window only, a row an iteration of a loop whose bound
+    (``n_real``) the device reads: every window narrower than a lane
+    tile, the ladder's 8 and 32, which a drain of one to 32 requests
+    fills with zero rows.  Two callers on an 8-wide window were paying
+    for eight rows' blocks, at k = 256 and 250f bfloat16 a 262 MB
+    gather: whole programs over 20M rows on a v5e, 16.07 ms by one
+    gather of the window, 14.99 by its two requests (a 5.1M-row float32
+    shard: 10.16 and 8.01; PERF.md section 6, PR 38).  From 128 rows on
+    a window is as good as full (a drain of over 32 requests), and its
+    phase B stays the batched one, operation for operation: it is what
+    the route measurement times."""
+    return b < 128
 
 
 def _selects_row_major(b: int, ksel: int) -> bool:
@@ -362,14 +381,23 @@ def _selects_row_major(b: int, ksel: int) -> bool:
     return b < 128 and ksel > _BLOCK_KSEL
 
 
-def _phase_b(Y, Qc, active, M, k: int, bs: int, ksel: int, steps=None):
+def _phase_b(Y, Qc, active, M, n_real, k: int, bs: int, ksel: int,
+             steps=None):
     """Phase B shared by the scan- and pallas-built phase A: pick the
     ``ksel`` best 128-row blocks per query from the block maxima ``M``
     with approx_max_k, exactly rescore the gathered rows, and emit
     top-k plus the exactness certificate kth_score >= max(unselected
-    block maxima).  A window too wide for one gather
-    (_phase_b_group_rows) runs in equal row groups under lax.map:
-    static shapes, one program, rows independent of each other.
+    block maxima).
+
+    ``n_real`` (a traced int32 scalar: one program a (window, k),
+    whatever the window holds) says how many leading rows of the window
+    are requests; the dispatch appends a window's padding behind them.
+    A narrow window (_rescores_requests) gathers and rescores blocks
+    for those rows only (_phase_b_rows).  From 128 rows on the count is
+    not looked at: every row is rescored, and a window too wide for one
+    gather (_phase_b_group_rows) runs in equal row groups under
+    lax.map: static shapes, one program, rows independent of each
+    other.
 
     ``steps`` of None: ``M`` holds every block of the store in store
     order.  A pruned window (_visit_plan) hands its maxima over in
@@ -377,15 +405,30 @@ def _phase_b(Y, Qc, active, M, k: int, bs: int, ksel: int, steps=None):
     no candidate of the row, and ``steps`` maps them back to the store:
     selection, rescoring and the certificate then run over the visited
     blocks only."""
+    if _rescores_requests(Qc.shape[0]):
+        return _phase_b_rows(Y, Qc, active, M, n_real, k, bs, ksel, steps)
     g = _phase_b_group_rows(Qc.shape[0], ksel, bs, _row_bytes(Y))
     return _map_row_groups(
-        lambda q, m: _phase_b_rows(Y, q, active, m, k, bs, ksel, steps),
+        lambda q, m: _phase_b_rows(Y, q, active, m, None, k, bs, ksel,
+                                   steps),
         g, Qc, M)
 
 
-def _phase_b_rows(Y, Qc, active, M, k: int, bs: int, ksel: int, steps):
-    """Phase B for one group of query rows (the whole window where its
-    gather fits)."""
+def _phase_b_rows(Y, Qc, active, M, n_real, k: int, bs: int, ksel: int,
+                  steps):
+    """Phase B for one group of query rows: a whole narrow window, of
+    which the first ``n_real`` rows are requests, or with ``n_real`` of
+    None one gather's worth of a wide one, every row rescored.
+
+    What reads the maxima or sorts scores (the selection, the best
+    unselected maximum, the final top_k) runs once over the group's
+    rows either way: a TopK costs over one row what it costs over eight
+    sublanes.  What moves bytes in proportion to rows (_rescored: the
+    gather of a row's ``ksel`` blocks, their products with the row, the
+    mask) runs under ``n_real`` a request an iteration, and a row
+    behind the requests returns -inf scores, row 0 and a certificate of
+    True: nobody decodes it, and it must neither send a window to the
+    exact scan nor be counted as a fallback."""
     b = Qc.shape[0]
     if _selects_row_major(b, ksel):
         M = with_layout_constraint(M, Layout(major_to_minor=(0, 1)))
@@ -395,21 +438,39 @@ def _phase_b_rows(Y, Qc, active, M, k: int, bs: int, ksel: int, steps):
         # visited block -> the store's block
         per_step = M.shape[1] // steps.shape[0]
         bi = jnp.take(steps, bi // per_step) * per_step + bi % per_step
-    # gathered blocks stay in the store dtype: phase B must reduce the
-    # SAME bf16 products phase A did or the exactness certificate's
-    # phase-A-bounds-phase-B argument breaks at the rounding margin
-    Yg = jnp.take(Y.reshape(-1, bs, Y.shape[1]), bi,
-                  axis=0)                              # (B, ksel, bs, F)
-    scores = jnp.einsum("bf,bkcf->bkc", Qc, Yg,
-                        preferred_element_type=jnp.float32,
-                        precision=_score_precision(Y)
-                        ).reshape(b, ksel * bs)
-    ok = jnp.take(active.reshape(-1, bs), bi, axis=0)
-    if steps is not None:
-        # fewer candidate blocks than ``ksel``: the selection filled up
-        # with blocks outside the row's ball, whose rows are no answer
-        ok = ok & (m_sel > -jnp.inf)[:, :, None]
-    scores = jnp.where(ok.reshape(b, ksel * bs), scores, -jnp.inf)
+    # fewer candidate blocks than ``ksel`` (a pruned window): the
+    # selection filled up with blocks outside the row's ball, whose rows
+    # are no answer
+    cand = m_sel > -jnp.inf if steps is not None else None
+    if n_real is None:
+        scores = _rescored(Y, Qc, active, bi, cand, bs)
+    else:
+        # a request an iteration, its blocks as a batch of two halves:
+        # the same products and sums as the batched form's, where a
+        # batch of ONE is a matrix-vector product that a backend may sum
+        # in another order (the CPU's does; on the chip one, halves and
+        # the whole window's gather agree bit for bit and halves cost
+        # what one costs, PERF.md section 6, PR 38).  A FULL window
+        # loses nothing to the loop: eight gathers of 33 MB beat one of
+        # 262 (15.43 ms for 16.04 at k = 256, 14.20 for 14.18 at k = 32;
+        # 32 of 32 rows 20.27 for 23.02).  Sorting inside the loop too
+        # would win 0.1 ms at two requests and lose 0.7 at eight
+        halves = 2 if ksel % 2 == 0 else 1
+
+        def rescored(r, out):
+            def at(x):
+                return jax.lax.dynamic_index_in_dim(x, r, 0, False)
+
+            q = jnp.broadcast_to(at(Qc), (halves, Qc.shape[1]))
+            s = _rescored(Y, q, active, at(bi).reshape(halves, -1),
+                          None if cand is None
+                          else at(cand).reshape(halves, -1), bs)
+            return jax.lax.dynamic_update_slice_in_dim(
+                out, s.reshape(1, -1), r, 0)
+
+        scores = jax.lax.fori_loop(
+            0, n_real, rescored,
+            jnp.full((b, ksel * bs), -jnp.inf, jnp.float32))
     ts, ti = jax.lax.top_k(scores, k)
     rows = (bi[:, :, None] * bs
             + jnp.arange(bs, dtype=jnp.int32)[None, None, :]).reshape(
@@ -427,7 +488,32 @@ def _phase_b_rows(Y, Qc, active, M, k: int, bs: int, ksel: int, steps):
     m_guard = jnp.where(jnp.isfinite(m_rest),
                         m_rest + jnp.abs(m_rest) * 1e-4, m_rest)
     cert = ts[:, k - 1] >= m_guard
+    if n_real is not None:
+        padding = jnp.arange(b) >= n_real
+        idx = jnp.where(padding[:, None], 0, idx)
+        cert = cert | padding
     return ts, idx, cert
+
+
+def _rescored(Y, Qc, active, bi, cand, bs: int):
+    """The exact scores of the blocks ``bi`` (rows, blocks) selected
+    for the query rows ``Qc``, (rows, blocks * bs), -inf for a retired
+    store row and for every row of a block that is no candidate
+    (``cand`` False)."""
+    b, n = bi.shape
+    # gathered blocks stay in the store dtype: phase B must reduce the
+    # SAME bf16 products phase A did or the exactness certificate's
+    # phase-A-bounds-phase-B argument breaks at the rounding margin
+    Yg = jnp.take(Y.reshape(-1, bs, Y.shape[1]), bi,
+                  axis=0)                              # (B, ksel, bs, F)
+    scores = jnp.einsum("bf,bkcf->bkc", Qc, Yg,
+                        preferred_element_type=jnp.float32,
+                        precision=_score_precision(Y)
+                        ).reshape(b, n * bs)
+    ok = jnp.take(active.reshape(-1, bs), bi, axis=0)
+    if cand is not None:
+        ok = ok & cand[:, :, None]
+    return jnp.where(ok.reshape(b, n * bs), scores, -jnp.inf)
 
 
 # Pallas phase A: rows per grid step.  The whole point is that the
@@ -683,26 +769,30 @@ def _candidate_maxima(M, step_ok):
 
 @partial(jax.jit, static_argnames=("k", "bs", "ksel", "max_bits",
                                    "interpret"))
-def _batch_top_n_twophase_pallas(Y, Q, penalty, active, prune, k: int,
-                                 bs: int, ksel: int, max_bits: int = 0,
+def _batch_top_n_twophase_pallas(Y, Q, penalty, active, prune, n_real,
+                                 k: int, bs: int, ksel: int,
+                                 max_bits: int = 0,
                                  interpret: bool = False):
     """Two-phase streaming top-k with the phase-A block maxima computed
     by a fused pallas dot+blockmax kernel (scores never touch HBM), in
     the layout the window's width asks for (_scores_rows_on_lanes);
-    ``penalty`` is the (N // bs, bs) 0/-inf active-row mask.  ``prune``
-    of None is the exact scan.  With a ``Pruning`` the pass streams
-    only the steps the window's rows can reach (_visit_plan) and the
-    program returns a fourth result, the plan's ``stats``."""
+    ``penalty`` is the (N // bs, bs) 0/-inf active-row mask; ``n_real``
+    (int32 scalar, traced) how many leading rows of ``Q`` are requests,
+    the rest padding (_phase_b).  ``prune`` of None is the exact scan.
+    With a ``Pruning`` the pass streams only the steps the window's
+    rows can reach (_visit_plan) and the program returns a fourth
+    result, the plan's ``stats``."""
     Qc = _q_cast(Q, Y)
     lanes = _scores_rows_on_lanes(Q.shape[0])
     if prune is None:
         M = _pallas_block_maxima(Qc, Y, penalty, bs, lanes, interpret)
-        return _phase_b(Y, Qc, active, M, k, bs, ksel)
-    steps, n_visit, step_ok, stats = _visit_plan(Q, prune, max_bits)
+        return _phase_b(Y, Qc, active, M, n_real, k, bs, ksel)
+    steps, n_visit, step_ok, stats = _visit_plan(Q, prune, n_real,
+                                                 max_bits)
     M = _pallas_block_maxima(Qc, Y, penalty, bs, lanes, interpret, steps,
                              n_visit)
-    return (*_phase_b(Y, Qc, active, _candidate_maxima(M, step_ok), k, bs,
-                      ksel, steps), stats)
+    return (*_phase_b(Y, Qc, active, _candidate_maxima(M, step_ok), n_real,
+                      k, bs, ksel, steps), stats)
 
 
 def _fold_factor(width: int, features: int) -> int:
@@ -754,9 +844,9 @@ def _fold_items_kernel(vecs, active, fold: int, bs: int):
 
 @partial(jax.jit, static_argnames=("k", "bs", "ksel", "fold",
                                    "interpret"))
-def _batch_top_n_twophase_pallas_fold(Y, Yf, Q, pen_f, active, k: int,
-                                      bs: int, ksel: int, fold: int,
-                                      interpret: bool = False):
+def _batch_top_n_twophase_pallas_fold(Y, Yf, Q, pen_f, active, n_real,
+                                      k: int, bs: int, ksel: int,
+                                      fold: int, interpret: bool = False):
     """Two-phase streaming top-k whose phase A scans the FOLDED mirror:
     one dot per fold slot against a slot-shifted query copy, per-block
     reduce, max across slots.  Phase B and the exactness certificate
@@ -804,7 +894,7 @@ def _batch_top_n_twophase_pallas_fold(Y, Yf, Q, pen_f, active, k: int,
         out_specs=pl.BlockSpec((Tf // bsf, B), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N // bs, B), jnp.float32),
         interpret=interpret)(*ins)
-    return _phase_b(Y, Qc, active, Mt.T, k, bs, ksel)
+    return _phase_b(Y, Qc, active, Mt.T, n_real, k, bs, ksel)
 
 
 def _scan_block_maxima(Qc, Y, active, chunk: int, bs: int):
@@ -853,8 +943,9 @@ def _scan_step_maxima(Qc, Y, active, steps, n_visit, bs: int):
 
 
 @partial(jax.jit, static_argnames=("k", "chunk", "bs", "ksel", "max_bits"))
-def _batch_top_n_twophase_kernel(Y, Q, active, prune, k: int, chunk: int,
-                                 bs: int, ksel: int, max_bits: int = 0):
+def _batch_top_n_twophase_kernel(Y, Q, active, prune, n_real, k: int,
+                                 chunk: int, bs: int, ksel: int,
+                                 max_bits: int = 0):
     """Streaming batched top-k, two-phase MIPS style, EXACT with a
     per-row certificate.
 
@@ -869,18 +960,21 @@ def _batch_top_n_twophase_kernel(Y, Q, active, prune, k: int, chunk: int,
     kth_score >= max(every unselected block's maximum) proves no
     unscanned block can hold a better item.  Rows whose certificate
     fails (approx selection missed a head block) are recomputed by the
-    caller on the exact lax.top_k scan path.  ``prune`` of None is
+    caller on the exact lax.top_k scan path.  The first ``n_real`` rows
+    of ``Q`` (int32 scalar, traced) are requests, the rest padding that
+    phase B neither gathers nor rescores.  ``prune`` of None is
     the exact scan; with a ``Pruning`` phase A loops over the steps the
     window's rows can reach only (_visit_plan; ``chunk`` is then not
     looked at) and the plan's ``stats`` are a fourth result."""
     Qc = _q_cast(Q, Y)
     if prune is None:
         M = _scan_block_maxima(Qc, Y, active, chunk, bs)
-        return _phase_b(Y, Qc, active, M, k, bs, ksel)
-    steps, n_visit, step_ok, stats = _visit_plan(Q, prune, max_bits)
+        return _phase_b(Y, Qc, active, M, n_real, k, bs, ksel)
+    steps, n_visit, step_ok, stats = _visit_plan(Q, prune, n_real,
+                                                 max_bits)
     M = _scan_step_maxima(Qc, Y, active, steps, n_visit, bs)
-    return (*_phase_b(Y, Qc, active, _candidate_maxima(M, step_ok), k, bs,
-                      ksel, steps), stats)
+    return (*_phase_b(Y, Qc, active, _candidate_maxima(M, step_ok), n_real,
+                      k, bs, ksel, steps), stats)
 
 
 def _merge_top_k(best_s, best_i, scores, base, k: int):
@@ -921,7 +1015,7 @@ def _batch_top_n_chunked_kernel(Y, Q, active, k: int, chunk: int):
 
 
 @partial(jax.jit, static_argnames=("k", "max_bits"))
-def _batch_top_n_pruned_exact_kernel(Y, Q, active, prune, k: int,
+def _batch_top_n_pruned_exact_kernel(Y, Q, active, prune, n_real, k: int,
                                      max_bits: int):
     """The exact scan held to a pruned window's candidates: the answer
     of a window whose two-phase certificate failed, and the primary
@@ -929,7 +1023,8 @@ def _batch_top_n_pruned_exact_kernel(Y, Q, active, prune, k: int,
     at a time over the steps the window's rows can reach (_visit_plan),
     a row scoring only the steps of its own ball, with the running
     (B, k) best carried along.  Returns (scores, rows, stats)."""
-    steps, n_visit, step_ok, stats = _visit_plan(Q, prune, max_bits)
+    steps, n_visit, step_ok, stats = _visit_plan(Q, prune, n_real,
+                                                 max_bits)
     tile = Y.shape[0] // steps.shape[0]
     Qc = _q_cast(Q, Y)
 
@@ -975,16 +1070,18 @@ def shard_plan(Y, n_shards: int, k: int, width: int) -> ShardPlan | None:
     return None
 
 
-def shard_candidates(Y, active, Q, penalty, k: int,
+def shard_candidates(Y, active, Q, penalty, n_real, k: int,
                      plan: ShardPlan | None):
     """The per-shard body of the sharded program: one shard's best ``k``
     rows (scores, LOCAL row ids) over its own ``Y``.  ``plan`` of None
     is the flat body, one matmul over all of them and ``top_k``.
     Otherwise the one-chip two-phase scan, and a third result, each
-    query row's certificate; ``penalty`` of None selects the lax.scan
-    phase A.  Where a certificate fails the shard scores its rows again
-    with the exact scan: its neighbours' answers stand, and the merge
-    waits for it."""
+    query row's certificate (True for the padding behind the first
+    ``n_real`` rows: a window of two requests must not send a shard to
+    its exact scan for six rows of zeros); ``penalty`` of None selects
+    the lax.scan phase A.  Where a certificate fails the shard scores
+    its rows again with the exact scan: its neighbours' answers stand,
+    and the merge waits for it."""
     if plan is None:
         return _batch_top_n_kernel.__wrapped__(Y, Q, active, k)
     Qc = _q_cast(Q, Y)
@@ -993,7 +1090,8 @@ def shard_candidates(Y, active, Q, penalty, k: int,
     else:
         M = _pallas_block_maxima(Qc, Y, penalty, plan.bs,
                                  _scores_rows_on_lanes(Q.shape[0]))
-    ts, ti, cert = _phase_b(Y, Qc, active, M, k, plan.bs, plan.ksel)
+    ts, ti, cert = _phase_b(Y, Qc, active, M, n_real, k, plan.bs,
+                            plan.ksel)
     ts, ti = jax.lax.cond(
         cert.all(), lambda: (ts, ti),
         lambda: _batch_top_n_chunked_kernel(Y, Q, active, k, plan.chunk))
@@ -1095,8 +1193,8 @@ def _fold_items_i8_kernel(y8, active, fold: int, bs: int):
 @partial(jax.jit, static_argnames=("k", "bs", "ksel", "fold",
                                    "interpret"))
 def _batch_top_n_twophase_pallas_i8_fold(Y, Y8f, sy_b, l1y_b, Q,
-                                         pen_i_f, active, k: int, bs: int,
-                                         ksel: int, fold: int,
+                                         pen_i_f, active, n_real, k: int,
+                                         bs: int, ksel: int, fold: int,
                                          interpret: bool = False):
     """The deepest phase-A mirror: int8 quantized AND row-folded, so a
     50-feature scan streams ~items x features BYTES (one int8 per
@@ -1159,13 +1257,13 @@ def _batch_top_n_twophase_pallas_i8_fold(Y, Y8f, sy_b, l1y_b, Q,
              + 0.5 * sy_b[:, None] * l1q[None, :]
              + 0.25 * W * sy_b[:, None] * sq[None, :])
     bound = jnp.where(masked | (l1q[None, :] == 0.0), -jnp.inf, bound)
-    return _phase_b(Y, Qc, active, bound.T, k, bs, ksel)
+    return _phase_b(Y, Qc, active, bound.T, n_real, k, bs, ksel)
 
 
 @partial(jax.jit, static_argnames=("k", "bs", "ksel", "interpret"))
 def _batch_top_n_twophase_pallas_i8(Y, Y8, sy_b, l1y_b, Q, penalty_i,
-                                    active, k: int, bs: int, ksel: int,
-                                    interpret: bool = False):
+                                    active, n_real, k: int, bs: int,
+                                    ksel: int, interpret: bool = False):
     """Two-phase streaming top-k with an INT8 phase A: block selection
     runs on a quantized mirror of the item matrix (half the HBM bytes
     of bf16, double MXU rate — measured 11.6 -> 5.3 ms per 256-window
@@ -1223,7 +1321,7 @@ def _batch_top_n_twophase_pallas_i8(Y, Y8, sy_b, l1y_b, Q, penalty_i,
     # both phases; a small positive margin bound would fail its
     # certificate on EVERY padded drain — its true bound is 0^- = -inf
     bound = jnp.where(masked | (l1q[None, :] == 0.0), -jnp.inf, bound)
-    return _phase_b(Y, Qc, active, bound.T, k, bs, ksel)
+    return _phase_b(Y, Qc, active, bound.T, n_real, k, bs, ksel)
 
 
 class ALSServingModel(FactorModelBase, ServingModel):
@@ -1362,6 +1460,12 @@ class ALSServingModel(FactorModelBase, ServingModel):
         # observability: exact-scan recomputes forced by a failed
         # two-phase certificate (expected ~0; see _block_ksel's notes)
         self.twophase_fallbacks = 0
+        # the rows phase B rescored (the requests of the narrow windows,
+        # every row of the wide ones), and the rows of the windows the
+        # two-phase program was dispatched for
+        self.phase_b_rows = 0
+        self.phase_b_window_rows = 0
+        self._counts: dict[int, jax.Array] = {}   # _count
         # the sharded path's own: two-phase windows scored by the SPMD
         # program, and the (query row, shard) pairs whose certificate
         # failed there, each of which that shard answered by its exact
@@ -1437,6 +1541,10 @@ class ALSServingModel(FactorModelBase, ServingModel):
             # exact-scan recomputes forced by a failed streaming top-k
             # certificate; nonzero is worth an operator's attention
             "twophase_fallbacks": self.twophase_fallbacks,
+            # the rows phase B rescored, and the rows of the windows
+            # dispatched: what a drain's padding no longer costs
+            "phase_b_rows": self.phase_b_rows,
+            "phase_b_window_rows": self.phase_b_window_rows,
             "sharded_windows": self.sharded_windows,
             "shard_fallback_rows": self.shard_fallback_rows,
             # the update path: in-place syncs of the item store, the
@@ -1603,6 +1711,12 @@ class ALSServingModel(FactorModelBase, ServingModel):
             self.top_n_batch(how_many,
                              np.zeros((b, self.features), np.float32))
             b *= 2
+        # the counts of requests a narrow window can hold, placed on
+        # the device(s) before traffic: no drain uploads one
+        place = self._shard_kernels.count if self._item_shards > 1 \
+            else self._count
+        for n in range(1, _WINDOW_LADDER[1] + 1):
+            place(n)
         # the in-place row sync's ladder of scatter programs: the first
         # UP records of a live model compile nothing
         self.Y.warm_sync()
@@ -1735,13 +1849,12 @@ class ALSServingModel(FactorModelBase, ServingModel):
                 self._penalty_i_src = active
             return self._penalty_i
 
-    def _pruning(self, active, n_real: int) -> Pruning:
+    def _pruning(self, active) -> Pruning:
         """What a pruned program over the resident arrays reads beside
         them (the caller holds the store's dispatch lock, so the table
         of the steps' buckets, read after the sync, is at least as new
         as the rows on the device: a step a bucket took since holds no
-        live row there yet).  ``n_real`` of the window's rows are
-        requests."""
+        live row there yet)."""
         with self._bucket_lock:
             if self._step_bucket is None \
                     or self._step_bucket_version != self.Y.partition_version \
@@ -1756,15 +1869,14 @@ class ALSServingModel(FactorModelBase, ServingModel):
                     self._step_live = _step_live_kernel(active, n_steps)
                     self._step_live_src = active
             return Pruning(self._step_bucket, self._step_live,
-                           self.lsh._device_hyperplanes(),
-                           np.int32(n_real))
+                           self.lsh._device_hyperplanes())
 
     def _lsh_mask(self, query_vec: np.ndarray | None, active):
         """``active`` held to the rows of the buckets inside the
         query's Hamming ball: a row's bucket is its step's."""
         if query_vec is None or not self._lsh_active():
             return active
-        table = self._pruning(active, 1).step_bucket   # -1: in no ball
+        table = self._pruning(active).step_bucket   # -1: in no ball
         ok = self.lsh.candidate_mask(query_vec, table)
         return active & jnp.repeat(ok, active.shape[0] // table.shape[0])
 
@@ -1904,7 +2016,6 @@ class ALSServingModel(FactorModelBase, ServingModel):
             twophase = streaming and _twophase_admits(k, ksel, vecs, bs) \
                 and (not pruned or self._lsh_step % bs == 0)
             attempted: list = []
-            reals: list | None = None
             if streaming:
                 # streaming path: static window shapes from the ladder
                 # (computed from the TRUE request count — a 257-query
@@ -1916,15 +2027,15 @@ class ALSServingModel(FactorModelBase, ServingModel):
                     Q = np.concatenate(
                         [Q, np.zeros((padded - n_req, Q.shape[1]),
                                      np.float32)])
-                windows, w = [], 0
+                # ``reals``: the requests of each window, THE count its
+                # program reads: its padding rows reach no bucket (a
+                # pruned pass) and phase B neither gathers nor rescores
+                # them
+                windows, reals, w = [], [], 0
                 for size in sizes:
                     windows.append(jnp.asarray(Q[w:w + size]))
+                    reals.append(min(size, n_req - w))
                     w += size
-                if pruned:
-                    # the requests of each window: its padding rows
-                    # reach no bucket
-                    reals = [min(size, max(0, n_req - at)) for size, at
-                             in zip(sizes, np.cumsum([0] + sizes))]
                 if rec is not None:
                     # from the first program enqueued to the last result
                     # fetched: it waits on the device, and on whatever
@@ -1932,14 +2043,16 @@ class ALSServingModel(FactorModelBase, ServingModel):
                     # width phase B selects (the int8 builds double it),
                     # 0 where the exact scan is the primary path;
                     # ``lane_rows`` how many of the windows phase A
-                    # scored with the store's rows on the lanes
+                    # scored with the store's rows on the lanes;
+                    # ``real_rows`` the requests among the windows' rows
                     rec.mark("serving.scan", k=k,
                              ksel=ksel if twophase else 0, windows=sizes,
-                             lane_rows=0)
+                             lane_rows=0, real_rows=n_req)
                 if twophase:
                     handles, attempted = self._dispatch_twophase(
-                        vecs, windows, active, version, reals, k, chunk,
-                        bs, ksel)
+                        vecs, windows, active, version, reals, pruned, k,
+                        chunk, bs, ksel)
+                    self._note_phase_b(rec, sizes, reals)
                     if rec is not None:
                         # known once each window's build is: a shape
                         # that did not lower ran the lax.scan build
@@ -1960,14 +2073,14 @@ class ALSServingModel(FactorModelBase, ServingModel):
                 Qd = jnp.asarray(Q)
                 if rec is not None:
                     rec.mark("serving.scan", k=k, ksel=0, windows=[b_pad],
-                             lane_rows=0)
+                             lane_rows=0, real_rows=n_req)
                 handles = _batch_top_n_kernel(vecs, Qd, active, k)
         # from here on the handles above are not touched again: a sync
         # may have donated them.  What a fallback needs it fetches anew
         # (_enqueue_exact), and answers from the version it finds
         if twophase:
             fetched = self._fetch_twophase(handles, attempted, windows, k,
-                                           chunk, bs, ksel, reals)
+                                           chunk, bs, ksel, reals, pruned)
             if pruned:
                 self._note_pruned(rec, [f[3] for f in fetched])
             for w, (ts, ti, cert, *_) in enumerate(fetched):
@@ -2007,6 +2120,22 @@ class ALSServingModel(FactorModelBase, ServingModel):
                                   k < n_rows, np.asarray(user_vectors,
                                                          np.float32),
                                   use_lsh)
+
+    def _note_phase_b(self, rec, sizes: list, reals: list) -> None:
+        """Book a drain whose windows the two-phase program was just
+        enqueued for: the rows phase B rescores (a narrow window's
+        requests, every row of a wide one: _rescores_requests) against
+        the rows of the windows, on the model's counters and on the
+        recorder's open ``serving.scan`` phase."""
+        rescored = sum(n if _rescores_requests(size) else size
+                       for size, n in zip(sizes, reals))
+        rows = sum(sizes)
+        with self._bucket_lock:
+            self.phase_b_rows += rescored
+            self.phase_b_window_rows += rows
+        if rec is not None:
+            rec.annotate(phase_b_row_share=round(100.0 * rescored / rows,
+                                                 3))
 
     def _note_pruned(self, rec, stats: list) -> None:
         """Book a drain's pruned windows from their programs' ``stats``
@@ -2056,7 +2185,7 @@ class ALSServingModel(FactorModelBase, ServingModel):
         if n_real is None:
             return _batch_top_n_chunked_kernel(vecs, qw, active, k, chunk)
         return _batch_top_n_pruned_exact_kernel(
-            vecs, qw, active, self._pruning(active, n_real), k,
+            vecs, qw, active, self._pruning(active), self._count(n_real), k,
             self.lsh.max_bits_differing)
 
     def _enqueue_exact(self, qw, k: int, chunk: int, n_real: int | None):
@@ -2069,19 +2198,18 @@ class ALSServingModel(FactorModelBase, ServingModel):
                                     n_real)
 
     def _enqueue_scan_build(self, qw, k: int, chunk: int, bs: int,
-                            ksel: int, n_real: int | None):
+                            ksel: int, n_real: int, pruned: bool):
         """Enqueue one window's ``lax.scan`` two-phase build over the
         resident arrays as they are now (see ``_enqueue_exact``)."""
         with self.Y.dispatching() as snap:
             return self._dispatch_kind(
                 "scan", qw, snap.vecs, snap.active, snap.version,
-                None if n_real is None
-                else self._pruning(snap.active, n_real),
+                self._pruning(snap.active) if pruned else None, n_real,
                 k, bs, ksel, 1, {}, chunk=chunk)
 
     def _dispatch_twophase(self, vecs, windows, active, version,
-                          reals: list | None, k: int, chunk: int, bs: int,
-                          ksel: int) -> tuple[list, list]:
+                          reals: list, pruned: bool, k: int, chunk: int,
+                          bs: int, ksel: int) -> tuple[list, list]:
         """Enqueue every window's two-phase program (async; the caller
         holds the store's dispatch lock) and return the handles with
         the keys of the shapes attempted, for ``_fetch_twophase``.
@@ -2091,10 +2219,9 @@ class ALSServingModel(FactorModelBase, ServingModel):
         that lands in ``kernel_route.errors`` on a TPU
         (``pallas_failure_level``).  A drain may mix full windows and
         one small tail window, and each shape stands or falls alone.
-        ``reals`` (how many rows of each window are requests) makes
-        every window a pruned one; None is the exact scan."""
+        ``reals``: how many leading rows of each window are requests;
+        ``pruned`` makes every window a pruned one."""
         n_rows = int(vecs.shape[0])
-        pruned = reals is not None
         mb = self.lsh.max_bits_differing if pruned else 0
         static_kinds, fold = self._phase_a_kinds(n_rows,
                                                  int(vecs.shape[1]), bs)
@@ -2115,17 +2242,17 @@ class ALSServingModel(FactorModelBase, ServingModel):
         # timed the live shape (config stops deciding, the stopwatch
         # does); invariant across a drain's windows
         kinds = self._route_order(static_kinds, n_rows, lsh_on=pruned)
+        prune = self._pruning(active) if pruned else None
         for w, qw in enumerate(windows):
             dispatched = False
-            prune = self._pruning(active, reals[w]) if pruned else None
             for kind in kinds:
                 key = key_of(qw, kind)
                 if _PALLAS_STATE.get(key) == "broken":
                     continue
                 try:
                     handles.append(self._dispatch_kind(
-                        kind, qw, vecs, active, version, prune, k, bs,
-                        ksel, fold, ctx, chunk=chunk))
+                        kind, qw, vecs, active, version, prune, reals[w],
+                        k, bs, ksel, fold, ctx, chunk=chunk))
                     attempted.append(key)
                     dispatched = True
                     break
@@ -2136,13 +2263,13 @@ class ALSServingModel(FactorModelBase, ServingModel):
                     _classify_pallas_failure([key], e)
             if not dispatched:
                 handles.append(self._dispatch_kind(
-                    "scan", qw, vecs, active, version, prune, k, bs,
-                    ksel, fold, ctx, chunk=chunk))
+                    "scan", qw, vecs, active, version, prune, reals[w],
+                    k, bs, ksel, fold, ctx, chunk=chunk))
         return handles, attempted
 
     def _fetch_twophase(self, handles: list, attempted: list, windows,
                         k: int, chunk: int, bs: int, ksel: int,
-                        reals: list | None) -> list:
+                        reals: list, pruned: bool) -> list:
         """ONE fetch for the drain's two-phase programs, outside the
         dispatch lock."""
         try:
@@ -2158,25 +2285,26 @@ class ALSServingModel(FactorModelBase, ServingModel):
             # misattribution) and serve the drain on the scan build
             _classify_pallas_failure(fresh, e)
             return jax.device_get([
-                self._enqueue_scan_build(
-                    qw, k, chunk, bs, ksel,
-                    None if reals is None else reals[w])
+                self._enqueue_scan_build(qw, k, chunk, bs, ksel, reals[w],
+                                         pruned)
                 for w, qw in enumerate(windows)])
         for kk in attempted:
             _PALLAS_STATE[kk] = "ok"
         return out
 
     def _dispatch_kind(self, kind: str, qw, vecs, active, version,
-                       prune: Pruning | None, k: int, bs: int, ksel: int,
-                       fold: int, ctx: dict, chunk: int = 0):
+                       prune: Pruning | None, n_real: int, k: int, bs: int,
+                       ksel: int, fold: int, ctx: dict, chunk: int = 0):
         """Enqueue ONE window's phase-A build of the given kind and
         return its output handle(s) without blocking.  ``ctx`` caches
         the lazily-built device mirrors across windows of a drain (and
         across the router's timing repetitions).  Shared by the serving
         dispatch and the measured-cost router — the timed program must
-        BE the served program.  ``prune`` makes the window a pruned one:
-        the two builds that can skip steps take it (_phase_a_kinds
-        offers a model under LSH no other)."""
+        BE the served program.  ``n_real`` of the window's leading rows
+        are requests: an argument of the program, never a shape.
+        ``prune`` makes the window a pruned one: the two builds that can
+        skip steps take it (_phase_a_kinds offers a model under LSH no
+        other)."""
         mb = self.lsh.max_bits_differing if prune is not None else 0
         if prune is not None and kind not in ("pallas", "scan"):
             raise ValueError(f"no pruned phase-A kind {kind!r}")
@@ -2188,36 +2316,38 @@ class ALSServingModel(FactorModelBase, ServingModel):
             if kind == "pallas" and "penalty" not in ctx:
                 ctx["penalty"] = self._cached_penalty(active, version)
             return self._shard_kernels.twophase(
-                vecs, active, qw, k, (ksel, chunk, bs),
+                vecs, active, qw, n_real, k, (ksel, chunk, bs),
                 ctx["penalty"] if kind == "pallas" else None)
+        n_real = self._count(n_real)
         if kind == "i8_fold":
             if "i8_fold" not in ctx:
                 ctx["i8_fold"] = self._cached_i8_fold(
                     vecs, active, version, fold, bs)
             y8f, pen_i_f, sy_b, l1y_b = ctx["i8_fold"]
             return _batch_top_n_twophase_pallas_i8_fold(
-                vecs, y8f, sy_b, l1y_b, qw, pen_i_f, active, k, bs,
-                _i8_ksel(ksel, int(vecs.shape[0]), bs), fold)
+                vecs, y8f, sy_b, l1y_b, qw, pen_i_f, active, n_real, k,
+                bs, _i8_ksel(ksel, int(vecs.shape[0]), bs), fold)
         if kind == "fold":
             if "fold" not in ctx:
                 ctx["fold"] = self._cached_fold(
                     vecs, active, version, fold, bs)
             yf, pen_f = ctx["fold"]
             return _batch_top_n_twophase_pallas_fold(
-                vecs, yf, qw, pen_f, active, k, bs, ksel, fold)
+                vecs, yf, qw, pen_f, active, n_real, k, bs, ksel, fold)
         if kind == "i8":
             if "i8" not in ctx:
                 ctx["i8"] = (self._cached_i8(vecs, version),
                              self._cached_penalty_i(active, version))
             (y8, sy_b, l1y_b), penalty_i = ctx["i8"]
             return _batch_top_n_twophase_pallas_i8(
-                vecs, y8, sy_b, l1y_b, qw, penalty_i, active, k, bs,
-                _i8_ksel(ksel, int(vecs.shape[0]), bs))
+                vecs, y8, sy_b, l1y_b, qw, penalty_i, active, n_real, k,
+                bs, _i8_ksel(ksel, int(vecs.shape[0]), bs))
         if kind == "pallas":
             if "penalty" not in ctx:
                 ctx["penalty"] = self._cached_penalty(active, version)
             return _batch_top_n_twophase_pallas(
-                vecs, qw, ctx["penalty"], active, prune, k, bs, ksel, mb)
+                vecs, qw, ctx["penalty"], active, prune, n_real, k, bs,
+                ksel, mb)
         if kind == "ivf":
             from . import ivf as _ivf
             if "ivf" not in ctx:
@@ -2228,8 +2358,20 @@ class ALSServingModel(FactorModelBase, ServingModel):
                 self._ann.cfg.nprobe)
         if kind == "scan":
             return _batch_top_n_twophase_kernel(
-                vecs, qw, active, prune, k, chunk, bs, ksel, mb)
+                vecs, qw, active, prune, n_real, k, chunk, bs, ksel, mb)
         raise ValueError(f"unknown phase-A kind {kind!r}")
+
+    def _count(self, n: int) -> jax.Array:
+        """``n`` as an int32 scalar on the device, placed once: what a
+        window's program is handed for its count of requests.  Always
+        the same strong type (a Python int would trace as a weak one and
+        compile the program a second time), and no host scalar, which
+        is uploaded on every call, on the dispatching thread (on four
+        chips, to each: ``ShardKernelCache.count``)."""
+        dev = self._counts.get(n)
+        if dev is None:
+            dev = self._counts[n] = jnp.asarray(np.int32(n))
+        return dev
 
     # -- measured-cost routing (kernel_router) -------------------------------
 
@@ -2410,18 +2552,20 @@ class ALSServingModel(FactorModelBase, ServingModel):
             if rec is not None:
                 rec.mark("serving.scan", shards=shards, k=k,
                          ksel=plan.ksel if plan is not None else 0,
-                         windows=sizes, lane_rows=0)
+                         windows=sizes, lane_rows=0, real_rows=n_req)
             if plan is None:
                 handles = kernels.flat(vecs, active, kernels.replicate(Q),
                                        k)
             else:
-                windows, w = [], 0
+                windows, reals, w = [], [], 0
                 for size in sizes:
                     windows.append(kernels.replicate(Q[w:w + size]))
+                    reals.append(min(size, n_req - w))
                     w += size
                 handles, attempted = self._dispatch_twophase(
-                    vecs, windows, active, snap.version, None, k,
+                    vecs, windows, active, snap.version, reals, False, k,
                     plan.chunk, plan.bs, plan.ksel)
+                self._note_phase_b(rec, sizes, reals)
                 if rec is not None:
                     rec.annotate(lane_rows=sum(
                         _scores_rows_on_lanes(key[2]) for key in attempted
@@ -2431,7 +2575,7 @@ class ALSServingModel(FactorModelBase, ServingModel):
         else:
             fetched = self._fetch_twophase(
                 handles, attempted, windows, k, plan.chunk, plan.bs,
-                plan.ksel, None)
+                plan.ksel, reals, False)
             failed = [~f[2] for f in fetched]       # (shards, B) a window
             with self._bucket_lock:
                 self.sharded_windows += len(fetched)

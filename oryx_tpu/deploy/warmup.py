@@ -116,13 +116,16 @@ def warm_serving_shapes(features: int, items: int, dtype: str,
     k = min(sm._pad_k(how_many), cap)
     Y = _aval((cap, W), dt)
     A = _aval((cap,), jnp.bool_)
+    # how many of a window's rows are requests: an argument of every
+    # two-phase program and of the pruned exact scan, never a shape
+    R = _aval((), jnp.int32)
     variants: list[tuple] = [(None, 0)]
     if lsh_on:
         n_steps = cap // sm._PA_TILE
         variants.append((sm.Pruning(
             _aval((n_steps,), jnp.int32), _aval((n_steps,), jnp.int32),
-            _aval((lsh.num_hashes, F), jnp.float32),
-            _aval((), jnp.int32)), lsh.max_bits_differing))
+            _aval((lsh.num_hashes, F), jnp.float32)),
+            lsh.max_bits_differing))
         # item bucketing (model-load path: bulk_load hashes the host
         # matrix a chunk of rows at a time, at its true width); the
         # per-drain QUERY bucketing compiles inside each serving kernel
@@ -181,17 +184,18 @@ def warm_serving_shapes(features: int, items: int, dtype: str,
                 # candidates, and the two builds that can skip steps
                 _compile(report, f"{tag}: pruned_exact{suffix}",
                          sm._batch_top_n_pruned_exact_kernel, Y, Q, A,
-                         prune, k=k, max_bits=mb)
+                         prune, R, k=k, max_bits=mb)
                 if not sm._twophase_admits(k, ksel, Y, bs):
                     continue
                 _compile(report, f"{tag}: twophase_scan{suffix}",
                          sm._batch_top_n_twophase_kernel, Y, Q, A, prune,
-                         k=k, chunk=chunk, bs=bs, ksel=ksel, max_bits=mb)
+                         R, k=k, chunk=chunk, bs=bs, ksel=ksel,
+                         max_bits=mb)
                 if cap % sm._PA_TILE == 0:
                     _compile(report, f"{tag}: pallas{suffix}",
                              sm._batch_top_n_twophase_pallas, Y, Q,
                              _aval((cap // bs, bs), jnp.float32), A,
-                             prune, k=k, bs=bs, ksel=ksel, max_bits=mb)
+                             prune, R, k=k, bs=bs, ksel=ksel, max_bits=mb)
                 continue
             if not big:
                 _compile(report, f"{tag}: flat{suffix}",
@@ -205,7 +209,7 @@ def warm_serving_shapes(features: int, items: int, dtype: str,
             if not twophase_ok:
                 continue
             _compile(report, f"{tag}: twophase_scan{suffix}",
-                     sm._batch_top_n_twophase_kernel, Y, Q, A, None,
+                     sm._batch_top_n_twophase_kernel, Y, Q, A, None, R,
                      k=k, chunk=chunk, bs=bs, ksel=ksel)
             if lsh_on:
                 # a model under LSH is offered pallas and scan only
@@ -214,7 +218,7 @@ def warm_serving_shapes(features: int, items: int, dtype: str,
                     _compile(report, f"{tag}: pallas{suffix}",
                              sm._batch_top_n_twophase_pallas, Y, Q,
                              _aval((cap // bs, bs), jnp.float32), A,
-                             None, k=k, bs=bs, ksel=ksel)
+                             None, R, k=k, bs=bs, ksel=ksel)
                 continue
             if (ann is not None and ann.enabled
                     and cap // bs >= ann.cells):
@@ -254,7 +258,7 @@ def warm_serving_shapes(features: int, items: int, dtype: str,
                 continue
             P = _aval((cap // bs, bs), jnp.float32)
             _compile(report, f"{tag}: pallas{suffix}",
-                     sm._batch_top_n_twophase_pallas, Y, Q, P, A, None,
+                     sm._batch_top_n_twophase_pallas, Y, Q, P, A, None, R,
                      k=k, bs=bs, ksel=ksel)
             ksel_i8 = sm._i8_ksel(ksel, cap, bs)
             _compile(report, f"{tag}: pallas_i8{suffix}",
@@ -262,14 +266,14 @@ def warm_serving_shapes(features: int, items: int, dtype: str,
                      _aval((cap, W), jnp.int8),
                      _aval((cap // bs,), jnp.float32),
                      _aval((cap // bs,), jnp.float32), Q,
-                     _aval((cap // bs, bs), jnp.int32), A,
+                     _aval((cap // bs, bs), jnp.int32), A, R,
                      k=k, bs=bs, ksel=ksel_i8)
             if fold > 1:
                 _compile(report, f"{tag}: pallas_fold{suffix}",
                          sm._batch_top_n_twophase_pallas_fold, Y,
                          _aval((cap // fold, W), dt), Q,
                          _aval((fold, cap // bs, bs // fold),
-                               jnp.float32), A,
+                               jnp.float32), A, R,
                          k=k, bs=bs, ksel=ksel, fold=fold)
                 _compile(report, f"{tag}: pallas_i8_fold{suffix}",
                          sm._batch_top_n_twophase_pallas_i8_fold, Y,
@@ -277,7 +281,7 @@ def warm_serving_shapes(features: int, items: int, dtype: str,
                          _aval((cap // bs,), jnp.float32),
                          _aval((cap // bs,), jnp.float32), Q,
                          _aval((fold, cap // bs, bs // fold),
-                               jnp.int32), A,
+                               jnp.int32), A, R,
                          k=k, bs=bs, ksel=ksel_i8, fold=fold)
 
 

@@ -57,21 +57,25 @@ def build_program(mesh: Mesh, axis: str, k_shard: int, k_final: int,
     more than its own row count, but the MERGED result may be wider
     than any one shard's candidate list (how_many > rows-per-shard).
 
-    Returns a jitted ``(Y, active, Q[, penalty]) -> (scores, rows[,
-    certificates (shards, B)])``.  The two-phase program's name
+    Returns a jitted ``(Y, active, Q) -> (scores, rows)`` for the flat
+    body and ``(Y, active, Q, n_real[, penalty]) -> (scores, rows,
+    certificates (shards, B))`` for the two-phase one, where ``n_real``
+    (int32 scalar, traced, the same on every shard) says how many
+    leading rows of ``Q`` are requests.  The two-phase program's name
     contains ``twophase``: the device metrics find it by that
     (tests/test_serving_spans.py)."""
     rows = P(axis, None)
-    in_specs = (rows, P(axis), P(None, None)) + ((rows,) if pallas else ())
+    in_specs = (rows, P(axis), P(None, None)) \
+        + (() if plan is None else (P(),)) + ((rows,) if pallas else ())
     out_specs = (P(None, None),) * (2 if plan is None else 3)
 
     # the all_gather-merged outputs ARE replicated, but the static
     # replication checker cannot infer that
     @partial(shard_map, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
              check_vma=False)
-    def scorer(Y_local, active_local, Q, penalty=None):
+    def scorer(Y_local, active_local, Q, n_real=None, penalty=None):
         found = sm.shard_candidates(Y_local, active_local, Q, penalty,
-                                    k_shard, plan)
+                                    n_real, k_shard, plan)
         ls, li = found[:2]
         gi = li + jax.lax.axis_index(axis) * Y_local.shape[0]
         # partials from every shard: (n_dev, B, ks) -> (B, n_dev*ks)
@@ -102,6 +106,7 @@ class ShardKernelCache:
         self.axis = axis
         self.shards = int(mesh.devices.size)
         self._programs: dict[tuple, object] = {}
+        self._counts: dict[int, jax.Array] = {}
 
     def _program(self, key: tuple, **build):
         prog = self._programs.get(key)
@@ -119,27 +124,42 @@ class ShardKernelCache:
         return self._program(("flat", k_shard, k_final), k_shard=k_shard,
                              k_final=k_final)(Y, active, Q_dev)
 
-    def twophase(self, Y, active, Q_dev, k: int, plan: tuple,
+    def twophase(self, Y, active, Q_dev, n_real, k: int, plan: tuple,
                  penalty=None):
         """(scores, global rows, certificates (shards, B)) by the
         two-phase scan on every shard, by ``plan`` (a ShardPlan or its
-        (ksel, chunk, bs)); phase A is the pallas kernel where its
-        ``penalty`` (the row-sharded (N // bs, bs) mask) is given, else
-        the lax.scan build."""
+        (ksel, chunk, bs)); the first ``n_real`` rows of ``Q_dev`` are
+        requests, the rest padding (an argument of the program: one
+        program a (window, k) whatever it holds); phase A is the pallas
+        kernel where its ``penalty`` (the row-sharded (N // bs, bs)
+        mask) is given, else the lax.scan build."""
         plan, pallas = sm.ShardPlan(*plan), penalty is not None
         prog = self._program(("twophase", k, plan, pallas), k_shard=k,
                              k_final=k, plan=plan, pallas=pallas)
-        return prog(Y, active, Q_dev, *((penalty,) if pallas else ()))
+        return prog(Y, active, Q_dev, self.count(int(n_real)),
+                    *((penalty,) if pallas else ()))
 
-    def top_k(self, Y, active, Q_dev, k: int):
-        """The merged top-``k`` by whichever body ``shard_plan`` admits
-        (the lax.scan phase A where it is the two-phase one): (scores,
-        global rows), the certificates dropped — a shard that failed
-        one has answered by its exact scan."""
+    def count(self, n: int):
+        """``n`` as an int32 scalar replicated over the mesh, placed
+        once.  Handed over as a host scalar it is uploaded to every
+        device on every call, on the dispatching thread: 0.66 ms of a
+        four-chip drain's host time (PERF.md section 6, PR 37)."""
+        dev = self._counts.get(n)
+        if dev is None:
+            dev = self._counts[n] = jax.device_put(
+                np.int32(n), NamedSharding(self.mesh, P()))
+        return dev
+
+    def top_k(self, Y, active, Q_dev, n_real: int, k: int):
+        """The merged top-``k`` of the first ``n_real`` rows of
+        ``Q_dev`` by whichever body ``shard_plan`` admits (the lax.scan
+        phase A where it is the two-phase one): (scores, global rows),
+        the certificates dropped — a shard that failed one has answered
+        by its exact scan."""
         plan = sm.shard_plan(Y, self.shards, k, int(Q_dev.shape[0]))
         if plan is None:
             return self.flat(Y, active, Q_dev, k)
-        return self.twophase(Y, active, Q_dev, k, plan)[:2]
+        return self.twophase(Y, active, Q_dev, n_real, k, plan)[:2]
 
     def replicate(self, Q: np.ndarray):
         return jax.device_put(
@@ -200,7 +220,7 @@ class ShardedItemScorer:
             Q = np.concatenate(
                 [Q, np.zeros((b_pad - n_req, Q.shape[1]), np.float32)])
         scores, idx = jax.device_get(self._kernels.top_k(
-            self._Y, self._active, self._kernels.replicate(Q),
+            self._Y, self._active, self._kernels.replicate(Q), n_req,
             min(sm._pad_k(how_many), int(self._Y.shape[0]))))
         out: list[list[tuple[str, float]]] = []
         for b in range(n_req):

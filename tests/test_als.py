@@ -906,28 +906,32 @@ def test_phase_b_groups_fit_the_gather_budget_at_250f_20m():
                                                 (2, 64)])
 def test_phase_b_in_row_groups_returns_what_one_gather_returns(
         rows_at_once, ksel, lsh, monkeypatch):
-    """A window over the gather budget runs phase B in equal row
-    groups inside the same program; scores, indices and certificates
-    are those of the ungrouped program, bit for bit."""
+    """A window from 128 rows on that is over the gather budget runs
+    phase B in equal row groups inside the same program; scores,
+    indices and certificates are those of the ungrouped program, bit
+    for bit.  (A narrower window gathers a request at a time and never
+    meets the budget: tests/test_phase_b_rows.py.)"""
     import jax
     import jax.numpy as jnp
 
     from oryx_tpu.app.als import serving_model as sm
 
     rng = np.random.default_rng(28)
-    n, f, b, k, bs = 4096, 8, 8, 16, 16
+    n, f, b, k, bs = 4096, 8, 128, 16, 16
+    assert not sm._rescores_requests(b)
     Y = jnp.asarray(rng.standard_normal((n, f)).astype(np.float32))
     Q = jnp.asarray(rng.standard_normal((b, f)).astype(np.float32))
     act = np.ones(n, bool)
     act[::7] = False
     active = jnp.asarray(act)
-    prune = _toy_pruning(rng, active, n // 512, f, b) if lsh else None
+    prune = _toy_pruning(rng, active, n // 512, f) if lsh else None
 
     def program():
         # a fresh jit each time: the budget is read at trace time
         return jax.device_get(jax.jit(
             lambda: sm._batch_top_n_twophase_kernel.__wrapped__(
-                Y, Q, active, prune, k, 1024, bs, ksel, 2)[:3])())
+                Y, Q, active, prune, np.int32(b), k, 1024, bs, ksel,
+                2)[:3])())
 
     whole = program()
     assert sm._phase_b_group_rows(b, ksel, bs, f * 4) == b
@@ -941,7 +945,7 @@ def test_phase_b_in_row_groups_returns_what_one_gather_returns(
     assert whole[2].all()
 
 
-def _toy_pruning(rng, active, n_steps: int, f: int, n_real: int):
+def _toy_pruning(rng, active, n_steps: int, f: int):
     """What a pruned window's program takes beside the store, for a toy
     store of ``n_steps`` steps: 4 hyperplanes, every step given one of
     the 16 buckets at random (step 1 to nobody).  The kernels only read
@@ -956,8 +960,7 @@ def _toy_pruning(rng, active, n_steps: int, f: int, n_real: int):
     return sm.Pruning(
         jnp.asarray(table),
         sm._step_live_kernel(active, n_steps),
-        jnp.asarray(rng.standard_normal((4, f)).astype(np.float32)),
-        np.int32(n_real))
+        jnp.asarray(rng.standard_normal((4, f)).astype(np.float32)))
 
 
 def _phase_a_case(n, f, b, lsh, seed=11, integers=False):
@@ -984,7 +987,7 @@ def _phase_a_case(n, f, b, lsh, seed=11, integers=False):
     act[1::5] = False
     assert act[last]
     Y, Q, active = jnp.asarray(y), jnp.asarray(q), jnp.asarray(act)
-    prune = _toy_pruning(rng, active, n // sm._PA_TILE, f, b - 1) \
+    prune = _toy_pruning(rng, active, n // sm._PA_TILE, f) \
         if lsh else None
     return Y, Q, active, prune, last
 
@@ -1013,14 +1016,17 @@ def test_pallas_phase_a_interpret_agrees_with_scan_kernel(b, lsh, rows):
     f, k, ksel, mb = 16, 8, 16, 2 if lsh else 0
     assert sm._scores_rows_on_lanes(b) == (b < 128)
     Y, Q, active, prune, last = _phase_a_case(n, f, b, lsh)
+    # the last row of a pruned window is padding; every row of the
+    # exact one is a request
+    n_real = np.int32(b - 1 if lsh else b)
     penalty = sm._penalty_kernel(active, bs)
     ts_p, ti_p, cert_p, *stats_p = jax.device_get(
         sm._batch_top_n_twophase_pallas(
-            Y, Q, penalty, active, prune, k, bs, ksel, mb,
+            Y, Q, penalty, active, prune, n_real, k, bs, ksel, mb,
             interpret=True))
     ts_s, ti_s, cert_s, *stats_s = jax.device_get(
         sm._batch_top_n_twophase_kernel(
-            Y, Q, active, prune, k, sm._PA_TILE, bs, ksel, mb))
+            Y, Q, active, prune, n_real, k, sm._PA_TILE, bs, ksel, mb))
     if lsh:
         # the plan's numbers, and the padding row reaches nothing
         np.testing.assert_array_equal(stats_p[0], stats_s[0])
@@ -1138,7 +1144,7 @@ def test_certificate_passes_when_all_unselected_blocks_masked():
     act = np.zeros(n, bool)
     act[:ksel * bs] = True
     ts, ti, cert = jax.device_get(sm._batch_top_n_twophase_kernel(
-        Y, Q, jnp.asarray(act), None, k, 256, bs, ksel))
+        Y, Q, jnp.asarray(act), None, np.int32(b), k, 256, bs, ksel))
     assert cert.all(), cert
 
 
@@ -1268,7 +1274,7 @@ def test_int8_twophase_matches_oracle_interpret():
     sm._PA_TILE = 1024
     try:
         ts, ti, cert = sm._batch_top_n_twophase_pallas_i8(
-            Y, y8, sy_b, l1y_b, Q, pen_i, active,
+            Y, y8, sy_b, l1y_b, Q, pen_i, active, np.int32(B),
             k=k, bs=bs, ksel=ksel, interpret=True)
     finally:
         sm._PA_TILE = old_tile
@@ -1374,7 +1380,7 @@ def test_int8_certificate_passes_on_zero_padded_rows():
     try:
         ts, ti, cert = sm._batch_top_n_twophase_pallas_i8(
             Y, y8, sy_b, l1y_b, jnp.asarray(Q), pen_i, active,
-            k=k, bs=bs, ksel=ksel, interpret=True)
+            np.int32(len(Q)), k=k, bs=bs, ksel=ksel, interpret=True)
     finally:
         sm._PA_TILE = old_tile
     assert np.asarray(cert)[3:].all()  # padding rows always certify
@@ -1435,11 +1441,11 @@ def test_fold_pallas_interpret_agrees_with_scan_kernel():
         yf, pen_f = sm._fold_items_kernel(Yj, active, fold, bs)
         ts_f, ti_f, cert_f = jax.device_get(
             sm._batch_top_n_twophase_pallas_fold(
-                Yj, yf, Q, pen_f, active, k, bs, ksel, fold,
-                interpret=True))
+                Yj, yf, Q, pen_f, active, np.int32(len(Q)), k, bs, ksel,
+                fold, interpret=True))
         ts_s, ti_s, cert_s = jax.device_get(
             sm._batch_top_n_twophase_kernel(
-                Yj, Q, active, None, k, 2048, bs, ksel))
+                Yj, Q, active, None, np.int32(len(Q)), k, 2048, bs, ksel))
         np.testing.assert_allclose(ts_f, ts_s, rtol=1e-5)
         np.testing.assert_array_equal(ti_f, ti_s)
         np.testing.assert_array_equal(cert_f, cert_s)

@@ -177,6 +177,132 @@ def test_pruned_answers_equal_the_plain_reference(case, build, monkeypatch):
 
 
 @pytest.mark.parametrize("build", ["scan", "pallas"])
+def test_one_count_of_requests_feeds_the_plan_and_phase_b(build,
+                                                          monkeypatch):
+    """PR 38: a window has ONE ``n_real``, an argument of its program
+    (``Pruning`` carries none): ``_visit_plan`` unions the balls of that
+    many rows and phase B gathers and rescores for that many.  Three
+    users on an [8]: the plan's buckets are their union's, the five
+    rows behind them come back -inf with their certificates passed, and
+    nothing falls to the exact scan."""
+    assert "n_real" not in sm.Pruning._fields
+    model = _build(monkeypatch, build, seed=33)
+    name = "_batch_top_n_twophase_kernel" if build == "scan" \
+        else "_batch_top_n_twophase_pallas"
+    real = getattr(sm, name)
+    seen = []
+
+    def spy(*args, **kw):
+        out = real(*args, **kw)
+        n_real = args[4 if build == "scan" else 5]
+        seen.append((n_real.dtype, int(n_real), jax.device_get(out)))
+        return out
+
+    monkeypatch.setattr(sm, name, spy)
+    users = ["u2", "u9", "u11"]
+    answers = _serve(model, users)
+    assert LshReference(model, 8, 2).check(answers, 10) == []
+    _ran(model, build)
+    (kind, n_real, (ts, ti, cert, stats)), = seen
+    assert (kind, n_real) == (np.int32, 3)
+    assert np.isfinite(ts[:3, 0]).all() and np.isneginf(ts[3:]).all()
+    assert (ti[3:] == 0).all()
+    assert cert.all() and model.twophase_fallbacks == 0
+    # the union of THREE balls, not of eight rows' (five zero rows
+    # would all hash to one bucket and add its ball)
+    balls = {int(b) for u in users for b in np.flatnonzero(
+        [bin(int(model.lsh.bucket_of(model.get_user_vector(u)[None])[0])
+             ^ c).count("1") <= 2 for c in range(256)])}
+    assert int(stats[1]) == len(balls)
+    m = model.metrics()
+    assert (m["phase_b_rows"], m["phase_b_window_rows"]) == (3, 8)
+
+
+@pytest.mark.parametrize("k", [16, 32])
+@pytest.mark.parametrize("b, n_real", [(8, 1), (8, 2), (8, 7), (8, 8),
+                                       (32, 9)])
+def test_a_pruned_windows_requests_answer_as_the_whole_window_did(
+        b, n_real, k, monkeypatch):
+    """The pruned program's phase B, a request an iteration, against
+    the whole-window arithmetic (every row gathered, multiplied and
+    sorted in one piece, as before PR 38) over the same plan: maxima in
+    visit order mapped back through ``steps``, -inf wherever a step is
+    no candidate of the row, and at k = 32 fewer candidate blocks (37)
+    than the 64 the selection takes.  Scores, rows and certificates of
+    the requests bit for bit; the padding -inf, row 0 and passed."""
+    model = _build(monkeypatch, "scan", seed=37)
+    vecs, active = model.Y.device_arrays()
+    prune = model._pruning(active)
+    ksel = sm._block_ksel(k, int(vecs.shape[0]), 128)
+    assert ksel == 2 * k
+    Q = np.zeros((b, FEATURES), np.float32)
+    Q[:n_real] = np.stack([model.get_user_vector(f"u{j}")
+                           for j in range(n_real)])
+
+    def program(whole: bool):
+        # traced afresh: which rows phase B rescores is read then
+        monkeypatch.setattr(sm, "_rescores_requests",
+                            (lambda width: False) if whole
+                            else (lambda width: width < 128))
+        fn = jax.jit(sm._batch_top_n_twophase_kernel.__wrapped__,
+                     static_argnames=("k", "chunk", "bs", "ksel",
+                                      "max_bits"))
+        return jax.device_get(fn(vecs, jnp.asarray(Q), active, prune,
+                                 np.int32(n_real), k, 0, 128, ksel, 2))
+
+    ts, ti, cert, stats = program(False)
+    want_s, want_i, want_c, want_stats = program(True)
+    np.testing.assert_array_equal(stats, want_stats)
+    r = slice(0, n_real)
+    np.testing.assert_array_equal(ts[r], want_s[r])
+    np.testing.assert_array_equal(ti[r], want_i[r])
+    np.testing.assert_array_equal(cert[r], want_c[r])
+    assert cert.all() and np.isfinite(ts[r, 0]).all()
+    if k == 32:
+        # 37 candidate blocks of ~20 live rows each hold the fetch, and
+        # the blocks the selection filled up with gave no row
+        assert np.isfinite(ts[r]).all()
+        table, step, _ = model.Y.partition_layout()
+        for row in range(n_real):
+            bucket = int(model.lsh.bucket_of(Q[row][None])[0])
+            assert all(bin(int(table[i // step]) ^ bucket).count("1") <= 2
+                       for i in ti[row])
+    assert np.isneginf(ts[n_real:]).all() and (ti[n_real:] == 0).all()
+
+
+@pytest.mark.parametrize("build", ["scan", "pallas"])
+def test_only_a_requests_failed_certificate_sends_the_window_on(
+        build, monkeypatch):
+    """The first request's certificate fails: the window is answered by
+    the exact scan over its candidates and ONE row is counted.  Nothing
+    behind the three requests can fail (the test above), so the same
+    drain unsabotaged counts none."""
+    model = _build(monkeypatch, build, seed=31)
+    name = "_batch_top_n_twophase_kernel" if build == "scan" \
+        else "_batch_top_n_twophase_pallas"
+    real = getattr(sm, name)
+    exact = []
+    real_exact = sm._batch_top_n_pruned_exact_kernel
+    monkeypatch.setattr(
+        sm, "_batch_top_n_pruned_exact_kernel",
+        lambda *a, **kw: exact.append(1) or real_exact(*a, **kw))
+    users = ["u2", "u9", "u11"]
+    want = _serve(model, users)
+    assert model.twophase_fallbacks == 0 and exact == []
+
+    def sabotaged(*args, **kw):
+        ts, ti, cert, stats = real(*args, **kw)
+        return ts, ti, cert.at[0].set(False), stats
+
+    monkeypatch.setattr(sm, name, sabotaged)
+    answers = _serve(model, users)
+    assert model.twophase_fallbacks == 1 and exact == [1]
+    assert LshReference(model, 8, 2).check(answers, 10) == []
+    assert [[g["id"] for g in a[1]] for a in answers] \
+        == [[g["id"] for g in a[1]] for a in want]
+
+
+@pytest.mark.parametrize("build", ["scan", "pallas"])
 def test_a_failed_certificate_is_answered_within_the_candidates(
         build, monkeypatch):
     model = _build(monkeypatch, build, seed=31)

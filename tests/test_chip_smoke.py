@@ -269,7 +269,8 @@ def test_served_scores_keep_the_stores_precision():
             "chunked_exact": sm._batch_top_n_chunked_kernel.lower(
                 Y, Q, A, k=k, chunk=512),
             "twophase_scan": sm._batch_top_n_twophase_kernel.lower(
-                Y, Q, A, None, k=k, chunk=512, bs=128, ksel=4),
+                Y, Q, A, None, jax.ShapeDtypeStruct((), jnp.int32), k=k,
+                chunk=512, bs=128, ksel=4),
         }
         return {name: low.as_text().count("HIGHEST")
                 for name, low in texts.items()}

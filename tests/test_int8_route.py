@@ -88,7 +88,7 @@ def test_int8_certificate_exact_at_f50_ties_and_retired():
     sm._PA_TILE = 1024
     try:
         ts, ti, cert = jax.device_get(sm._batch_top_n_twophase_pallas_i8(
-            Y, y8, sy_b, l1y_b, Q, pen_i, active,
+            Y, y8, sy_b, l1y_b, Q, pen_i, active, np.int32(b),
             k=k, bs=bs, ksel=ksel, interpret=True))
     finally:
         sm._PA_TILE = old_tile
@@ -125,6 +125,7 @@ def test_int8_fold_certificate_exact_at_f50():
         ts, ti, cert = jax.device_get(
             sm._batch_top_n_twophase_pallas_i8_fold(
                 Y, y8f, sy_b, l1y_b, Q, pen_i_f, active,
+                np.int32(Q.shape[0]),
                 k=k, bs=bs, ksel=ksel, fold=fold, interpret=True))
         # and bit-identical to the UNFOLDED int8 build: same integer
         # maxima, same bounds, same phase B
@@ -132,6 +133,7 @@ def test_int8_fold_certificate_exact_at_f50():
         ts_u, ti_u, cert_u = jax.device_get(
             sm._batch_top_n_twophase_pallas_i8(
                 Y, y8, sy_b, l1y_b, Q, pen_i, active,
+                np.int32(Q.shape[0]),
                 k=k, bs=bs, ksel=ksel, interpret=True))
     finally:
         sm._PA_TILE = old_tile

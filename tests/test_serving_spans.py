@@ -137,10 +137,13 @@ def test_phases_land_under_each_jobs_device_execute(branch, model, traced,
         # the counts known at the boundary where each phase begins
         assert spans["serving.prepare"][0]["attrs"] == {"rows": 3}
         # ... the width phase B selects among them: twice the fetch on
-        # the ladder, 0 where no block is selected (the flat kernel)
-        assert spans["serving.scan"][0]["attrs"] == {
-            "k": 8, "ksel": 16 if branch == "ladder" else 0,
-            "windows": [8], "lane_rows": 0}
+        # the ladder, 0 where no block is selected (the flat kernel);
+        # the requests among the window's rows, and on the ladder the
+        # share of its rows phase B rescores: those three of eight
+        assert spans["serving.scan"][0]["attrs"] == dict(
+            {"k": 8, "ksel": 16 if branch == "ladder" else 0,
+             "windows": [8], "lane_rows": 0, "real_rows": 3},
+            **({"phase_b_row_share": 37.5} if branch == "ladder" else {}))
         assert spans["serving.decode"][0]["attrs"] == {"rows": 3}
         stamps.add(tuple((spans[n][0]["start_ms"],
                           spans[n][0]["duration_ms"]) for n in PHASES))
@@ -172,9 +175,12 @@ def test_the_scan_span_says_which_width_ran(known, k, ksel, model, traced,
     assert len(exact) == (1 if ksel == 0 else 0)
     spans = _by_name(tracer.spans_for(req.trace_id))
     assert "serving.fallback" not in spans
-    assert spans["serving.scan"][0]["attrs"] == {"k": k, "ksel": ksel,
-                                                 "windows": [8],
-                                                 "lane_rows": 0}
+    assert spans["serving.scan"][0]["attrs"] == dict(
+        {"k": k, "ksel": ksel, "windows": [8], "lane_rows": 0,
+         "real_rows": 1},
+        # one request on an 8-wide window; no phase B where the exact
+        # scan is the primary path
+        **({"phase_b_row_share": 12.5} if ksel else {}))
     assert ksel == 0 or ksel == sm._block_ksel(k, ITEMS, 64)
 
 
@@ -198,12 +204,48 @@ def test_the_scan_span_counts_the_windows_scored_rows_on_lanes(
     _, (req,) = _drain(batcher, tracer, model, jobs, sampled=1)
     attrs = _by_name(tracer.spans_for(req.trace_id))[
         "serving.scan"][0]["attrs"]
+    # (phase B rescores the requests of a narrow window and every row
+    # of a 256-wide one)
+    rescored = sum(w if w >= 128 else min(w, jobs - at)
+                   for w, at in zip(windows, np.cumsum([0] + windows)))
     assert attrs == {"k": 8, "ksel": 16, "windows": windows,
-                     "lane_rows": lane_rows}
+                     "lane_rows": lane_rows, "real_rows": jobs,
+                     "phase_b_row_share": round(
+                         100.0 * rescored / sum(windows), 3)}
     # ... and it ran, for every window: no shape fell to the scan build
     ran = {key[2]: state for key, state in sm._PALLAS_STATE.items()
            if key[-1] == "pallas"}
     assert ran == {w: "ok" for w in windows}
+
+
+@pytest.mark.parametrize("jobs, windows, rescored", [
+    (2, [8], 2), (8, [8], 8), (9, [32], 9), (258, [256, 8], 258),
+    (140, [256], 256)])
+def test_the_scan_span_says_how_many_rows_phase_b_rescored(
+        jobs, windows, rescored, model, traced, ladder):
+    """PR 38: ``real_rows`` is the drain's requests and
+    ``phase_b_row_share`` the rows phase B rescored (a narrow window's
+    requests, every row of a 256-wide one) over the rows of its windows:
+    25.0 for two callers on an ``[8]``, 100.0 for eight.  ``/metrics``
+    keeps the running totals beside ``twophase_fallbacks``.  The
+    benchmark's ``kernel.phase_b_row_share`` is the mean of the share
+    over the ``serving.scan`` spans."""
+    batcher, tracer = traced
+    before = model.metrics()
+    _, (req,) = _drain(batcher, tracer, model, jobs, sampled=1)
+    attrs = _by_name(tracer.spans_for(req.trace_id))[
+        "serving.scan"][0]["attrs"]
+    assert attrs["windows"] == windows
+    assert attrs["real_rows"] == jobs
+    assert attrs["phase_b_row_share"] == pytest.approx(
+        100.0 * rescored / sum(windows), abs=1e-3)
+    if jobs == 2:
+        assert attrs["phase_b_row_share"] == 25.0
+    after = model.metrics()
+    assert after["phase_b_rows"] - before["phase_b_rows"] == rescored
+    assert after["phase_b_window_rows"] - before["phase_b_window_rows"] \
+        == sum(windows)
+    assert after["twophase_fallbacks"] == before["twophase_fallbacks"]
 
 
 @pytest.mark.parametrize("failing, widths", [("tail", [8]),
@@ -562,14 +604,13 @@ def test_each_scan_program_is_jitted_under_its_own_name(name, pattern):
                                     or "exact_kernel" in name)
 
 
-def _toy_pruning(rows: int, n_real: int):
+def _toy_pruning(rows: int):
     """A pruned window's side inputs over ``rows`` zero rows: 3
     hyperplanes, every step in bucket 0."""
     n_steps = rows // sm._PA_TILE
     return sm.Pruning(jnp.zeros((n_steps,), jnp.int32),
                       jnp.full((n_steps,), sm._PA_TILE, jnp.int32),
-                      jnp.ones((3, FEATURES), jnp.float32),
-                      np.int32(n_real))
+                      jnp.ones((3, FEATURES), jnp.float32))
 
 
 @pytest.mark.parametrize("pruned", [False, True], ids=["exact", "pruned"])
@@ -588,45 +629,52 @@ def test_the_pallas_build_is_one_named_program_at_every_ladder_width(
     Y = jnp.zeros((rows, FEATURES), jnp.float32)
     Q = jnp.zeros((width, FEATURES), jnp.float32)
     active = jnp.ones((rows,), bool)
-    prune = _toy_pruning(rows, width) if pruned else None
+    prune = _toy_pruning(rows) if pruned else None
     text = sm._batch_top_n_twophase_pallas.lower(
-        Y, Q, sm._penalty_kernel(active, bs), active, prune, k, bs,
-        ksel, 1 if pruned else 0, interpret=True).as_text()
+        Y, Q, sm._penalty_kernel(active, bs), active, prune,
+        np.int32(width), k, bs, ksel, 1 if pruned else 0,
+        interpret=True).as_text()
     assert "@jit__batch_top_n_twophase_pallas" in text[:200]
     assert text.count("func.func public") == 1
     assert sm._scores_rows_on_lanes(width) == (width < 128)
     if pruned:
         scan = sm._batch_top_n_twophase_kernel.lower(
-            Y, Q, active, prune, k, 0, bs, ksel, 1).as_text()
+            Y, Q, active, prune, np.int32(width), k, 0, bs, ksel,
+            1).as_text()
         assert "@jit__batch_top_n_twophase_kernel" in scan[:200]
         assert scan.count("func.func public") == 1
 
 
-@pytest.mark.parametrize("k, rows_at_once", [(8, 8), (64, 8), (64, 2)])
+@pytest.mark.parametrize("k, width, rows_at_once", [
+    (8, 8, 8), (64, 8, 8), (64, 128, 128), (64, 128, 2)])
 def test_the_lowered_programs_carry_the_names_the_trace_shows(
-        k, rows_at_once, monkeypatch):
-    """... for the widths a wide fetch selects too, and where phase B
-    runs a window in row groups: the groups are a loop INSIDE the one
-    two-phase program, not programs of their own."""
+        k, width, rows_at_once, monkeypatch):
+    """... for the widths a wide fetch selects too, where phase B loops
+    over a narrow window's requests and where it runs a wide window in
+    row groups: either is a loop INSIDE the one two-phase program, not
+    programs of their own."""
     # a shape nothing else traces: the budget is read when a program
     # is traced, and a cached trace would keep the one it was made with
-    rows, bs = 16384 + 256 * (k + rows_at_once), 8
+    rows, bs = 16384 + 256 * (k + width + rows_at_once), 8
     ksel = sm._block_ksel(k, rows, bs)
     assert ksel == max(sm._BLOCK_KSEL, 2 * k)
     monkeypatch.setattr(sm, "_PHASE_B_GATHER_BYTES",
                         rows_at_once * ksel * bs * FEATURES * 4)
     Y = jnp.zeros((rows, FEATURES), jnp.float32)
-    Q = jnp.zeros((8, FEATURES), jnp.float32)
+    Q = jnp.zeros((width, FEATURES), jnp.float32)
     active = jnp.ones((rows,), bool)
     two = sm._batch_top_n_twophase_kernel.lower(
-        Y, Q, active, None, k, 256, bs, ksel)
+        Y, Q, active, None, np.int32(width), k, 256, bs, ksel)
     assert "@jit__batch_top_n_twophase_kernel" in two.as_text()[:200]
-    # the floor width's program is as it was; a wider selection reads
-    # the block maxima row-major (``_selects_row_major``)
-    assert ("@LayoutConstraint" in two.as_text()) == (k == 64)
-    # phase A's lax.scan is one loop, the row groups a second
+    # the floor width's program is as it was; a wider selection by a
+    # narrow window, or by a narrow group of a wide one, reads the
+    # block maxima row-major (``_selects_row_major``)
+    assert ("@LayoutConstraint" in two.as_text()) \
+        == (k == 64 and rows_at_once < 128)
+    # phase A's lax.scan is one loop; a narrow window's requests
+    # (``_rescores_requests``) or a wide one's row groups a second
     assert two.as_text().count("stablehlo.while") \
-        == (1 if rows_at_once == 8 else 2)
+        == (1 if rows_at_once == width == 128 else 2)
     exact = sm._batch_top_n_chunked_kernel.lower(
         Y, Q, active, k, 256)
     assert "@jit__batch_top_n_chunked_kernel" in exact.as_text()[:200]

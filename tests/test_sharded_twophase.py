@@ -151,6 +151,71 @@ def test_one_shard_fails_its_certificate_and_answers_by_its_exact_scan(
     assert model.twophase_fallbacks == 8
 
 
+@pytest.mark.parametrize("n_real", [1, 3, 7, 8])
+def test_padding_rows_pass_on_every_shard_and_send_none_to_its_exact_scan(
+        n_real, toy, monkeypatch):
+    """PR 38: the SPMD program reads how many rows of its window are
+    requests.  Every shard's phase B rescores those alone; the rows
+    behind them come back -inf from every shard with a certificate of
+    True, so ``cert.all()`` sends no shard to its exact scan for them,
+    and the requests' merged answers are the full window's, bit for
+    bit."""
+    exact = []
+    real = sm._batch_top_n_chunked_kernel
+    monkeypatch.setattr(
+        sm, "_batch_top_n_chunked_kernel",
+        lambda *a, **kw: exact.append(1) or real(*a, **kw))
+    Y, X = _factors(46, 4 * SHARD_ROWS)
+    model = _model(4, Y, X)
+    vecs, active = model.Y.device_arrays()
+    kernels = model._shard_kernels
+    plan = sm.shard_plan(vecs, 4, 16, 8)
+    Q = kernels.replicate(X[:8])
+    whole = jax.device_get(kernels.twophase(vecs, active, Q, 8, 16, plan))
+    # the exact scan is TRACED as the branch of a failed certificate
+    # (once a program), and never a program of its own
+    assert len(exact) == 1
+    ts, ti, cert = jax.device_get(
+        kernels.twophase(vecs, active, Q, n_real, 16, plan))
+    # one program a (window, k), whatever the window holds
+    assert len(exact) == 1 and len(kernels._programs) == 1
+    assert cert.shape == (4, 8) and cert.all()
+    np.testing.assert_array_equal(ts[:n_real], whole[0][:n_real])
+    np.testing.assert_array_equal(ti[:n_real], whole[1][:n_real])
+    assert np.isfinite(ts[:n_real]).all()
+    assert np.isneginf(ts[n_real:]).all()
+    # the count rides replicated, placed once a value
+    assert sorted(kernels._counts) == sorted({8, n_real})
+    # ... and through the model: the requests of an [8] fail nothing
+    got = model.top_n_batch(10, X[:n_real])
+    assert [len(r) for r in got] == [10] * n_real
+    assert (model.sharded_windows, model.shard_fallback_rows,
+            model.twophase_fallbacks) == (1, 0, 0)
+    m = model.metrics()
+    assert (m["phase_b_rows"], m["phase_b_window_rows"]) == (n_real, 8)
+
+
+def test_a_request_that_fails_on_one_shard_is_counted_once(toy, monkeypatch):
+    """Shard 1 fails the certificate of the FIRST of three requests on
+    an [8] window: that shard answers by its exact scan inside the
+    program, one (row, shard) pair and one row are counted, and the
+    five rows of padding behind the requests add nothing."""
+    real = sm._phase_b
+
+    def sabotaged(Y, *args, **kw):
+        ts, ti, cert = real(Y, *args, **kw)
+        bad = (jax.lax.axis_index("items") == 1) & (jnp.arange(8) == 0)
+        return ts, ti, cert & ~bad
+
+    monkeypatch.setattr(sm, "_phase_b", sabotaged)
+    Y, X = _factors(47, 4 * SHARD_ROWS)
+    model = _model(4, Y, X)
+    answers = _answers(model, [f"u{j}" for j in range(3)])
+    assert ShardedReference(model).check(answers, 10) == []
+    assert (model.sharded_windows, model.shard_fallback_rows,
+            model.twophase_fallbacks) == (1, 1, 1)
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_the_shards_together_are_the_one_chip_model(dtype, toy):
     """Same factors, one device and four: the same ids in the same
@@ -288,16 +353,16 @@ def _phase_a_builds(dtype):
         Q = jnp.zeros((b, f), jnp.float32)
         out[f"pallas-{b}"] = jax.make_jaxpr(
             lambda Y, Q: sm._batch_top_n_twophase_pallas(
-                Y, Q, pen, act, None, k, bs, ksel))(Y, Q)
+                Y, Q, pen, act, None, np.int32(b), k, bs, ksel))(Y, Q)
     Q = jnp.zeros((8, f), jnp.float32)
     out["scan"] = jax.make_jaxpr(
         lambda Y, Q: sm._batch_top_n_twophase_kernel(
-            Y, Q, act, None, k, 1024, bs, ksel))(Y, Q)
+            Y, Q, act, None, np.int32(8), k, 1024, bs, ksel))(Y, Q)
     fold = 2
     Yf, pen_f = sm._fold_items_kernel(Y, act, fold, bs)
     out["fold"] = jax.make_jaxpr(
         lambda Y, Yf, Q: sm._batch_top_n_twophase_pallas_fold(
-            Y, Yf, Q[:, :f // fold], pen_f, act, k, bs, ksel,
+            Y, Yf, Q[:, :f // fold], pen_f, act, np.int32(8), k, bs, ksel,
             fold))(Y, Yf, Q)
     from jax.sharding import Mesh
     mesh = Mesh(np.array(jax.devices()[:2]), ("items",))
@@ -305,7 +370,7 @@ def _phase_a_builds(dtype):
     for pallas in (False, True):
         prog = sd.build_program(mesh, "items", k, k, plan, pallas=pallas)
         out[f"sharded-{'pallas' if pallas else 'scan'}"] = jax.make_jaxpr(
-            prog)(Y, act, Q, *((pen,) if pallas else ()))
+            prog)(Y, act, Q, np.int32(8), *((pen,) if pallas else ()))
     return {name: _dot_precisions(j.jaxpr) for name, j in out.items()}
 
 
@@ -440,7 +505,8 @@ def test_the_sharded_drain_marks_the_phases_of_the_one_chip_drain(toy):
                           "serving.decode"}
     assert spans["serving.prepare"]["attrs"] == {"rows": 9}
     assert spans["serving.scan"]["attrs"] == {
-        "shards": 4, "k": 32, "ksel": 64, "windows": [32], "lane_rows": 0}
+        "shards": 4, "k": 32, "ksel": 64, "windows": [32], "lane_rows": 0,
+        "real_rows": 9, "phase_b_row_share": 28.125}
     assert spans["serving.decode"]["attrs"] == {"rows": 9}
 
 
@@ -456,7 +522,8 @@ def test_the_sharded_program_is_one_program_named_twophase(width, toy):
     assert plan == sm.ShardPlan(ksel=32, chunk=1024, bs=BS)
     prog = sd.build_program(model._mesh, "items", 16, 16, plan)
     text = prog.lower(vecs, active,
-                      jnp.zeros((width, FEATURES), jnp.float32)).as_text()
+                      jnp.zeros((width, FEATURES), jnp.float32),
+                      np.int32(width)).as_text()
     assert "@jit_sharded_twophase_top_k" in text[:200]
     assert text.count("func.func public") == 1
     assert "all_gather" in text and "stablehlo.case" in text \
