@@ -2027,6 +2027,9 @@ class ALSServingModel(FactorModelBase, ServingModel):
                     Q = np.concatenate(
                         [Q, np.zeros((padded - n_req, Q.shape[1]),
                                      np.float32)])
+                if rec is not None:
+                    rec.step("serving.upload", windows=len(sizes),
+                             bytes=Q.nbytes)
                 # ``reals``: the requests of each window, THE count its
                 # program reads: its padding rows reach no bucket (a
                 # pruned pass) and phase B neither gathers nor rescores
@@ -2038,8 +2041,12 @@ class ALSServingModel(FactorModelBase, ServingModel):
                     w += size
                 if rec is not None:
                     # from the first program enqueued to the last result
-                    # fetched: it waits on the device, and on whatever
-                    # other drain the device is running.  ``ksel`` is the
+                    # on the host, in three steps: ``serving.launch``
+                    # (the enqueues, under the dispatch lock),
+                    # ``serving.device_wait`` (until every result is
+                    # ready: it waits on the device, and on whatever
+                    # other drain the device is running) and
+                    # ``serving.fetch`` (_fetch).  ``ksel`` is the
                     # width phase B selects (the int8 builds double it),
                     # 0 where the exact scan is the primary path;
                     # ``lane_rows`` how many of the windows phase A
@@ -2048,6 +2055,7 @@ class ALSServingModel(FactorModelBase, ServingModel):
                     rec.mark("serving.scan", k=k,
                              ksel=ksel if twophase else 0, windows=sizes,
                              lane_rows=0, real_rows=n_req)
+                    rec.step("serving.launch", programs=len(sizes))
                 if twophase:
                     handles, attempted = self._dispatch_twophase(
                         vecs, windows, active, version, reals, pruned, k,
@@ -2070,17 +2078,24 @@ class ALSServingModel(FactorModelBase, ServingModel):
                     Q = np.concatenate(
                         [Q, np.zeros((b_pad - n_req, Q.shape[1]),
                                      np.float32)])
+                if rec is not None:
+                    rec.step("serving.upload", windows=1, bytes=Q.nbytes)
                 Qd = jnp.asarray(Q)
                 if rec is not None:
                     rec.mark("serving.scan", k=k, ksel=0, windows=[b_pad],
                              lane_rows=0, real_rows=n_req)
+                    rec.step("serving.launch", programs=1)
                 handles = _batch_top_n_kernel(vecs, Qd, active, k)
+            if rec is not None:
+                # every program of the drain is enqueued; the dispatch
+                # lock goes on the way out
+                rec.step("serving.device_wait")
         # from here on the handles above are not touched again: a sync
         # may have donated them.  What a fallback needs it fetches anew
         # (_enqueue_exact), and answers from the version it finds
         if twophase:
-            fetched = self._fetch_twophase(handles, attempted, windows, k,
-                                           chunk, bs, ksel, reals, pruned)
+            fetched = self._fetch_twophase(rec, handles, attempted, windows,
+                                           k, chunk, bs, ksel, reals, pruned)
             if pruned:
                 self._note_pruned(rec, [f[3] for f in fetched])
             for w, (ts, ti, cert, *_) in enumerate(fetched):
@@ -2105,7 +2120,7 @@ class ALSServingModel(FactorModelBase, ServingModel):
             top_scores = np.concatenate([f[0] for f in fetched])
             top_idx = np.concatenate([f[1] for f in fetched])
         elif streaming:
-            fetched = jax.device_get(handles)
+            fetched = self._fetch(rec, handles)
             if pruned:
                 self._note_pruned(rec, [f[2] for f in fetched])
             top_scores = np.concatenate([f[0] for f in fetched])
@@ -2113,13 +2128,36 @@ class ALSServingModel(FactorModelBase, ServingModel):
         else:
             # fetch both outputs in ONE host round-trip (matters when the
             # device sits behind a high-latency transport)
-            top_scores, top_idx = jax.device_get(handles)
+            top_scores, top_idx = self._fetch(rec, handles)
         if rec is not None:
             rec.mark("serving.decode", rows=n_req)
         return self._decode_top_n(top_scores, top_idx, hm, excl, n_req,
                                   k < n_rows, np.asarray(user_vectors,
                                                          np.float32),
                                   use_lsh)
+
+    @staticmethod
+    def _fetch(rec, handles):
+        """A drain's results on the host, in ONE fetch.  With a recorder
+        open, and only then, the wait for the device and the copy are
+        told apart: the running ``serving.device_wait`` step ends when
+        every result is ready on the device, and ``serving.fetch``
+        (``arrays``, ``bytes``: what crosses) runs from there to the
+        drain's next phase.  The copies are started BEFORE that wait,
+        as ``device_get`` starts them, so that they follow the programs
+        on the device's queue as they do with no recorder: started
+        after it, each would cost a round trip of its own that an
+        untraced drain never pays (0.6 ms for three arrays, measured).
+        Without a recorder this is the one ``device_get`` and no other
+        device call."""
+        if rec is not None:
+            leaves = jax.tree_util.tree_leaves(handles)
+            for x in leaves:
+                x.copy_to_host_async()
+            jax.block_until_ready(handles)
+            rec.step("serving.fetch", arrays=len(leaves),
+                     bytes=sum(x.nbytes for x in leaves))
+        return jax.device_get(handles)
 
     def _note_phase_b(self, rec, sizes: list, reals: list) -> None:
         """Book a drain whose windows the two-phase program was just
@@ -2267,13 +2305,13 @@ class ALSServingModel(FactorModelBase, ServingModel):
                     k, bs, ksel, fold, ctx, chunk=chunk))
         return handles, attempted
 
-    def _fetch_twophase(self, handles: list, attempted: list, windows,
+    def _fetch_twophase(self, rec, handles: list, attempted: list, windows,
                         k: int, chunk: int, bs: int, ksel: int,
                         reals: list, pruned: bool) -> list:
-        """ONE fetch for the drain's two-phase programs, outside the
-        dispatch lock."""
+        """ONE fetch for the drain's two-phase programs (``_fetch``),
+        outside the dispatch lock."""
         try:
-            out = jax.device_get(handles)
+            out = self._fetch(rec, handles)
         except Exception as e:  # noqa: BLE001 — classified below
             fresh = [kk for kk in attempted
                      if _PALLAS_STATE.get(kk) != "ok"]
@@ -2553,15 +2591,20 @@ class ALSServingModel(FactorModelBase, ServingModel):
                 rec.mark("serving.scan", shards=shards, k=k,
                          ksel=plan.ksel if plan is not None else 0,
                          windows=sizes, lane_rows=0, real_rows=n_req)
+                # the one-chip drain's steps; the windows go to every
+                # device of the mesh, inside this phase
+                rec.step("serving.upload", windows=len(sizes),
+                         bytes=Q.nbytes * shards)
+            windows, reals, w = [], [], 0
+            for size in sizes:
+                windows.append(kernels.replicate(Q[w:w + size]))
+                reals.append(min(size, n_req - w))
+                w += size
+            if rec is not None:
+                rec.step("serving.launch", programs=len(sizes))
             if plan is None:
-                handles = kernels.flat(vecs, active, kernels.replicate(Q),
-                                       k)
+                handles = kernels.flat(vecs, active, windows[0], k)
             else:
-                windows, reals, w = [], [], 0
-                for size in sizes:
-                    windows.append(kernels.replicate(Q[w:w + size]))
-                    reals.append(min(size, n_req - w))
-                    w += size
                 handles, attempted = self._dispatch_twophase(
                     vecs, windows, active, snap.version, reals, False, k,
                     plan.chunk, plan.bs, plan.ksel)
@@ -2570,11 +2613,13 @@ class ALSServingModel(FactorModelBase, ServingModel):
                     rec.annotate(lane_rows=sum(
                         _scores_rows_on_lanes(key[2]) for key in attempted
                         if key[-1] == "pallas"))
+            if rec is not None:
+                rec.step("serving.device_wait")
         if plan is None:
-            top_scores, top_idx = jax.device_get(handles)
+            top_scores, top_idx = self._fetch(rec, handles)
         else:
             fetched = self._fetch_twophase(
-                handles, attempted, windows, k, plan.chunk, plan.bs,
+                rec, handles, attempted, windows, k, plan.chunk, plan.bs,
                 plan.ksel, reals, False)
             failed = [~f[2] for f in fetched]       # (shards, B) a window
             with self._bucket_lock:

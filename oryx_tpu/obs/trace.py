@@ -21,12 +21,13 @@ Context crosses process boundaries two ways:
 
 The batched device call is the one place where work is recorded once
 and read twice: :class:`DrainPhases` holds the phases of one drain
-(``serving.prepare`` / ``scan`` / ``fallback`` / ``decode``), each
-marked where the work happens.  A phase is a
-``jax.profiler.TraceAnnotation`` on the dispatcher thread — so it sits
-on the profiler's clock beside the device's operations — and a
-monotonic stamp; the batcher replays the stamps as ring spans under
-every sampled job of that drain.
+(``serving.prepare`` / ``scan`` / ``fallback`` / ``decode``) and, one
+level down, the steps inside a phase (``serving.upload`` / ``launch`` /
+``device_wait`` / ``fetch``), each marked where the work happens.  A
+phase or step is a ``jax.profiler.TraceAnnotation`` on the dispatcher
+thread — so it sits on the profiler's clock beside the device's
+operations — and a monotonic stamp; the batcher replays the stamps as
+ring spans under every sampled job of that drain.
 
 Recording is STRICTLY best-effort: a raising recorder (the
 ``obs-trace-drop`` chaos point stands in for any internal failure)
@@ -247,9 +248,9 @@ _drain = threading.local()
 
 def current_drain() -> "DrainPhases | None":
     """The recorder open on this thread, or None — tracing off, or a
-    ``top_n_batch`` caller that is not the batcher.  A phase site is
-    ``if rec is not None: rec.mark(...)``: with no recorder that is one
-    branch, no annotation, no clock read, no allocation."""
+    ``top_n_batch`` caller that is not the batcher.  A phase or step
+    site is ``if rec is not None: rec.mark(...)``: with no recorder that
+    is one branch, no annotation, no clock read, no allocation."""
     return getattr(_drain, "open", None)
 
 
@@ -257,19 +258,32 @@ class DrainPhases:
     """The phases of ONE batched device call, recorded once where the
     work happens.  While open (a context manager, on the thread that
     makes the call) :meth:`mark` closes the phase that is running and
-    opens the next: one clock read and one profiler annotation each.
-    Phases follow each other and never nest; the last one closes with
-    the recorder, as an error if an exception ends it.  Afterwards
-    :meth:`replay` writes them into a tracer's ring under one job's
-    ``serving.device_execute`` span."""
+    opens the next, and :meth:`step` does the same one level down, for a
+    piece of work INSIDE the running phase: one clock read and one
+    profiler annotation each.  Phases follow each other and never nest;
+    a phase's steps follow each other inside it, the last one ending
+    with the phase; the last phase closes with the recorder, and it and
+    its running step as errors if an exception ends it.  The two
+    readings differ in one thing.  On the profiler's line the
+    annotations are FLAT: exactly one is open at any instant, the
+    innermost piece of work running, so a phase's annotation closes
+    when its first step opens (an idle gap of the device takes the name
+    of the event that covers most of it, and an outer annotation would
+    win every gap that straddles two steps).  In the ring
+    (:meth:`replay`, under one job's ``serving.device_execute`` span) a
+    phase keeps its whole duration and its steps are its children."""
 
-    __slots__ = ("_phases", "_note", "_spans")
+    __slots__ = ("_phases", "_note", "_at", "_spans")
 
     def __init__(self):
-        # [name, start, end, attrs, status] in the order they ran
+        # [name, start, end, attrs, status, up] in the order they began:
+        # ``up`` is None for a phase and, for a step, its phase's index
         self._phases: list[list] = []
         self._note = None
-        self._spans: list[dict] | None = None
+        # the running phase's index; its running step, if any, is the
+        # last entry
+        self._at = 0
+        self._spans: tuple[list[dict], list] | None = None
 
     def __enter__(self):
         _drain.open = self
@@ -285,37 +299,64 @@ class DrainPhases:
         if self._note is not None:
             self._note.__exit__(None, None, None)
             self._note = None
-            self._phases[-1][2] = now
-            self._phases[-1][4] = status
+            # the running phase and its running step (one entry where
+            # no step runs)
+            for p in (self._phases[self._at], self._phases[-1]):
+                p[2] = now
+                p[4] = status
+
+    def _open(self, name: str, now: float, attrs: dict, up) -> None:
+        note = annotation(name)
+        note.__enter__()
+        # together, and last: an open annotation IS an open last entry,
+        # so the recorder's exit leaves none without its end
+        self._note = note
+        self._phases.append([name, now, None, attrs, "ok", up])
 
     def mark(self, name: str, **attrs) -> None:
         """``name`` starts here, with the counts known at this boundary,
-        and whatever phase was running ends here."""
+        and whatever phase was running ends here, its last step with
+        it."""
         now = clockmod.monotonic()
         self._close(now, "ok")
-        note = annotation(name)
-        note.__enter__()
-        # together, and last: an open annotation IS an open last phase,
-        # so the recorder's exit leaves no phase without its end
-        self._note = note
-        self._phases.append([name, now, None, attrs, "ok"])
+        self._at = len(self._phases)
+        self._open(name, now, attrs, None)
+
+    def step(self, name: str, **counts) -> None:
+        """``name``, a piece of work inside the running phase, starts
+        here, and the step that was running, if any, ends here.  The
+        one annotation open on the profiler's line from here on is the
+        step's."""
+        if self._note is None:  # no phase is running: it is one itself
+            return self.mark(name, **counts)
+        now = clockmod.monotonic()
+        self._note.__exit__(None, None, None)
+        self._note = None
+        last = self._phases[-1]
+        if last[5] is not None:
+            last[2] = now
+        self._open(name, now, counts, self._at)
 
     def annotate(self, **attrs) -> None:
         """Counts that are known only once the running phase's work is
-        done (what a sync carried) go onto it here."""
+        done (what a sync carried) go onto it here, whichever step of
+        it is running."""
         if self._phases:
-            self._phases[-1][3].update(attrs)
+            self._phases[self._at][3].update(attrs)
 
     def replay(self, tracer: "Tracer", trace_id: str,
                parent_id: str) -> None:
-        """The drain's phases as ring spans under ``parent_id``, written
-        under one acquisition of the tracer's lock.  What is the same
-        for every sampled job of the drain — names, stamps, attributes
-        (shared between the jobs' spans, read-only from here on) — is
-        worked out on the first call."""
+        """The drain's phases as ring spans under ``parent_id`` and
+        their steps under them, written under one acquisition of the
+        tracer's lock.  What is the same for every sampled job of the
+        drain — names, stamps, attributes (shared between the jobs'
+        spans, read-only from here on), who is whose child — is worked
+        out on the first call."""
         if self._spans is None:
-            self._spans = [tracer.span_fields(*p) for p in self._phases]
-        tracer.record_spans(trace_id, parent_id, self._spans)
+            self._spans = ([tracer.span_fields(*p[:5])
+                            for p in self._phases],
+                           [p[5] for p in self._phases])
+        tracer.record_spans(trace_id, parent_id, *self._spans)
 
 
 class Tracer:
@@ -443,19 +484,23 @@ class Tracer:
         }
 
     def record_spans(self, trace_id: str, parent_id: str,
-                     fields: list[dict]) -> None:
-        """Several finished spans (:meth:`span_fields`) of one trace,
-        siblings under ``parent_id``, under ONE acquisition of the
-        ring's lock.  ``fields`` is left as it was, so the same list
-        can be recorded under other traces; each span lost to a failing
-        recorder counts in ``record_failures``."""
+                     fields: list[dict], under: list | None = None) -> None:
+        """Several finished spans (:meth:`span_fields`) of one trace
+        under ONE acquisition of the ring's lock: children of
+        ``parent_id``, or, where ``under`` names for a span the index
+        of an EARLIER one of ``fields``, of that span.  ``fields`` is
+        left as it was, so the same list can be recorded under other
+        traces; each span lost to a failing recorder counts in
+        ``record_failures``."""
         try:
             spans = []
-            for f in fields:
+            for i, f in enumerate(fields):
                 span = dict(f)
                 span["trace_id"] = trace_id
                 span["span_id"] = _new_span_id()
-                span["parent_id"] = parent_id
+                up = under[i] if under is not None else None
+                span["parent_id"] = parent_id if up is None \
+                    else spans[up]["span_id"]
                 spans.append(span)
             self._append(trace_id, spans)
         except Exception:  # noqa: BLE001 — observability is best-effort

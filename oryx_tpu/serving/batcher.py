@@ -164,14 +164,17 @@ class TopNBatcher:
         device-execute span — the evidence that separates "the device
         is slow" from "the queue is deep" — and opens a phase recorder
         (obs/trace.py ``DrainPhases``) around every batched call, so
-        the model's prepare / scan / fallback / decode phases land
+        the model's prepare / scan / fallback / decode phases, and the
+        upload / launch / device_wait / fetch steps inside them, land
         under each sampled job's device-execute span and, as profiler
         annotations, on the dispatcher thread's line of a device trace.
         The pool's three idle states are annotated there too, each on
         one thread at a time (``serving.await_work``: nothing queued and
         nothing in flight; ``serving.await_slot``: work queued behind
         the in-flight cap; ``serving.await_return``: a drain held for
-        the callers just answered).
+        the callers just answered), and so is what a dispatcher does
+        between the model's return and its next wait
+        (``serving.release``), so that its line has no hole.
 
         ``accountant`` (obs/device_time.py, or None) books every
         batched device-execute bracket as route-class ``serve`` time
@@ -485,7 +488,11 @@ class TopNBatcher:
         whoever is still out is left behind and no longer waited for
         (they find a program running and share the next).  Returns what
         the drain's ``serving.queue_wait`` spans say of the batcher's
-        state."""
+        state: the verdict it leaves under (``in_flight``: the drains
+        dispatched and not completed, 0 for a lone drain, from 1 on a
+        drain bound BEHIND a running program) and what the verdict was
+        taken from (``overlap_share``, None while unmeasured, and
+        ``service_ms``, the S of the hold's rule)."""
         depth, why = self._depth()
         if why == "serial-probe" and self._in_flight:
             # this is the probe's one drain: whoever comes next waits
@@ -510,6 +517,10 @@ class TopNBatcher:
         left_behind, self._awaited = self._awaited, 0
         self.return_left_behind += left_behind
         return {"depth": depth, "depth_reason": why,
+                "in_flight": self._in_flight,
+                "overlap_share": None if self._overlap is None
+                else round(self._overlap, 3),
+                "service_ms": round(self._exec_ewma * 1e3, 3),
                 "held_ms": round(held * 1e3, 3),
                 "renewals": renewals, "left_behind": left_behind,
                 "return_hit_share": round(self._hit_share, 3)}
@@ -560,7 +571,7 @@ class TopNBatcher:
             scored = self._dispatch(jobs, note) if jobs else 0
             if stopped:
                 return
-            with self._cond:
+            with self._releasing(), self._cond:
                 self._in_flight -= 1
                 if probe:
                     self._probe_out = False
@@ -653,7 +664,9 @@ class TopNBatcher:
         the host had nothing to dispatch then says so, where it would
         otherwise carry no host event at all, and the annotation ends
         when the state does and covers no later gap.  No ring span: no
-        request owns the wait."""
+        request owns the wait.  (A wait that is this thread's alone
+        stays bare: the pool's line is then another thread's drain or
+        its ``serving.release``, :meth:`_releasing`.)"""
         if not pool_state or self._noted_by is not None:
             self._cond.wait(timeout)  # wall-clock: Condition poll on the real dispatch thread
             return
@@ -666,6 +679,16 @@ class TopNBatcher:
         # another has taken the place since
         if self._noted_by is me:
             self._noted_by = None
+
+    def _releasing(self):
+        """What a dispatcher does between the model's return and its
+        next wait or drain — the spans recorded, the lock taken, the
+        callers released, the lesson learnt — as a profiler annotation,
+        ``serving.release``, with a tracer only and like the waits no
+        ring span: with it every instant of a dispatcher's cycle is a
+        wait, a phase or step of the drain, or this."""
+        return obstrace.annotation("serving.release") \
+            if self._tracer is not None else obstrace.NOOP_SPAN
 
     def _wake_locked(self, n: int) -> None:
         """Wake ``n`` waiting dispatchers (the caller holds the
@@ -685,9 +708,11 @@ class TopNBatcher:
         """Queue-wait / device-execute spans for the sampled jobs of a
         drained group, and the drain's phases under each job's
         device-execute span (grandchildren of the request, so the
-        request's own children stay the two they were).  The queue-wait
-        span carries ``note``: the depth in force and why, how long the
-        drain was held for returning callers, how often one of them
+        request's own children stay the two they were), each phase's
+        steps under it.  The queue-wait span carries ``note``: the depth
+        in force and why, how many drains were in flight when this one
+        left and the two measurements the depth was chosen by, how long
+        the drain was held for returning callers, how often one of them
         extended the hold, how many it left behind, the hold's score
         (:meth:`_bind_locked`; shared, read-only from here on).  Recorded
         retroactively from stored monotonic stamps (the dispatcher has
@@ -785,31 +810,32 @@ class TopNBatcher:
                 for j in group:
                     j.error = e
             next_exec_start = clockmod.monotonic()
-            if self._accountant is not None:
-                # continuous occupancy: the same bracket the
-                # device_execute span measures, booked as serve-class
-                # device time against the model's route + generation
-                self._accountant.note(
-                    "serve",
-                    getattr(model, "kernel_route_label", None),
-                    getattr(model, "generation", None),
-                    next_exec_start - t_exec)
-            if phases is not None:
-                self._record_spans(group, t_exec, next_exec_start,
-                                   status, phases, note)
-            with self._cond:
-                # under the lock: up to `pipeline` dispatcher threads
-                # land here concurrently, and a bare += loses updates
-                self.batch_sizes.append(len(group))
-                self.total_dispatches += 1
-                if len(self.batch_sizes) > 10000:
-                    del self.batch_sizes[:5000]
-                # these callers are out with their answers from here
-                # on (before they are released: one that is back at
-                # once is counted), as far as the next drain has room
-                self._awaited = max(0, min(
-                    len(group), self.max_batch - len(self._pending)))
-                self._last_return = None
-            for j in group:
-                j.done.set()
+            with self._releasing():
+                if self._accountant is not None:
+                    # continuous occupancy: the same bracket the
+                    # device_execute span measures, booked as serve-class
+                    # device time against the model's route + generation
+                    self._accountant.note(
+                        "serve",
+                        getattr(model, "kernel_route_label", None),
+                        getattr(model, "generation", None),
+                        next_exec_start - t_exec)
+                if phases is not None:
+                    self._record_spans(group, t_exec, next_exec_start,
+                                       status, phases, note)
+                with self._cond:
+                    # under the lock: up to `pipeline` dispatcher threads
+                    # land here concurrently, and a bare += loses updates
+                    self.batch_sizes.append(len(group))
+                    self.total_dispatches += 1
+                    if len(self.batch_sizes) > 10000:
+                        del self.batch_sizes[:5000]
+                    # these callers are out with their answers from here
+                    # on (before they are released: one that is back at
+                    # once is counted), as far as the next drain has room
+                    self._awaited = max(0, min(
+                        len(group), self.max_batch - len(self._pending)))
+                    self._last_return = None
+                for j in group:
+                    j.done.set()
         return len(jobs)

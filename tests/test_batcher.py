@@ -797,11 +797,13 @@ def test_an_overlapping_device_keeps_its_deep_pipeline():
     assert stats["overlap_share"] > 0.5, stats
 
 
-def test_the_queue_wait_span_says_what_the_batcher_did():
+def _queue_waits_of_two_traced_callers(device):
+    """A second of two closed-loop callers with every request sampled;
+    returns (their ``serving.queue_wait`` spans in the order they were
+    recorded, the batcher's stats at the end)."""
     from oryx_tpu.obs.trace import Tracer
 
     tracer = Tracer("serving", sample_ratio=1.0, max_traces=4096)
-    device = _SerialDevice(0.03)
     batcher = TopNBatcher(pipeline=8, idle_wait_s=0.02, tracer=tracer)
     stop = time.monotonic() + 1.0
 
@@ -819,11 +821,17 @@ def test_the_queue_wait_span_says_what_the_batcher_did():
         for t in threads:
             t.join(10.0)
             assert not t.is_alive()
+        stats = batcher.stats()
     finally:
         batcher.close()
     waits = [s for spans in tracer.traces_snapshot(limit=4096).values()
              for s in spans if s["name"] == "serving.queue_wait"]
     assert waits
+    return waits, stats
+
+
+def test_the_queue_wait_span_says_what_the_batcher_did():
+    waits, _ = _queue_waits_of_two_traced_callers(_SerialDevice(0.03))
     assert all({"depth", "depth_reason", "held_ms", "renewals",
                 "left_behind", "return_hit_share"}
                <= set(s["attrs"]) for s in waits)
@@ -835,6 +843,53 @@ def test_the_queue_wait_span_says_what_the_batcher_did():
     # never renewed, and a drain leaves nobody behind once in step
     assert {a["renewals"] for a in late} == {0}
     assert sum(a["left_behind"] for a in late) <= len(late) // 10
+
+
+@pytest.mark.parametrize("overlapping", [False, True],
+                         ids=["serial", "overlapping"])
+def test_the_queue_wait_span_carries_the_verdict_and_its_measurements(
+        overlapping):
+    """PR 39: what ``_depth`` decided is on the span already (``depth``,
+    ``depth_reason``); these say what it decided FROM and what became of
+    the drain: ``in_flight`` (the drains dispatched and not completed
+    when this one left: 0 a lone drain, from 1 on a drain bound behind a
+    running program), ``overlap_share`` (what was compared with
+    ``_SERIAL_OVERLAP``; None while unmeasured) and ``service_ms`` (S).
+    The benchmark's ``batcher.behind_share`` is the share of the spans
+    with ``in_flight`` >= 1."""
+    from oryx_tpu.serving.batcher import _SERIAL_OVERLAP
+
+    spans, stats = _queue_waits_of_two_traced_callers(
+        _SerialDevice(0.03, overlapping=overlapping))
+    waits = [s["attrs"] for s in spans]
+    assert all({"in_flight", "overlap_share", "service_ms"} <= set(a)
+               for a in waits)
+    # nothing is known before two drains have queued one behind the
+    # other, which is how the first two callers leave: one of them bound
+    # behind the other's running program
+    first = min(waits, key=lambda a: a["overlap_share"] is not None)
+    assert first["overlap_share"] is None
+    assert first["depth_reason"] == "unmeasured"
+    assert any(a["in_flight"] >= 1 for a in waits)
+    late = waits[len(waits) // 2:]
+    assert all(0.0 <= a["overlap_share"] <= 1.0 for a in late)
+    assert late[-1]["overlap_share"] == pytest.approx(
+        stats["overlap_share"], abs=0.2)
+    if overlapping:
+        # S is the gap between completions, most of a 30 ms call hidden
+        assert all(0.0 < a["service_ms"] < 30.0 for a in late)
+        assert all(a["overlap_share"] >= _SERIAL_OVERLAP for a in late)
+        assert {a["depth_reason"] for a in late} <= {"pipelined",
+                                                     "pipelined-probe"}
+        # two in flight hide each other: drains leave behind drains
+        assert any(a["in_flight"] >= 1 for a in late)
+    else:
+        # the program's 30 ms, as the batcher has learnt it
+        assert all(20.0 <= a["service_ms"] <= 45.0 for a in late)
+        assert all(a["overlap_share"] < _SERIAL_OVERLAP for a in late)
+        assert {a["depth_reason"] for a in late} == {"serial"}
+        # one program at a time: every drain leaves alone
+        assert {a["in_flight"] for a in late} == {0}
 
 
 def test_no_wakeup_is_lost_under_a_crowd_of_callers():

@@ -36,11 +36,12 @@ _SPAN_RE = re.compile(r"^[a-z][a-z0-9_]*\.[a-z][a-z0-9_]*$")
 _NAME_RE = re.compile(r"^[a-z][a-z0-9_]*$")
 
 # (method attribute, index of the positional name argument)
-# mark/annotation: obs/trace.py's drain phases and bare profiler
-# annotations; _await_locked: the batcher's annotated condition wait
+# mark/step/annotation: obs/trace.py's drain phases, the steps inside
+# them and bare profiler annotations; _await_locked: the batcher's
+# annotated condition wait
 _SPAN_METHODS = {"span": 0, "child_span": 1, "record_span": 0,
-                 "mark": 0, "annotation": 0, "_await_locked": 0,
-                 "phase": 0}
+                 "mark": 0, "step": 0, "annotation": 0,
+                 "_await_locked": 0, "phase": 0}
 _COUNTER_METHODS = {"inc": 0}
 _GAUGE_METHODS = {"set_gauge": 0, "gauge_fn": 0}
 
@@ -123,6 +124,18 @@ def test_walk_sees_the_known_call_sites(source_names):
     assert "partial_answers" in source_names["counter"]
     assert "ingest_to_servable_ms" in source_names["gauge"]
     assert "update_lag_records" in source_names["gauge"]
+
+
+@pytest.mark.parametrize("name", [
+    "serving.upload", "serving.launch", "serving.device_wait",
+    "serving.fetch", "serving.release"])
+def test_the_drain_steps_are_walked_and_catalogued(name, source_names,
+                                                   catalog):
+    """PR 39: the steps inside a drain's phases (``DrainPhases.step``)
+    and the dispatcher's annotation between a drain and its next wait
+    are literals the walk sees, and rows of the span table."""
+    assert name in source_names["span"]
+    assert name in catalog
 
 
 def test_every_source_name_is_catalogued(source_names, catalog):

@@ -1,9 +1,12 @@
 """The phases of a drain (``serving.prepare`` / ``scan`` / ``fallback``
-/ ``decode``): recorded once where the work happens, read twice — as
-ring spans under each sampled job's ``serving.device_execute`` and as
-profiler annotations on the dispatcher thread — plus the dispatchers'
-two annotated waits, and the program names the benchmark's device
-metrics tell the two-phase scan from its exact-scan fallback by."""
+/ ``decode``) and the steps inside them (``serving.upload`` / ``launch``
+/ ``device_wait`` / ``fetch``): recorded once where the work happens,
+read twice — as ring spans under each sampled job's
+``serving.device_execute`` and as profiler annotations on the
+dispatcher thread, where one is open at a time — plus the dispatchers'
+annotated waits and ``serving.release``, and the program names the
+benchmark's device metrics tell the two-phase scan from its exact-scan
+fallback by."""
 
 import ast
 import inspect
@@ -25,6 +28,19 @@ from oryx_tpu.serving.batcher import TopNBatcher, _Job
 
 ITEMS, FEATURES = 4096, 8
 PHASES = ["serving.prepare", "serving.scan", "serving.decode"]
+# the steps inside each phase of a one-chip drain, in the order they run
+# (the sharded drain uploads inside ``serving.scan``: SHARDED_STEPS)
+STEPS = {"serving.prepare": ["serving.upload"],
+         "serving.scan": ["serving.launch", "serving.device_wait",
+                          "serving.fetch"]}
+SHARDED_STEPS = {"serving.scan": ["serving.upload", "serving.launch",
+                                  "serving.device_wait", "serving.fetch"]}
+STEP_NAMES = [step for phase in PHASES for step in STEPS.get(phase, [])]
+# a drain's annotations on the dispatcher's line in the order they open
+# (a phase's closes when its first step opens), and the batcher's own
+# once the model has returned
+LINE = [name for phase in PHASES
+        for name in [phase, *STEPS.get(phase, [])]] + ["serving.release"]
 
 
 @pytest.fixture(scope="module")
@@ -74,9 +90,10 @@ def _vectors(n, seed=7):
         (n, FEATURES)).astype(np.float32)
 
 
-def _drain(batcher, tracer, model, n, sampled):
+def _drain(batcher, tracer, model, n, sampled, known=0):
     """One drain of ``n`` jobs dispatched on this thread, the first
-    ``sampled`` of them traced; returns (jobs, their request spans)."""
+    ``sampled`` of them traced, each excluding the first ``known``
+    items; returns (jobs, their request spans)."""
     requests, jobs = [], []
     for i, vec in enumerate(_vectors(n)):
         ctx = None
@@ -85,7 +102,8 @@ def _drain(batcher, tracer, model, n, sampled):
             tracer._swap(None)
             requests.append(req)
             ctx = (req.trace_id, req.span_id)
-        jobs.append(_Job(model, 5, vec, set(), trace_ctx=ctx))
+        jobs.append(_Job(model, 5, vec, {f"i{j}" for j in range(known)},
+                         trace_ctx=ctx))
     assert batcher._dispatch(jobs) == n
     for j in jobs:
         assert j.error is None and len(j.result) == 5
@@ -303,7 +321,7 @@ def test_the_requests_own_children_stay_the_two_they_were(model, ladder):
     (execute,) = [s for s in spans if s["name"] == "serving.device_execute"]
     assert sorted(s["name"] for s in spans
                   if s["parent_id"] == execute["span_id"]) == sorted(PHASES)
-    assert len(spans) == 6
+    assert len(spans) == 6 + len(STEP_NAMES)
 
 
 @pytest.mark.parametrize("jobs, sampled", [(1, 0), (3, 1), (3, 3), (9, 2)])
@@ -315,13 +333,13 @@ def test_each_phase_is_annotated_once_per_drain(jobs, sampled, model,
     _, requests = _drain(batcher, tracer, model, jobs, sampled)
     mine = [name for name, thread in seen if thread == me]
     # whatever the drain's size and however many of it were sampled
-    assert mine == PHASES
+    assert mine == LINE
     assert [e for e in order if e[1] in PHASES] == [
         (what, name) for name in PHASES for what in ("open", "close")]
     recorded = [s for r in requests for s in tracer.spans_for(r.trace_id)]
     assert sorted(s["name"] for s in recorded) == sorted(
-        (PHASES + ["serving.queue_wait", "serving.device_execute"])
-        * sampled)
+        (PHASES + STEP_NAMES + ["serving.queue_wait",
+                                "serving.device_execute"]) * sampled)
 
 
 def _until(cond, what, timeout=10.0):
@@ -383,8 +401,8 @@ def test_the_waits_are_annotated_and_never_ring_recorded(model, ladder,
     assert all(t.startswith("TopNBatcher-") for _, t in waits)
     ring = {s["name"] for spans in tracer.traces_snapshot().values()
             for s in spans}
-    assert ring == set(PHASES) | {"serving.request", "serving.queue_wait",
-                                  "serving.device_execute"}
+    assert ring == set(PHASES) | set(STEP_NAMES) | {
+        "serving.request", "serving.queue_wait", "serving.device_execute"}
 
 
 def _open_waits(order):
@@ -430,8 +448,11 @@ def test_one_dispatcher_carries_the_pools_wait_and_work_ends_it(
     assert set(_open_waits(order)) == {0, 1}
     assert _open_waits(order)[-1] == 0  # close() let the last one go
     # each request ended one wait, and the drained pool began the next
+    # (``serving.release`` twice: the callers released in the dispatch,
+    # the lesson learnt in the loop)
     assert [name for what, name in order if what == "open"] == (
-        ["serving.await_work"] + PHASES) * 3 + ["serving.await_work"]
+        ["serving.await_work"] + LINE + ["serving.release"]) * 3 \
+        + ["serving.await_work"]
     closes = [i for i, e in enumerate(order)
               if e == ("close", "serving.await_work")]
     decodes = [i for i, e in enumerate(order)
@@ -504,8 +525,9 @@ def test_a_raising_recorder_still_answers_the_drain(model, traced, ladder):
     try:
         faults.inject("obs-trace-drop", mode="error", times=100)
         _, (req,) = _drain(batcher, tracer, model, 3, sampled=1)
-        # queue_wait, device_execute and the three phases all dropped
-        assert tracer.record_failures == 5
+        # queue_wait, device_execute, the three phases and their steps
+        # all dropped
+        assert tracer.record_failures == 2 + len(PHASES) + len(STEP_NAMES)
         assert tracer.spans_for(req.trace_id) == []
     finally:
         faults.clear()
@@ -531,10 +553,275 @@ def test_a_failing_scan_closes_its_phase_as_an_error(model, traced, ladder,
     assert spans["serving.prepare"][0]["status"] == "ok"
     assert spans["serving.scan"][0]["status"] == "error"
     assert "serving.decode" not in spans
-    # no annotation is left open on the dispatcher's line
-    assert order == [(what, name) for name in PHASES[:2]
+    # no annotation is left open on the dispatcher's line: the drain's
+    # up to the step the failure ended, then the batcher's own
+    ran = LINE[:LINE.index("serving.launch") + 1] + ["serving.release"]
+    assert order == [(what, name) for name in ran
                      for what in ("open", "close")]
     assert obstrace.current_drain() is None
+
+
+# -- the steps inside a phase (PR 39) -------------------------------------------
+
+SHARDS, SHARD_ROWS = 4, 8192
+# the three branches of the one-chip drain and the two of the sharded one
+DRAINS = ["ladder", "exact", "flat", "sharded", "sharded-flat"]
+
+
+@pytest.fixture(scope="module")
+def sharded():
+    """The same kind of model row-sharded over four of tier-1's virtual
+    CPU devices: under ``ladder`` a shard's 8,192 rows run the two-phase
+    scan inside the SPMD program, without it the flat body."""
+    rng = np.random.default_rng(39)
+    rows = SHARDS * SHARD_ROWS
+    m = ALSServingModel(FEATURES, implicit=True, dtype="float32",
+                        item_shards=SHARDS)
+    m.Y.bulk_load([f"i{j}" for j in range(rows)],
+                  rng.standard_normal((rows, FEATURES)).astype(np.float32))
+    return m
+
+
+def _drain_of(kind, request):
+    """(model, excluded ids a job, steps by phase, the counts each step
+    carries) for a three-job drain of that kind."""
+    if kind in ("ladder", "exact", "sharded"):
+        request.getfixturevalue("ladder")
+    # 50 known items fetch k = 64, which the toy store's 64 blocks cannot
+    # select for: the exact scan, as the primary path
+    known = 50 if kind == "exact" else 0
+    k = 64 if kind == "exact" else 8
+    window = 8 * FEATURES * 4             # an [8] window of float32 queries
+    result = 8 * k * 4                    # its scores, or its rows
+    if kind.startswith("sharded"):
+        # scores, rows and on the ladder a certificate a (shard, row)
+        fetch = {"arrays": 3, "bytes": 2 * result + SHARDS * 8} \
+            if kind == "sharded" else {"arrays": 2, "bytes": 2 * result}
+        return (request.getfixturevalue("sharded"), known, SHARDED_STEPS,
+                {"serving.upload": {"windows": 1,
+                                    "bytes": window * SHARDS},
+                 "serving.launch": {"programs": 1},
+                 "serving.device_wait": {}, "serving.fetch": fetch})
+    fetch = {"arrays": 3, "bytes": 2 * result + 8} if kind == "ladder" \
+        else {"arrays": 2, "bytes": 2 * result}
+    return (request.getfixturevalue("model"), known, STEPS,
+            {"serving.upload": {"windows": 1, "bytes": window},
+             "serving.launch": {"programs": 1},
+             "serving.device_wait": {}, "serving.fetch": fetch})
+
+
+def _traced_drain(batcher, tracer, model, known, sampled=3):
+    """Three jobs in one drain on this thread; returns (jobs, each
+    sampled job's request span and its trace's spans by name)."""
+    jobs, requests = _drain(batcher, tracer, model, 3, sampled, known)
+    return jobs, [(req, _by_name(tracer.spans_for(req.trace_id)))
+                  for req in requests]
+
+
+@pytest.mark.parametrize("kind", DRAINS)
+def test_steps_land_under_their_phase_in_every_sampled_job(kind, traced,
+                                                           request):
+    model, known, steps, counts = _drain_of(kind, request)
+    batcher, tracer = traced
+    _, traces = _traced_drain(batcher, tracer, model, known)
+    stamps = set()
+    for req, spans in traces:
+        (execute,) = spans["serving.device_execute"]
+        # the request's children and the drain's phases are who they were
+        assert sorted(s["name"] for ss in spans.values() for s in ss
+                      if s["parent_id"] == req.span_id) == [
+            "serving.device_execute", "serving.queue_wait"]
+        assert sorted(s["name"] for ss in spans.values() for s in ss
+                      if s["parent_id"] == execute["span_id"]) \
+            == sorted(PHASES)
+        for phase, names in steps.items():
+            (parent,) = spans[phase]
+            for name in names:
+                (step,) = spans[name]
+                assert step["parent_id"] == parent["span_id"]
+                assert step["trace_id"] == req.trace_id
+                assert step["status"] == "ok"
+                assert step["attrs"] == counts[name], name
+        assert sum(len(ss) for ss in spans.values()) \
+            == 2 + len(PHASES) + len(STEP_NAMES)
+        stamps.add(tuple((spans[n][0]["start_ms"], spans[n][0]["duration_ms"])
+                         for n in STEP_NAMES))
+    # recorded once, replayed three times
+    assert len(stamps) == 1
+    # the scan keeps its attributes, whichever step was running when
+    # they became known
+    scan = traces[0][1]["serving.scan"][0]["attrs"]
+    assert {"k", "ksel", "windows", "lane_rows", "real_rows"} <= set(scan)
+    assert ("phase_b_row_share" in scan) == (kind in ("ladder", "sharded"))
+    assert ("shards" in scan) == kind.startswith("sharded")
+
+
+@pytest.mark.parametrize("kind", DRAINS)
+def test_steps_tile_their_phase(kind, traced, request):
+    """From the first step's start to the last one's end a phase's steps
+    follow each other with no hole, and the last ends with the phase:
+    the phase less the sliver before its first step (stamps are rounded
+    to a microsecond)."""
+    model, known, steps, _ = _drain_of(kind, request)
+    batcher, tracer = traced
+    _, ((_, spans),) = _traced_drain(batcher, tracer, model, known,
+                                     sampled=1)
+    for phase, names in steps.items():
+        (parent,) = spans[phase]
+        end = parent["start_ms"] + parent["duration_ms"]
+        at, covered = None, 0.0
+        for name in names:
+            (step,) = spans[name]
+            if at is None:
+                assert step["start_ms"] >= parent["start_ms"] - 0.002
+            else:
+                assert step["start_ms"] == pytest.approx(at, abs=0.002)
+            at = step["start_ms"] + step["duration_ms"]
+            covered += step["duration_ms"]
+        assert at == pytest.approx(end, abs=0.002)
+        sliver = spans[names[0]][0]["start_ms"] - parent["start_ms"]
+        assert covered == pytest.approx(parent["duration_ms"] - sliver,
+                                        abs=0.002 * (len(names) + 1))
+    # the scan's sliver is the one mark: its steps ARE the scan
+    (scan,) = spans["serving.scan"]
+    first = spans[steps["serving.scan"][0]][0]
+    assert first["start_ms"] - scan["start_ms"] < 0.5
+
+
+@pytest.mark.parametrize("kind", DRAINS)
+def test_one_annotation_is_open_at_a_time(kind, traced, request, notes):
+    """The profiler's line is FLAT: a phase's annotation closes when its
+    first step opens, a step's when the next step or phase opens, so an
+    idle gap of the device takes the name of the piece of work that
+    covers most of it and never the enclosing phase's."""
+    model, known, steps, _ = _drain_of(kind, request)
+    _, order = notes
+    batcher, tracer = traced
+    _traced_drain(batcher, tracer, model, known, sampled=1)
+    mine = [e for e in order if not e[1].startswith("serving.await")]
+    line = [name for phase in PHASES
+            for name in [phase, *steps.get(phase, [])]] + ["serving.release"]
+    assert mine == [(what, name) for name in line
+                    for what in ("open", "close")]
+
+
+@pytest.mark.parametrize("step, breaks", [
+    ("serving.launch", (ALSServingModel, "_dispatch_twophase")),
+    ("serving.device_wait", (jax, "block_until_ready")),
+    ("serving.fetch", (jax, "device_get"))])
+def test_a_failing_step_closes_itself_and_its_phase_as_errors(
+        step, breaks, model, traced, ladder, monkeypatch, notes):
+    _, order = notes
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(*breaks, broken)
+    batcher, tracer = traced
+    req = tracer.begin_request("serving.request")
+    tracer._swap(None)
+    job = _Job(model, 5, _vectors(1)[0], set(),
+               trace_ctx=(req.trace_id, req.span_id))
+    batcher._dispatch([job])
+    assert isinstance(job.error, RuntimeError)
+    spans = _by_name(tracer.spans_for(req.trace_id))
+    ran = LINE[:LINE.index(step) + 1]
+    assert sorted(spans) == sorted(
+        ran + ["serving.queue_wait", "serving.device_execute"])
+    status = {name: spans[name][0]["status"] for name in ran}
+    assert status == dict({name: "ok" for name in ran},
+                          **{"serving.scan": "error", step: "error"})
+    (scan,), (last,) = spans["serving.scan"], spans[step]
+    # ... at the same instant, the failure's
+    assert last["start_ms"] + last["duration_ms"] == pytest.approx(
+        scan["start_ms"] + scan["duration_ms"], abs=0.002)
+    assert [e for e in order
+            if not e[1].startswith("serving.await")] == [
+        (what, name) for name in ran + ["serving.release"]
+        for what in ("open", "close")]
+    assert obstrace.current_drain() is None
+
+
+@pytest.mark.parametrize("kind", DRAINS)
+def test_without_a_recorder_nothing_waits_for_the_device_and_answers_match(
+        kind, traced, request, monkeypatch):
+    """``block_until_ready`` is what tells the wait for the device from
+    the copy, and a recorder's alone: a drain with none makes the ONE
+    ``device_get`` it made before the steps existed, and returns the
+    traced drain's answers bit for bit."""
+    model, known, _, _ = _drain_of(kind, request)
+    batcher, tracer = traced
+    jobs, _ = _traced_drain(batcher, tracer, model, known)
+
+    def never(*args, **kwargs):
+        raise AssertionError("an untraced drain waited for the device")
+
+    fetches = []
+    real = jax.device_get
+    monkeypatch.setattr(jax, "block_until_ready", never)
+    monkeypatch.setattr(jax, "device_get",
+                        lambda x: fetches.append(1) or real(x))
+    got = model.top_n_batch([5] * 3, np.stack([j.vector for j in jobs]),
+                            [j.exclude for j in jobs])
+    assert got == [j.result for j in jobs]
+    assert len(fetches) == 1
+    plain = TopNBatcher(pipeline=1)
+    try:
+        untraced = [_Job(model, 5, j.vector, j.exclude) for j in jobs]
+        assert plain._dispatch(untraced) == 3
+    finally:
+        plain.close()
+    assert [j.result for j in untraced] == [j.result for j in jobs]
+    assert len(fetches) == 2
+
+
+class _Ticks:
+    """A clock that moves a millisecond a reading."""
+
+    def __init__(self):
+        self.now = 0.0
+
+    def __call__(self):
+        self.now += 0.001
+        return self.now
+
+
+def test_a_recorder_replays_its_steps_under_their_phases(monkeypatch,
+                                                         notes):
+    """``DrainPhases`` alone, on a clock that ticks once a reading: one
+    reading a ``mark`` or ``step``; ``annotate`` reaches the running
+    PHASE whichever step runs; a step with no phase running is a phase."""
+    _, order = notes
+    monkeypatch.setattr(obstrace.clockmod, "monotonic", _Ticks())
+    with obstrace.DrainPhases() as rec:
+        rec.step("serving.early")                    # 1 ms: a phase
+        rec.mark("serving.scan", k=8)                # 2
+        rec.step("serving.launch", programs=2)       # 3
+        rec.annotate(lane_rows=1)
+        rec.step("serving.device_wait")              # 4
+        rec.annotate(lsh_steps=7)
+        rec.mark("serving.decode", rows=3)           # 5
+    assert [(p[0], round(p[1] * 1e3), round(p[2] * 1e3), p[3], p[5])
+            for p in rec._phases] == [
+        ("serving.early", 1, 2, {}, None),
+        ("serving.scan", 2, 5, {"k": 8, "lane_rows": 1, "lsh_steps": 7},
+         None),
+        ("serving.launch", 3, 4, {"programs": 2}, 1),
+        ("serving.device_wait", 4, 5, {}, 1),
+        ("serving.decode", 5, 6, {"rows": 3}, None)]
+    assert order == [(what, p[0]) for p in rec._phases
+                     for what in ("open", "close")]
+    tracer = Tracer("svc", sample_ratio=1.0)
+    for trace in ("a" * 32, "b" * 32):
+        rec.replay(tracer, trace, "e" * 16)
+        by = {s["name"]: s for s in tracer.spans_for(trace)}
+        named = {s["span_id"]: n for n, s in by.items()}
+        assert {n: named.get(s["parent_id"]) for n, s in by.items()} == {
+            "serving.early": None, "serving.scan": None,
+            "serving.launch": "serving.scan",
+            "serving.device_wait": "serving.scan", "serving.decode": None}
+        assert by["serving.scan"]["duration_ms"] == 3.0
+        assert by["serving.launch"]["duration_ms"] == 1.0
 
 
 def test_record_span_hands_back_the_id_it_made():

@@ -502,7 +502,14 @@ def test_the_sharded_drain_marks_the_phases_of_the_one_chip_drain(toy):
     spans = {s["name"]: s for s in tracer.spans_for(req.trace_id)}
     assert set(spans) == {"serving.queue_wait", "serving.device_execute",
                           "serving.prepare", "serving.scan",
-                          "serving.decode"}
+                          "serving.decode",
+                          # the scan's steps (PR 39): the sharded drain
+                          # places its windows inside it
+                          "serving.upload", "serving.launch",
+                          "serving.device_wait", "serving.fetch"}
+    assert {spans[s]["parent_id"] for s in (
+        "serving.upload", "serving.launch", "serving.device_wait",
+        "serving.fetch")} == {spans["serving.scan"]["span_id"]}
     assert spans["serving.prepare"]["attrs"] == {"rows": 9}
     assert spans["serving.scan"]["attrs"] == {
         "shards": 4, "k": 32, "ksel": 64, "windows": [32], "lane_rows": 0,
