@@ -12,28 +12,37 @@ threads drain whatever is queued and issue one batched kernel call
 each.  WHEN pending requests are bound to a device program is the one
 decision made here, and it follows two measurements:
 
-- *How deep to keep the device's queue.*  The wall of a drain that ran
-  alone (round trip + one execution) against the gap between the
-  completions of drains that queued one behind the other (one
-  execution).  Where the two are far apart — device calls overlap, or a
-  transport round trip dominates — the cap is ceil(round_trip /
-  service_time) + 1: deeper only stacks device-queue latency (observed
-  before the cap existed: free dispatchers shredded a 5M-item model's
-  queue into tiny batches that serialized on the device, 3% of
-  achievable throughput with 3 s device-queue latency), and a blocked
-  dispatcher wakes on the next completion and drains everything that
-  queued during one service interval.  Where they are equal within a
-  quarter — a locally attached chip that runs one program after another
-  — a second program in flight hides nothing and costs every request
-  that arrives meanwhile a pass over the store of its own, so the cap
-  is ONE: a request that arrives while a program runs is bound late,
-  at that program's completion, together with everything else that is
-  waiting.  It loses nothing (its program could not have started
-  earlier) and shares its pass.  Each depth hides one of the two
-  measurements (at one nothing queues behind anything; a saturated
-  pipeline never runs a drain alone), so now and then one drain is
-  dispatched the other way to take the hidden one: :meth:`_depth` says
-  when and what it costs.
+- *How deep to keep the device's queue.*  What a second program in
+  flight buys or costs the callers is their CYCLE, one answer to the
+  next, and the batcher clocks both ways of running it, each in drains
+  of the kind it speaks of.  *One behind another:* the loop's N
+  requests ride two drains, the running program and the drain bound
+  behind it, and every caller's program runs after the other's, so a
+  cycle is twice the gap between the completions of drains that queued
+  one behind the other.  *In one shared pass:* a cycle is the wall of a
+  drain that ran ALONE and carried about N requests (kept by drain
+  size, because a pass is free to share over an exact store and is not
+  where a wider window streams more), plus the callers' way back (from
+  the completion to the moment the drain the hold held for them left).
+  Where the shared cycle is no longer than the other — a locally
+  attached chip that runs one program after another — the cap is ONE: a
+  request that arrives while a program runs is bound late, at that
+  program's completion, together with everything else that is waiting.
+  It loses nothing (its program could not have started earlier) and
+  shares its pass.  Where two programs one behind the other turn the
+  callers round sooner — device calls overlap, a transport round trip
+  dominates, or a lone request's program is so much shorter than the
+  shared one that two of them and the host path between beat it — the
+  cap is ceil(round_trip / service_time) + 1: deeper only stacks
+  device-queue latency (observed before the cap existed: free
+  dispatchers shredded a 5M-item model's queue into tiny batches that
+  serialized on the device, 3% of achievable throughput with 3 s
+  device-queue latency), and a blocked dispatcher wakes on the next
+  completion and drains everything that queued during one service
+  interval.  Each depth hides one side of the comparison (at one
+  nothing queues behind anything; a saturated pipeline never runs a
+  drain alone), so now and then one drain is dispatched the other way
+  to take the hidden one: :meth:`_depth` says when and what it costs.
 - *Whether to hold a drain for the callers just answered.*  A
   completion releases n callers; closed-loop callers are back within a
   fraction of a service time, one after another through the door, and
@@ -54,6 +63,7 @@ decision made here, and it follows two measurements:
 from __future__ import annotations
 
 import threading
+from collections import deque
 from typing import Iterable
 
 import numpy as np
@@ -71,13 +81,6 @@ __all__ = ["TopNBatcher"]
 _MIN_EXEC_S = 0.0005
 _MAX_EXEC_S = 5.0
 
-# The device runs drains one after another with nothing to hide behind a
-# second one where pipelining saves less than this share of a lone
-# drain's wall.  This chip: 15.1 ms completion gap at depth 2 against a
-# 15.5 ms lone wall, 3%, and single samples up to 16% where a narrow
-# program follows a wide one (the fetched k moves a program by +-7%);
-# an overlapping or remote device: 85-99%.
-_SERIAL_OVERLAP = 0.25
 # Each depth hides one of the two measurements it is chosen by: at depth
 # one no drain queues behind another, in a saturated pipeline none runs
 # alone.  After this many completions without the hidden one, ONE drain
@@ -109,6 +112,33 @@ _HOLD_TOTAL_FRACTION = 4
 _HIT_SHARE_ON = 0.5
 _HIT_SHARE_GAIN = 0.1
 _HOLD_PROBE_EVERY = 32
+
+
+def _size_class(n: int) -> int:
+    """Drains of 1, 2, 3-4, 5-8, ... requests: class 0, 1, 2, 3, ..."""
+    return max(0, n - 1).bit_length()
+
+
+class _Recent:
+    """A time the batcher clocks, as the verdict reads it: ``mid``, the
+    middle one of its last five readings (None before the first).  No
+    single reading moves it, whichever way it is wrong: a stall of the
+    host lengthens a wall, a way back or the gaps of both drains in
+    flight, a completion stamped late shortens the next gap to nothing,
+    and one of a hundred lone drains is short; a mean or a least value
+    that one of those carries over the line stays there until the next
+    probe.  Sorted when a reading comes in, so that reading it inside
+    the wait loops is an attribute."""
+
+    __slots__ = ("_last", "mid")
+
+    def __init__(self):
+        self._last: deque[float] = deque(maxlen=5)
+        self.mid: float | None = None
+
+    def add(self, reading: float) -> None:
+        self._last.append(reading)
+        self.mid = sorted(self._last)[len(self._last) // 2]
 
 
 class _Job:
@@ -203,15 +233,29 @@ class TopNBatcher:
         # one, the wall of a drain; optimistic until measured
         self._exec_ewma = _MIN_EXEC_S
         self._exec_measured = False
-        # least recent wall of a drain that was dispatched with nothing
-        # in flight ~= round trip + one exec.  A drain that queued
-        # behind another teaches nothing here: its wall holds the other
-        # one's device time, which is no round trip
+        # the two cycles the verdict compares (_cycles), every reading a
+        # _Recent.  In one shared pass: the wall of a drain dispatched
+        # with nothing in flight (round trip + one exec; a drain that
+        # queued behind another teaches nothing here, its wall holds the
+        # other one's device time), by the size of the drain (class c
+        # holds 2^(c-1) < n <= 2^c: 1, 2, 3-4, 5-8, ...), the least of
+        # them over the sizes (the round trip's floor, for the depth's
+        # formula), and how long after a completion the drain the hold
+        # held for the returning callers left.  One behind another: the
+        # completion gap of drains that queued behind a running program
+        # (only such drains feed it, where _exec_ewma turns into a lone
+        # drain's wall at a depth of one), the gap before it, and how
+        # many requests the last of them and the drains in flight
+        # before it carried together, the N of the loop
+        self._lone_walls = [_Recent()
+                            for _ in range(1 + _size_class(max_batch))]
         self._wall_min = float("inf")
-        # share of a lone drain's wall that a second drain in flight
-        # hides: 1 - completion gap / lone wall, running mean; None
-        # until two drains have queued one behind the other
-        self._overlap: float | None = None
+        self._t_ret = _Recent()
+        self._gap = _Recent()
+        self._last_gap = 0.0
+        self._loop_n = 0
+        # requests aboard the drains in flight
+        self._aboard = 0
         # completions since a drain last ran alone / last queued behind
         # another, how many of them arm a probe now (_PROBE_EVERY and
         # its doubling), whether the one drain of a serial probe is out,
@@ -327,7 +371,8 @@ class TopNBatcher:
         qw = self.recent_queue_wait_ms()
         with self._cond:
             sizes = self.batch_sizes[-1000:]
-            depth, why = self._depth()
+            cycles = self._cycles()
+            depth, why = self._depth(cycles)
             return {
                 "dispatches": self.total_dispatches,
                 "queue_wait_ms": round(qw, 2),
@@ -336,8 +381,7 @@ class TopNBatcher:
                 "service_time_ms": round(self._exec_ewma * 1e3, 2),
                 "round_trip_floor_ms": round(self._wall_min * 1e3, 1)
                 if self._wall_min != float("inf") else None,
-                "overlap_share": None if self._overlap is None
-                else round(self._overlap, 3),
+                **self._verdict_note(cycles),
                 "in_flight": self._in_flight,
                 "in_flight_target": depth,
                 "depth_reason": why,
@@ -365,37 +409,105 @@ class TopNBatcher:
 
     # -- dispatcher ----------------------------------------------------------
 
-    def _serial(self) -> bool:
-        """Whether the device was seen to run drains one after another
-        with (next to) nothing for a second one in flight to hide."""
-        return self._overlap is not None and self._overlap < _SERIAL_OVERLAP
+    def _cycles(self) -> tuple[float, float] | None:
+        """The callers' cycle, one answer to the next, run both ways, in
+        seconds: (one behind another, in one shared pass); None until a
+        drain has queued behind another and one has run alone.  Read
+        from what is known NOW each time it is asked: a lone wall of
+        the loop's size that arrives after the last gap moves the
+        verdict at once.
 
-    def _depth(self) -> tuple[int, str]:
-        """How many dispatches to keep in flight, and why.  Called inside
+        *One behind another* is what a second program in flight does to
+        closed-loop callers out of step: the loop's N requests ride two
+        drains, the running program and the one bound behind it, each
+        caller's program runs after the other's, and a cycle is two
+        completion gaps.  Two, however deep the pipeline: the verdict
+        is about the first step away from one program at a time, and
+        how much deeper to go where that step pays is the formula's in
+        :meth:`_depth`.  (Counted over a deep pipeline the product is
+        the cycle by construction in a closed loop and means nothing in
+        an open one.)  *In one shared pass* is what a depth of one and
+        the hold do: the wall of a lone drain of about N requests, the
+        nearest size seen where that size has not run alone yet (a pass
+        is free to share until measured otherwise), plus the callers'
+        way back while the hold is on (an open loop has nobody coming
+        back).  Every reading is a :class:`_Recent`.  Plain float math:
+        :meth:`_depth` calls this inside the wait loops."""
+        gap = self._gap.mid
+        if gap is None or self._wall_min == float("inf"):
+            return None
+        back = self._t_ret.mid
+        if back is None or self._hit_share < _HIT_SHARE_ON:
+            back = 0.0
+        return 2.0 * gap, self._lone_wall(self._loop_n) + back
+
+    def _lone_wall(self, size: int) -> float:
+        """The wall of a lone drain of about ``size`` requests: of its
+        size class, or of the nearest class that has run alone (the
+        smaller of two as near); inf before any has."""
+        walls = self._lone_walls
+        c = min(len(walls) - 1, _size_class(size))
+        for step in range(len(walls)):
+            for near in (c - step, c + step):
+                if 0 <= near < len(walls) and walls[near].mid is not None:
+                    return walls[near].mid
+        return float("inf")
+
+    def _verdict_note(self, cycles: tuple[float, float] | None) -> dict:
+        """What the verdict was taken from, for ``stats()`` and the
+        ``serving.queue_wait`` span: the two cycles in ms (None while
+        unmeasured), the N they speak of, and, reported only, the
+        host's share of a lone drain's wall (1 - completion gap / least
+        lone wall)."""
+        if cycles is None:
+            return {"overlap_share": None, "cycle_behind_ms": None,
+                    "cycle_shared_ms": None, "cycle_n": self._loop_n}
+        return {"overlap_share": round(max(
+                    0.0, 1.0 - self._gap.mid / self._wall_min), 3),
+                "cycle_behind_ms": round(cycles[0] * 1e3, 3),
+                "cycle_shared_ms": round(cycles[1] * 1e3, 3),
+                "cycle_n": self._loop_n}
+
+    def _serial(self) -> bool:
+        """Whether one program at a time turns the callers round at
+        least as soon as two, one behind the other."""
+        cycles = self._cycles()
+        return cycles is not None and cycles[0] >= cycles[1]
+
+    def _depth(self, cycles: tuple[float, float] | None | bool = False
+               ) -> tuple[int, str]:
+        """How many dispatches to keep in flight, and why (``cycles``:
+        :meth:`_cycles` as the caller has just read it).  Called inside
         the dispatchers' wait loops — plain float math, no numpy scalars
         (they cost microseconds each).
 
-        Both measurements come for free only in part, so the hidden one
-        is taken by a probe, ``_probe_every`` completions after it was
-        last seen.  A pipeline that a closed loop keeps saturated never
-        runs a drain alone: the cap drops to one until the pipeline has
-        run dry and one drain has gone alone (``pipelined-probe``; costs
-        one round trip of throughput).  At a depth of one no drain
-        queues behind another: the next drain that finds a program
-        running is bound behind it (``serial-probe``), that ONE drain
-        and no other.  It costs the callers that come back while it runs
-        one program more each — with two callers one request, with n at
-        most n - 1 — and nothing until traffic splits by itself: callers
-        in step arrive while no program runs, and the probe stays armed.
+        The verdict is :meth:`_cycles`' comparison: one program at a
+        time (``serial``) where the callers' cycle in one shared pass is
+        no longer than their cycle one behind another, both of them
+        times the batcher clocked.  Its two sides come for free only in
+        part, so the hidden one is taken by a probe, ``_probe_every``
+        completions after it was last seen.  A pipeline that a closed
+        loop keeps saturated never runs a drain alone: the cap drops to
+        one until the pipeline has run dry and one drain has gone alone
+        (``pipelined-probe``; costs one round trip of throughput).  At a
+        depth of one no drain queues behind another: the next drain that
+        finds a program running is bound behind it (``serial-probe``),
+        that ONE drain and no other.  It costs the callers that come
+        back while it runs one program more each — with two callers one
+        request, with n at most n - 1 — and nothing until traffic splits
+        by itself: callers in step arrive while no program runs, and the
+        probe stays armed.
         """
-        if self._overlap is None:
+        if cycles is False:
+            cycles = self._cycles()
+        if cycles is None:
             # (no lone wall yet, or no queued pair yet)
             # two, so that the first requests that coincide queue one
             # behind the other and their completion gap is seen; no
             # deeper, or a device that turns out to be serial starts
             # with a queue of lone-request programs
             return min(len(self._threads), 2), "unmeasured"
-        if self._serial():
+        if cycles[0] >= cycles[1]:
             if self._since_gap >= self._probe_every \
                     and not self._probe_out and len(self._threads) > 1:
                 return 2, "serial-probe"
@@ -490,10 +602,15 @@ class TopNBatcher:
         the drain's ``serving.queue_wait`` spans say of the batcher's
         state: the verdict it leaves under (``in_flight``: the drains
         dispatched and not completed, 0 for a lone drain, from 1 on a
-        drain bound BEHIND a running program) and what the verdict was
-        taken from (``overlap_share``, None while unmeasured, and
-        ``service_ms``, the S of the hold's rule)."""
-        depth, why = self._depth()
+        drain bound BEHIND a running program), what the verdict was
+        taken from (:meth:`_verdict_note`) and ``service_ms``, the S of
+        the hold's rule."""
+        if self._hold_t0 is not None:
+            # the callers' way back, as the hold clocked it: from the
+            # completion that released them to the held drain's leaving
+            self._t_ret.add(max(0.0, now - self._last_completion))
+        cycles = self._cycles()
+        depth, why = self._depth(cycles)
         if why == "serial-probe" and self._in_flight:
             # this is the probe's one drain: whoever comes next waits
             # for a free device again
@@ -518,8 +635,7 @@ class TopNBatcher:
         self.return_left_behind += left_behind
         return {"depth": depth, "depth_reason": why,
                 "in_flight": self._in_flight,
-                "overlap_share": None if self._overlap is None
-                else round(self._overlap, 3),
+                **self._verdict_note(cycles),
                 "service_ms": round(self._exec_ewma * 1e3, 3),
                 "held_ms": round(held * 1e3, 3),
                 "renewals": renewals, "left_behind": left_behind,
@@ -568,11 +684,16 @@ class TopNBatcher:
                     lone = self._in_flight == 0
                     probe = self._probe_out  # set by this bind, or not
                     self._in_flight += 1
+                    # the loop as this drain's gap will speak of it: the
+                    # requests aboard the drains in flight, its own too
+                    self._aboard += len(jobs)
+                    aboard = self._aboard
             scored = self._dispatch(jobs, note) if jobs else 0
             if stopped:
                 return
             with self._releasing(), self._cond:
                 self._in_flight -= 1
+                self._aboard -= len(jobs)
                 if probe:
                     self._probe_out = False
                 # a drain whose every job was deadline-shed made no
@@ -580,7 +701,7 @@ class TopNBatcher:
                 # estimators would collapse them long after the
                 # deadline burst ends
                 if scored:
-                    self._learn_locked(t0, lone)
+                    self._learn_locked(t0, lone, scored, aboard)
                 # this thread goes round and takes the next drain
                 # itself if one can leave.  It wakes another only for
                 # what it cannot do: end the wait of the thread that
@@ -593,20 +714,24 @@ class TopNBatcher:
                         and self._in_flight + 1 < self._in_flight_target()):
                     self._wake_locked(1)
 
-    def _learn_locked(self, t0: float, lone: bool) -> None:
-        """A drain dispatched at ``t0`` (``lone``: with nothing in
-        flight) has completed: what its wall and the gap since the last
-        completion say of the device."""
+    def _learn_locked(self, t0: float, lone: bool, size: int,
+                      aboard: int) -> None:
+        """A drain of ``size`` requests dispatched at ``t0`` (``lone``:
+        with nothing in flight; else ``aboard`` requests rode it and the
+        drains in flight before it) has completed: what its wall and
+        the gap since the last completion say of the device."""
         now = clockmod.monotonic()
         wall = now - t0
+        was_serial = self._serial()
         self._since_lone += 1
         self._since_gap += 1
         self._since_hold += 1
         if lone:
             self._probe_taken(self._since_lone)
-            # decay toward recent walls so a transient stall (compile,
-            # GC) cannot pin the round-trip estimate
-            self._wall_min = min(self._wall_min * 1.02, wall)
+            # by the drain's size (a drain holds max_batch at most)
+            self._lone_walls[_size_class(size)].add(wall)
+            self._wall_min = min(w.mid for w in self._lone_walls
+                                 if w.mid is not None)
             self._since_lone = 0
         gap = now - self._last_completion
         if t0 < self._last_completion and gap < _MAX_EXEC_S:
@@ -616,19 +741,24 @@ class TopNBatcher:
             # service time with its queue kept fed
             self._probe_taken(self._since_gap)
             self._learn_exec(gap)
-            if self._wall_min != float("inf"):
-                was_serial = self._serial()
-                hidden = max(0.0, 1.0 - gap / self._wall_min)
-                self._overlap = hidden if self._overlap is None \
-                    else 0.7 * self._overlap + 0.3 * hidden
-                if self._serial() != was_serial:
-                    # the answer changed: look again soon
-                    self._probe_every = _PROBE_EVERY
+            # for the verdict, half the time two drains took one behind
+            # the other: the mean of this gap and the one before where
+            # the last completion brought one too (callers out of step
+            # on a device they do not saturate complete in turns of a
+            # short gap and a long one), else this gap
+            self._gap.add(0.5 * (gap + self._last_gap)
+                          if self._since_gap == 1 and self._last_gap
+                          else gap)
+            self._last_gap = gap
+            self._loop_n = aboard
             self._since_gap = 0
         elif lone and (self._serial() or len(self._threads) == 1):
             # at a depth of one no completion gap is ever seen; a lone
             # drain's wall IS the service time there
             self._learn_exec(wall)
+        if self._serial() != was_serial:
+            # the answer changed: look again soon
+            self._probe_every = _PROBE_EVERY
         # a dispatch's whole wall (round trip + exec) upper-bounds exec:
         # clamping lets the estimate relearn DOWNWARD after a hot-swap
         # to a smaller model or an anomalous gap
@@ -640,7 +770,7 @@ class TopNBatcher:
         completions after it was last seen.  Where that is more than the
         count that arms a probe, the depth in force was hiding it and a
         probe took it: ask again after twice as many."""
-        if since > self._probe_every and self._overlap is not None:
+        if since > self._probe_every and self._cycles() is not None:
             self.probes += 1
             self._probe_every = min(_PROBE_EVERY_MAX,
                                     2 * self._probe_every)

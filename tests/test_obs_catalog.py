@@ -138,6 +138,43 @@ def test_the_drain_steps_are_walked_and_catalogued(name, source_names,
     assert name in catalog
 
 
+@pytest.mark.parametrize("surface", ["queue_wait", "scoring_batcher"])
+def test_the_batchers_verdict_is_catalogued_key_by_key(surface):
+    """PR 40: what the batcher says of its verdict, on every drain's
+    ``serving.queue_wait`` span and in ``/metrics``' ``scoring_batcher``
+    block, is catalogued attribute by attribute: each key the code
+    emits is backticked in the span's row, or in the first cell of a
+    row of the block's table."""
+    import time
+
+    from oryx_tpu.serving.batcher import TopNBatcher
+
+    batcher = TopNBatcher(pipeline=1)
+    try:
+        with batcher._cond:
+            note = batcher._bind_locked(time.monotonic())
+        stats = batcher.stats()
+    finally:
+        batcher.close()
+    assert {"cycle_behind_ms", "cycle_shared_ms", "cycle_n",
+            "overlap_share"} <= set(note) & set(stats)
+    rows = [line for line in DOC.read_text(encoding="utf-8").splitlines()
+            if line.startswith("|")]
+    if surface == "queue_wait":
+        keys = note
+        # (the span table's row: the anatomy table names the span too)
+        row = next(r for r in rows
+                   if r.split("|")[1].strip() == "`serving.queue_wait`")
+        documented = set(re.findall(r"`([a-z_]+)`", row))
+    else:
+        keys = stats
+        documented = {name for r in rows
+                      for name in re.findall(r"`([a-z_]+)`",
+                                             r.split("|")[1])}
+    assert not sorted(set(keys) - documented), sorted(
+        set(keys) - documented)
+
+
 def test_every_source_name_is_catalogued(source_names, catalog):
     missing = [
         f"{kind} {name!r} ({', '.join(sites)})"
